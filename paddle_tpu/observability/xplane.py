@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import atexit
 import os
+import tempfile
 
 from . import metrics, recorder, spans
 
@@ -67,11 +68,14 @@ def arm(steps: int | None = None, xdir: str | None = None,
     a window is already active or armed, or after the profiler proved
     broken — a trigger storm must collapse to one capture, not a pile-up.
     `xdir` defaults to $PADDLE_XPLANE_DIR, else <PADDLE_TRACE_DIR>/xplane,
-    else ./xplane."""
+    else <system temp dir>/paddle_xplane — never the current directory: an
+    unasked dump must not land in a checkout."""
     if _state["active"] or _state["armed"] is not None or _state["broken"]:
         return False
-    xdir = xdir or os.environ.get(ENV_DIR) or os.path.join(
-        os.environ.get("PADDLE_TRACE_DIR") or ".", "xplane")
+    trace_dir = os.environ.get("PADDLE_TRACE_DIR")
+    xdir = (xdir or os.environ.get(ENV_DIR)
+            or (os.path.join(trace_dir, "xplane") if trace_dir
+                else os.path.join(tempfile.gettempdir(), "paddle_xplane")))
     n = max(1, _env_int(ENV_STEPS, 2) if steps is None else int(steps))
     _state["armed"] = {"steps": n, "dir": xdir, "reason": reason}
     metrics.counter("xplane.arms").inc()
