@@ -1,0 +1,396 @@
+"""chip_smoke.py — the quickest proof that paddle_tpu still starts on the chip.
+
+    python chip_smoke.py              one TPU chip: phase `train`, then `serve`
+    python chip_smoke.py --chips 4    four chips: the mesh train step and the
+                                      single-device run it is compared with
+    python chip_smoke.py --rehearse   tiny sizes on whatever backend JAX has;
+                                      never passes off the TPU
+
+Both phases run Llama-2-7B at its published widths (hidden 4096, FFN 11008,
+32 heads x 128, vocab 32000, bf16) with random weights made from --seed.
+Only depth is cut, to what one 16 GB chip holds; every layer is the same
+kind, so any depth is a whole period.
+
+One process, JAX imported once, no child that needs the chip. Each phase
+prints one JSON object; the last line of stdout is
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+when every phase passed on a TPU, and {"ok": false, ...} with a non-zero
+exit code otherwise. Step and request times on the phase lines are
+information seen during bring-up, not benchmark results.
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import os
+import sys
+import tempfile
+import time
+import traceback
+
+MODEL = "Llama-2-7B (LlamaConfig.llama2_7b)"
+TRAIN_LAYERS, TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS = 4, 2, 2048, 16
+SERVE_LAYERS, SERVE_F32_LAYERS = 8, 2
+SERVE_MAX_BATCH, SERVE_MAX_LEN, SERVE_PROMPT_BUCKETS = 8, 1024, (128, 256)
+SERVE_POOL_BYTES = 8 << 30
+# (prompt length, new tokens): ten requests over both prompt buckets, more
+# than max_batch so that the queue, admission and slot reuse are on the path
+SERVE_REQUESTS = ((40, 16), (100, 24), (120, 8), (128, 16), (130, 24),
+                  (200, 8), (250, 16), (256, 24), (77, 8), (180, 16))
+MESH_AXES, MESH_SHAPE = ("dp", "tp"), (2, 2)
+MESH_REL_TOL = 2e-2     # the tolerance __graft_entry__ holds virtual meshes to
+
+
+def emit(obj) -> None:
+    print(json.dumps(obj), flush=True)
+
+
+def device_info(jax) -> dict:
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def memory(dev) -> dict:
+    stats = dev.memory_stats() or {}    # the CPU backend reports none
+    return {k: stats.get(k) for k in ("bytes_in_use", "peak_bytes_in_use")}
+
+
+def free_device_memory(jax) -> None:
+    gc.collect()
+    jax.clear_caches()
+
+
+def width_summary(cfg) -> dict:
+    import jax.numpy as jnp
+    return {"model": MODEL, "hidden": cfg.hidden_size,
+            "ffn": cfg.intermediate_size, "heads": cfg.num_attention_heads,
+            "kv_heads": cfg.num_key_value_heads, "head_dim": cfg.head_dim,
+            "vocab": cfg.vocab_size, "layers": cfg.num_hidden_layers,
+            "dtype": jnp.dtype(cfg.dtype).name}
+
+
+def custom_calls(lowered) -> int:
+    """Pallas kernels in a lowered program: each is one tpu_custom_call."""
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def model_config(rehearse: bool, layers: int, **kw):
+    from paddle_tpu.models import LlamaConfig
+    if rehearse:
+        return LlamaConfig.tiny(num_hidden_layers=2,
+                                max_position_embeddings=2048, **kw)
+    return LlamaConfig.llama2_7b(num_hidden_layers=layers, **kw)
+
+
+# ------------------------------------------------------------------ train
+
+def make_batches(cfg, batch, seq, steps, seed, workdir):
+    """Fresh batches through the input pipeline: a seeded Zipf-Markov
+    corpus on disk, cut by TokenDataLoader (native feeder when it builds)."""
+    from paddle_tpu.io.token_loader import (TokenDataLoader, synthetic_corpus,
+                                            write_token_file)
+    path = os.path.join(workdir, "corpus.u16")
+    n_tokens = max(8 * steps * batch * (seq + 1), 65536)
+    write_token_file(path, synthetic_corpus(n_tokens, cfg.vocab_size, seed))
+    loader = TokenDataLoader(path, batch, seq, seed=seed)
+    try:
+        return [next(loader) for _ in range(steps)], loader._native
+    finally:
+        loader.close()
+
+
+def run_trainer(jax, cfg, mesh, batches, seed, on_tpu):
+    """A few steps of LlamaTrainStep; returns (losses, facts, trainer)."""
+    import jax.numpy as jnp
+    from paddle_tpu.models import LlamaTrainStep
+    from paddle_tpu.optimizer import AdamW
+
+    t0 = time.perf_counter()
+    step = LlamaTrainStep(
+        cfg, mesh=mesh, remat=True, seed=seed,
+        optimizer=AdamW(learning_rate=3e-4, weight_decay=0.1,
+                        moment_dtype=jnp.bfloat16))
+    jax.block_until_ready(step.params)
+    init_s = time.perf_counter() - t0
+
+    tok0 = jnp.asarray(batches[0][0], jnp.int32)
+    sh = step.data_sharding(2)
+    if sh is not None:
+        tok0 = jax.device_put(tok0, sh)
+    n_kernels = custom_calls(step._jitted.lower(
+        step._params, step._opt_state, tok0, tok0, jnp.float32(3e-4),
+        jnp.int32(1)))
+    if on_tpu and n_kernels < 3:
+        raise AssertionError(
+            f"the Pallas flash kernel is not in the train step: "
+            f"{n_kernels} tpu_custom_call (forward + two backward expected)")
+
+    losses, times = [], []
+    for tokens, labels in batches:
+        t0 = time.perf_counter()
+        losses.append(float(jax.block_until_ready(step(tokens, labels))))
+        times.append(round(time.perf_counter() - t0, 4))
+    if not all(l == l and abs(l) != float("inf") for l in losses):
+        raise AssertionError(f"non-finite losses {losses}")
+    # fresh batches make single steps noisy (a window that overlaps an
+    # earlier one is half memorized): compare the ends of the run
+    k = max(1, len(losses) // 4)
+    if not sum(losses[-k:]) < sum(losses[:k]):
+        raise AssertionError(f"losses did not fall: {losses}")
+    facts = {"init_s": round(init_s, 2), "first_step_s_with_compile": times[0],
+             "step_s": times[1:], "losses": [round(l, 4) for l in losses],
+             "flash_tpu_custom_calls": n_kernels}
+    return losses, facts, step
+
+
+def phase_train(jax, args, dev) -> dict:
+    cfg = model_config(args.rehearse, TRAIN_LAYERS)
+    batch, seq, steps = ((2, 128, 4) if args.rehearse
+                         else (TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS))
+    with tempfile.TemporaryDirectory() as tmp:
+        batches, native = make_batches(cfg, batch, seq, steps, args.seed, tmp)
+    _, facts, step = run_trainer(jax, cfg, None, batches, args.seed,
+                                 dev["platform"] == "tpu")
+    del step
+    return {"config": {**width_summary(cfg), "batch": batch, "seq": seq,
+                       "steps": steps, "optimizer": "AdamW bf16 moments",
+                       "remat": True},
+            "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: bf16 "
+                        f"params + grads + two bf16 AdamW moments + remat "
+                        f"activations at B={batch} T={seq} fit one 16 GB chip"],
+            "native_feeder": bool(native), **facts}
+
+
+# ------------------------------------------------------------------ serve
+
+def make_requests(cfg, seed):
+    import numpy as np
+    rng = np.random.RandomState(seed)
+    return [(rng.randint(1, cfg.vocab_size, n).astype(np.int32).tolist(), m)
+            for n, m in SERVE_REQUESTS]
+
+
+def serve_and_compare(jax, cfg, params, requests, on_tpu, **engine_kw):
+    """Serve `requests` through ContinuousBatcher, then run each through
+    llama_generate on the same device. Returns the facts and whether every
+    greedy token agreed."""
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.models.llama_decode import llama_generate
+    from paddle_tpu.models.llama_paged import llama_paged_prefill_slot
+    from paddle_tpu.observability import metrics
+
+    eng = ContinuousBatcher(cfg, params, max_batch=SERVE_MAX_BATCH,
+                            max_len=SERVE_MAX_LEN,
+                            prompt_buckets=SERVE_PROMPT_BUCKETS, burst=8,
+                            **engine_kw)
+    ps = eng.page_size
+    bucket = eng._buckets[0]
+    n_kernels = custom_calls(llama_paged_prefill_slot.lower(
+        params, eng._cache, jnp.zeros(bucket, jnp.int32),
+        jnp.zeros(-(-bucket // ps), jnp.int32), jnp.int32(1),
+        jax.random.PRNGKey(0), config=cfg, temperature=0.0, top_k=0,
+        dequant=None, kv_dtype=None))
+    if on_tpu and n_kernels < 1:
+        raise AssertionError("the Pallas flash kernel is not in the "
+                             "bucketed-prefill program")
+
+    t0 = time.perf_counter()
+    rids = [eng.add_request(p, max_new_tokens=m) for p, m in requests]
+    out = eng.run()
+    serve_s = time.perf_counter() - t0
+    for rid, (_, m) in zip(rids, requests):
+        if len(out[rid]) != m:
+            raise AssertionError(f"request {rid} asked for {m} tokens, got "
+                                 f"{len(out[rid])}")
+    gauge = metrics.snapshot()["gauges"].get("serve.pages_in_use")
+    if eng.pages_in_use != 0 or gauge != 0:
+        raise AssertionError(f"pages leaked after the drain: allocator "
+                             f"{eng.pages_in_use}, serve.pages_in_use {gauge}")
+    stats = {k: eng.stats[k] for k in ("bursts", "decode_steps", "prefills",
+                                       "max_concurrent", "page_buckets_used")}
+    num_pages = eng._alloc.num_pages
+    del eng
+    free_device_memory(jax)
+
+    t0 = time.perf_counter()
+    agree = total = 0
+    for rid, (p, m) in zip(rids, requests):
+        ref = np.asarray(llama_generate(params, jnp.asarray([p], jnp.int32),
+                                        cfg, m, temperature=0.0))[0]
+        agree += int(np.sum(ref == np.asarray(out[rid])))
+        total += m
+    return {"requests": len(requests), "tokens": total,
+            "prompt_lens": [len(p) for p, _ in requests],
+            "prompt_buckets": list(SERVE_PROMPT_BUCKETS),
+            "max_batch": SERVE_MAX_BATCH, "max_len": SERVE_MAX_LEN,
+            "page_size": ps,
+            "num_pages": num_pages, "serve_s_with_compile": round(serve_s, 2),
+            "reference_s_with_compile": round(time.perf_counter() - t0, 2),
+            "flash_tpu_custom_calls_in_prefill": n_kernels,
+            "tokens_equal_llama_generate": f"{agree}/{total}",
+            **stats}, agree == total
+
+
+def phase_serve(jax, args, dev) -> dict:
+    import jax.numpy as jnp
+    from paddle_tpu.models.llama import llama_init_params
+
+    on_tpu = dev["platform"] == "tpu"
+    cfg = model_config(args.rehearse, SERVE_LAYERS,
+                       **({"dtype": jnp.bfloat16} if args.rehearse else {}))
+    requests = make_requests(cfg, args.seed)
+    params = llama_init_params(cfg, jax.random.PRNGKey(args.seed))
+    pool = {} if args.rehearse else {"pool_hbm_bytes": SERVE_POOL_BYTES}
+    facts, equal = serve_and_compare(jax, cfg, params, requests, on_tpu,
+                                     **pool)
+    result = {"config": {**width_summary(cfg), "kv_layout": "paged"},
+              "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: bf16 "
+                          f"weights plus an {SERVE_POOL_BYTES >> 30} GiB KV "
+                          f"page pool fit one 16 GB chip"],
+              "bf16": facts, "compared_in": "bfloat16"}
+    if not equal or args.rehearse:      # a rehearsal walks both passes
+        # bf16 near-ties between random-weight logits flip a greedy argmax
+        # between two correct programs; the equality tier-1 pins on the CPU
+        # is then decided in float32 at a depth that fits
+        del params
+        free_device_memory(jax)
+        cfg32 = model_config(args.rehearse, SERVE_F32_LAYERS,
+                             dtype=jnp.float32)
+        params32 = llama_init_params(cfg32, jax.random.PRNGKey(args.seed))
+        with jax.default_matmul_precision("highest"):
+            facts32, equal32 = serve_and_compare(jax, cfg32, params32,
+                                                 requests, on_tpu)
+        result.update(float32=facts32, compared_in="float32",
+                      float32_config=width_summary(cfg32))
+        result["reduced"].append(
+            f"token equality with llama_generate decided in float32 at "
+            f"{cfg32.num_hidden_layers} layers (bf16 agreed on "
+            f"{facts['tokens_equal_llama_generate']} tokens)")
+        if not equal32:
+            raise AssertionError(
+                f"float32 greedy tokens differ from llama_generate: "
+                f"{facts32['tokens_equal_llama_generate']}")
+    return result
+
+
+# ------------------------------------------------------------- four chips
+
+def phase_mesh_train(jax, args, dev) -> dict:
+    """The sharded train step on four devices against the single-device
+    run of the same model on the same batches."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from paddle_tpu.distributed.process_mesh import ProcessMesh
+
+    on_tpu = dev["platform"] == "tpu"
+    devices = jax.devices()
+    if len(devices) < 4:
+        raise AssertionError(f"--chips 4 needs four devices, JAX has "
+                             f"{len(devices)}")
+    cfg = model_config(args.rehearse, TRAIN_LAYERS)
+    batch, seq, steps = ((2, 128, 4) if args.rehearse
+                         else (TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS))
+    with tempfile.TemporaryDirectory() as tmp:
+        batches, native = make_batches(cfg, batch, seq, steps, args.seed, tmp)
+
+    single, facts1, step = run_trainer(jax, cfg, None, batches, args.seed,
+                                       on_tpu)
+    del step
+    free_device_memory(jax)
+
+    mesh = ProcessMesh(Mesh(np.asarray(devices[:4]).reshape(MESH_SHAPE),
+                            MESH_AXES))
+    sharded, facts4, step = run_trainer(jax, cfg, mesh, batches, args.seed,
+                                        on_tpu)
+    # the parameters must really span the four devices
+    spans = {}
+    for name in ("wq", "wo", "w_gate", "w_down"):     # sharded on dp AND tp
+        p = step.params[name]
+        shards = p.addressable_shards
+        spans[name] = {"spec": str(p.sharding.spec),
+                       "devices": len({s.device for s in shards}),
+                       "shard_shape": list(shards[0].data.shape),
+                       "shape": list(p.shape)}
+        if spans[name]["devices"] != 4 or \
+                4 * shards[0].data.size != p.size:
+            raise AssertionError(f"{name} does not span four devices: "
+                                 f"{spans[name]}")
+    per_device = [memory(d)["bytes_in_use"] for d in devices[:4]]
+    if on_tpu and not all(b and b > 0 for b in per_device):
+        raise AssertionError(f"a device holds nothing: {per_device}")
+    del step
+
+    rel = np.abs(np.asarray(sharded) - np.asarray(single)) \
+        / np.maximum(np.abs(np.asarray(single)), 1e-6)
+    if not rel.max() < MESH_REL_TOL:
+        raise AssertionError(f"mesh trajectory left the single-device one: "
+                             f"rel {rel.tolist()} > {MESH_REL_TOL}")
+    return {"config": {**width_summary(cfg), "batch": batch, "seq": seq,
+                       "steps": steps, "mesh": dict(zip(MESH_AXES, MESH_SHAPE))},
+            "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: the "
+                        f"single-device run it is compared with must fit one "
+                        f"16 GB chip"],
+            "native_feeder": bool(native), "single": facts1, "mesh": facts4,
+            "max_rel_diff": float(rel.max()), "rel_tolerance": MESH_REL_TOL,
+            "param_spans": spans, "bytes_in_use_per_device": per_device}
+
+
+# ------------------------------------------------------------------- main
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes on any backend; never prints the "
+                         "passing line off the TPU")
+    args = ap.parse_args(argv)
+
+    dev, failed = None, None
+    try:
+        import jax
+
+        from paddle_tpu.utils.compile_cache import enable_compile_cache
+        cache_dir = enable_compile_cache()
+        dev = device_info(jax)
+        emit({"phase": "start", **dev, "chips": args.chips,
+              "rehearse": args.rehearse, "seed": args.seed,
+              "compile_cache": cache_dir, "jax": jax.__version__})
+        if not args.rehearse:
+            if dev["platform"] != "tpu":
+                raise RuntimeError(f"no TPU: JAX found platform "
+                                   f"{dev['platform']!r}")
+            if dev["count"] != args.chips:
+                raise RuntimeError(f"--chips {args.chips} but JAX has "
+                                   f"{dev['count']} devices")
+        phases = ((("mesh_train", phase_mesh_train),) if args.chips == 4
+                  else (("train", phase_train), ("serve", phase_serve)))
+        for name, fn in phases:
+            t0 = time.perf_counter()
+            try:
+                facts = fn(jax, args, dev)
+            except Exception:
+                failed = name
+                raise
+            emit({"phase": name, "ok": True, **dev, **facts,
+                  "phase_s": round(time.perf_counter() - t0, 2),
+                  **memory(jax.devices()[0])})
+            free_device_memory(jax)
+        if dev["platform"] != "tpu":
+            raise RuntimeError("rehearsal: every phase ran, but not on a TPU")
+    except Exception as e:
+        traceback.print_exc()
+        sys.stderr.flush()
+        emit({"ok": False, "device": dev, "failed_phase": failed,
+              "error": f"{type(e).__name__}: {e}"[:2000]})
+        return 1
+    emit({"ok": True, "device": dev})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

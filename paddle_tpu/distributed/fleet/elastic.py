@@ -591,7 +591,7 @@ class KVRegistry:
     """Client of a KVServer: heartbeat + membership over HTTP.
 
     Every PUT/GET routes through resilience.retry — one dropped HTTP
-    request (tunnel flap, master GC pause) retries with jittered backoff
+    request (connection reset, master GC pause) retries with jittered backoff
     instead of surfacing as a dead node / empty membership."""
 
     def __init__(self, endpoint: str, ttl: float = 10.0, timeout: float = 3.0,

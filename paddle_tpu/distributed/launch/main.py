@@ -152,9 +152,16 @@ def _spawn(args, local_rank: int, world: int, base_rank: int, nnodes: int,
         if port:
             env.setdefault("MASTER_PORT", port)
     if args.nproc_per_node > 1:
-        # CPU simulation: give each rank its own virtual device set
+        # CPU simulation: give each rank its own virtual device set. A chip
+        # belongs to one process at a time, so several local ranks cannot
+        # share one — say where they went, once per launch
         env.setdefault("JAX_PLATFORMS", "cpu")
         env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
+        if local_rank == 0:
+            print(f"[launch] --nproc_per_node {args.nproc_per_node} > 1: "
+                  f"local ranks run on JAX_PLATFORMS={env['JAX_PLATFORMS']} "
+                  f"(one process per chip; use --nproc_per_node 1 on a TPU "
+                  f"host)", file=sys.stderr)
 
     stdout = stderr = None
     if args.log_dir:

@@ -10,7 +10,7 @@ below) with the full robustness contract:
     timeouts) restore the last VALID checkpoint and replay — because the
     step program is deterministic given (state, batch), the recovered
     trajectory is bitwise identical to a fault-free run (the contract
-    MULTICHIP_r05.json proved: resume_max_rel == 0.0);
+    tests/test_resilience.py pins: resume_max_rel == 0.0);
   * SIGTERM/SIGINT latches an emergency save + ``PREEMPTED.json`` marker at
     the next step boundary, and a relaunch resumes step-exact. The
     emergency save is ASYNC: the marker (naming the last known-good

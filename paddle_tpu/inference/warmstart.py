@@ -65,11 +65,24 @@ def enable_jit_cache(cache_dir: str):
     """Point jax's persistent compilation cache at ``cache_dir`` with
     thresholds at zero — the serving executables are small on CPU CI,
     and a warm start that silently skipped caching them would measure
-    cold. Idempotent; safe before any trace."""
+    cold. Idempotent; safe before any trace. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the directory stays the
+    variable's (one logged line); only the thresholds are applied."""
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    from ..utils.compile_cache import ENV_CACHE_DIR
+    placed = os.environ.get(ENV_CACHE_DIR)
+    if placed:
+        # the cache was placed from outside: JAX reads the variable
+        # itself, and a second path set here would split the cache
+        _recorder.record("warmstart.cache_dir_yielded", echo=True,
+                         message=f"[warmstart] {ENV_CACHE_DIR}={placed} is "
+                                 f"set — not re-pointing the jit cache at "
+                                 f"{cache_dir}",
+                         env_dir=placed, cache_dir=cache_dir)
+    else:
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     # the GPU-only XLA side caches (kernel cache, fusion autotuner) get

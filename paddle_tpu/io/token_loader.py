@@ -10,6 +10,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
@@ -25,16 +26,17 @@ def _load_lib():
     with _lib_lock:
         if _lib[0] is not None:
             return _lib[0]
-        if not os.path.exists(_SO_PATH):
-            try:
+        try:
+            if not os.path.exists(_SO_PATH):
                 subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
                                capture_output=True)
-            except Exception:
-                _lib[0] = False
-                return False
-        try:
             lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
+        except (OSError, subprocess.CalledProcessError) as e:
+            # said once per process: the numpy path is correct but slower,
+            # and an input pipeline that changed must not change in silence
+            warnings.warn(f"native data feeder unavailable ({e}); "
+                          f"TokenDataLoader uses its numpy implementation",
+                          RuntimeWarning, stacklevel=3)
             _lib[0] = False
             return False
         lib.ptdf_open.restype = ctypes.c_void_p

@@ -262,14 +262,23 @@ def _expand_gqa(k, v, config):
     return k, v
 
 
-def _attention(q, k, v, config, use_flash=True):
-    """q:[B,T,H,hd] k,v:[B,T,KV,hd] causal."""
+def _attention(q, k, v, config, use_flash=True, mesh=None):
+    """q:[B,T,H,hd] k,v:[B,T,KV,hd] causal. `mesh` (a jax Mesh): the
+    program is GSPMD-partitioned over it, so the flash kernel runs per
+    shard of (batch, heads) — see flash_attention_raw."""
     k, v = _expand_gqa(k, v, config)
     if use_flash:
-        # Pallas kernel on TPU, XLA reference otherwise — the fallback
-        # predicate lives in flash_attention_raw, not here
+        # Pallas kernel on TPU, XLA reference otherwise — the predicate
+        # lives in flash_attention_raw, not here
         from ..ops.flash_attention import flash_attention_raw
-        return flash_attention_raw(q, k, v, causal=True)
+        spec = None
+        if mesh is not None:
+            # drop an axis the batch or head count does not divide: that
+            # dim is then replicated into the kernel instead of failing
+            spec = P(*(a if a is None or n % mesh.shape[a] == 0 else None
+                       for a, n in zip(_act_spec(set(mesh.axis_names),
+                                                 "bthd"), q.shape)))
+        return flash_attention_raw(q, k, v, causal=True, mesh=mesh, spec=spec)
     scale = 1.0 / math.sqrt(config.head_dim)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     T, S_ = logits.shape[-2], logits.shape[-1]
@@ -340,7 +349,7 @@ def _decoder_layer(x, lp, config, mesh, positions):
         att = ring_attention_sharded(q, k, v, mesh, "sep", causal=True)
     else:
         q = cst(q, "bthd")  # heads on tp (attention region: seq gathered)
-        att = _attention(q, k, v, c)
+        att = _attention(q, k, v, c, mesh=mesh)
     # named residual hook for save_only_these_names remat experiments; the
     # default policy (dots_saveable, see remat_policy) does NOT save it —
     # saving measured slower on v5e than recomputing the flash kernel
@@ -490,7 +499,8 @@ def llama_loss(params, tokens, labels, config: LlamaConfig, mesh=None, remat=Tru
     """loss_chunk: sequence-chunk size for the cross-entropy (None = dense
     [B,T,V] logits). Chunking trades a second lm-head matmul in the backward
     for ~2 GB of logits HBM — measured neutral at B=4 but it is what lets
-    B=8 fit under the dots_saveable remat policy (benchmarks/ROUND3_PERF.md)."""
+    B=8 fit under the dots_saveable remat policy (an earlier builder's note,
+    not re-measured on the current chip)."""
     if loss_chunk:
         layer_p, other = split_layer_params(params)
         x = jnp.take(other["embed_tokens"], tokens, axis=0).astype(config.dtype)

@@ -777,7 +777,7 @@ def llama_ragged_burst(params, cache, block_table, pos, tok, done, limit,
                        new_tokens, new_lens, prefill_start, eos_id, key,
                        config: LlamaConfig, n: int, has_prefill: bool,
                        temperature: float = 0.0, top_k: int = 0,
-                       pad_id: int = 0, dequant=None, interpret: bool = True,
+                       pad_id: int = 0, dequant=None, *, interpret: bool,
                        mesh=None, kv_dtype: str | None = None):
     """ONE executable for a mixed prefill+decode burst (ISSUE 8).
 
@@ -882,8 +882,8 @@ def _verify_attention(q, kc, vc, start, config: LlamaConfig):
     "config", "ragged", "interpret", "mesh", "dequant", "kv_dtype"),
     donate_argnums=(1,))
 def llama_paged_verify(params, cache, block_table, start, tokens, n_tok,
-                       config: LlamaConfig, ragged: bool = False,
-                       interpret: bool = True, mesh=None, dequant=None,
+                       config: LlamaConfig, ragged: bool = False, *,
+                       interpret: bool, mesh=None, dequant=None,
                        kv_dtype: str | None = None):
     """ONE launch verifying every slot's speculative segment (ISSUE 14).
 

@@ -6,9 +6,9 @@ VMEM blocks (q-block × kv-block grid), bf16 in / fp32 accumulate on the MXU,
 with a custom_vjp whose backward recomputes attention blockwise
 (flash-attention-2 style).
 
-The jnp fallback (used off-TPU and for tiny shapes) is in
-nn.functional.scaled_dot_product_attention; this module exports
-`flash_attention(q, k, v, causal=...)` on [B, L, H, D] Tensors.
+`flash_attention_raw` is the entry for model code and says when the kernel
+runs; `flash_attention(q, k, v, causal=...)` is the same on [B, L, H, D]
+Tensors.
 """
 from __future__ import annotations
 
@@ -76,21 +76,41 @@ def _fa_reference(q, k, v, causal):
 
 
 def flash_attention_raw(q, k, v, causal: bool = False, block_q: int = 512,
-                        block_k: int = 512):
+                        block_k: int = 512, mesh=None, spec=None):
     """Raw-jnp-array flash attention ([B, L, H, D] in/out) — the shared entry
-    for the Tensor API and model code. Falls back to the XLA path for
-    small/ragged sequence lengths or off-TPU.
+    for the Tensor API and model code.
+
+    The Pallas kernel runs when the platform is TPU and both sequence
+    lengths are multiples of 128 (its minimum tile); any other shape, and
+    every off-TPU call, takes the XLA reference `_fa_reference`. The shape
+    rule is a property of the kernel, not a safety net: chip_smoke.py and
+    tests/test_tpu_compile.py prove the main path's shapes take the kernel.
+
+    mesh/spec: inside a GSPMD-partitioned program (the sharded train step)
+    the kernel is wrapped in a `shard_map` over `mesh` with `spec` on q, k,
+    v and the output — Mosaic kernels cannot be partitioned automatically,
+    and attention is independent per (batch row, head), so each shard runs
+    the same kernel on its own rows and heads with no collective.
 
     FLAGS_flash_block_q / FLAGS_flash_block_k (env or set_flags) override
-    the tile sizes globally — the tuning knob benchmarks/r4 sweeps use; 0
-    keeps the caller's value."""
+    the tile sizes globally; 0 keeps the caller's value."""
     from ..utils.flags import flag_value
     block_q = int(flag_value("flash_block_q") or block_q)
     block_k = int(flag_value("flash_block_k") or block_k)
-    L, S, D = q.shape[1], k.shape[1], q.shape[-1]
+    L, S = q.shape[1], k.shape[1]
     if (L % _MIN_BLOCK) or (S % _MIN_BLOCK) or not flash_attention_tpu_available():
         return _fa_reference(q, k, v, causal)
-    bq, bk = _fit_block(block_q, L), _fit_block(block_k, S)
+    kernel = functools.partial(_flash_kernel, causal=causal,
+                               bq=_fit_block(block_q, L),
+                               bk=_fit_block(block_k, S))
+    if mesh is not None:
+        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                               out_specs=spec, check_vma=False)
+    return kernel(q, k, v)
+
+
+def _flash_kernel(q, k, v, *, causal, bq, bk):
+    D = q.shape[-1]
     if D % 128 == 0:
         return _flash_fwd_bwd(q, k, v, causal, bq, bk)
     # head_dim 64 (GPT-2 / tiny-llama class): zero-pad D to the 128-lane

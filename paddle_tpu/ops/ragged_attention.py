@@ -27,12 +27,13 @@ are token-identical to the gather and dense paths — pinned by
 ``tests/test_ragged_attention.py``.
 
 CPU/tier-1: the kernel runs under ``interpret=True`` (same jnp ops, DMAs
-emulated). ``PADDLE_RAGGED_ATTN=0`` makes the serving engine fall back to
-the XLA gather path entirely (``enabled()`` below); on real TPUs the
-compiled path additionally requires MXU-friendly shapes (``head_dim`` a
-lane multiple, ``page_size`` a sublane multiple) — ``supported()`` says
-whether this pool/config can take the compiled kernel, and callers fall
-back to the gather when it cannot.
+emulated). The COMPILED path does not exist yet: Mosaic refuses the kernel
+at every serving geometry (the two messages are in ``supported()`` below
+and pinned by ``tests/test_tpu_compile.py``), so ``supported(...,
+interpret=False)`` is False everywhere and ``ContinuousBatcher(
+kv_layout="ragged")`` raises on a TPU instead of serving through another
+path unasked. ``PADDLE_RAGGED_ATTN=0`` is the one explicit way to ask a
+ragged-mode caller for the XLA block-table gather (``enabled()`` below).
 
 Sharding (GSPMD, arxiv 2105.04663): programs are independent per
 (slot, kv-head), so a pool sharded ``P(None, None, "model", None)`` runs
@@ -58,11 +59,6 @@ ENV_RAGGED_ATTN = "PADDLE_RAGGED_ATTN"
 # Mosaic cannot legalize in BlockSpec index maps (see ops/flash_attention)
 _i0 = np.int32(0)
 
-# TPU lane / sublane minima for the compiled (non-interpret) path
-_LANE = 128
-_SUBLANE = 8
-
-
 def enabled() -> bool:
     """The PADDLE_RAGGED_ATTN fallback switch: '0' sends every ragged-mode
     caller back to the XLA block-table gather (token-identical, just
@@ -72,26 +68,32 @@ def enabled() -> bool:
 
 def supported(head_dim: int, page_size: int, interpret: bool,
               kv_dtype: str | None = None) -> bool:
-    """Can this (pool, config) run the kernel? Interpret mode always can;
-    the compiled TPU path needs MXU-tileable blocks. Quantized pools
-    (``kv_dtype`` int8/fp8, ISSUE 10) are interpret-only for now: the
-    per-page [page_size] scale-slice DMAs have been validated in
-    interpret mode but not against Mosaic's tiling on a real TPU window —
-    callers fall back to the XLA gather path there (which dequantizes the
-    same pool, token-identically)."""
-    if interpret:
-        return True
-    if kv_dtype is not None:
-        return False
-    return head_dim % _LANE == 0 and page_size % _SUBLANE == 0
+    """Can this (pool, config) run the kernel? It says what the compiler
+    says. Interpret mode always can. The compiled path is refused by
+    Mosaic (jax 0.9.0 / libtpu 0.0.34, described v5e) at every geometry
+    tried, for two separate reasons:
 
+      * ``page_size`` < 128 (the batcher's default is 16) — decode
+        ``B8 Q1 H32 KV32 hd128``, GQA ``H32 KV8`` and prefill ``Q128``
+        alike: "cannot statically prove that index in dimension 1 is a
+        multiple of 128" on the ``tpu.vector_store`` of each page's logits
+        tile into the ``[span, max_pages*page_size]`` scratch at lane
+        offset ``j*page_size`` (``page_step`` in both kernel bodies);
+      * ``page_size`` = 128 — that store passes, and the per-(page,
+        kv-head) DMA is refused: "Slice shape along dimension 2 must be
+        aligned to tiling (8), but is 1" on the ``memref_slice`` of the
+        ``[num_pages, page_size, KV, hd]`` pool (``kdma``/``vdma``): one
+        KV head cannot be sliced out of the sublane-tiled ``KV`` dim in
+        HBM.
 
-def _compiler_params(dimension_semantics):
-    """pltpu.CompilerParams across jax versions (0.4.x: TPUCompilerParams)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(dimension_semantics=dimension_semantics)
+    The repair (a later perf_opt issue) is a per-page online softmax
+    instead of a full-width logits scratch, and a page DMA that moves
+    whole tiles — head-major pages, or all KV heads of a page at once.
+    Until a geometry compiles, the compiled path is refused outright;
+    ``tests/test_tpu_compile.py`` holds the two refusals as strict xfails
+    and must agree with this function in every case it holds."""
+    del head_dim, page_size, kv_dtype   # no geometry compiles yet
+    return bool(interpret)
 
 
 def _kernel_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
@@ -101,7 +103,7 @@ def _kernel_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
 
     Scalar prefetch (SMEM): bt_ref [B, Pmax] block table, qlen_ref /
     kvlen_ref [B]. q_ref block [1, 1, q_max*groups, hd] (row = qpos*g+gi).
-    kp/vp_ref: the WHOLE pool in HBM (pltpu.ANY) — only live pages move.
+    kp/vp_ref: the WHOLE pool in HBM (pl.ANY) — only live pages move.
 
     Pipeline: page j's K lands in kbuf[j%2] while page j+1's copy is in
     flight (double buffering); its logits tile goes to lbuf as soon as the
@@ -307,7 +309,7 @@ def _kernel_body_quant(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref,
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def ragged_paged_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
-                           *, page_size: int, interpret: bool = True,
+                           *, page_size: int, interpret: bool,
                            k_scale=None, v_scale=None):
     """Ragged paged attention over a shared page pool.
 
@@ -356,8 +358,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
         q_max=q_max, scale=scale)
     in_specs = [
         pl.BlockSpec((1, 1, span, hd), lambda b, k, *_: (b, k, _i0, _i0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM;
-        pl.BlockSpec(memory_space=pltpu.ANY),   # live pages are DMA'd
+        pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM;
+        pl.BlockSpec(memory_space=pl.ANY),   # live pages are DMA'd
     ]
     if quant:
         scratch = [
@@ -373,8 +375,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ]
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),   # K scales
-                     pl.BlockSpec(memory_space=pltpu.ANY)]   # V scales
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY),   # K scales
+                     pl.BlockSpec(memory_space=pl.ANY)]   # V scales
         operands = (qh, k_pool, v_pool, k_scale.astype(jnp.float32),
                     v_scale.astype(jnp.float32))
     else:
@@ -397,8 +399,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, span, hd), q.dtype),
-        compiler_params=(None if interpret else
-                         _compiler_params(("parallel", "parallel"))),
+        compiler_params=(None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"))),
         interpret=interpret,
     )(block_table.astype(jnp.int32), q_lens.astype(jnp.int32),
       kv_lens.astype(jnp.int32), *operands)

@@ -125,20 +125,38 @@ class TestRaggedKernel:
             qp, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
             jnp.asarray(qlens), jnp.asarray(qlens), page_size=ps,
             interpret=True))
+        # NOT bitwise, unlike the decode test above: there both sides
+        # reduce over the same padded width, here the kernel's softmax sum
+        # and probs@V contraction run over max_pages*ps = 32 lanes (dead
+        # lanes exact zeros) while the dense reference runs over the T live
+        # ones. XLA's CPU backend (jaxlib 0.9.0) vectorizes a row reduction
+        # by its width, so the same numbers are summed in another order:
+        # 1-ulp differences (measured max 1.2e-7 on O(1) outputs). The
+        # bound is reassociation of <= 32 f32 terms of magnitude <= 1,
+        # twice: 32 * eps, set from the dtype and not from the measurement.
+        atol = pmax * ps * np.finfo(np.float32).eps
         for b in range(2):
             T = int(qlens[b])
             ref = _attention(qp[b:b + 1, :T], jnp.asarray(ks[b:b + 1, :T]),
                              jnp.asarray(vs[b:b + 1, :T]), cfg,
                              use_flash=False)
-            assert (np.asarray(ref)[0] == out[b, :T]).all(), b
+            np.testing.assert_allclose(out[b, :T], np.asarray(ref)[0],
+                                       rtol=0, atol=atol, err_msg=str(b))
         assert (out[2] == 0).all()               # skipped slot: zeros
         assert np.isfinite(out[:2, :12]).all()   # NaN pool never leaked
 
-    def test_supported_gates_compiled_shapes(self):
-        assert ra.supported(64, 8, interpret=True)        # CPU: always
-        assert ra.supported(128, 8, interpret=False)      # lane-tileable
-        assert not ra.supported(64, 8, interpret=False)   # hd % 128
-        assert not ra.supported(128, 5, interpret=False)  # ps % 8
+    @pytest.mark.parametrize("head_dim,page_size,kv_dtype", [
+        (128, 16, None), (128, 128, None), (128, 8, None), (64, 8, None),
+        (128, 16, "int8")])
+    def test_supported_says_what_the_compiler_says(self, head_dim, page_size,
+                                                   kv_dtype):
+        """Interpret mode always can; the compiled path is refused at every
+        geometry until Mosaic accepts one (tests/test_tpu_compile.py holds
+        the compiler's verdicts this must agree with)."""
+        assert ra.supported(head_dim, page_size, interpret=True,
+                            kv_dtype=kv_dtype)
+        assert not ra.supported(head_dim, page_size, interpret=False,
+                                kv_dtype=kv_dtype)
 
 
 # ---------------------------------------------------------------- serving
@@ -179,9 +197,28 @@ class TestRaggedServingParity:
             assert out[rid] == _reference_generate(cfg, params, p, m)
         assert eng.pages_in_use == 0
 
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_uncompilable_pool_raises_on_tpu(self, small_model, monkeypatch,
+                                             kv_dtype):
+        """On a TPU the kernel must be compiled, and the compiler refuses
+        it: an explicit kv_layout="ragged" raises, naming the geometry —
+        it never serves through the gather or the interpreter unasked.
+        PADDLE_RAGGED_ATTN=0 stays the one explicit way to the gather."""
+        cfg, params = small_model
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError) as err:
+            _engine(cfg, params, kv_layout="ragged", kv_dtype=kv_dtype)
+        for part in (f"head_dim={cfg.head_dim}", "page_size=8",
+                     f"kv_dtype={kv_dtype!r}", "PADDLE_RAGGED_ATTN=0"):
+            assert part in str(err.value)
+        monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
+        eng = _engine(cfg, params, kv_layout="ragged", kv_dtype=kv_dtype)
+        assert eng._ragged is False and eng._interpret is False
+
     def test_env_flag_falls_back_to_gather(self, small_model, monkeypatch):
-        """PADDLE_RAGGED_ATTN=0: a ragged engine silently serves through
-        the gather path — token-identical, parity gated both ways."""
+        """PADDLE_RAGGED_ATTN=0: a ragged engine serves through the gather
+        path because it was asked to — token-identical, parity gated both
+        ways."""
         cfg, params = small_model
         p, m = _mixed_requests(cfg, 41, [(9, 6)])[0]
         monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")

@@ -1,10 +1,10 @@
 """Resilience layer tests (ISSUE 1): unified retry/backoff, deterministic
 chaos injection, preemption-safe checkpointing, and ResilientLoop's
-bitwise-exact recovery contract (resume_max_rel == 0.0, the property
-MULTICHIP_r05.json proved on hardware — here proven on CPU via chaos).
+bitwise-exact recovery contract (resume_max_rel == 0.0, proven on CPU via
+chaos).
 
 Also wires the static resilience lint (tools/lint_resilience.py) and the
-bench never-JSON-less contract (VERDICT r5) into tier-1.
+bench never-JSON-less contract into tier-1.
 """
 import json
 import os
@@ -589,8 +589,9 @@ class TestCommWatchdog:
 # ------------------------------------------------------- bench.py contract
 
 class TestBenchNeverJsonless:
-    """VERDICT r5: BENCH_r05.json rc=124, parsed: null. The bench must now
-    emit exactly one machine-readable JSON line on EVERY exit path."""
+    """A caller's timeout once killed the bench mid-retry (rc=124, nothing
+    to parse). The bench must emit exactly one machine-readable JSON line
+    on EVERY exit path."""
 
     @staticmethod
     def _json_lines(out: str):
