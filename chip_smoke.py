@@ -46,7 +46,8 @@ def emit(obj) -> None:
     print(json.dumps(obj), flush=True)
 
 
-def device_info(jax) -> dict:
+def device_info() -> dict:
+    import jax
     d = jax.devices()[0]
     return {"platform": d.platform, "kind": d.device_kind,
             "count": len(jax.devices())}
@@ -57,9 +58,8 @@ def memory(dev) -> dict:
     return {k: stats.get(k) for k in ("bytes_in_use", "peak_bytes_in_use")}
 
 
-def free_device_memory(jax) -> None:
-    gc.collect()
-    jax.clear_caches()
+def free_device_memory() -> None:
+    gc.collect()    # engines and trainers hold reference cycles
 
 
 def width_summary(cfg) -> dict:
@@ -101,8 +101,9 @@ def make_batches(cfg, batch, seq, steps, seed, workdir):
         loader.close()
 
 
-def run_trainer(jax, cfg, mesh, batches, seed, on_tpu):
+def run_trainer(cfg, mesh, batches, seed, on_tpu):
     """A few steps of LlamaTrainStep; returns (losses, facts, trainer)."""
+    import jax
     import jax.numpy as jnp
     from paddle_tpu.models import LlamaTrainStep
     from paddle_tpu.optimizer import AdamW
@@ -145,13 +146,13 @@ def run_trainer(jax, cfg, mesh, batches, seed, on_tpu):
     return losses, facts, step
 
 
-def phase_train(jax, args, dev) -> dict:
+def phase_train(args, dev) -> dict:
     cfg = model_config(args.rehearse, TRAIN_LAYERS)
     batch, seq, steps = ((2, 128, 4) if args.rehearse
                          else (TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS))
     with tempfile.TemporaryDirectory() as tmp:
         batches, native = make_batches(cfg, batch, seq, steps, args.seed, tmp)
-    _, facts, step = run_trainer(jax, cfg, None, batches, args.seed,
+    _, facts, step = run_trainer(cfg, None, batches, args.seed,
                                  dev["platform"] == "tpu")
     del step
     return {"config": {**width_summary(cfg), "batch": batch, "seq": seq,
@@ -165,17 +166,20 @@ def phase_train(jax, args, dev) -> dict:
 
 # ------------------------------------------------------------------ serve
 
-def make_requests(cfg, seed):
+def make_requests(cfg, seed, rehearse):
     import numpy as np
     rng = np.random.RandomState(seed)
+    # each request compiles its own llama_generate: a rehearsal takes four
+    spec = SERVE_REQUESTS[::3] if rehearse else SERVE_REQUESTS
     return [(rng.randint(1, cfg.vocab_size, n).astype(np.int32).tolist(), m)
-            for n, m in SERVE_REQUESTS]
+            for n, m in spec]
 
 
-def serve_and_compare(jax, cfg, params, requests, on_tpu, **engine_kw):
+def serve_and_compare(cfg, params, requests, on_tpu, **engine_kw):
     """Serve `requests` through ContinuousBatcher, then run each through
     llama_generate on the same device. Returns the facts and whether every
     greedy token agreed."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.inference import ContinuousBatcher
@@ -214,7 +218,7 @@ def serve_and_compare(jax, cfg, params, requests, on_tpu, **engine_kw):
                                        "max_concurrent", "page_buckets_used")}
     num_pages = eng._alloc.num_pages
     del eng
-    free_device_memory(jax)
+    free_device_memory()
 
     t0 = time.perf_counter()
     agree = total = 0
@@ -235,35 +239,60 @@ def serve_and_compare(jax, cfg, params, requests, on_tpu, **engine_kw):
             **stats}, agree == total
 
 
-def phase_serve(jax, args, dev) -> dict:
+def ragged_verdict(cfg, params, on_tpu) -> str:
+    """kv_layout="ragged" on the chip: until Mosaic accepts the ragged
+    kernel the engine must refuse at construction, naming the geometry —
+    never serve through the gather or the interpreter unasked."""
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.ops import ragged_attention as ra
+    if not on_tpu:
+        return "off the TPU the kernel is interpreted; not exercised here"
+    if ra.supported(cfg.head_dim, 16, interpret=False):
+        raise AssertionError("the ragged kernel is said to compile: give "
+                             "the serve phase its kv_layout='ragged' pass")
+    try:
+        ContinuousBatcher(cfg, params, kv_layout="ragged",
+                          max_batch=SERVE_MAX_BATCH, max_len=SERVE_MAX_LEN,
+                          prompt_buckets=SERVE_PROMPT_BUCKETS)
+    except ValueError as e:
+        if "page_size=16" not in str(e):
+            raise
+        return f"raises: {e}"
+    raise AssertionError("kv_layout='ragged' built an engine although the "
+                         "compiler refuses the kernel")
+
+
+def phase_serve(args, dev) -> dict:
+    import jax
     import jax.numpy as jnp
     from paddle_tpu.models.llama import llama_init_params
 
     on_tpu = dev["platform"] == "tpu"
     cfg = model_config(args.rehearse, SERVE_LAYERS,
                        **({"dtype": jnp.bfloat16} if args.rehearse else {}))
-    requests = make_requests(cfg, args.seed)
+    requests = make_requests(cfg, args.seed, args.rehearse)
     params = llama_init_params(cfg, jax.random.PRNGKey(args.seed))
+    ragged = ragged_verdict(cfg, params, on_tpu)
     pool = {} if args.rehearse else {"pool_hbm_bytes": SERVE_POOL_BYTES}
-    facts, equal = serve_and_compare(jax, cfg, params, requests, on_tpu,
-                                     **pool)
+    facts, equal = serve_and_compare(cfg, params, requests, on_tpu, **pool)
     result = {"config": {**width_summary(cfg), "kv_layout": "paged"},
               "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: bf16 "
                           f"weights plus an {SERVE_POOL_BYTES >> 30} GiB KV "
                           f"page pool fit one 16 GB chip"],
-              "bf16": facts, "compared_in": "bfloat16"}
+              "ragged_layout": ragged, "bf16": facts,
+              "compared_in": "bfloat16"}
     if not equal or args.rehearse:      # a rehearsal walks both passes
         # bf16 near-ties between random-weight logits flip a greedy argmax
         # between two correct programs; the equality tier-1 pins on the CPU
         # is then decided in float32 at a depth that fits
         del params
-        free_device_memory(jax)
+        free_device_memory()
         cfg32 = model_config(args.rehearse, SERVE_F32_LAYERS,
                              dtype=jnp.float32)
         params32 = llama_init_params(cfg32, jax.random.PRNGKey(args.seed))
         with jax.default_matmul_precision("highest"):
-            facts32, equal32 = serve_and_compare(jax, cfg32, params32,
-                                                 requests, on_tpu)
+            facts32, equal32 = serve_and_compare(cfg32, params32, requests,
+                                                 on_tpu)
         result.update(float32=facts32, compared_in="float32",
                       float32_config=width_summary(cfg32))
         result["reduced"].append(
@@ -279,9 +308,10 @@ def phase_serve(jax, args, dev) -> dict:
 
 # ------------------------------------------------------------- four chips
 
-def phase_mesh_train(jax, args, dev) -> dict:
+def phase_mesh_train(args, dev) -> dict:
     """The sharded train step on four devices against the single-device
     run of the same model on the same batches."""
+    import jax
     import numpy as np
     from jax.sharding import Mesh
     from paddle_tpu.distributed.process_mesh import ProcessMesh
@@ -297,15 +327,13 @@ def phase_mesh_train(jax, args, dev) -> dict:
     with tempfile.TemporaryDirectory() as tmp:
         batches, native = make_batches(cfg, batch, seq, steps, args.seed, tmp)
 
-    single, facts1, step = run_trainer(jax, cfg, None, batches, args.seed,
-                                       on_tpu)
+    single, facts1, step = run_trainer(cfg, None, batches, args.seed, on_tpu)
     del step
-    free_device_memory(jax)
+    free_device_memory()
 
     mesh = ProcessMesh(Mesh(np.asarray(devices[:4]).reshape(MESH_SHAPE),
                             MESH_AXES))
-    sharded, facts4, step = run_trainer(jax, cfg, mesh, batches, args.seed,
-                                        on_tpu)
+    sharded, facts4, step = run_trainer(cfg, mesh, batches, args.seed, on_tpu)
     # the parameters must really span the four devices
     spans = {}
     for name in ("wq", "wo", "w_gate", "w_down"):     # sharded on dp AND tp
@@ -356,7 +384,7 @@ def main(argv=None) -> int:
 
         from paddle_tpu.utils.compile_cache import enable_compile_cache
         cache_dir = enable_compile_cache()
-        dev = device_info(jax)
+        dev = device_info()
         emit({"phase": "start", **dev, "chips": args.chips,
               "rehearse": args.rehearse, "seed": args.seed,
               "compile_cache": cache_dir, "jax": jax.__version__})
@@ -372,14 +400,16 @@ def main(argv=None) -> int:
         for name, fn in phases:
             t0 = time.perf_counter()
             try:
-                facts = fn(jax, args, dev)
+                facts = fn(args, dev)
             except Exception:
                 failed = name
                 raise
+            if args.rehearse:   # tiny widths: nothing below is the model's
+                facts = {"rehearsal": True, **facts}
             emit({"phase": name, "ok": True, **dev, **facts,
                   "phase_s": round(time.perf_counter() - t0, 2),
                   **memory(jax.devices()[0])})
-            free_device_memory(jax)
+            free_device_memory()
         if dev["platform"] != "tpu":
             raise RuntimeError("rehearsal: every phase ran, but not on a TPU")
     except Exception as e:

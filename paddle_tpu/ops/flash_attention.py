@@ -104,8 +104,8 @@ def flash_attention_raw(q, k, v, causal: bool = False, block_q: int = 512,
                                bq=_fit_block(block_q, L),
                                bk=_fit_block(block_k, S))
     if mesh is not None:
-        kernel = jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
-                               out_specs=spec, check_vma=False)
+        from ..utils.jax_compat import shard_map
+        kernel = shard_map(kernel, mesh, (spec, spec, spec), spec)
     return kernel(q, k, v)
 
 

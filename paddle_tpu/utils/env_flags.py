@@ -288,8 +288,10 @@ declare("PADDLE_PREFIX_CACHE_PAGES", "0",
         "cached pages copy-on-write and prefill only their suffix; "
         "0 = off, the pre-sharing engine byte-for-byte)")
 declare("PADDLE_RAGGED_ATTN", "1",
-        "'0' falls back from the ragged Pallas kernel (kv_layout='ragged') "
-        "to the XLA block-table gather — token-identical, bucket-bound")
+        "'0' asks a kv_layout='ragged' engine for the XLA block-table "
+        "gather instead of the ragged Pallas kernel — token-identical, "
+        "bucket-bound; the one explicit way to the gather (an "
+        "uncompilable pool raises)")
 declare("PADDLE_SERVE_MESH_MODEL", "0",
         "shard the serving KV page pool over this many devices along the "
         "'model' mesh axis (GSPMD; 0/1 = single-chip)")

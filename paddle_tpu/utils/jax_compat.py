@@ -1,10 +1,8 @@
-"""Version shims for jax APIs that moved between releases.
+"""One spelling of `jax.shard_map` for every call site.
 
-The runtime targets current jax (`jax.shard_map`, `check_vma`) but must
-degrade gracefully on the 0.4.x line the CI container ships, where the
-same primitive lives at `jax.experimental.shard_map.shard_map` with the
-older `check_rep`/`auto` spelling. One choke point here so call sites
-never probe versions themselves (ISSUE 1: gate missing deps, don't crash).
+Written for the one installation there is (jax 0.9.0): `jax.shard_map`
+with `check_vma`, `jax.lax.axis_size`. Call sites pass `check` and
+`axis_names` here and never spell the keywords themselves.
 """
 from __future__ import annotations
 
@@ -12,34 +10,12 @@ import jax
 
 __all__ = ["shard_map", "axis_size"]
 
-
-def axis_size(axis_name):
-    """jax.lax.axis_size across versions: old jax spells it psum(1, axis)
-    (a constant psum folds to the static axis size at trace time)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+axis_size = jax.lax.axis_size
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check=False, axis_names=None):
-    """jax.shard_map across jax versions.
-
-    check: the new `check_vma` (old `check_rep`).
-    axis_names: axes `fn` is manual over (None = all of them). Old jax
-    spells this inversely as `auto` = the axes that stay automatic.
-    """
-    new_sm = getattr(jax, "shard_map", None)
-    if new_sm is not None:
-        kw = {"check_vma": check}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return new_sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-    from jax.experimental.shard_map import shard_map as old_sm
-    kw = {"check_rep": check}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return old_sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """check: `check_vma`. axis_names: the axes `fn` is manual over
+    (None = all of them; the rest stay GSPMD-automatic)."""
+    kw = {} if axis_names is None else {"axis_names": frozenset(axis_names)}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kw)

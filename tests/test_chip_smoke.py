@@ -1,0 +1,129 @@
+"""chip_smoke.py's contract, as far as a CPU can hold it: it never passes
+off the TPU, a phase that raises ends non-zero with a failing last line,
+the passing line is exactly the contract's, and the rehearsal walks every
+phase at a tiny size (the path the chip run takes at the real one)."""
+import json
+import os
+
+import jax
+import pytest
+
+import chip_smoke as cs
+from paddle_tpu.utils import compile_cache
+
+PASSING = {"ok": True, "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                                  "count": 1}}
+
+
+def _run(capsys, argv):
+    rc = cs.main(argv)
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("{")]
+    return rc, lines
+
+
+@pytest.fixture
+def stub_phases(monkeypatch):
+    for name in ("phase_train", "phase_serve", "phase_mesh_train"):
+        monkeypatch.setattr(cs, name, lambda args, dev: {"stub": True})
+
+
+@pytest.fixture
+def restore_cache_dir():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_no_tpu_fails_at_once(capsys, restore_cache_dir):
+    rc, lines = _run(capsys, [])
+    assert rc != 0
+    assert [ln.get("phase") for ln in lines] == ["start", None]
+    assert lines[-1]["ok"] is False and "no TPU" in lines[-1]["error"]
+
+
+def test_raising_phase_ends_nonzero(capsys, monkeypatch, stub_phases,
+                                    restore_cache_dir):
+    def boom(args, dev):
+        raise RuntimeError("injected")
+
+    monkeypatch.setattr(cs, "phase_serve", boom)
+    monkeypatch.setattr(cs, "device_info", lambda: PASSING["device"])
+    rc, lines = _run(capsys, [])
+    assert rc != 0
+    assert lines[-1]["ok"] is False
+    assert lines[-1]["failed_phase"] == "serve"
+    assert "injected" in lines[-1]["error"]
+    assert [ln["phase"] for ln in lines[:-1]] == ["start", "train"]
+
+
+def test_passing_line_is_the_contracts(capsys, monkeypatch, stub_phases,
+                                       restore_cache_dir):
+    monkeypatch.setattr(cs, "device_info", lambda: PASSING["device"])
+    rc, lines = _run(capsys, [])
+    assert rc == 0 and lines[-1] == PASSING
+    assert [ln["phase"] for ln in lines[:-1]] == ["start", "train", "serve"]
+
+
+def test_chips_4_runs_only_the_mesh_phase(capsys, monkeypatch, stub_phases,
+                                          restore_cache_dir):
+    dev = {**PASSING["device"], "count": 4}
+    monkeypatch.setattr(cs, "device_info", lambda: dev)
+    rc, lines = _run(capsys, ["--chips", "4"])
+    assert rc == 0 and lines[-1] == {"ok": True, "device": dev}
+    assert [ln["phase"] for ln in lines[:-1]] == ["start", "mesh_train"]
+    # one chip asked for, four found: refuse before any phase
+    rc, lines = _run(capsys, [])
+    assert rc != 0 and [ln.get("phase") for ln in lines] == ["start", None]
+
+
+@pytest.mark.parametrize("argv,phases", [
+    (["--rehearse"], ["train", "serve"]),
+    (["--rehearse", "--chips", "4"], ["mesh_train"])])
+def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases,
+                                                      restore_cache_dir):
+    rc, lines = _run(capsys, argv)
+    assert rc != 0 and lines[-1]["ok"] is False
+    assert lines[-1]["failed_phase"] is None, lines[-1]
+    assert [ln["phase"] for ln in lines[1:-1]] == phases
+    assert all(ln["ok"] for ln in lines[1:-1])
+    if "serve" in phases:
+        serve = lines[2]
+        assert serve["compared_in"] == "float32"    # both passes walked
+        assert serve["float32"]["tokens_equal_llama_generate"] == "64/64"
+
+
+def test_ragged_layout_must_raise_on_the_chip(monkeypatch):
+    """The serve phase's chip-only branch: the engine refuses
+    kv_layout="ragged" at construction, naming the geometry."""
+    from paddle_tpu.models import LlamaConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    verdict = cs.ragged_verdict(LlamaConfig.tiny(), {}, on_tpu=True)
+    assert verdict.startswith("raises: ") and "page_size=16" in verdict
+
+
+class TestCompileCachePlacement:
+    def test_env_places_the_cache_and_code_sets_no_path(
+            self, monkeypatch, tmp_path, restore_cache_dir):
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+
+    def test_default_is_a_fixed_dir_inside_the_checkout(
+            self, monkeypatch, restore_cache_dir):
+        monkeypatch.delenv(compile_cache.ENV_CACHE_DIR, raising=False)
+        path = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.abspath(cs.__file__))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+
+    def test_warmstart_yields_to_the_variable(self, monkeypatch, tmp_path,
+                                              restore_cache_dir, capsys):
+        from paddle_tpu.inference.warmstart import enable_jit_cache
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, str(tmp_path / "env"))
+        enable_jit_cache(str(tmp_path / "own"))
+        assert jax.config.jax_compilation_cache_dir is None
+        assert not (tmp_path / "own").exists()
+        assert "is set" in capsys.readouterr().err
