@@ -175,32 +175,39 @@ def make_requests(cfg, seed, rehearse):
             for n, m in spec]
 
 
-def serve_and_compare(cfg, params, requests, on_tpu, **engine_kw):
-    """Serve `requests` through ContinuousBatcher, then run each through
-    llama_generate on the same device. Returns the facts and whether every
-    greedy token agreed."""
+def serve(cfg, params, requests, on_tpu, kv_layout, **engine_kw):
+    """Serve `requests` through ContinuousBatcher(kv_layout); returns each
+    request's greedy tokens and the facts of the pass."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from paddle_tpu.inference import ContinuousBatcher
-    from paddle_tpu.models.llama_decode import llama_generate
     from paddle_tpu.models.llama_paged import llama_paged_prefill_slot
     from paddle_tpu.observability import metrics
 
-    eng = ContinuousBatcher(cfg, params, max_batch=SERVE_MAX_BATCH,
-                            max_len=SERVE_MAX_LEN,
+    eng = ContinuousBatcher(cfg, params, kv_layout=kv_layout,
+                            max_batch=SERVE_MAX_BATCH, max_len=SERVE_MAX_LEN,
                             prompt_buckets=SERVE_PROMPT_BUCKETS, burst=8,
                             **engine_kw)
     ps = eng.page_size
-    bucket = eng._buckets[0]
-    n_kernels = custom_calls(llama_paged_prefill_slot.lower(
-        params, eng._cache, jnp.zeros(bucket, jnp.int32),
-        jnp.zeros(-(-bucket // ps), jnp.int32), jnp.int32(1),
-        jax.random.PRNGKey(0), config=cfg, temperature=0.0, top_k=0,
-        dequant=None, kv_dtype=None))
-    if on_tpu and n_kernels < 1:
-        raise AssertionError("the Pallas flash kernel is not in the "
-                             "bucketed-prefill program")
+    if kv_layout == "ragged":
+        # prefill and decode both read through the ragged kernel, and on a
+        # TPU the burst is traced with interpret=False: Mosaic compiles it
+        kernel = {"ragged_kernel": eng._ragged,
+                  "interpreted": eng._interpret}
+        if not eng._ragged or eng._interpret == on_tpu:
+            raise AssertionError(f"the ragged engine is not on the compiled "
+                                 f"kernel: {kernel}")
+    else:
+        bucket = eng._buckets[0]
+        kernel = {"flash_tpu_custom_calls_in_prefill": custom_calls(
+            llama_paged_prefill_slot.lower(
+                params, eng._cache, jnp.zeros(bucket, jnp.int32),
+                jnp.zeros(-(-bucket // ps), jnp.int32), jnp.int32(1),
+                jax.random.PRNGKey(0), config=cfg, temperature=0.0, top_k=0,
+                dequant=None, kv_dtype=None))}
+        if on_tpu and kernel["flash_tpu_custom_calls_in_prefill"] < 1:
+            raise AssertionError("the Pallas flash kernel is not in the "
+                                 "bucketed-prefill program")
 
     t0 = time.perf_counter()
     rids = [eng.add_request(p, max_new_tokens=m) for p, m in requests]
@@ -214,52 +221,48 @@ def serve_and_compare(cfg, params, requests, on_tpu, **engine_kw):
     if eng.pages_in_use != 0 or gauge != 0:
         raise AssertionError(f"pages leaked after the drain: allocator "
                              f"{eng.pages_in_use}, serve.pages_in_use {gauge}")
-    stats = {k: eng.stats[k] for k in ("bursts", "decode_steps", "prefills",
-                                       "max_concurrent", "page_buckets_used")}
-    num_pages = eng._alloc.num_pages
-    del eng
+    facts = {"requests": len(requests),
+             "tokens": sum(m for _, m in requests),
+             "page_size": ps, "num_pages": eng._alloc.num_pages,
+             "serve_s_with_compile": round(serve_s, 2), **kernel,
+             **{k: eng.stats[k] for k in ("bursts", "decode_steps",
+                                          "prefills", "max_concurrent")}}
+    return [list(out[rid]) for rid in rids], facts
+
+
+def reference_tokens(cfg, params, requests):
+    """Per-request llama_generate on the same device."""
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models.llama_decode import llama_generate
+    return [np.asarray(llama_generate(params, jnp.asarray([p], jnp.int32),
+                                      cfg, m, temperature=0.0))[0].tolist()
+            for p, m in requests]
+
+
+def agreement(a, b) -> str:
+    same = sum(x == y for ta, tb in zip(a, b) for x, y in zip(ta, tb))
+    return f"{same}/{sum(len(t) for t in a)}"
+
+
+def serve_both_layouts(cfg, params, requests, on_tpu, **engine_kw):
+    """The default gather layout and the ragged kernel layout against
+    per-request llama_generate. Returns the facts and whether all three
+    agree on every token."""
+    paged, facts_p = serve(cfg, params, requests, on_tpu, "paged",
+                           **engine_kw)
     free_device_memory()
-
+    ragged, facts_r = serve(cfg, params, requests, on_tpu, "ragged",
+                            **engine_kw)
+    free_device_memory()
     t0 = time.perf_counter()
-    agree = total = 0
-    for rid, (p, m) in zip(rids, requests):
-        ref = np.asarray(llama_generate(params, jnp.asarray([p], jnp.int32),
-                                        cfg, m, temperature=0.0))[0]
-        agree += int(np.sum(ref == np.asarray(out[rid])))
-        total += m
-    return {"requests": len(requests), "tokens": total,
-            "prompt_lens": [len(p) for p, _ in requests],
-            "prompt_buckets": list(SERVE_PROMPT_BUCKETS),
-            "max_batch": SERVE_MAX_BATCH, "max_len": SERVE_MAX_LEN,
-            "page_size": ps,
-            "num_pages": num_pages, "serve_s_with_compile": round(serve_s, 2),
-            "reference_s_with_compile": round(time.perf_counter() - t0, 2),
-            "flash_tpu_custom_calls_in_prefill": n_kernels,
-            "tokens_equal_llama_generate": f"{agree}/{total}",
-            **stats}, agree == total
-
-
-def ragged_verdict(cfg, params, on_tpu) -> str:
-    """kv_layout="ragged" on the chip: until Mosaic accepts the ragged
-    kernel the engine must refuse at construction, naming the geometry —
-    never serve through the gather or the interpreter unasked."""
-    from paddle_tpu.inference import ContinuousBatcher
-    from paddle_tpu.ops import ragged_attention as ra
-    if not on_tpu:
-        return "off the TPU the kernel is interpreted; not exercised here"
-    if ra.supported(cfg.head_dim, 16, interpret=False):
-        raise AssertionError("the ragged kernel is said to compile: give "
-                             "the serve phase its kv_layout='ragged' pass")
-    try:
-        ContinuousBatcher(cfg, params, kv_layout="ragged",
-                          max_batch=SERVE_MAX_BATCH, max_len=SERVE_MAX_LEN,
-                          prompt_buckets=SERVE_PROMPT_BUCKETS)
-    except ValueError as e:
-        if "page_size=16" not in str(e):
-            raise
-        return f"raises: {e}"
-    raise AssertionError("kv_layout='ragged' built an engine although the "
-                         "compiler refuses the kernel")
+    ref = reference_tokens(cfg, params, requests)
+    facts_p["tokens_equal_llama_generate"] = agreement(paged, ref)
+    facts_r["tokens_equal_llama_generate"] = agreement(ragged, ref)
+    facts_r["tokens_equal_paged"] = agreement(ragged, paged)
+    return {"paged": facts_p, "ragged": facts_r,
+            "reference_s_with_compile": round(time.perf_counter() - t0, 2)
+            }, paged == ref and ragged == ref
 
 
 def phase_serve(args, dev) -> dict:
@@ -272,15 +275,17 @@ def phase_serve(args, dev) -> dict:
                        **({"dtype": jnp.bfloat16} if args.rehearse else {}))
     requests = make_requests(cfg, args.seed, args.rehearse)
     params = llama_init_params(cfg, jax.random.PRNGKey(args.seed))
-    ragged = ragged_verdict(cfg, params, on_tpu)
     pool = {} if args.rehearse else {"pool_hbm_bytes": SERVE_POOL_BYTES}
-    facts, equal = serve_and_compare(cfg, params, requests, on_tpu, **pool)
-    result = {"config": {**width_summary(cfg), "kv_layout": "paged"},
+    facts, equal = serve_both_layouts(cfg, params, requests, on_tpu, **pool)
+    result = {"config": {**width_summary(cfg),
+                         "prompt_lens": [len(p) for p, _ in requests],
+                         "prompt_buckets": list(SERVE_PROMPT_BUCKETS),
+                         "max_batch": SERVE_MAX_BATCH,
+                         "max_len": SERVE_MAX_LEN},
               "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: bf16 "
                           f"weights plus an {SERVE_POOL_BYTES >> 30} GiB KV "
                           f"page pool fit one 16 GB chip"],
-              "ragged_layout": ragged, "bf16": facts,
-              "compared_in": "bfloat16"}
+              "bf16": facts, "compared_in": "bfloat16"}
     if not equal or args.rehearse:      # a rehearsal walks both passes
         # bf16 near-ties between random-weight logits flip a greedy argmax
         # between two correct programs; the equality tier-1 pins on the CPU
@@ -291,18 +296,17 @@ def phase_serve(args, dev) -> dict:
                              dtype=jnp.float32)
         params32 = llama_init_params(cfg32, jax.random.PRNGKey(args.seed))
         with jax.default_matmul_precision("highest"):
-            facts32, equal32 = serve_and_compare(cfg32, params32, requests,
-                                                 on_tpu)
+            facts32, equal32 = serve_both_layouts(cfg32, params32, requests,
+                                                  on_tpu)
         result.update(float32=facts32, compared_in="float32",
                       float32_config=width_summary(cfg32))
         result["reduced"].append(
-            f"token equality with llama_generate decided in float32 at "
-            f"{cfg32.num_hidden_layers} layers (bf16 agreed on "
-            f"{facts['tokens_equal_llama_generate']} tokens)")
+            f"token equality of both layouts with llama_generate decided in "
+            f"float32 at {cfg32.num_hidden_layers} layers")
         if not equal32:
             raise AssertionError(
                 f"float32 greedy tokens differ from llama_generate: "
-                f"{facts32['tokens_equal_llama_generate']}")
+                f"{facts32}")
     return result
 
 

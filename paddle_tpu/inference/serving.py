@@ -24,10 +24,10 @@ Three KV layouts share that scheduler:
     DMAs only live pages), and the executable inventory collapses to the
     {prefill-carrying, decode-only} pair — O(1) in the request mix.
     ``PADDLE_RAGGED_ATTN=0`` asks for the gather-paged path instead,
-    token-identical; a pool the kernel cannot take (today: every pool on
-    a TPU — Mosaic refuses the kernel, see ``ops.ragged_attention.
-    supported``) RAISES at construction, it is never served another way
-    unasked.
+    token-identical; a pool the compiled kernel cannot take on a TPU
+    (``ops.ragged_attention.supported``: quantized pages, head_dim not a
+    lane multiple, a KV head count the page DMA cannot tile) RAISES at
+    construction, it is never served another way unasked.
 
   * ``kv_layout="paged"`` (default) — a shared ``[num_pages, page_size,
     KV, hd]`` pool per layer with per-slot block tables
@@ -261,14 +261,15 @@ class ContinuousBatcher:
         if kv_layout == "ragged":
             from ..ops import ragged_attention as _ra
             self._ragged = _ra.enabled()
+            kv_heads = self._cfg.num_key_value_heads
             if self._ragged and not _ra.supported(
-                    self._cfg.head_dim, int(page_size), self._interpret,
-                    kv_dtype=self._kv_dtype):
+                    self._cfg.head_dim, int(page_size), kv_heads, self.S,
+                    self._interpret, kv_dtype=self._kv_dtype):
                 raise ValueError(
                     f"kv_layout='ragged' cannot run the ragged kernel on "
                     f"platform {jax.default_backend()!r} at head_dim="
                     f"{self._cfg.head_dim}, page_size={int(page_size)}, "
-                    f"kv_heads={self._cfg.num_key_value_heads}, kv_dtype="
+                    f"kv_heads={kv_heads}, max_len={self.S}, kv_dtype="
                     f"{self._kv_dtype!r}: the compiler refuses it (see "
                     f"ops.ragged_attention.supported). Use kv_layout="
                     f"'paged', or set {_ra.ENV_RAGGED_ATTN}=0 to ask for "

@@ -10,14 +10,14 @@ an inventory that grows with the bucket grid, and a bytes/token bill that
 follows the bucket width, not the live context.
 
 This module is the kernel-level replacement. One Pallas program per
-(slot, kv-head) reads the slot's LIVE pages from the HBM pool with
-per-page async copies (double-buffered: page j+1 streams in while page j's
-logits are on the MXU), driven by scalar-prefetched block tables and
-per-slot sequence lengths. Because raggedness lives in SMEM scalars
-instead of array shapes, ONE executable covers every context length AND
-every prefill length: prefill rows (q_len = prompt length, causal) and
-decode rows (q_len = 1) are just different ``q_lens`` values against the
-same compiled program — the mixed prefill+decode burst of
+(slot, block of kv-heads) copies the slot's LIVE pages from the HBM pool
+into contiguous K and V runs in VMEM (one async copy per page, all in
+flight at once), driven by scalar-prefetched block tables and per-slot
+sequence lengths. Because raggedness lives in SMEM scalars instead of
+array shapes, ONE executable covers every context length AND every
+prefill length: prefill rows (q_len = prompt length, causal) and decode
+rows (q_len = 1) are just different ``q_lens`` values against the same
+compiled program — the mixed prefill+decode burst of
 ``llama_ragged_burst`` launches it with no bucket grid at all.
 
 Semantics match ``llama_decode._cached_attention_slots`` /
@@ -27,16 +27,22 @@ are token-identical to the gather and dense paths — pinned by
 ``tests/test_ragged_attention.py``.
 
 CPU/tier-1: the kernel runs under ``interpret=True`` (same jnp ops, DMAs
-emulated). The COMPILED path does not exist yet: Mosaic refuses the kernel
-at every serving geometry (the two messages are in ``supported()`` below
-and pinned by ``tests/test_tpu_compile.py``), so ``supported(...,
-interpret=False)`` is False everywhere and ``ContinuousBatcher(
-kv_layout="ragged")`` raises on a TPU instead of serving through another
-path unasked. ``PADDLE_RAGGED_ATTN=0`` is the one explicit way to ask a
-ragged-mode caller for the XLA block-table gather (``enabled()`` below).
+emulated). On a TPU it is compiled by Mosaic; ``supported()`` below says
+which pools the compiler takes (pinned by ``tests/test_tpu_compile.py``),
+and ``ContinuousBatcher(kv_layout="ragged")`` raises for the others
+instead of serving through another path unasked. ``PADDLE_RAGGED_ATTN=0``
+is the one explicit way to ask a ragged-mode caller for the XLA
+block-table gather (``enabled()`` below).
+
+What the compiled kernel is NOT yet (ROADMAP S4, a perf_opt issue): it is
+shaped by what Mosaic accepts, not tuned. Bytes moved follow the live
+context, but the logits product, the softmax and probs@V run over the
+slot's FULL width; each head's K/V rows are read out of the [rows, heads,
+hd] runs with sublane-strided loads; and compile time grows steeply with
+``max_len`` (``_MAX_COMPILED_ROWS``). No speed has been measured.
 
 Sharding (GSPMD, arxiv 2105.04663): programs are independent per
-(slot, kv-head), so a pool sharded ``P(None, None, "model", None)`` runs
+(slot, kv-head block), so a pool sharded ``P(None, None, "model", None)`` runs
 the SAME kernel per shard under ``shard_map`` — each chip DMAs only its
 own KV heads' pages. See ``parallel/sharding.py:kv_pool_sharding``.
 """
@@ -66,57 +72,78 @@ def enabled() -> bool:
     return env_flags.get_bool(ENV_RAGGED_ATTN)
 
 
-def supported(head_dim: int, page_size: int, interpret: bool,
-              kv_dtype: str | None = None) -> bool:
+# the compiled kernel holds a slot's whole context in VMEM and unrolls over
+# it: on a described v5e one kernel compiled in 10 s at 512 rows, 29 s at
+# 1024, 79 s at 2048, minutes at 4096, and not within 17 min at 8192
+_MAX_COMPILED_ROWS = 4096
+
+
+def supported(head_dim: int, page_size: int, kv_heads: int, max_len: int,
+              interpret: bool, kv_dtype: str | None = None) -> bool:
     """Can this (pool, config) run the kernel? It says what the compiler
-    says. Interpret mode always can. The compiled path is refused by
-    Mosaic (jax 0.9.0 / libtpu 0.0.34, described v5e) at every geometry
-    tried, for two separate reasons:
+    says (jax 0.9.0 / libtpu 0.0.34, compiled for a described v5e;
+    ``tests/test_tpu_compile.py`` holds a case on each side of every rule
+    and must agree with this function). Interpret mode always can. The
+    compiled path needs:
 
-      * ``page_size`` < 128 (the batcher's default is 16) — decode
-        ``B8 Q1 H32 KV32 hd128``, GQA ``H32 KV8`` and prefill ``Q128``
-        alike: "cannot statically prove that index in dimension 1 is a
-        multiple of 128" on the ``tpu.vector_store`` of each page's logits
-        tile into the ``[span, max_pages*page_size]`` scratch at lane
-        offset ``j*page_size`` (``page_step`` in both kernel bodies);
-      * ``page_size`` = 128 — that store passes, and the per-(page,
-        kv-head) DMA is refused: "Slice shape along dimension 2 must be
-        aligned to tiling (8), but is 1" on the ``memref_slice`` of the
-        ``[num_pages, page_size, KV, hd]`` pool (``kdma``/``vdma``): one
-        KV head cannot be sliced out of the sublane-tiled ``KV`` dim in
-        HBM.
+      * ``head_dim % 128 == 0`` — else the page DMA is refused: "Slice
+        shape along dimension 3 must be aligned to tiling (128), but is
+        64";
+      * ``kv_heads % 8 == 0``, or 2 or 4 — the page DMA moves a block of
+        KV heads (``_head_block``), which must be whole sublane tiles or
+        the whole dim: 12 heads give "Slice shape along dimension 2 must
+        be aligned to tiling (8), but is 12";
+      * an unquantized pool — the [page_size, heads] slice of the scale
+        pools is refused: "Slice shape along dimension 2 must be aligned
+        to tiling (128), but is 16";
+      * ``max_len <= 4096`` — see ``_MAX_COMPILED_ROWS``.
 
-    The repair (a later perf_opt issue) is a per-page online softmax
-    instead of a full-width logits scratch, and a page DMA that moves
-    whole tiles — head-major pages, or all KV heads of a page at once.
-    Until a geometry compiles, the compiled path is refused outright;
-    ``tests/test_tpu_compile.py`` holds the two refusals as strict xfails
-    and must agree with this function in every case it holds."""
-    del head_dim, page_size, kv_dtype   # no geometry compiles yet
-    return bool(interpret)
+    Any ``page_size`` compiles (1, 5, 8, 16, 32 and 128 were tried)."""
+    if interpret:
+        return True
+    return (kv_dtype is None and head_dim % 128 == 0
+            and (kv_heads % 8 == 0 or kv_heads in (2, 4))
+            and max_len <= _MAX_COMPILED_ROWS)
 
 
-def _kernel_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
-                 kbuf, vbuf, lbuf, ksem, vsem, *, page_size, max_pages,
-                 groups, q_max, scale):
-    """One (slot b, kv-head k) program.
+def _head_block(kv_heads: int) -> int:
+    """KV heads per kernel program. The page DMA slices the pool's KV dim,
+    which HBM tiles in sublanes, and Mosaic takes such a slice only in
+    whole tiles or as the whole dim (a single head is refused: "Slice
+    shape along dimension 2 must be aligned to tiling (8), but is 1").
+    16 fills a bf16 tile, 8 an f32 one."""
+    for block in (16, 8):
+        if kv_heads % block == 0:
+            return block
+    return kv_heads
+
+
+def _kernel_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, *rest,
+                 page_size, max_pages, groups, q_max, heads, scale, quant):
+    """One (slot b, block of `heads` kv-heads) program.
 
     Scalar prefetch (SMEM): bt_ref [B, Pmax] block table, qlen_ref /
-    kvlen_ref [B]. q_ref block [1, 1, q_max*groups, hd] (row = qpos*g+gi).
-    kp/vp_ref: the WHOLE pool in HBM (pl.ANY) — only live pages move.
+    kvlen_ref [B]. q_ref block [1, heads, q_max*groups, hd] (row =
+    qpos*g+gi). kp/vp_ref: the WHOLE pool in HBM (pl.ANY) — only live
+    pages move. ``quant``: ksp/vsp_ref, the per-(page, row, head) f32 scale
+    pools of an int8/fp8 pool (ISSUE 10), ride alongside.
 
-    Pipeline: page j's K lands in kbuf[j%2] while page j+1's copy is in
-    flight (double buffering); its logits tile goes to lbuf as soon as the
-    wait clears. V pages stream into the contiguous vbuf because every
-    live row is needed AFTER the softmax. Raggedness: n_pages = ceil(
-    kv_len/page_size) bounds the fori_loop — bytes moved follow the LIVE
-    context, and no shape depends on it.
+    Every live page's [page_size, heads, hd] slice is copied into the
+    contiguous K and V runs (all copies in flight at once, then awaited):
+    n_pages = ceil(kv_len/page_size) bounds both loops, so bytes moved
+    follow the LIVE context and no shape depends on it. Each head then
+    takes ONE full-width logits product against its K run — no per-page
+    store at a lane offset, which Mosaic refuses below 128 lanes ("cannot
+    statically prove that index in dimension 1 is a multiple of 128").
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if quant:
+        ksp_ref, vsp_ref, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem = rest
+    else:
+        o_ref, kbuf, vbuf, sem = rest
     b = pl.program_id(0)
-    k = pl.program_id(1)
     ps = page_size
     span = q_max * groups
     rows_total = max_pages * ps
@@ -125,59 +152,40 @@ def _kernel_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
     # every traced scalar is pinned i32: paddle_tpu enables jax_enable_x64,
     # under which a stray Python-int promotion to i64 breaks lowering
     n_pages = (kv_len + jnp.int32(ps - 1)) // jnp.int32(ps)
+    head0 = pl.multiple_of(pl.program_id(1) * jnp.int32(heads), heads)
 
     @pl.when(q_len == 0)
     def _skip():
         # slot takes no queries this launch (e.g. a decoding slot during
         # the prefill-phase launch): write zeros, never NaN residue
-        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
+        o_ref[0] = jnp.zeros_like(o_ref[0])
 
     @pl.when(q_len > 0)
     def _run():
-        q = q_ref[0, 0].astype(jnp.float32)          # [span, hd]
+        def page_copies(j):
+            page = bt_ref[b, j]
+            rows = pl.ds(pl.multiple_of(j * jnp.int32(ps), ps), ps)
+            block = pl.ds(head0, heads)
+            copies = [(kp_ref.at[page, :, block, :], kbuf.at[rows]),
+                      (vp_ref.at[page, :, block, :], vbuf.at[rows])]
+            if quant:
+                copies += [(ksp_ref.at[page, :, block], ksbuf.at[rows]),
+                           (vsp_ref.at[page, :, block], vsbuf.at[rows])]
+            return [pltpu.make_async_copy(src, dst, sem.at[jnp.int32(i)])
+                    for i, (src, dst) in enumerate(copies)]
 
-        def kdma(j, slot):
-            return pltpu.make_async_copy(
-                kp_ref.at[bt_ref[b, j], :, k, :], kbuf.at[slot],
-                ksem.at[slot])
-
-        def vdma(j, slot):
-            return pltpu.make_async_copy(
-                vp_ref.at[bt_ref[b, j], :, k, :],
-                vbuf.at[pl.ds(j * jnp.int32(ps), ps), :],
-                vsem.at[jax.lax.rem(j, jnp.int32(2))])
-
-        kdma(jnp.int32(0), jnp.int32(0)).start()
-        vdma(jnp.int32(0), jnp.int32(0)).start()
-
-        def page_step(j, _):
-            slot = jax.lax.rem(j, jnp.int32(2))
-            nxt = jax.lax.rem(j + jnp.int32(1), jnp.int32(2))
-
-            @pl.when(j + jnp.int32(1) < n_pages)
-            def _prefetch():                         # double buffer: j+1
-                kdma(j + jnp.int32(1), nxt).start()  # streams while j
-                vdma(j + jnp.int32(1), nxt).start()  # computes below
-
-            kdma(j, slot).wait()
-            vdma(j, slot).wait()
-            kpage = kbuf[slot].astype(jnp.float32)   # [ps, hd]
-            lbuf[:, pl.ds(j * jnp.int32(ps), ps)] = jax.lax.dot_general(
-                q, kpage, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+        def start(j, _):
+            for copy in page_copies(j):
+                copy.start()
             return 0
 
-        jax.lax.fori_loop(0, n_pages, page_step, 0)
-
-        def zero_tail(j, _):
-            # vbuf rows past the live pages are stale VMEM: the masked
-            # softmax zeroes their PROBS exactly, but 0 * NaN is NaN —
-            # zero the rows themselves so dead lanes contribute exact 0
-            vbuf[pl.ds(j * jnp.int32(ps), ps), :] = jnp.zeros(
-                (ps, vbuf.shape[1]), vbuf.dtype)
+        def wait(j, _):
+            for copy in page_copies(j):
+                copy.wait()
             return 0
 
-        jax.lax.fori_loop(n_pages, jnp.int32(max_pages), zero_tail, 0)
+        jax.lax.fori_loop(jnp.int32(0), n_pages, start, 0)
+        jax.lax.fori_loop(jnp.int32(0), n_pages, wait, 0)
 
         # mask + softmax over the FULL static width, exactly like the XLA
         # gather path: invalid lanes pinned at -1e30 underflow to exact
@@ -186,125 +194,35 @@ def _kernel_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
         qpos = jax.lax.broadcasted_iota(jnp.int32, (span, rows_total),
                                         0) // jnp.int32(groups)
         valid = (cols < kv_len) & (cols <= kv_len - q_len + qpos)
-        logits = jnp.where(valid, lbuf[:], jnp.float32(-1e30))
-        probs = jax.nn.softmax(logits, axis=-1).astype(vbuf.dtype)
-        out = jax.lax.dot_general(probs, vbuf[:], (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-def _kernel_body_quant(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref,
-                       ksp_ref, vsp_ref, o_ref, kbuf, ksbuf, vtmp, vsbuf,
-                       vbuf, lbuf, ksem, kssem, vsem, vssem, *, page_size,
-                       max_pages, groups, q_max, scale):
-    """The quantized-pool variant of ``_kernel_body`` (ISSUE 10).
-
-    The payload pools are int8/fp8 and per-(page, row, head) f32 scale
-    pools ride alongside (``ksp_ref``/``vsp_ref``, [num_pages, ps, KV]).
-    Each streamed page is DEQUANTIZED inside the double-buffered DMA loop:
-    page j's payload and its [ps] scale slice land together, and the f32
-    ``payload × scale`` product feeds the same logits tile / masked
-    softmax as the unquantized kernel. V pages stream through their own
-    double buffer (``vtmp``) and land dequantized-f32 in the contiguous
-    ``vbuf`` run, so the post-softmax ``probs @ V`` consumes exact f32 —
-    the arithmetic the XLA gather path gets from dequantizing right after
-    its ``jnp.take`` (token-identical on CPU, pinned by
-    tests/test_quant.py)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b = pl.program_id(0)
-    k = pl.program_id(1)
-    ps = page_size
-    span = q_max * groups
-    rows_total = max_pages * ps
-    q_len = qlen_ref[b]
-    kv_len = kvlen_ref[b]
-    n_pages = (kv_len + jnp.int32(ps - 1)) // jnp.int32(ps)
-
-    @pl.when(q_len == 0)
-    def _skip():
-        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
-
-    @pl.when(q_len > 0)
-    def _run():
-        q = q_ref[0, 0].astype(jnp.float32)          # [span, hd]
-
-        def kdma(j, slot):
-            return pltpu.make_async_copy(
-                kp_ref.at[bt_ref[b, j], :, k, :], kbuf.at[slot],
-                ksem.at[slot])
-
-        def ksdma(j, slot):
-            return pltpu.make_async_copy(
-                ksp_ref.at[bt_ref[b, j], :, k], ksbuf.at[slot],
-                kssem.at[slot])
-
-        def vdma(j, slot):
-            return pltpu.make_async_copy(
-                vp_ref.at[bt_ref[b, j], :, k, :], vtmp.at[slot],
-                vsem.at[slot])
-
-        def vsdma(j, slot):
-            return pltpu.make_async_copy(
-                vsp_ref.at[bt_ref[b, j], :, k], vsbuf.at[slot],
-                vssem.at[slot])
-
-        for dma in (kdma, ksdma, vdma, vsdma):
-            dma(jnp.int32(0), jnp.int32(0)).start()
-
-        def page_step(j, _):
-            slot = jax.lax.rem(j, jnp.int32(2))
-            nxt = jax.lax.rem(j + jnp.int32(1), jnp.int32(2))
-
-            @pl.when(j + jnp.int32(1) < n_pages)
-            def _prefetch():                         # double buffer: j+1
-                for dma in (kdma, ksdma, vdma, vsdma):
-                    dma(j + jnp.int32(1), nxt).start()
-
-            kdma(j, slot).wait()
-            ksdma(j, slot).wait()
-            # per-page dequantize INSIDE the DMA loop, mirroring the
-            # gather path's arithmetic EXACTLY: payload × scale in f32,
-            # rounded to the model dtype (the gather's _kv_decode(...,
-            # c.dtype) after its jnp.take), then f32 for the logits dot —
-            # for a bf16 model both paths round identically, so gather
-            # and kernel stay token-identical for ANY model dtype
-            kpage = (kbuf[slot].astype(jnp.float32)
-                     * ksbuf[slot][:, None]).astype(q_ref.dtype) \
-                .astype(jnp.float32)                 # [ps, hd]
-            lbuf[:, pl.ds(j * jnp.int32(ps), ps)] = jax.lax.dot_general(
-                q, kpage, (((1,), (1,)), ((), ())),
+        # rows past the live context are stale VMEM: their PROBS are exact
+        # zeros, but 0 * NaN is NaN — zero the V rows themselves
+        live = jax.lax.broadcasted_iota(
+            jnp.int32, (rows_total, vbuf.shape[-1]), 0) < kv_len
+        for h in range(heads):
+            q = q_ref[0, h].astype(jnp.float32)          # [span, hd]
+            k, v = kbuf[:, h, :], vbuf[:, h, :]          # [rows_total, hd]
+            if quant:
+                # dequantize mirroring the gather path's arithmetic
+                # EXACTLY: payload × scale in f32, rounded to the model
+                # dtype (the gather's _kv_decode(..., c.dtype) after its
+                # jnp.take) — for a bf16 model both paths round
+                # identically, so gather and kernel stay token-identical
+                # for ANY model dtype
+                k = (k.astype(jnp.float32)
+                     * ksbuf[:, h][:, None]).astype(q_ref.dtype)
+                v = (v.astype(jnp.float32)
+                     * vsbuf[:, h][:, None]).astype(q_ref.dtype)
+            logits = jax.lax.dot_general(
+                q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            vdma(j, slot).wait()
-            vsdma(j, slot).wait()
-            vbuf[pl.ds(j * jnp.int32(ps), ps), :] = \
-                (vtmp[slot].astype(jnp.float32)
-                 * vsbuf[slot][:, None]).astype(vbuf.dtype)
-            return 0
-
-        jax.lax.fori_loop(0, n_pages, page_step, 0)
-
-        def zero_tail(j, _):
-            vbuf[pl.ds(j * jnp.int32(ps), ps), :] = jnp.zeros(
-                (ps, vbuf.shape[1]), vbuf.dtype)
-            return 0
-
-        jax.lax.fori_loop(n_pages, jnp.int32(max_pages), zero_tail, 0)
-
-        cols = jax.lax.broadcasted_iota(jnp.int32, (span, rows_total), 1)
-        qpos = jax.lax.broadcasted_iota(jnp.int32, (span, rows_total),
-                                        0) // jnp.int32(groups)
-        valid = (cols < kv_len) & (cols <= kv_len - q_len + qpos)
-        logits = jnp.where(valid, lbuf[:], jnp.float32(-1e30))
-        # probs round to the model dtype like the unquantized kernel (and
-        # the gather path's softmax(...).astype(q.dtype)) — vbuf already
-        # holds model-dtype dequantized rows, so the value product is the
-        # same arithmetic the gather einsum runs
-        probs = jax.nn.softmax(logits, axis=-1).astype(vbuf.dtype)
-        out = jax.lax.dot_general(probs, vbuf[:], (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
+            logits = jnp.where(valid, logits, jnp.float32(-1e30))
+            # probs round to the model dtype like the gather path's
+            # softmax(...).astype(q.dtype)
+            probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+            out = jax.lax.dot_general(
+                probs, jnp.where(live, v, jnp.zeros_like(v)),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            o_ref[0, h] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
@@ -352,55 +270,40 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
     qh = q.reshape(B, q_max, KV, groups, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, KV, span, hd)
 
-    body = _kernel_body_quant if quant else _kernel_body
+    heads = _head_block(KV)
+    rows_total = max_pages * ps
     kernel = functools.partial(
-        body, page_size=ps, max_pages=max_pages, groups=groups,
-        q_max=q_max, scale=scale)
-    in_specs = [
-        pl.BlockSpec((1, 1, span, hd), lambda b, k, *_: (b, k, _i0, _i0)),
-        pl.BlockSpec(memory_space=pl.ANY),   # K pool stays in HBM;
-        pl.BlockSpec(memory_space=pl.ANY),   # live pages are DMA'd
-    ]
+        _kernel_body, page_size=ps, max_pages=max_pages, groups=groups,
+        q_max=q_max, heads=heads, scale=scale, quant=quant)
+    q_block = pl.BlockSpec((1, heads, span, hd),
+                           lambda b, k, *_: (b, k, _i0, _i0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)   # pools stay in HBM; live
+    in_specs = [q_block, hbm, hbm]            # pages are DMA'd
+    scratch = [pltpu.VMEM((rows_total, heads, hd), k_pool.dtype),   # K run
+               pltpu.VMEM((rows_total, heads, hd), v_pool.dtype)]   # V run
+    operands = (qh, k_pool, v_pool)
     if quant:
-        scratch = [
-            pltpu.VMEM((2, ps, hd), k_pool.dtype),           # K payload dbuf
-            pltpu.VMEM((2, ps), jnp.float32),                # K scale dbuf
-            pltpu.VMEM((2, ps, hd), v_pool.dtype),           # V payload dbuf
-            pltpu.VMEM((2, ps), jnp.float32),                # V scale dbuf
-            pltpu.VMEM((max_pages * ps, hd), q.dtype),       # V dequant run
-            #              (model dtype: rounds like the gather's decode)
-            pltpu.VMEM((span, max_pages * ps), jnp.float32),  # logits
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),   # K scales
-                     pl.BlockSpec(memory_space=pl.ANY)]   # V scales
-        operands = (qh, k_pool, v_pool, k_scale.astype(jnp.float32),
-                    v_scale.astype(jnp.float32))
-    else:
-        scratch = [
-            pltpu.VMEM((2, ps, hd), k_pool.dtype),          # K double buffer
-            pltpu.VMEM((max_pages * ps, hd), v_pool.dtype),  # V, contiguous
-            pltpu.VMEM((span, max_pages * ps), jnp.float32),  # logits
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-        operands = (qh, k_pool, v_pool)
+        in_specs += [hbm, hbm]                                  # scales
+        scratch += [pltpu.VMEM((rows_total, heads), jnp.float32)] * 2
+        operands += (k_scale.astype(jnp.float32),
+                     v_scale.astype(jnp.float32))
+    scratch.append(pltpu.SemaphoreType.DMA((len(operands) - 1,)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, KV),
+        grid=(B, KV // heads),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, span, hd),
-                               lambda b, k, *_: (b, k, _i0, _i0)),
+        out_specs=q_block,
         scratch_shapes=scratch,
     )
+    # the K and V runs hold a slot's whole context: sublane-padded to a
+    # bf16 tile they outgrow the 16 MiB default scoped limit near 1k rows
+    run_bytes = 2 * rows_total * max(heads, 16) * hd * k_pool.dtype.itemsize
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, span, hd), q.dtype),
         compiler_params=(None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"))),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=min(max(2 * run_bytes, 32 << 20), 100 << 20))),
         interpret=interpret,
     )(block_table.astype(jnp.int32), q_lens.astype(jnp.int32),
       kv_lens.astype(jnp.int32), *operands)

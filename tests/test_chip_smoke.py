@@ -90,16 +90,9 @@ def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases,
     if "serve" in phases:
         serve = lines[2]
         assert serve["compared_in"] == "float32"    # both passes walked
-        assert serve["float32"]["tokens_equal_llama_generate"] == "64/64"
-
-
-def test_ragged_layout_must_raise_on_the_chip(monkeypatch):
-    """The serve phase's chip-only branch: the engine refuses
-    kv_layout="ragged" at construction, naming the geometry."""
-    from paddle_tpu.models import LlamaConfig
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    verdict = cs.ragged_verdict(LlamaConfig.tiny(), {}, on_tpu=True)
-    assert verdict.startswith("raises: ") and "page_size=16" in verdict
+        for layout in ("paged", "ragged"):
+            facts = serve["float32"][layout]
+            assert facts["tokens_equal_llama_generate"] == "64/64", layout
 
 
 class TestCompileCachePlacement:

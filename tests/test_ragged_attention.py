@@ -145,18 +145,22 @@ class TestRaggedKernel:
         assert (out[2] == 0).all()               # skipped slot: zeros
         assert np.isfinite(out[:2, :12]).all()   # NaN pool never leaked
 
-    @pytest.mark.parametrize("head_dim,page_size,kv_dtype", [
-        (128, 16, None), (128, 128, None), (128, 8, None), (64, 8, None),
-        (128, 16, "int8")])
-    def test_supported_says_what_the_compiler_says(self, head_dim, page_size,
-                                                   kv_dtype):
-        """Interpret mode always can; the compiled path is refused at every
-        geometry until Mosaic accepts one (tests/test_tpu_compile.py holds
-        the compiler's verdicts this must agree with)."""
-        assert ra.supported(head_dim, page_size, interpret=True,
-                            kv_dtype=kv_dtype)
-        assert not ra.supported(head_dim, page_size, interpret=False,
-                                kv_dtype=kv_dtype)
+    @pytest.mark.parametrize("geometry,compiles", [
+        (dict(head_dim=128, page_size=16, kv_heads=32, max_len=1024), True),
+        (dict(head_dim=128, page_size=5, kv_heads=8, max_len=4096), True),
+        (dict(head_dim=256, page_size=128, kv_heads=4, max_len=512), True),
+        (dict(head_dim=64, page_size=16, kv_heads=32, max_len=1024), False),
+        (dict(head_dim=128, page_size=16, kv_heads=12, max_len=1024), False),
+        (dict(head_dim=128, page_size=16, kv_heads=1, max_len=1024), False),
+        (dict(head_dim=128, page_size=16, kv_heads=32, max_len=8192), False),
+        (dict(head_dim=128, page_size=16, kv_heads=32, max_len=1024,
+              kv_dtype="int8"), False)])
+    def test_supported_says_what_the_compiler_says(self, geometry, compiles):
+        """Interpret mode always can; the compiled path follows the rules
+        Mosaic was seen to enforce (tests/test_tpu_compile.py compiles a
+        case on each side of every rule and must agree with this)."""
+        assert ra.supported(interpret=True, **geometry)
+        assert ra.supported(interpret=False, **geometry) is compiles
 
 
 # ---------------------------------------------------------------- serving
@@ -201,14 +205,16 @@ class TestRaggedServingParity:
     def test_uncompilable_pool_raises_on_tpu(self, small_model, monkeypatch,
                                              kv_dtype):
         """On a TPU the kernel must be compiled, and the compiler refuses
-        it: an explicit kv_layout="ragged" raises, naming the geometry —
-        it never serves through the gather or the interpreter unasked.
+        this pool (head_dim 16; quantized pages): an explicit
+        kv_layout="ragged" raises, naming the geometry — it never serves
+        through the gather or the interpreter unasked.
         PADDLE_RAGGED_ATTN=0 stays the one explicit way to the gather."""
         cfg, params = small_model
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         with pytest.raises(ValueError) as err:
             _engine(cfg, params, kv_layout="ragged", kv_dtype=kv_dtype)
-        for part in (f"head_dim={cfg.head_dim}", "page_size=8",
+        for part in (f"head_dim={cfg.head_dim}", "page_size=8", "max_len=96",
+                     f"kv_heads={cfg.num_key_value_heads}",
                      f"kv_dtype={kv_dtype!r}", "PADDLE_RAGGED_ATTN=0"):
             assert part in str(err.value)
         monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")

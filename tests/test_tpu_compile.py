@@ -31,18 +31,25 @@ TRAIN_QKV = (cs.TRAIN_BATCH, cs.TRAIN_SEQ, HEADS, HEAD_DIM)   # [B, T, H, D]
 PREFILL_BUCKETS = cs.SERVE_PROMPT_BUCKETS
 SERVE_BATCH, SERVE_MAX_LEN = cs.SERVE_MAX_BATCH, cs.SERVE_MAX_LEN
 
-# what Mosaic says to the ragged kernel (jax 0.9.0, libtpu 0.0.34)
-REFUSAL_STORE = ("cannot statically prove that index in dimension 1 is a "
-                 "multiple of 128")
-REFUSAL_SLICE = ("Slice shape along dimension 2 must be aligned to tiling "
-                 "(8), but is 1")
-# (q rows, kv heads, page_size, the compiler's message); page_size 16 is
-# the batcher's default, 128 is where the first refusal gives way
+# the ragged kernel at the serve phase's geometry (page_size 16 is the
+# batcher's default), then one case past each rule of ra.supported(): the
+# value is the compiler's message, None where it compiles
+_SERVE = dict(q_rows=1, kv_heads=HEADS, head_dim=HEAD_DIM, page_size=16,
+              kv_dtype=None)
 RAGGED_CASES = {
-    "decode": (1, HEADS, 16, REFUSAL_STORE),
-    "decode_gqa": (1, 8, 16, REFUSAL_STORE),
-    "prefill": (PREFILL_BUCKETS[0], HEADS, 16, REFUSAL_STORE),
-    "decode_page128": (1, HEADS, 128, REFUSAL_SLICE),
+    "decode": (_SERVE, None),
+    "prefill": ({**_SERVE, "q_rows": PREFILL_BUCKETS[-1]}, None),
+    "decode_gqa": ({**_SERVE, "kv_heads": 8}, None),
+    "decode_kv_heads_4": ({**_SERVE, "kv_heads": 4}, None),
+    "head_dim_64": ({**_SERVE, "head_dim": 64},
+                    "Slice shape along dimension 3 must be aligned to "
+                    "tiling (128), but is 64"),
+    "kv_heads_12": ({**_SERVE, "kv_heads": 12},
+                    "Slice shape along dimension 2 must be aligned to "
+                    "tiling (8), but is 12"),
+    "int8_pages": ({**_SERVE, "kv_dtype": "int8"},
+                   "Slice shape along dimension 2 must be aligned to "
+                   "tiling (128), but is 16"),
 }
 
 
@@ -136,43 +143,41 @@ class TestFlashCompiles:
         assert _kernels(c) == 3
 
 
-def _ragged_lowered(q_rows, kv_heads, page_size, sharding):
+def _ragged_lowered(sharding, q_rows, kv_heads, head_dim, page_size, kv_dtype):
     max_pages = SERVE_MAX_LEN // page_size
     pool_pages = SERVE_BATCH * max_pages + 1
+    q_heads = max(HEADS // kv_heads, 1) * kv_heads
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    pool = sds((pool_pages, page_size, kv_heads, HEAD_DIM), jnp.bfloat16)
+    pool = sds((pool_pages, page_size, kv_heads, head_dim),
+               jnp.bfloat16 if kv_dtype is None else jnp.dtype(kv_dtype))
+    scales = {}
+    if kv_dtype is not None:
+        scale = sds((pool_pages, page_size, kv_heads), jnp.float32)
+        scales = dict(k_scale=scale, v_scale=scale)
     lens = sds((SERVE_BATCH,), jnp.int32)
     return ra.ragged_paged_attention.lower(
-        sds((SERVE_BATCH, q_rows, HEADS, HEAD_DIM), jnp.bfloat16), pool, pool,
-        sds((SERVE_BATCH, max_pages), jnp.int32), lens, lens,
-        page_size=page_size, interpret=False)
+        sds((SERVE_BATCH, q_rows, q_heads, head_dim), jnp.bfloat16), pool,
+        pool, sds((SERVE_BATCH, max_pages), jnp.int32), lens, lens,
+        page_size=page_size, interpret=False, **scales)
 
 
-class TestRaggedKernelVerdict:
-    """Strict xfails: the day the kernel compiles, the suite says so — and
-    ``supported()`` and ``ContinuousBatcher(kv_layout='ragged')`` then
-    change with it."""
-
-    @pytest.mark.parametrize("case", [
-        pytest.param(name, marks=pytest.mark.xfail(
-            strict=True, reason=f"Mosaic refuses the ragged kernel: {msg}"))
-        for name, (_, _, _, msg) in RAGGED_CASES.items()])
-    def test_compiles_at_serve_geometry(self, one_chip, no_compile_cache,
-                                        case):
-        q_rows, kv_heads, page_size, _ = RAGGED_CASES[case]
-        _ragged_lowered(q_rows, kv_heads, page_size, one_chip).compile()
-
-    @pytest.mark.parametrize("case", sorted(RAGGED_CASES))
-    def test_refusal_is_the_recorded_one(self, one_chip, no_compile_cache,
-                                         case):
-        """The reason on the xfail above is the compiler's own message —
-        and supported() says what the compiler says."""
-        q_rows, kv_heads, page_size, msg = RAGGED_CASES[case]
-        with pytest.raises(Exception) as err:
-            _ragged_lowered(q_rows, kv_heads, page_size, one_chip).compile()
-        assert msg in str(err.value)
-        assert not ra.supported(HEAD_DIM, page_size, interpret=False)
-        assert msg in " ".join(ra.supported.__doc__.split())
+@pytest.mark.parametrize("case", sorted(RAGGED_CASES))
+def test_ragged_kernel_verdict(one_chip, no_compile_cache, case):
+    """The compiler's verdict on the ragged kernel, and supported() saying
+    the same: what it accepts compiles to one kernel, what it refuses is
+    refused with the message supported() records."""
+    geometry, refusal = RAGGED_CASES[case]
+    lowered = _ragged_lowered(one_chip, **geometry)
+    says = ra.supported(geometry["head_dim"], geometry["page_size"],
+                        geometry["kv_heads"], SERVE_MAX_LEN, interpret=False,
+                        kv_dtype=geometry["kv_dtype"])
+    if refusal is None:
+        assert _kernels(lowered.compile()) == 1 and says
+        return
+    with pytest.raises(Exception) as err:
+        lowered.compile()
+    assert refusal in str(err.value) and not says
+    assert refusal in " ".join(ra.supported.__doc__.split())
