@@ -207,7 +207,7 @@ def _autoscale_drill() -> dict:
             idle_windows=4, high_water=1.0, low_water=0.05,
             cooldown_s=4.0, min_replicas=1, max_replicas=2,
             drain_timeout_s=60.0).start()
-        ev0 = len(_recorder.events())
+        ev0 = _recorder.events_since(0)[1]
         for p, m in reqs:                     # the flash crowd
             deadline = _time.perf_counter() + 150.0
             while True:
@@ -238,7 +238,7 @@ def _autoscale_drill() -> dict:
                     and len(alive) == 1:
                 break
             _time.sleep(0.2)
-        ready = [e for e in _recorder.events()[ev0:]
+        ready = [e for e in _recorder.events_since(ev0)[0]
                  if e.get("kind") == "autoscale.scale_out_ready"]
         return {
             "requests": n_req,

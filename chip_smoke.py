@@ -58,10 +58,6 @@ def memory(dev) -> dict:
     return {k: stats.get(k) for k in ("bytes_in_use", "peak_bytes_in_use")}
 
 
-def free_device_memory() -> None:
-    gc.collect()    # engines and trainers hold reference cycles
-
-
 def width_summary(cfg) -> dict:
     import jax.numpy as jnp
     return {"model": MODEL, "hidden": cfg.hidden_size,
@@ -86,19 +82,27 @@ def model_config(rehearse: bool, layers: int, **kw):
 
 # ------------------------------------------------------------------ train
 
-def make_batches(cfg, batch, seq, steps, seed, workdir):
-    """Fresh batches through the input pipeline: a seeded Zipf-Markov
-    corpus on disk, cut by TokenDataLoader (native feeder when it builds)."""
+def train_inputs(args):
+    """The train phases' model and fresh batches through the input
+    pipeline: a seeded Zipf-Markov corpus on disk, cut by TokenDataLoader
+    (native feeder when it builds). Returns (cfg, batches, sizes)."""
     from paddle_tpu.io.token_loader import (TokenDataLoader, synthetic_corpus,
                                             write_token_file)
-    path = os.path.join(workdir, "corpus.u16")
+    cfg = model_config(args.rehearse, TRAIN_LAYERS)
+    batch, seq, steps = ((2, 128, 4) if args.rehearse
+                         else (TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS))
     n_tokens = max(8 * steps * batch * (seq + 1), 65536)
-    write_token_file(path, synthetic_corpus(n_tokens, cfg.vocab_size, seed))
-    loader = TokenDataLoader(path, batch, seq, seed=seed)
-    try:
-        return [next(loader) for _ in range(steps)], loader._native
-    finally:
-        loader.close()
+    with tempfile.TemporaryDirectory() as tmp:
+        path = os.path.join(tmp, "corpus.u16")
+        write_token_file(path, synthetic_corpus(n_tokens, cfg.vocab_size,
+                                                args.seed))
+        loader = TokenDataLoader(path, batch, seq, seed=args.seed)
+        try:
+            batches = [next(loader) for _ in range(steps)]
+        finally:
+            loader.close()
+    return cfg, batches, {"batch": batch, "seq": seq, "steps": steps,
+                          "native_feeder": bool(loader._native)}
 
 
 def run_trainer(cfg, mesh, batches, seed, on_tpu):
@@ -147,21 +151,17 @@ def run_trainer(cfg, mesh, batches, seed, on_tpu):
 
 
 def phase_train(args, dev) -> dict:
-    cfg = model_config(args.rehearse, TRAIN_LAYERS)
-    batch, seq, steps = ((2, 128, 4) if args.rehearse
-                         else (TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS))
-    with tempfile.TemporaryDirectory() as tmp:
-        batches, native = make_batches(cfg, batch, seq, steps, args.seed, tmp)
+    cfg, batches, sizes = train_inputs(args)
     _, facts, step = run_trainer(cfg, None, batches, args.seed,
                                  dev["platform"] == "tpu")
     del step
-    return {"config": {**width_summary(cfg), "batch": batch, "seq": seq,
-                       "steps": steps, "optimizer": "AdamW bf16 moments",
-                       "remat": True},
+    return {"config": {**width_summary(cfg), **sizes,
+                       "optimizer": "AdamW bf16 moments", "remat": True},
             "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: bf16 "
                         f"params + grads + two bf16 AdamW moments + remat "
-                        f"activations at B={batch} T={seq} fit one 16 GB chip"],
-            "native_feeder": bool(native), **facts}
+                        f"activations at B={sizes['batch']} T={sizes['seq']} "
+                        f"fit one 16 GB chip"],
+            **facts}
 
 
 # ------------------------------------------------------------------ serve
@@ -198,7 +198,7 @@ def serve(cfg, params, requests, on_tpu, kv_layout, **engine_kw):
             raise AssertionError(f"the ragged engine is not on the compiled "
                                  f"kernel: {kernel}")
     else:
-        bucket = eng._buckets[0]
+        bucket = SERVE_PROMPT_BUCKETS[0]
         kernel = {"flash_tpu_custom_calls_in_prefill": custom_calls(
             llama_paged_prefill_slot.lower(
                 params, eng._cache, jnp.zeros(bucket, jnp.int32),
@@ -251,10 +251,10 @@ def serve_both_layouts(cfg, params, requests, on_tpu, **engine_kw):
     agree on every token."""
     paged, facts_p = serve(cfg, params, requests, on_tpu, "paged",
                            **engine_kw)
-    free_device_memory()
+    gc.collect()
     ragged, facts_r = serve(cfg, params, requests, on_tpu, "ragged",
                             **engine_kw)
-    free_device_memory()
+    gc.collect()
     t0 = time.perf_counter()
     ref = reference_tokens(cfg, params, requests)
     facts_p["tokens_equal_llama_generate"] = agreement(paged, ref)
@@ -291,7 +291,7 @@ def phase_serve(args, dev) -> dict:
         # between two correct programs; the equality tier-1 pins on the CPU
         # is then decided in float32 at a depth that fits
         del params
-        free_device_memory()
+        gc.collect()
         cfg32 = model_config(args.rehearse, SERVE_F32_LAYERS,
                              dtype=jnp.float32)
         params32 = llama_init_params(cfg32, jax.random.PRNGKey(args.seed))
@@ -325,15 +325,10 @@ def phase_mesh_train(args, dev) -> dict:
     if len(devices) < 4:
         raise AssertionError(f"--chips 4 needs four devices, JAX has "
                              f"{len(devices)}")
-    cfg = model_config(args.rehearse, TRAIN_LAYERS)
-    batch, seq, steps = ((2, 128, 4) if args.rehearse
-                         else (TRAIN_BATCH, TRAIN_SEQ, TRAIN_STEPS))
-    with tempfile.TemporaryDirectory() as tmp:
-        batches, native = make_batches(cfg, batch, seq, steps, args.seed, tmp)
-
+    cfg, batches, sizes = train_inputs(args)
     single, facts1, step = run_trainer(cfg, None, batches, args.seed, on_tpu)
     del step
-    free_device_memory()
+    gc.collect()
 
     mesh = ProcessMesh(Mesh(np.asarray(devices[:4]).reshape(MESH_SHAPE),
                             MESH_AXES))
@@ -361,12 +356,12 @@ def phase_mesh_train(args, dev) -> dict:
     if not rel.max() < MESH_REL_TOL:
         raise AssertionError(f"mesh trajectory left the single-device one: "
                              f"rel {rel.tolist()} > {MESH_REL_TOL}")
-    return {"config": {**width_summary(cfg), "batch": batch, "seq": seq,
-                       "steps": steps, "mesh": dict(zip(MESH_AXES, MESH_SHAPE))},
+    return {"config": {**width_summary(cfg), **sizes,
+                       "mesh": dict(zip(MESH_AXES, MESH_SHAPE))},
             "reduced": [f"depth 32 -> {cfg.num_hidden_layers} layers: the "
                         f"single-device run it is compared with must fit one "
                         f"16 GB chip"],
-            "native_feeder": bool(native), "single": facts1, "mesh": facts4,
+            "single": facts1, "mesh": facts4,
             "max_rel_diff": float(rel.max()), "rel_tolerance": MESH_REL_TOL,
             "param_spans": spans, "bytes_in_use_per_device": per_device}
 
@@ -413,7 +408,7 @@ def main(argv=None) -> int:
             emit({"phase": name, "ok": True, **dev, **facts,
                   "phase_s": round(time.perf_counter() - t0, 2),
                   **memory(jax.devices()[0])})
-            free_device_memory()
+            gc.collect()
         if dev["platform"] != "tpu":
             raise RuntimeError("rehearsal: every phase ran, but not on a TPU")
     except Exception as e:

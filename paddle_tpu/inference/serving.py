@@ -263,8 +263,8 @@ class ContinuousBatcher:
             self._ragged = _ra.enabled()
             kv_heads = self._cfg.num_key_value_heads
             if self._ragged and not _ra.supported(
-                    self._cfg.head_dim, int(page_size), kv_heads, self.S,
-                    self._interpret, kv_dtype=self._kv_dtype):
+                    self._cfg.head_dim, kv_heads, self.S, self._interpret,
+                    kv_dtype=self._kv_dtype):
                 raise ValueError(
                     f"kv_layout='ragged' cannot run the ragged kernel on "
                     f"platform {jax.default_backend()!r} at head_dim="

@@ -78,8 +78,8 @@ def enabled() -> bool:
 _MAX_COMPILED_ROWS = 4096
 
 
-def supported(head_dim: int, page_size: int, kv_heads: int, max_len: int,
-              interpret: bool, kv_dtype: str | None = None) -> bool:
+def supported(head_dim: int, kv_heads: int, max_len: int, interpret: bool,
+              kv_dtype: str | None = None) -> bool:
     """Can this (pool, config) run the kernel? It says what the compiler
     says (jax 0.9.0 / libtpu 0.0.34, compiled for a described v5e;
     ``tests/test_tpu_compile.py`` holds a case on each side of every rule

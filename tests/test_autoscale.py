@@ -278,13 +278,13 @@ class TestChaosNeverWedges:
         act = _StubActuator()
         state = {"obs": _obs({"unified": [("r0", 9, 3, 3, True)]})}
         c = _ctl(lambda: state["obs"], act, breach_windows=2)
-        before = len(_recorder.events())
+        before = _recorder.events_since(0)[1]
         with chaos.inject("autoscale.decide:1+"):
             for _ in range(5):
                 c.tick()
         assert act.calls == []
         assert c.status()["breach"]["unified"] == 0    # frozen, not built
-        skips = [e for e in _recorder.events()[before:]
+        skips = [e for e in _recorder.events_since(before)[0]
                  if e.get("kind") == "autoscale.chaos_skip"]
         assert len(skips) == 5
         c.tick()
@@ -296,12 +296,12 @@ class TestChaosNeverWedges:
         flight record; the caller falls back to local compile/init."""
         from paddle_tpu.inference.warmstart import (fetch_warm_cache,
                                                     fetch_weights)
-        before = len(_recorder.events())
+        before = _recorder.events_since(0)[1]
         with chaos.inject("warmstart.fetch:1+"):
             assert fetch_warm_cache("127.0.0.1:9", "abc",
                                     str(tmp_path)) is None
             assert fetch_weights("127.0.0.1:9", "abc") is None
-        evs = [e for e in _recorder.events()[before:]
+        evs = [e for e in _recorder.events_since(before)[0]
                if e.get("kind") == "warmstart.fetch_failed"]
         assert len(evs) == 2
         assert metrics.counter("warmstart.fetch_failed").value >= 2
@@ -313,11 +313,11 @@ class TestChaosNeverWedges:
         state = {"obs": two}
         c = _ctl(lambda: state["obs"], act, idle_windows=1,
                  cooldown_s=3600.0, drain_timeout_s=0.0)
-        before = len(_recorder.events())
+        before = _recorder.events_since(0)[1]
         c.tick()                        # decides: drain r0 (emptiest)
         assert act.of("drain") == [("drain", "r0")]
         c.tick()                        # past the 0s deadline → stall
-        stalls = [e for e in _recorder.events()[before:]
+        stalls = [e for e in _recorder.events_since(before)[0]
                   if e.get("kind") == "autoscale.drain_stalled"]
         assert stalls and stalls[0]["replica"] == "r0"
         # the reaction to a stall is ANOTHER drain POST — never a signal

@@ -28,22 +28,22 @@ def stub_phases(monkeypatch):
         monkeypatch.setattr(cs, name, lambda args, dev: {"stub": True})
 
 
-@pytest.fixture
+@pytest.fixture(autouse=True)
 def restore_cache_dir():
+    """main() and the cache helpers point jax's cache somewhere."""
     was = jax.config.jax_compilation_cache_dir
     yield
     jax.config.update("jax_compilation_cache_dir", was)
 
 
-def test_no_tpu_fails_at_once(capsys, restore_cache_dir):
+def test_no_tpu_fails_at_once(capsys):
     rc, lines = _run(capsys, [])
     assert rc != 0
     assert [ln.get("phase") for ln in lines] == ["start", None]
     assert lines[-1]["ok"] is False and "no TPU" in lines[-1]["error"]
 
 
-def test_raising_phase_ends_nonzero(capsys, monkeypatch, stub_phases,
-                                    restore_cache_dir):
+def test_raising_phase_ends_nonzero(capsys, monkeypatch, stub_phases):
     def boom(args, dev):
         raise RuntimeError("injected")
 
@@ -57,16 +57,14 @@ def test_raising_phase_ends_nonzero(capsys, monkeypatch, stub_phases,
     assert [ln["phase"] for ln in lines[:-1]] == ["start", "train"]
 
 
-def test_passing_line_is_the_contracts(capsys, monkeypatch, stub_phases,
-                                       restore_cache_dir):
+def test_passing_line_is_the_contracts(capsys, monkeypatch, stub_phases):
     monkeypatch.setattr(cs, "device_info", lambda: PASSING["device"])
     rc, lines = _run(capsys, [])
     assert rc == 0 and lines[-1] == PASSING
     assert [ln["phase"] for ln in lines[:-1]] == ["start", "train", "serve"]
 
 
-def test_chips_4_runs_only_the_mesh_phase(capsys, monkeypatch, stub_phases,
-                                          restore_cache_dir):
+def test_chips_4_runs_only_the_mesh_phase(capsys, monkeypatch, stub_phases):
     dev = {**PASSING["device"], "count": 4}
     monkeypatch.setattr(cs, "device_info", lambda: dev)
     rc, lines = _run(capsys, ["--chips", "4"])
@@ -80,8 +78,7 @@ def test_chips_4_runs_only_the_mesh_phase(capsys, monkeypatch, stub_phases,
 @pytest.mark.parametrize("argv,phases", [
     (["--rehearse"], ["train", "serve"]),
     (["--rehearse", "--chips", "4"], ["mesh_train"])])
-def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases,
-                                                      restore_cache_dir):
+def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases):
     rc, lines = _run(capsys, argv)
     assert rc != 0 and lines[-1]["ok"] is False
     assert lines[-1]["failed_phase"] is None, lines[-1]
@@ -97,14 +94,14 @@ def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases,
 
 class TestCompileCachePlacement:
     def test_env_places_the_cache_and_code_sets_no_path(
-            self, monkeypatch, tmp_path, restore_cache_dir):
+            self, monkeypatch, tmp_path):
         jax.config.update("jax_compilation_cache_dir", None)
         monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, str(tmp_path))
         assert compile_cache.enable_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir is None
 
     def test_default_is_a_fixed_dir_inside_the_checkout(
-            self, monkeypatch, restore_cache_dir):
+            self, monkeypatch):
         monkeypatch.delenv(compile_cache.ENV_CACHE_DIR, raising=False)
         path = compile_cache.enable_compile_cache()
         root = os.path.dirname(os.path.abspath(cs.__file__))
@@ -112,7 +109,7 @@ class TestCompileCachePlacement:
         assert jax.config.jax_compilation_cache_dir == path
 
     def test_warmstart_yields_to_the_variable(self, monkeypatch, tmp_path,
-                                              restore_cache_dir, capsys):
+                                              capsys):
         from paddle_tpu.inference.warmstart import enable_jit_cache
         jax.config.update("jax_compilation_cache_dir", None)
         monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, str(tmp_path / "env"))

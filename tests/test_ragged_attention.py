@@ -146,14 +146,14 @@ class TestRaggedKernel:
         assert np.isfinite(out[:2, :12]).all()   # NaN pool never leaked
 
     @pytest.mark.parametrize("geometry,compiles", [
-        (dict(head_dim=128, page_size=16, kv_heads=32, max_len=1024), True),
-        (dict(head_dim=128, page_size=5, kv_heads=8, max_len=4096), True),
-        (dict(head_dim=256, page_size=128, kv_heads=4, max_len=512), True),
-        (dict(head_dim=64, page_size=16, kv_heads=32, max_len=1024), False),
-        (dict(head_dim=128, page_size=16, kv_heads=12, max_len=1024), False),
-        (dict(head_dim=128, page_size=16, kv_heads=1, max_len=1024), False),
-        (dict(head_dim=128, page_size=16, kv_heads=32, max_len=8192), False),
-        (dict(head_dim=128, page_size=16, kv_heads=32, max_len=1024,
+        (dict(head_dim=128, kv_heads=32, max_len=1024), True),
+        (dict(head_dim=128, kv_heads=8, max_len=4096), True),
+        (dict(head_dim=256, kv_heads=4, max_len=512), True),
+        (dict(head_dim=64, kv_heads=32, max_len=1024), False),
+        (dict(head_dim=128, kv_heads=12, max_len=1024), False),
+        (dict(head_dim=128, kv_heads=1, max_len=1024), False),
+        (dict(head_dim=128, kv_heads=32, max_len=8192), False),
+        (dict(head_dim=128, kv_heads=32, max_len=1024,
               kv_dtype="int8"), False)])
     def test_supported_says_what_the_compiler_says(self, geometry, compiles):
         """Interpret mode always can; the compiled path follows the rules

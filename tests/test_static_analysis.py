@@ -1684,10 +1684,10 @@ class TestChaosRuntimeMirror:
         from paddle_tpu.distributed.resilience import chaos
         from paddle_tpu.observability import recorder
         with chaos.inject("unrelated.site:1"):
-            before = len(recorder.events())
+            before = recorder.events_since(0)[1]
             assert chaos.hit("never.registered") == 1  # no raise
             assert chaos.hit("never.registered") == 2
-            evs = [e for e in recorder.events()[before:]
+            evs = [e for e in recorder.events_since(before)[0]
                    if e.get("kind") == "chaos.unregistered_site"]
             assert len(evs) == 1
             assert evs[0]["site"] == "never.registered"
@@ -1696,9 +1696,9 @@ class TestChaosRuntimeMirror:
         from paddle_tpu.distributed.resilience import chaos
         from paddle_tpu.observability import recorder
         with chaos.inject("unrelated.site:1"):
-            before = len(recorder.events())
+            before = recorder.events_since(0)[1]
             chaos.hit("serve.burst")
-            evs = [e for e in recorder.events()[before:]
+            evs = [e for e in recorder.events_since(before)[0]
                    if e.get("kind") == "chaos.unregistered_site"]
             assert evs == []
 

@@ -171,8 +171,8 @@ def test_ragged_kernel_verdict(one_chip, no_compile_cache, case):
     refused with the message supported() records."""
     geometry, refusal = RAGGED_CASES[case]
     lowered = _ragged_lowered(one_chip, **geometry)
-    says = ra.supported(geometry["head_dim"], geometry["page_size"],
-                        geometry["kv_heads"], SERVE_MAX_LEN, interpret=False,
+    says = ra.supported(geometry["head_dim"], geometry["kv_heads"],
+                        SERVE_MAX_LEN, interpret=False,
                         kv_dtype=geometry["kv_dtype"])
     if refusal is None:
         assert _kernels(lowered.compile()) == 1 and says

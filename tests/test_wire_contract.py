@@ -450,7 +450,7 @@ class TestAdminRouteMirror:
     the warn-once/never-raise contract chaos.hit keeps for sites."""
 
     def _mirror_events(self, since):
-        return [e for e in _recorder.events()[since:]
+        return [e for e in _recorder.events_since(since)[0]
                 if e.get("kind") == "admin.unregistered_route"]
 
     def test_registry_is_armed_by_serving_import(self):
@@ -469,7 +469,7 @@ class TestAdminRouteMirror:
         srv.start()
         try:
             base = f"http://127.0.0.1:{srv.port}"
-            before = len(_recorder.events())
+            before = _recorder.events_since(0)[1]
             st, body, _ = _req(base, "/zzz_undeclared", token=False)
             assert st == 200 and json.loads(body)["ok"] is True  # served!
             st, _, _ = _req(base, "/zzz_undeclared", token=False)
@@ -485,7 +485,7 @@ class TestAdminRouteMirror:
         srv.start()
         try:
             base = f"http://127.0.0.1:{srv.port}"
-            before = len(_recorder.events())
+            before = _recorder.events_since(0)[1]
             for path in ("/health", "/metrics", "/snapshot", "/flight"):
                 st, _, _ = _req(base, path, token=False)
                 assert st == 200
