@@ -8,11 +8,21 @@ LoC of CUDA kernels / allocators / stream executors are replaced by XLA.
 """
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.time_ns()     # the span clock: import.paddle_tpu, below
+
 # Full dtype surface (int64/float64) as the reference has. Hot paths pass
 # explicit f32/bf16/i32 dtypes, so TPU compute is unaffected by x64 mode.
 import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
+
+# every later compilation (the rest of this import's included) becomes a
+# compile.* span: three listeners registered, nothing started or compiled
+from .observability import spans as _spans
+
+_spans.watch_compiles()
 
 # -- core ---------------------------------------------------------------
 from .core import dtypes as _dtypes
@@ -122,3 +132,8 @@ def __getattr__(name):
     if name in _OPS_CACHE:
         return _OPS_CACHE[name]
     raise AttributeError(f"module 'paddle_tpu' has no attribute {name!r}")
+
+
+# set-up's first phase as a span: this file's first line to its last
+_spans.add_span("import.paddle_tpu", "setup", _IMPORT_T0 * 1e-9,
+                _spans.now())

@@ -70,7 +70,17 @@ Metrics published (observability.metrics): ``serve.pages_in_use`` gauge,
 ``serve.tokens`` / ``serve.requests`` / ``serve.admission_stalls`` /
 ``serve.preemptions`` / ``serve.chaos_retired`` counters,
 ``serve.tokens_per_s`` and ``serve.kv_read_mb_per_tok`` gauges,
-``serve.burst_time_s`` histogram.
+``serve.burst_time_s`` histogram, ``serve.prefill_tokens_real`` /
+``serve.prefill_tokens_padded`` counters (what a prompt bucket pads).
+
+Spans (observability.spans, on the device trace's clock): every ``step()``
+is one ``serve.step`` (args: burst number, live slots) whose children are
+``serve.dispatch_burst`` (page growth, block table, transfers, the async
+launch), ``serve.admit`` (pop, bucket, allocate, prefill dispatch; under
+the in-flight burst on the paged path; arg: prefills staged),
+``serve.readback`` (the step's one blocking ``device_get``) and
+``serve.merge`` (the host bookkeeping after it). The ragged and dense loops
+use the same four names. Construction is one ``serve.init``.
 
 Request-level SLO observability (ISSUE 6 tentpole): every request gets a
 process-unique trace id at enqueue and its lifecycle edges
@@ -104,8 +114,8 @@ import numpy as np
 
 from ..distributed.resilience import chaos
 from ..observability import (exporters as _exporters, fleet as _fleet,
-                             metrics, slo as _slo, triggers as _triggers,
-                             xplane as _xplane)
+                             metrics, slo as _slo, spans as _spans,
+                             triggers as _triggers, xplane as _xplane)
 from .admission import AdmissionPolicy, reject as _admission_reject, \
     retry_after_floor, slo_hists
 from .paging import (PageAllocator, SCRATCH_PAGE, default_page_buckets,
@@ -165,6 +175,7 @@ class ContinuousBatcher:
     count, prompt mix, context lengths, and admission order.
     """
 
+    @_spans.traced("serve.init", cat="setup")
     def __init__(self, model_config, params, max_batch: int = 4,
                  max_len: int = 512,
                  prompt_buckets: Sequence[int] = (32, 64, 128, 256),
@@ -349,6 +360,7 @@ class ContinuousBatcher:
             self._page_tbl: list[list[int]] = [[] for _ in range(self.B)]
             self._admit_seq = [0] * self.B
             self._seq = 0
+            self._kv_read_bucket = None  # page bucket the gauge was set at
             if self._ragged:
                 # decode-only bursts (the steady state) reuse these
                 # device-resident empty-admission inputs instead of
@@ -386,6 +398,10 @@ class ContinuousBatcher:
             k=spec_k, draft_layers=spec_draft_layers)
         del spec_src
 
+        # what a prompt bucket pads: real and padded prompt tokens of
+        # every bucketed prefill dispatched (the ragged path has no bucket)
+        self._pf_real = metrics.counter("serve.prefill_tokens_real")
+        self._pf_padded = metrics.counter("serve.prefill_tokens_padded")
         self._queue: deque[ServedRequest] = deque()
         self._finished: dict[int, ServedRequest] = {}
         # disagg (ISSUE 11): pages parked between a prefill_only retire
@@ -804,6 +820,8 @@ class ContinuousBatcher:
             slot = self._slot_req.index(None)
             tlen = len(req.prompt)
             tb = self._bucket_len(tlen)
+            self._pf_real.inc(tlen)
+            self._pf_padded.inc(tb)
             toks = np.full(tb, self.pad_id, np.int32)
             toks[:tlen] = req.prompt
             self._key, sub = jax.random.split(self._key)
@@ -913,9 +931,11 @@ class ContinuousBatcher:
         if P not in self.stats["page_buckets_used"]:
             self.stats["page_buckets_used"] = sorted(
                 self.stats["page_buckets_used"] + [P])
-        metrics.gauge("serve.kv_read_mb_per_tok").set(
-            paged_kv_bytes_per_token(self._cfg, P, self._ps,
-                                     kv_dtype=self._kv_dtype) / 1e6)
+        if P != self._kv_read_bucket:   # the gather reads the whole bucket
+            self._kv_read_bucket = P
+            metrics.gauge("serve.kv_read_mb_per_tok").set(
+                paged_kv_bytes_per_token(self._cfg, P, self._ps,
+                                         kv_dtype=self._kv_dtype) / 1e6)
         bt = np.full((self.B, P), SCRATCH_PAGE, np.int32)
         for b in active:
             ids = self._page_tbl[b]
@@ -1122,6 +1142,8 @@ class ContinuousBatcher:
                 continue
             pages = self._alloc.alloc(need)
             suffix = tlen - matched
+            self._pf_real.inc(suffix)
+            self._pf_padded.inc(tb)
             toks = np.full(tb, self.pad_id, np.int32)
             toks[:suffix] = req.prompt[matched:]
             self._key, sub = jax.random.split(self._key)
@@ -1202,9 +1224,17 @@ class ContinuousBatcher:
         slots."""
         if inflight is None and not staged and not installed:
             return 0
-        burst_vals, firsts = jax.device_get(
-            (inflight[1:] if inflight else (),
-             [f for *_, f in staged]))
+        with _spans.span("serve.readback", cat="serve"):
+            burst_vals, firsts = jax.device_get(
+                (inflight[1:] if inflight else (),
+                 [f for *_, f in staged]))
+        with _spans.span("serve.merge", cat="serve"):
+            return self._merge_paged(inflight, staged, installed,
+                                     burst_vals, firsts)
+
+    def _merge_paged(self, inflight, staged, installed, burst_vals,
+                     firsts) -> int:
+        """The host bookkeeping after the step's readback."""
         emitted_total = 0
         staged_slots = {s for _, s, _, _ in staged} \
             | {e[1] for e in installed}
@@ -1423,8 +1453,13 @@ class ContinuousBatcher:
         pure host bookkeeping."""
         if inflight is None:
             return 0
-        old_pos = inflight[0]
-        pos, tok, done, emitted, firsts = jax.device_get(inflight[1:])
+        with _spans.span("serve.readback", cat="serve"):
+            vals = jax.device_get(inflight[1:])
+        with _spans.span("serve.merge", cat="serve"):
+            return self._merge_ragged(inflight[0], staged, *vals)
+
+    def _merge_ragged(self, old_pos, staged, pos, tok, done, emitted,
+                      firsts) -> int:
         self._pos = np.array(pos)    # device_get views are read-only;
         self._tok = np.array(tok)    # admissions write these in place
         self._done = np.array(done)
@@ -1453,8 +1488,11 @@ class ContinuousBatcher:
         executable — lower TTFT than the overlap schedule's next-burst
         landing), and the single blocking readback follows the dispatch."""
         t0 = _slo.now()
-        staged = self._admit_ragged()
-        inflight = self._dispatch_ragged(staged)
+        with _spans.span("serve.admit", cat="serve") as sp:
+            staged = self._admit_ragged()
+            sp.args = {"prefills": len(staged)}
+        with _spans.span("serve.dispatch_burst", cat="serve"):
+            inflight = self._dispatch_ragged(staged)
         emitted = self._sync_merge_ragged(inflight, staged)
         dt = _slo.now() - t0
         metrics.histogram("serve.burst_time_s").observe(dt)
@@ -1614,44 +1652,58 @@ class ContinuousBatcher:
         through draft-propose + one-launch verify instead of the scanned
         burst — same tokens, more of them per launch.
         """
-        if self._cancels or self._deadlines_seen:
-            # request reliability (ISSUE 19): apply cancels + expire
-            # deadlines before any scheduling — guarded so a fleet with
-            # neither feature in play pays two attribute reads
-            self._lifecycle_pass()
-        if self._admission is not None:
-            # graceful degradation under forced overload (router failover
-            # can push past the cap): shed newest-queued first, never wedge
-            cap = self._admission.max_queue_for(self.B)
-            if len(self._queue) > cap:
-                self.shed_newest(len(self._queue) - cap)
-        if self._spec_applicable() and self._try_step_spec():
-            pass                      # spec step served this iteration
-        elif self._ragged:
-            self._step_ragged()
-        elif self._layout == "paged":
-            t0 = _slo.now()  # the sanctioned request-timing clock (lint O4)
-            inflight = self._dispatch_burst_paged()
-            staged, installed = self._admit_paged()
-            emitted = self._sync_merge_paged(inflight, staged, installed)
-            dt = _slo.now() - t0
-            metrics.histogram("serve.burst_time_s").observe(dt)
-            if emitted and dt > 0:
-                metrics.gauge("serve.tokens_per_s").set(emitted / dt)
-        else:
-            self._step_dense()
-        # fleet heartbeat (env-gated, interval-paced, loss-tolerant): the
-        # rank-0 aggregator sees live serve.* gauges between bursts too
-        _fleet.maybe_push(self.stats["decode_steps"])
-        # device-trace window state machine: an env window or a
-        # trigger/fleet-armed window opens at the next burst boundary
-        _xplane.maybe_step(self.stats["bursts"])
-        if self._triggers is not None:
-            self._triggers.poll()
+        # one span, no frame of its own: the programs are first traced and
+        # lowered under this call, and on the chip the lowering of the
+        # unrolled burst program slows steeply with every Python frame
+        # between the harness and the jitted call (PERF.md, PR 27)
+        with _spans.span("serve.step", cat="serve",
+                         burst=self.stats["bursts"],
+                         live=self.B - self._slot_req.count(None)):
+            if self._cancels or self._deadlines_seen:
+                # request reliability (ISSUE 19): apply cancels + expire
+                # deadlines before any scheduling — guarded so a fleet with
+                # neither feature in play pays two attribute reads
+                self._lifecycle_pass()
+            if self._admission is not None:
+                # graceful degradation under forced overload (router failover
+                # can push past the cap): shed newest-queued first, never wedge
+                cap = self._admission.max_queue_for(self.B)
+                if len(self._queue) > cap:
+                    self.shed_newest(len(self._queue) - cap)
+            if self._spec_applicable() and self._try_step_spec():
+                pass                      # spec step served this iteration
+            elif self._ragged:
+                self._step_ragged()
+            elif self._layout == "paged":
+                t0 = _slo.now()     # the request-timing clock (lint O4)
+                with _spans.span("serve.dispatch_burst", cat="serve"):
+                    inflight = self._dispatch_burst_paged()
+                with _spans.span("serve.admit", cat="serve") as sp:
+                    real0, padded0 = self._pf_real.value, self._pf_padded.value
+                    staged, installed = self._admit_paged()
+                    sp.args = {"prefills": len(staged),
+                               "real": self._pf_real.value - real0,
+                               "padded": self._pf_padded.value - padded0}
+                emitted = self._sync_merge_paged(inflight, staged, installed)
+                dt = _slo.now() - t0
+                metrics.histogram("serve.burst_time_s").observe(dt)
+                if emitted and dt > 0:
+                    metrics.gauge("serve.tokens_per_s").set(emitted / dt)
+            else:
+                self._step_dense()
+            # fleet heartbeat (env-gated, interval-paced, loss-tolerant): the
+            # rank-0 aggregator sees live serve.* gauges between bursts too
+            _fleet.maybe_push(self.stats["decode_steps"])
+            # device-trace window state machine: an env window or a
+            # trigger/fleet-armed window opens at the next burst boundary
+            _xplane.maybe_step(self.stats["bursts"])
+            if self._triggers is not None:
+                self._triggers.poll()
 
     def _step_dense(self):
         from ..models.llama_decode import llama_decode_burst
-        self._admit_dense()
+        with _spans.span("serve.admit", cat="serve"):
+            self._admit_dense()
         if all(r is None for r in self._slot_req):
             return
         try:
@@ -1664,22 +1716,28 @@ class ContinuousBatcher:
             sum(r is not None for r in self._slot_req))
         old_pos = self._pos.copy()
         t0 = _slo.now()
-        self._key, sub = jax.random.split(self._key)
-        (self._cache, pos_d, tok_d, done_d, emitted) = llama_decode_burst(
-            self._params, self._cache, jnp.asarray(self._pos),
-            jnp.asarray(self._tok), jnp.asarray(self._done),
-            jnp.asarray(self._limit), jnp.int32(self.eos_id), sub,
-            config=self._cfg, n=self.burst, temperature=self._temp,
-            top_k=self._top_k, pad_id=self.pad_id, dequant=self._dequant)
-        self.stats["bursts"] += 1
-        self.stats["decode_steps"] += self.burst
+        with _spans.span("serve.dispatch_burst", cat="serve"):
+            self._key, sub = jax.random.split(self._key)
+            (self._cache, pos_d, tok_d, done_d, emitted) = \
+                llama_decode_burst(
+                    self._params, self._cache, jnp.asarray(self._pos),
+                    jnp.asarray(self._tok), jnp.asarray(self._done),
+                    jnp.asarray(self._limit), jnp.int32(self.eos_id), sub,
+                    config=self._cfg, n=self.burst, temperature=self._temp,
+                    top_k=self._top_k, pad_id=self.pad_id,
+                    dequant=self._dequant)
+            self.stats["bursts"] += 1
+            self.stats["decode_steps"] += self.burst
         # ONE host sync for the whole burst result
-        pos, tok, done, emitted = jax.device_get(
-            (pos_d, tok_d, done_d, emitted))
-        self._pos = np.array(pos)    # device_get views are read-only;
-        self._tok = np.array(tok)    # admissions write these in place
-        self._done = np.array(done)
-        emitted_total = self._drain_burst(old_pos, done, np.asarray(emitted))
+        with _spans.span("serve.readback", cat="serve"):
+            pos, tok, done, emitted = jax.device_get(
+                (pos_d, tok_d, done_d, emitted))
+        with _spans.span("serve.merge", cat="serve"):
+            self._pos = np.array(pos)    # device_get views are read-only;
+            self._tok = np.array(tok)    # admissions write these in place
+            self._done = np.array(done)
+            emitted_total = self._drain_burst(old_pos, done,
+                                              np.asarray(emitted))
         dt = _slo.now() - t0
         metrics.histogram("serve.burst_time_s").observe(dt)
         metrics.counter("serve.tokens").inc(emitted_total)

@@ -14,6 +14,8 @@ import warnings
 
 import numpy as np
 
+from ..observability import spans as _spans
+
 __all__ = ["TokenDataLoader", "write_token_file"]
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
@@ -112,6 +114,7 @@ class TokenDataLoader:
     def __iter__(self):
         return self
 
+    @_spans.traced("loader.next", cat="data")
     def __next__(self):
         if self._native:
             rc = self._lib.ptdf_next(self._h, self._buf)

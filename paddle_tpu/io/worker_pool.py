@@ -150,8 +150,10 @@ class WorkerPool:
         epoch = self._epoch
         _recorder.record("io.epoch", epoch=epoch, batches=n,
                          workers=self.num_workers)
+        # ended when the generator is exhausted or collected, not by this
+        # frame: never the parent of what the consumer does between yields
         epoch_span = _spans.span("io.epoch", cat="data", epoch=epoch,
-                                 batches=n).begin()
+                                 batches=n).begin(nest=False)
         submitted = 0
         pending: dict = {}
         nxt = 0

@@ -35,6 +35,7 @@ from ..core import dtypes as _dt
 from ..core.engine import apply
 from ..core.tensor import Parameter, Tensor
 from ..nn.layer.layers import Layer
+from ..observability import spans as _spans
 
 __all__ = ["LlamaConfig", "llama_init_params", "llama_forward", "llama_loss",
            "LlamaForCausalLM", "shard_llama_params", "llama_param_specs"]
@@ -332,6 +333,13 @@ def _decoder_layer(x, lp, config, mesh, positions):
         return v
 
     x = cst(x, "btd_seq")  # Megatron-SP: residual stream sharded on seq
+    with jax.named_scope("attn"):
+        x = _attn_block(x, lp, c, mesh, mesh_axes, positions, cst)
+    with jax.named_scope("mlp"):
+        return _mlp_block(x, lp, c)
+
+
+def _attn_block(x, lp, c, mesh, mesh_axes, positions, cst):
     h = _rmsnorm(x, lp["ln1"], c.rms_norm_eps)
     B, T, D = h.shape
     q = (h @ lp["wq"]).reshape(B, T, c.num_attention_heads, c.head_dim)
@@ -356,8 +364,10 @@ def _decoder_layer(x, lp, config, mesh, positions):
     from jax.ad_checkpoint import checkpoint_name
     att = checkpoint_name(att, "flash_out")
     x = x + (att.reshape(B, T, -1) @ lp["wo"])
-    x = cst(x, "btd_seq")
+    return cst(x, "btd_seq")
 
+
+def _mlp_block(x, lp, c):
     h2 = _rmsnorm(x, lp["ln2"], c.rms_norm_eps)
     if c.num_experts > 0:
         moe_out, aux = _moe_block(h2, lp["gate_w"], lp["moe_w_gate"], lp["moe_w_up"],
@@ -459,9 +469,12 @@ def lm_head_logits(x, other, config: LlamaConfig):
 def llama_forward(params, tokens, config: LlamaConfig, mesh=None, remat=True):
     """tokens [B, T] int32 → logits [B, T, V] (compute dtype per config)."""
     layer_p, other = split_layer_params(params)
-    x = jnp.take(other["embed_tokens"], tokens, axis=0).astype(config.dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(other["embed_tokens"], tokens,
+                     axis=0).astype(config.dtype)
     x, aux = llama_trunk(x, layer_p, config, mesh, remat=remat)
-    return lm_head_logits(x, other, config), aux
+    with jax.named_scope("head_loss"):
+        return lm_head_logits(x, other, config), aux
 
 
 def _chunked_ce(x, head, labels, chunk):
@@ -503,18 +516,23 @@ def llama_loss(params, tokens, labels, config: LlamaConfig, mesh=None, remat=Tru
     not re-measured on the current chip)."""
     if loss_chunk:
         layer_p, other = split_layer_params(params)
-        x = jnp.take(other["embed_tokens"], tokens, axis=0).astype(config.dtype)
+        with jax.named_scope("embed"):
+            x = jnp.take(other["embed_tokens"], tokens,
+                         axis=0).astype(config.dtype)
         jm = mesh.jax_mesh if hasattr(mesh, "jax_mesh") else mesh
         x, aux = llama_trunk(x, layer_p, config, jm, remat=remat)
-        x = _rmsnorm(x, other["norm"], config.rms_norm_eps)
-        nll, n = _chunked_ce(x, resolve_head(other), labels, loss_chunk)
-        loss = nll / jnp.maximum(n, 1.0)
+        with jax.named_scope("head_loss"):
+            x = _rmsnorm(x, other["norm"], config.rms_norm_eps)
+            nll, n = _chunked_ce(x, resolve_head(other), labels, loss_chunk)
+            loss = nll / jnp.maximum(n, 1.0)
     else:
         logits, aux = llama_forward(params, tokens, config, mesh, remat)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        mask = (labels >= 0).astype(jnp.float32)
-        loss = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("head_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            ll = jnp.take_along_axis(
+                logp, labels[..., None].astype(jnp.int32), axis=-1)[..., 0]
+            mask = (labels >= 0).astype(jnp.float32)
+            loss = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     if config.num_experts > 0:
         loss = loss + aux_weight * aux
     return loss
@@ -550,7 +568,7 @@ class LlamaForCausalLM(Layer):
             return apply(f, *plist, input_ids, name="llama")
         return apply(f, *plist, input_ids, labels, name="llama")
 
-    @jax.profiler.annotate_function
+    @_spans.traced("llama.generate", cat="serve")
     def generate(self, input_ids, max_new_tokens=32, temperature=0.0, top_k=0):
         """KV-cache incremental decode: one compiled prefill + a scanned
         single-token step (O(T) per token; see models/llama_decode.py).

@@ -250,6 +250,13 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
     ``kv_dtype``: writes quantize the fresh K/V row (payload + per-head
     scale land together), the gather dequantizes payload×scale right
     after the two jnp.takes — same attention arithmetic downstream.
+
+    Device-side names (``jax.named_scope``, read by the trace reducers):
+    per layer ``kv_write`` (the per-slot row writes), ``kv_read`` (the
+    gather, its dequantize and reshape, and the masked attention over the
+    gathered rows), ``attn_out``, ``mlp``; ``head_sample`` the logits (and,
+    in the burst, the sampling). One scope a layer and phase, none inside
+    the per-slot loop, whose 48 x 24 bodies are traced in every run.
     """
     c = config
     layer_p, other = split_layer_params(params)
@@ -277,41 +284,51 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
             ku, ksr = _kv_encode(ku, kv_dtype)   # [B, KV, hd] + [B, KV]
             vu, vsr = _kv_encode(vu, kv_dtype)
             ksp, vsp = kss[l], vss[l]
-        for b in range(B):
-            at = (block_table[b, page_of[b]], row_of[b], z, z)
-            kp = jax.lax.dynamic_update_slice(kp, ku[b][None, None], at)
-            vp = jax.lax.dynamic_update_slice(vp, vu[b][None, None], at)
-            if quant:
-                ats = (block_table[b, page_of[b]], row_of[b], z)
-                ksp = jax.lax.dynamic_update_slice(
-                    ksp, ksr[b][None, None], ats)
-                vsp = jax.lax.dynamic_update_slice(
-                    vsp, vsr[b][None, None], ats)
+        with jax.named_scope("kv_write"):
+            for b in range(B):
+                at = (block_table[b, page_of[b]], row_of[b], z, z)
+                kp = jax.lax.dynamic_update_slice(kp, ku[b][None, None], at)
+                vp = jax.lax.dynamic_update_slice(vp, vu[b][None, None], at)
+                if quant:
+                    ats = (block_table[b, page_of[b]], row_of[b], z)
+                    ksp = jax.lax.dynamic_update_slice(
+                        ksp, ksr[b][None, None], ats)
+                    vsp = jax.lax.dynamic_update_slice(
+                        vsp, vsr[b][None, None], ats)
         ks[l], vs[l] = kp, vp
         if quant:
             kss[l], vss[l] = ksp, vsp
-        # gather the slot's pages into a [B, P*ps, KV, hd] view — THIS is
-        # the read whose bytes scale with the page bucket instead of S_max
-        kc = jnp.take(kp, block_table, axis=0)
-        vc = jnp.take(vp, block_table, axis=0)
-        if quant:
-            kc = _kv_decode(kc, jnp.take(ksp, block_table, axis=0), c.dtype)
-            vc = _kv_decode(vc, jnp.take(vsp, block_table, axis=0), c.dtype)
-        kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-        vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-        att = _cached_attention_slots(q, kc, vc, pos, c)
-        y = x + (att.reshape(B, 1, -1) @ lp["wo"])
-        x = _mlp(y, lp, c)
+        with jax.named_scope("kv_read"):
+            # gather the slot's pages into a [B, P*ps, KV, hd] view — THIS
+            # is the read whose bytes scale with the page bucket instead of
+            # S_max
+            kc = jnp.take(kp, block_table, axis=0)
+            vc = jnp.take(vp, block_table, axis=0)
+            if quant:
+                kc = _kv_decode(kc, jnp.take(ksp, block_table, axis=0),
+                                c.dtype)
+                vc = _kv_decode(vc, jnp.take(vsp, block_table, axis=0),
+                                c.dtype)
+            kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
+            vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
+            att = _cached_attention_slots(q, kc, vc, pos, c)
+        with jax.named_scope("attn_out"):
+            y = x + (att.reshape(B, 1, -1) @ lp["wo"])
+        with jax.named_scope("mlp"):
+            x = _mlp(y, lp, c)
 
     out = {"k": tuple(ks), "v": tuple(vs)}
     if quant:
         out["k_scale"], out["v_scale"] = tuple(kss), tuple(vss)
-    return lm_head_logits(x[:, 0, :], other, c), out
+    with jax.named_scope("head_sample"):
+        logits = lm_head_logits(x[:, 0, :], other, c)
+    return logits, out
 
 
 @functools.partial(jax.jit, static_argnames=(
     "config", "temperature", "top_k", "dequant", "kv_dtype"),
     donate_argnums=(1,))
+@jax.named_scope("prefill")
 def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
                              config: LlamaConfig,
                              temperature: float = 0.0, top_k: int = 0,
@@ -526,6 +543,7 @@ def llama_paged_prefill_suffix(params, cache, tokens, page_ids,
 @functools.partial(jax.jit, static_argnames=(
     "config", "n", "temperature", "top_k", "pad_id", "dequant", "kv_dtype"),
     donate_argnums=(1,))
+@jax.named_scope("burst")
 def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
                              limit, eos_id, key, config: LlamaConfig,
                              n: int, temperature: float = 0.0,
@@ -548,7 +566,8 @@ def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
                                                  pos, tok, config,
                                                  kv_dtype=kv_dtype)
         key, sub = jax.random.split(key)
-        nxt = _sample(logits, temperature, top_k, sub)
+        with jax.named_scope("head_sample"):
+            nxt = _sample(logits, temperature, top_k, sub)
         emit = jnp.where(done, jnp.int32(pad_id), nxt)
         new_pos = jnp.where(done, pos, pos + 1)
         new_tok = jnp.where(done, tok, nxt)

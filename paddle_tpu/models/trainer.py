@@ -29,8 +29,17 @@ __all__ = ["LlamaTrainStep"]
 
 
 class LlamaTrainStep:
-    """step = LlamaTrainStep(config, mesh, optimizer); loss = step(tokens, labels)"""
+    """step = LlamaTrainStep(config, mesh, optimizer); loss = step(tokens, labels)
 
+    Spans: construction is one ``train.init``; every call is one
+    ``train.step`` around the host's work for a step (input transfer and the
+    async dispatch; the device step is not waited for). On the device the
+    program's operations carry the scopes ``embed``, ``attn``, ``mlp``,
+    ``head_loss`` (models/llama.py) and ``optimizer``; the backward's carry
+    them under ``transpose(jvp(...))``, remat's recompute under
+    ``rematted_computation``."""
+
+    @_spans.traced("train.init", cat="setup")
     def __init__(self, config: L.LlamaConfig, mesh: ProcessMesh | None = None,
                  optimizer: Optimizer | None = None, num_microbatches: int = 1,
                  remat: bool = True, seed: int = 0, pp_schedule: str = "gpipe",
@@ -172,7 +181,9 @@ class LlamaTrainStep:
 
         def step_fn(p, opt_state, tokens, labels, lr, step_i):
             loss, grads = value_and_grad_fn(p, tokens, labels)
-            new_p, new_s = opt.apply_gradients(grads, p, opt_state, lr=lr, step=step_i)
+            with jax.named_scope("optimizer"):
+                new_p, new_s = opt.apply_gradients(grads, p, opt_state,
+                                                   lr=lr, step=step_i)
             return loss, new_p, new_s
 
         self._jitted = jax.jit(step_fn, donate_argnums=(0, 1))
@@ -189,21 +200,22 @@ class LlamaTrainStep:
             tokens = tokens._value
         if hasattr(labels, "_value"):
             labels = labels._value
-        tokens = jnp.asarray(tokens, jnp.int32)
-        labels = jnp.asarray(labels, jnp.int32)
-        if self._jm is not None:
-            sh = self.data_sharding(tokens.ndim)
-            tokens = jax.device_put(tokens, sh)
-            labels = jax.device_put(labels, sh)
         self._step_i += 1
-        # host-side dispatch time; the async device step is NOT synced here
-        # (bench/tests own their sync points — per-step host syncs would
-        # serialize the chip)
-        with _spans.span("train.step", cat="step", step=self._step_i), \
-                _metrics.timer("train.step_time_s"):
-            loss, self._params, self._opt_state = self._jitted(
-                self._params, self._opt_state, tokens, labels,
-                jnp.float32(self.optimizer.get_lr()), jnp.int32(self._step_i))
+        # the host's work for one step: input transfer and dispatch. The
+        # async device step is NOT synced here (bench/tests own their sync
+        # points — per-step host syncs would serialize the chip)
+        with _spans.span("train.step", cat="step", step=self._step_i):
+            tokens = jnp.asarray(tokens, jnp.int32)
+            labels = jnp.asarray(labels, jnp.int32)
+            if self._jm is not None:
+                sh = self.data_sharding(tokens.ndim)
+                tokens = jax.device_put(tokens, sh)
+                labels = jax.device_put(labels, sh)
+            with _metrics.timer("train.step_time_s"):
+                loss, self._params, self._opt_state = self._jitted(
+                    self._params, self._opt_state, tokens, labels,
+                    jnp.float32(self.optimizer.get_lr()),
+                    jnp.int32(self._step_i))
         _metrics.counter("train.steps").inc()
         _metrics.counter("train.tokens").inc(int(tokens.size))
         _metrics.maybe_emit_step(self._step_i)

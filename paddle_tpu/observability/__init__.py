@@ -4,10 +4,12 @@ One substrate that every layer of the runtime reports through, replacing the
 pre-PR-2 archipelago (comm_watchdog prints, resilience stderr lines, ad-hoc
 ``time.time()`` deltas, the distributed/metric island):
 
-  spans    — thread-safe span/trace API (``span("train.step")`` context
-             manager + decorator) with a near-zero-cost disabled path and
-             chrome-trace (Perfetto-compatible) JSON export that merges the
-             profiler's host events and scheduler windows.
+  spans    — THE span API (``span("train.step")`` context manager +
+             decorator, ``add_span``) on the device trace's clock: a
+             bounded in-memory ring that is always there plus a profiler
+             annotation per span; chrome-trace (Perfetto-compatible) JSON
+             export when PADDLE_TRACE_DIR / enable_tracing() asks for it;
+             one compile.* span per JAX compile-path event.
   metrics  — process-wide registry of counters / gauges / histograms
              (step time, tokens/sec, retry counts, checkpoint bytes,
              collective latency) with a ``snapshot()`` dict and an optional
@@ -40,7 +42,7 @@ pre-PR-2 archipelago (comm_watchdog prints, resilience stderr lines, ad-hoc
              captures + CAPTURE_<n>.json snapshots.
 
 Env vars:
-  PADDLE_TRACE_DIR        enable span tracing; chrome trace + FLIGHT.json
+  PADDLE_TRACE_DIR        turn the span export on; chrome trace + FLIGHT.json
                           land here (trace exported at process exit too)
   PADDLE_METRICS_SINK     path ending .jsonl or .csv: per-step metric rows
   PADDLE_FLIGHT_RECORDER  ring capacity (default 512; 0/off disables)
@@ -53,9 +55,10 @@ Env vars:
   PADDLE_ADMIN_READ_TOKEN admin GET read auth (403 without when set)
   PADDLE_TRIGGERS         0 disables trigger-driven deep capture
 
-The core modules import only the stdlib — any module in paddle_tpu
-(including the earliest-imported resilience layer) can depend on them
-without cycles (fleet/xplane resolve chaos/jax lazily, inside guarded
+The core modules import nothing of paddle_tpu (the stdlib, and in spans
+jax.profiler's TraceAnnotation) — any module in paddle_tpu (including the
+earliest-imported resilience layer) can depend on them without cycles
+(fleet/xplane resolve chaos/jax.profiler sessions lazily, inside guarded
 calls).
 """
 from __future__ import annotations

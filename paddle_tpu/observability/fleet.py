@@ -9,7 +9,7 @@ step/clock anchors — to the rank-0 launcher's ``TelemetryAggregator``.
 On top of the aggregate:
 
   * ``merged_chrome_trace`` — ONE Perfetto trace for the whole job, one
-    track per (node, rank). Per-rank ``perf_counter`` timelines are
+    track per (node, rank). Per-rank span-clock timelines are
     clock-aligned with a heartbeat-exchange offset estimate (each report
     carries a (wall, perf) anchor plus its send time; the aggregator keeps
     the MINIMUM observed send→receive skew per rank — the NTP-style
@@ -144,10 +144,11 @@ class TelemetryClient:
             "pid": os.getpid(),
             "step": None if step is None else int(step),
             "t_send": now_wall,
-            # clock anchor: perf_counter ts in span events map onto this
-            # rank's wall clock via (anchor_wall - anchor_perf)
+            # clock anchor: span-clock ts in span events map onto this
+            # rank's wall clock via (anchor_wall - anchor_perf); the span
+            # clock IS the wall clock, so the pair differs by microseconds
             "anchor_wall": now_wall,
-            "anchor_perf": time.perf_counter(),
+            "anchor_perf": spans.now(),
             "step_time": None if step_h is None else
                 {"p50": step_h["p50"], "last": step_h["last"],
                  "count": step_h["count"]},
@@ -633,7 +634,7 @@ class TelemetryAggregator:
 
     # ---- merged fleet trace ----
     def _rank_offset_s(self, rec: dict) -> float | None:
-        """perf_counter → aggregator-wall mapping for one rank: the
+        """span clock → aggregator-wall mapping for one rank: the
         report's (wall, perf) anchor plus the minimum-filter skew estimate
         (min over observed send→receive deltas ≈ clock offset + network
         floor — the heartbeat-exchange offset estimate)."""

@@ -55,7 +55,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 
-from . import metrics
+from . import metrics, slo
 from .slo import SPAN_TAXONOMY, STAGES
 
 __all__ = ["enabled", "clock_anchor", "crit_hist", "note_autoscale",
@@ -114,7 +114,7 @@ def clock_anchor() -> dict:
     into every /results and /trace_pull RESPONSE (not at publish time —
     a batch can sit in the buffer for many poll intervals, and the
     minimum filter needs t_send ≈ the moment the bytes leave)."""
-    return {"anchor_wall": time.time(), "anchor_perf": time.perf_counter(),
+    return {"anchor_wall": time.time(), "anchor_perf": slo.now(),
             "t_send": time.time()}
 
 
@@ -277,7 +277,7 @@ class RouterTraceAssembler:
             self._clocks["router"] = {
                 "min_skew": 0.0, "spread": 0.0,
                 "anchor_wall": time.time(),
-                "anchor_perf": time.perf_counter(), "samples": 1}
+                "anchor_perf": slo.now(), "samples": 1}
 
     # ------------------------------------------------- clock alignment
     def note_anchor(self, source: str, anchor: dict):

@@ -26,7 +26,7 @@ LIFECYCLE is traced, not the burst. This module is that substrate:
     (rid, trace id, dims, measured vs target) — the signal
     observability.triggers turns into an automatic XPlane capture, and the
     measurement the ROADMAP's SLO-aware admission will consume;
-  * with span tracing on, retire reconstructs the request's phase spans
+  * with the span export on, retire reconstructs the request's phase spans
     (``req.queue`` / ``req.prefill`` / ``req.decode`` under one
     ``req`` span, cat="request", args carrying rid/trace/tokens/breach) so
     the merged fleet trace shows request lifecycles next to bursts.
@@ -34,7 +34,11 @@ LIFECYCLE is traced, not the burst. This module is that substrate:
 ``now()`` is the sanctioned request-timing clock for ``inference/`` —
 tools/lint_observability.py rule O4 bans ad-hoc ``time.perf_counter()``
 request timing there so latency math cannot drift away from the histograms
-the SLO policy evaluates.
+the SLO policy evaluates. It is MONOTONIC: deadlines, timeouts, hedge
+delays, drain grace and autoscaler timing are arithmetic on it, and a step
+of the wall clock must move none of them. Only when a retired request's
+phases go into the span ring are they shifted onto the span clock (the
+wall clock, which the device trace uses), by ``span_clock_offset()``.
 
 Preemption semantics: a preempted request keeps its trace id and its
 ENQUEUE anchor (e2e covers the whole life, preemptions included) and keeps
@@ -45,7 +49,8 @@ preemption→re-admit gap — never an earlier attempt's execution). At
 temperature=0 the regenerated tokens are identical, so this is the honest
 client-visible story.
 
-No jax imports; safe from any layer.
+Nothing of paddle_tpu beyond observability is imported; safe from any
+layer.
 """
 from __future__ import annotations
 
@@ -56,8 +61,10 @@ import time
 
 from . import metrics, recorder, spans
 
-__all__ = ["SloPolicy", "RequestTracker", "now", "bench_payload",
-           "HIST_TTFT", "HIST_TPOT", "HIST_QUEUE", "HIST_E2E", "STAGES",
+__all__ = ["SloPolicy", "RequestTracker", "now", "span_clock_offset",
+           "bench_payload",
+           "HIST_TTFT", "HIST_TPOT", "HIST_QUEUE", "HIST_E2E",
+           "HIST_ADMIT_WAIT", "STAGES",
            "SPAN_TAXONOMY"]
 
 ENV_TTFT = "PADDLE_SLO_TTFT_S"
@@ -69,6 +76,11 @@ HIST_TTFT = "slo.ttft_s"
 HIST_TPOT = "slo.tpot_s"
 HIST_QUEUE = "slo.queue_wait_s"
 HIST_E2E = "slo.e2e_s"
+# one observation per ADMISSION, made at admit: the wait that admission
+# ended (enqueue -> admit, or preemption -> re-admit). slo.queue_wait_s is a
+# request's accumulated wait and is only known when it retires, which under
+# an open loop is long after the queue was felt.
+HIST_ADMIT_WAIT = "slo.admit_wait_s"
 
 COUNTER_BREACH = "slo.breach"
 
@@ -108,9 +120,19 @@ _trace_ids = itertools.count(1)
 
 
 def now() -> float:
-    """The request-timing clock (``time.perf_counter``): same clock as
-    spans, so request phase spans land on the trace timeline unshifted."""
+    """The request-timing clock (``time.perf_counter``): monotonic, so no
+    step of the wall clock expires a deadline or stretches a wait.
+    RequestTracker reads it through this name, so a test steps it by
+    patching ``slo.now``."""
     return time.perf_counter()
+
+
+def span_clock_offset() -> float:
+    """What to add to a ``now()`` stamp to put it on the span clock: one
+    read of each clock, taken when a retired request's phases are emitted
+    (seconds after they happened; the two clocks drift microseconds in
+    that time)."""
+    return spans.now() - now()
 
 
 def _env_target(name: str) -> float | None:
@@ -182,9 +204,9 @@ class _Rec:
 def _build_spans(rec: _Rec, rid: int, t_retire: float, n_tokens: int,
                  reason: str, breaches: list) -> list[dict]:
     """The request's retire-time span list as plain data
-    (``{name, t0, t1, args}``, SPAN_TAXONOMY names, perf-clock seconds):
-    one builder feeds BOTH the chrome span ring and the reqtrace sink so
-    the two views cannot drift apart."""
+    (``{name, t0, t1, args}``, SPAN_TAXONOMY names, ``now()`` seconds):
+    one builder feeds BOTH the span ring (shifted onto its clock on the
+    way in) and the reqtrace sink so the two views cannot drift apart."""
     args = {"rid": rid, "trace": rec.trace_id, "tokens": n_tokens,
             "preemptions": rec.preemptions, "reason": reason}
     if breaches:
@@ -235,7 +257,8 @@ class RequestTracker:
         self.trace_sink = None
         # pre-register so scrapers/exporters see the latency series (and
         # the breach counter) before the first request ever lands
-        for h in (HIST_TTFT, HIST_TPOT, HIST_QUEUE, HIST_E2E):
+        for h in (HIST_TTFT, HIST_TPOT, HIST_QUEUE, HIST_E2E,
+                  HIST_ADMIT_WAIT):
             metrics.histogram(h)
         metrics.counter(COUNTER_BREACH)
 
@@ -261,6 +284,7 @@ class RequestTracker:
 
     def on_admit(self, rid: int):
         t = now()
+        waited = None
         with self._lk:
             rec = self._recs.get(rid)
             if rec is not None and rec.t_admit is None:
@@ -271,7 +295,10 @@ class RequestTracker:
                 # re-queued it — never the earlier attempt's execution
                 start = rec.t_requeued if rec.t_requeued is not None \
                     else rec.t_enqueue
-                rec.queue_s += max(0.0, t - start)
+                waited = max(0.0, t - start)
+                rec.queue_s += waited
+        if waited is not None:
+            metrics.histogram(HIST_ADMIT_WAIT).observe(waited)
 
     def on_first_token(self, rid: int):
         t = now()
@@ -392,9 +419,10 @@ class RequestTracker:
 
     @staticmethod
     def _emit_spans(built: list):
+        off = span_clock_offset()
         for d in built:
-            spans.add_span(d["name"], "request", d["t0"], d["t1"],
-                           **d["args"])
+            spans.add_span(d["name"], "request", d["t0"] + off,
+                           d["t1"] + off, **d["args"])
 
     # ------------------------------------------------------------ summary
     def summary(self) -> dict:
@@ -411,7 +439,10 @@ class RequestTracker:
         return {"inflight": inflight, "breached": self.breached,
                 "targets": dict(self.policy.targets),
                 "ttft": pick(HIST_TTFT), "tpot": pick(HIST_TPOT),
-                "e2e": pick(HIST_E2E)}
+                "e2e": pick(HIST_E2E),
+                # the queue as it is felt NOW: observed at each admission,
+                # not when the request retires
+                "admit_wait": pick(HIST_ADMIT_WAIT)}
 
 
 def bench_payload() -> dict | None:

@@ -154,6 +154,7 @@ def _bsa_fwd_impl(q, k, v, block_map, masks, block_q, block_k,
                                  "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="block_sparse_fwd",
     )(block_map, qt, kt, vt, masks)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]
 
@@ -248,6 +249,7 @@ def _bsa_bwd_impl(q, k, v, out, lse, dout, block_map, masks, block_q,
                                  "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="block_sparse_bwd_dq",
     )(block_map, qt, kt, vt, dot, lse4, delta, masks)
 
     # dk/dv iterate (ki, qi) — needs the transposed map semantics
@@ -322,6 +324,7 @@ def _bsa_bwd_impl(q, k, v, out, lse, dout, block_map, masks, block_q,
                                  "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="block_sparse_bwd_dkv",
     )(block_map, qt, kt, vt, dot, lse4, delta, masks)
 
     return (jnp.swapaxes(dqt, 1, 2), jnp.swapaxes(dkt, 1, 2),

@@ -257,6 +257,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse4, delta)
 
     # ---- kernel 2: dk, dv (rows = kv blocks, reduce over q blocks) ----
@@ -312,6 +313,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, kt, vt, dot, lse4, delta)
 
     return (jnp.swapaxes(dqt, 1, 2), jnp.swapaxes(dkt, 1, 2),
@@ -402,5 +404,6 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret=False,
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ) if not interpret else None,
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return jnp.swapaxes(out, 1, 2), lse[..., 0]

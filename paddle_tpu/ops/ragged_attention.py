@@ -305,6 +305,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=min(max(2 * run_bytes, 32 << 20), 100 << 20))),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_table.astype(jnp.int32), q_lens.astype(jnp.int32),
       kv_lens.astype(jnp.int32), *operands)
 

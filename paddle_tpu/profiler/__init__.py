@@ -5,9 +5,9 @@ with scheduler windows, chrome-trace export via the C++ host/CUPTI tracers —
 SURVEY.md §5.1).
 
 TPU-native: device tracing is jax.profiler (XPlane → TensorBoard/Perfetto);
-`RecordEvent` ≈ jax.profiler.TraceAnnotation; the host-side event recorder is
-a light python timer tree for summary() tables. The chrome-trace file comes
-from jax's trace dump (perfetto-compatible).
+`RecordEvent` is an observability span (which annotates the device trace and
+lands in the span ring and its chrome export) plus a light python timer tree
+for summary() tables.
 """
 from __future__ import annotations
 
@@ -99,7 +99,6 @@ class RecordEvent:
         from .statistics import TracerEventType
         self.name = name
         self.event_type = event_type or TracerEventType.UserDefined
-        self._ann = jax.profiler.TraceAnnotation(name)
 
     def __enter__(self):
         self.begin()
@@ -115,15 +114,13 @@ class RecordEvent:
             tls.first_start = now
         # frame: [name, type, start, child_time_accumulator]
         tls.stack.append([self.name, self.event_type, now, 0.0])
-        # mirror into the observability span stream (same perf_counter
-        # clock), so ONE exported chrome trace carries RecordEvent scopes
-        # next to train-step / checkpoint / collective spans
+        # the span annotates the device trace and lands in the span ring,
+        # so ONE exported chrome trace carries RecordEvent scopes next to
+        # train-step / checkpoint / collective spans
         self._span = _spans.span(self.name, cat="profiler").begin()
-        self._ann.__enter__()
 
     def end(self):
         from .statistics import EventRecord
-        self._ann.__exit__(None, None, None)
         self._span.end()
         tls = _tree()
         name, etype, t0, child = tls.stack.pop()
@@ -203,8 +200,9 @@ class Profiler:
         if self._win_span is None:
             # the scheduler WINDOW itself is a span: the merged chrome trace
             # shows exactly which steps each profiling window covered
-            self._win_span = _spans.span("profiler.window", cat="profiler",
-                                         step=self._step).begin()
+            self._win_span = _spans.span(
+                "profiler.window", cat="profiler",
+                step=self._step).begin(nest=False)  # ended by a later step
         if not self._tracing:
             self._trace_dir = self._export_dir or os.environ.get(
                 "PADDLE_PROFILER_DIR", "/tmp/paddle_tpu_trace")

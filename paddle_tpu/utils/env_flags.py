@@ -168,10 +168,11 @@ declare("PADDLE_KV_WAL_DIR", "",
 # ----------------------------------------------------------- observability
 
 declare("PADDLE_TRACE_DIR", "",
-        "enables span tracing; traces, FLIGHT.json and capture artifacts "
-        "land here (launcher fans out per-(node,rank) subdirs)")
+        "turns the span export on; chrome traces, FLIGHT.json and capture "
+        "artifacts land here (launcher fans out per-(node,rank) subdirs)")
 declare("PADDLE_TRACE_MAX_EVENTS", "100000",
-        "span ring bound; spans past it are counted as dropped")
+        "span ring bound while the export is on (8192 otherwise); the "
+        "oldest spans fall off and are counted as dropped")
 declare("PADDLE_FLIGHT_RECORDER", "512",
         "flight-recorder ring capacity ('0'/'off' disables)")
 declare("PADDLE_METRICS_SINK", "",
@@ -180,7 +181,8 @@ declare("PADDLE_PROFILER_DIR", "/tmp/paddle_tpu_trace",
         "profiler chrome-trace export directory")
 declare("PADDLE_XPLANE_DIR", "",
         "XPlane (jax.profiler) dump dir; enables the env-configured "
-        "capture window")
+        "capture window, whose trace holds the program's spans beside the "
+        "device's operations")
 declare("PADDLE_XPLANE_START", "2",
         "first step of the env XPlane window")
 declare("PADDLE_XPLANE_STEPS", "2",

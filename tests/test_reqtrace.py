@@ -208,7 +208,7 @@ def _scene():
     router req 0→100ms; prefill replica queue 5→15ms, prefill 15→35ms;
     transfer 40→50ms (router); decode replica queue 50→60ms, decode
     60→95ms. e2e=100ms ttft=50ms queue=5ms."""
-    t0 = time.perf_counter()
+    t0 = slo.now()             # the request-timing (span) clock
     r0 = t0 + SKEW
 
     def sp(name, a, b, base):
@@ -233,7 +233,7 @@ def _scene():
               "spans": [sp("req.queue", 0.050, 0.060, r0),
                         sp("req.decode", 0.060, 0.095, r0)]}
     anchor = {"anchor_wall": time.time(),
-              "anchor_perf": time.perf_counter() + SKEW,
+              "anchor_perf": slo.now() + SKEW,
               "t_send": time.time()}
     return payload, prefill, decode, anchor
 

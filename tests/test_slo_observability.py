@@ -194,25 +194,84 @@ class TestRequestTracing:
         # TPOT fills only for requests with >= 2 tokens (all of these)
         assert metrics.histogram(slo.HIST_TPOT).count >= len(reqs) - 1
 
-    def test_queue_wait_excludes_preempted_execution(self):
+    def test_queue_wait_excludes_preempted_execution(self, monkeypatch):
         """Unit: queue wait is TIME WAITING only — enqueue→first admit
-        plus each preemption→re-admit gap, never an attempt's execution."""
+        plus each preemption→re-admit gap, never an attempt's execution.
+        The tracker reads its clock through ``slo.now``: a stepped fake
+        makes the sums exact."""
+        clock = [100.0]
+        monkeypatch.setattr(slo, "now", lambda: clock[0])
+
+        def wait(dt):
+            clock[0] += dt
+
         tr = slo.RequestTracker(policy=slo.SloPolicy())
+        admits0 = metrics.histogram(slo.HIST_ADMIT_WAIT).count
         tr.on_enqueue(1)
-        time.sleep(0.03)            # waiting in queue
+        wait(0.03)                  # waiting in queue
         tr.on_admit(1)
+        # the wait is observed AT admit, before anything retires
+        h_admit = metrics.histogram(slo.HIST_ADMIT_WAIT)
+        assert h_admit.count == admits0 + 1
+        assert h_admit.stats()["last"] == pytest.approx(0.03)
         tr.on_first_token(1)
-        time.sleep(0.08)            # EXECUTING (must not count)
+        wait(0.08)                  # EXECUTING (must not count)
         tr.on_preempt(1)
-        time.sleep(0.02)            # waiting again
+        wait(0.02)                  # waiting again
         tr.on_admit(1)
+        assert h_admit.count == admits0 + 2
+        assert h_admit.stats()["last"] == pytest.approx(0.02)
+        # the admin /snapshot's "slo" object reads it while the request is
+        # still in flight
+        assert tr.summary()["admit_wait"]["count"] == admits0 + 2
         tr.on_retire(1, n_tokens=3)
         h = metrics.histogram(slo.HIST_QUEUE)
         assert h.count == 1
-        q = h.stats()["last"]
-        assert 0.04 <= q < 0.08, q  # ~0.05 of wait, never the 0.08 run
+        assert h.stats()["last"] == pytest.approx(0.05)   # never the 0.08
         e2e = metrics.histogram(slo.HIST_E2E).stats()["last"]
-        assert e2e > 0.12           # e2e still covers the whole life
+        assert e2e == pytest.approx(0.13)   # e2e still covers the whole life
+        ttft = metrics.histogram(slo.HIST_TTFT).stats()["last"]
+        assert ttft == pytest.approx(0.03)
+
+    def test_wall_clock_step_moves_no_deadline_and_no_wait(
+            self, small_model, tmp_path, monkeypatch):
+        """``slo.now`` is the time base of deadlines, timeouts and waits,
+        and is monotonic: a step of the wall clock (NTP, a VM resume)
+        expires nothing and stretches nothing. Only the spans of a retired
+        request follow the wall clock, which is the span clock."""
+        cfg, params = small_model
+        step = [0]          # seconds the wall clock has been stepped by
+        real_ns, real_s = time.time_ns, time.time
+        monkeypatch.setattr(time, "time_ns",
+                            lambda: real_ns() + step[0] * 10 ** 9)
+        monkeypatch.setattr(time, "time", lambda: real_s() + step[0])
+
+        tr = slo.RequestTracker(policy=slo.SloPolicy())
+        tr.on_enqueue(1)
+        spans.enable_tracing(str(tmp_path))
+        try:
+            eng = _engine(cfg, params)
+            (p, m), = _mixed_requests(cfg, 43, [(6, 4)])
+            rid = eng.add_request(p, max_new_tokens=m, deadline_s=600.0)
+            t_mono = slo.now()
+            step[0] = 3600      # the wall clock jumps an hour ahead
+            assert slo.now() - t_mono < 60.0
+            tr.on_admit(1)
+            res = eng.run()
+            t_wall = spans.now()
+        finally:
+            spans.disable_tracing()
+        # the deadline (600 s) did not expire: the request ran to its end
+        assert len(res[rid]) == m
+        assert eng.stats.get("deadline_exceeded", 0) == 0
+        # the wait the step straddled is the seconds that really passed
+        assert metrics.histogram(slo.HIST_ADMIT_WAIT).stats()["last"] < 60.0
+        assert metrics.histogram(slo.HIST_E2E).stats()["last"] < 600.0
+        # and the request's spans sit on the span clock as it reads NOW
+        whole, = [e for e in spans.events() if e["name"] == "req"
+                  and e["args"]["rid"] == rid]
+        end_s = (whole["ts"] + whole["dur"]) * 1e-6
+        assert 0.0 <= t_wall - end_s < 60.0
 
     def test_phase_spans_land_on_the_trace(self, small_model, tmp_path):
         cfg, params = small_model
