@@ -38,6 +38,10 @@ SERVE_POOL_BYTES = 8 << 30
 # than max_batch so that the queue, admission and slot reuse are on the path
 SERVE_REQUESTS = ((40, 16), (100, 24), (120, 8), (128, 16), (130, 24),
                   (200, 8), (250, 16), (256, 24), (77, 8), (180, 16))
+# a served token that differs from llama_generate's is held to the
+# benchmark's measure: its logit within this of the best at its position
+# (perfbench/limits/internlm2-1.8b.longctx-batch.json: served_logit_gap_max)
+SERVE_LOGIT_GAP_LIMIT = 0.25
 MESH_AXES, MESH_SHAPE = ("dp", "tp"), (2, 2)
 MESH_REL_TOL = 2e-2     # the tolerance __graft_entry__ holds virtual meshes to
 
@@ -199,12 +203,21 @@ def serve(cfg, params, requests, on_tpu, kv_layout, **engine_kw):
                                  f"kernel: {kernel}")
     else:
         bucket = SERVE_PROMPT_BUCKETS[0]
-        kernel = {"flash_tpu_custom_calls_in_prefill": custom_calls(
+        # Llama-2-7B's pool (32 KV heads x 128, bf16 or float32) is one the
+        # decode kernel takes: on a TPU the burst must read through it
+        if on_tpu and eng.stats["kv_read"] != "kernel":
+            raise AssertionError(f"the paged engine's decode steps read "
+                                 f"through {eng.stats['kv_read']!r}, not "
+                                 f"the kernel")
+        kernel = {"kv_read": eng.stats["kv_read"],
+                  "flash_tpu_custom_calls_in_prefill": custom_calls(
             llama_paged_prefill_slot.lower(
                 params, eng._cache, jnp.zeros(bucket, jnp.int32),
                 jnp.zeros(-(-bucket // ps), jnp.int32), jnp.int32(1),
                 jax.random.PRNGKey(0), config=cfg, temperature=0.0, top_k=0,
-                dequant=None, kv_dtype=None))}
+                dequant=None, kv_dtype=None,
+                # the page writes as a loop: every kernel counted is flash
+                kv_read="gather"))}
         if on_tpu and kernel["flash_tpu_custom_calls_in_prefill"] < 1:
             raise AssertionError("the Pallas flash kernel is not in the "
                                  "bucketed-prefill program")
@@ -245,6 +258,36 @@ def agreement(a, b) -> str:
     return f"{same}/{sum(len(t) for t in a)}"
 
 
+def served_logit_gap(cfg, params, requests, served, ref) -> float:
+    """The widest gap by which a served token's logit lies below the best
+    at its position, over the requests whose tokens differ from `ref`: one
+    plain forward over prompt + served tokens each (no cache, no kernel in
+    the read), padded to one length so that one program serves them all.
+    A near-tie that a changed summation order flips reads a few hundredths
+    here; a wrong read reads whole units."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.models.llama import llama_forward
+
+    width = -(-max(len(p) + m for p, m in requests) // 128) * 128
+    # params as an argument: closed over, 3.8 GB of weights would be
+    # lowered as constants of the program (40 GiB of host memory on the chip)
+    forward = jax.jit(lambda p, toks: llama_forward(p, toks, cfg)[0])
+    gap = 0.0
+    for (prompt, _), out, want in zip(requests, served, ref):
+        if out == want:
+            continue
+        toks = np.zeros((1, width), np.int32)
+        toks[0, :len(prompt) + len(out)] = list(prompt) + list(out)
+        logits = np.asarray(forward(params, jnp.asarray(toks))[0],
+                            np.float32)
+        at = logits[len(prompt) - 1:len(prompt) - 1 + len(out)]
+        gap = max(gap, float((at.max(axis=-1)
+                              - at[np.arange(len(out)), out]).max()))
+    return gap
+
+
 def serve_both_layouts(cfg, params, requests, on_tpu, **engine_kw):
     """The default gather layout and the ragged kernel layout against
     per-request llama_generate. Returns the facts and whether all three
@@ -260,9 +303,17 @@ def serve_both_layouts(cfg, params, requests, on_tpu, **engine_kw):
     facts_p["tokens_equal_llama_generate"] = agreement(paged, ref)
     facts_r["tokens_equal_llama_generate"] = agreement(ragged, ref)
     facts_r["tokens_equal_paged"] = agreement(ragged, paged)
-    return {"paged": facts_p, "ragged": facts_r,
-            "reference_s_with_compile": round(time.perf_counter() - t0, 2)
-            }, paged == ref and ragged == ref
+    equal = paged == ref and ragged == ref
+    facts = {"paged": facts_p, "ragged": facts_r,
+             "reference_s_with_compile": round(time.perf_counter() - t0, 2)}
+    if not equal:
+        # the kernel's online softmax sums in another order than
+        # llama_generate's full-width one: hold what differs to the
+        # benchmark's measure instead of to equality
+        facts["served_logit_gap_max"] = max(
+            served_logit_gap(cfg, params, requests, out, ref)
+            for out in (paged, ragged))
+    return facts, equal
 
 
 def phase_serve(args, dev) -> dict:
@@ -286,6 +337,10 @@ def phase_serve(args, dev) -> dict:
                           f"weights plus an {SERVE_POOL_BYTES >> 30} GiB KV "
                           f"page pool fit one 16 GB chip"],
               "bf16": facts, "compared_in": "bfloat16"}
+    if not facts.get("served_logit_gap_max", 0.0) < SERVE_LOGIT_GAP_LIMIT:
+        raise AssertionError(
+            f"bfloat16 greedy tokens differ from llama_generate by more "
+            f"than a near-tie (limit {SERVE_LOGIT_GAP_LIMIT}): {facts}")
     if not equal or args.rehearse:      # a rehearsal walks both passes
         # bf16 near-ties between random-weight logits flip a greedy argmax
         # between two correct programs; the equality tier-1 pins on the CPU
@@ -302,10 +357,14 @@ def phase_serve(args, dev) -> dict:
                       float32_config=width_summary(cfg32))
         result["reduced"].append(
             f"token equality of both layouts with llama_generate decided in "
-            f"float32 at {cfg32.num_hidden_layers} layers")
-        if not equal32:
+            f"float32 at {cfg32.num_hidden_layers} layers (a token that "
+            f"still differs there is held to served_logit_gap_max < "
+            f"{SERVE_LOGIT_GAP_LIMIT})")
+        if not (facts32.get("served_logit_gap_max", 0.0)
+                < SERVE_LOGIT_GAP_LIMIT):
             raise AssertionError(
-                f"float32 greedy tokens differ from llama_generate: "
+                f"float32 greedy tokens differ from llama_generate by more "
+                f"than a near-tie (limit {SERVE_LOGIT_GAP_LIMIT}): "
                 f"{facts32}")
     return result
 

@@ -31,10 +31,20 @@ Three KV layouts share that scheduler:
 
   * ``kv_layout="paged"`` (default) — a shared ``[num_pages, page_size,
     KV, hd]`` pool per layer with per-slot block tables
-    (models/llama_paged.py, the Ragged-Paged-Attention idea at the XLA
-    level). Cache HBM scales with LIVE tokens (pages alloc on admit, free
-    on retire) and decode attention gathers only ``page_bucket ×
-    page_size`` rows — bandwidth follows actual context length. Admission
+    (models/llama_paged.py). Cache HBM scales with LIVE tokens (pages
+    alloc on admit, free on retire). A decode step reads each slot's
+    context through whichever read the pool's geometry allows
+    (``llama_paged.paged_kv_read``, reported as ``stats["kv_read"]`` and
+    as the ``kv_read`` argument of every ``serve.dispatch_burst`` span):
+    "kernel" — the decode body of ``ops/ragged_attention.py``, which
+    copies and attends only the slot's ceil((pos+1)/page_size) LIVE pages
+    (unquantized pool, ``head_dim % 128 == 0``, one device: the benchmark
+    configurations and ``chip_smoke.py``); or "gather" — ``jnp.take`` of
+    the whole ``page_bucket × page_size`` rows and masked attention over
+    them (int8/fp8 pages, head_dim 64, tier-1's tiny configurations, a
+    GSPMD-sharded pool). Same choice on every backend (off the TPU the
+    kernel is interpreted); the page bucket keeps setting the block
+    table's width and the burst program's inventory either way. Admission
     is gated by free pages, not by ``max_batch × max_len`` worst case;
     when the pool runs dry mid-flight the youngest slot is preempted back
     to the queue (its tokens regenerate exactly at temperature=0). The
@@ -69,15 +79,18 @@ lookup to a miss / spare an eviction, tokens identical either way.
 Metrics published (observability.metrics): ``serve.pages_in_use`` gauge,
 ``serve.tokens`` / ``serve.requests`` / ``serve.admission_stalls`` /
 ``serve.preemptions`` / ``serve.chaos_retired`` counters,
-``serve.tokens_per_s`` and ``serve.kv_read_mb_per_tok`` gauges,
+``serve.tokens_per_s`` and ``serve.kv_read_mb_per_tok`` gauges (the
+latter bills the live pages where the kernel reads, the page bucket where
+the gather does),
 ``serve.burst_time_s`` histogram, ``serve.prefill_tokens_real`` /
 ``serve.prefill_tokens_padded`` counters (what a prompt bucket pads).
 
 Spans (observability.spans, on the device trace's clock): every ``step()``
 is one ``serve.step`` (args: burst number, live slots) whose children are
 ``serve.dispatch_burst`` (page growth, block table, transfers, the async
-launch), ``serve.admit`` (pop, bucket, allocate, prefill dispatch; under
-the in-flight burst on the paged path; arg: prefills staged),
+launch; arg ``kv_read``: "kernel", "gather" or "dense"), ``serve.admit``
+(pop, bucket, allocate, prefill dispatch; under the in-flight burst on the
+paged path; arg: prefills staged),
 ``serve.readback`` (the step's one blocking ``device_get``) and
 ``serve.merge`` (the host bookkeeping after it). The ragged and dense loops
 use the same four names. Construction is one ``serve.init``.
@@ -269,9 +282,14 @@ class ContinuousBatcher:
         self._ragged = False
         self._interpret = jax.default_backend() != "tpu"
         self._mesh = None
+        # which read a decode step takes: stats["kv_read"] and the
+        # serve.dispatch_burst span's argument ("dense" has no pool)
+        self._kv_read = "dense"
         if kv_layout == "ragged":
             from ..ops import ragged_attention as _ra
             self._ragged = _ra.enabled()
+            # PADDLE_RAGGED_ATTN=0: the gather, whatever the pool's shape
+            self._kv_read = "kernel" if self._ragged else "gather"
             kv_heads = self._cfg.num_key_value_heads
             if self._ragged and not _ra.supported(
                     self._cfg.head_dim, kv_heads, self.S, self._interpret,
@@ -361,6 +379,13 @@ class ContinuousBatcher:
             self._admit_seq = [0] * self.B
             self._seq = 0
             self._kv_read_bucket = None  # page bucket the gauge was set at
+            if self._kv_read == "dense":
+                # the default layout takes the read its pool's geometry
+                # allows (ISSUE 28): the decode kernel over live pages, or
+                # the XLA gather over the page bucket
+                from ..models.llama_paged import paged_kv_read
+                self._kv_read = paged_kv_read(
+                    model_config, self._ps, self._kv_dtype, self._mesh)
             if self._ragged:
                 # decode-only bursts (the steady state) reuse these
                 # device-resident empty-admission inputs instead of
@@ -430,7 +455,7 @@ class ContinuousBatcher:
         self.stats = {"bursts": 0, "decode_steps": 0, "prefills": 0,
                       "admission_stalls": 0, "preemptions": 0,
                       "chaos_retired": 0, "max_concurrent": 0,
-                      "page_buckets_used": []}
+                      "page_buckets_used": [], "kv_read": self._kv_read}
         # request-level SLO observability: lifecycle tracker + policy
         # (PADDLE_SLO_* env unless an explicit policy is given); pure
         # observation — no tracker call can change a served token
@@ -931,7 +956,9 @@ class ContinuousBatcher:
         if P not in self.stats["page_buckets_used"]:
             self.stats["page_buckets_used"] = sorted(
                 self.stats["page_buckets_used"] + [P])
-        if P != self._kv_read_bucket:   # the gather reads the whole bucket
+        if self._kv_read == "kernel":   # the kernel reads live pages only
+            self._set_live_kv_gauge(active)
+        elif P != self._kv_read_bucket:  # the gather reads the whole bucket
             self._kv_read_bucket = P
             metrics.gauge("serve.kv_read_mb_per_tok").set(
                 paged_kv_bytes_per_token(self._cfg, P, self._ps,
@@ -951,10 +978,21 @@ class ContinuousBatcher:
                 jnp.int32(self.eos_id), sub, config=self._cfg, n=self.burst,
                 temperature=self._temp, top_k=self._top_k,
                 pad_id=self.pad_id, dequant=self._dequant,
-                kv_dtype=self._kv_dtype)
+                kv_dtype=self._kv_dtype, kv_read=self._kv_read,
+                interpret=self._interpret, mesh=self._mesh)
         self.stats["bursts"] += 1
         self.stats["decode_steps"] += self.burst
         return old_pos, pos_d, tok_d, done_d, emitted_d
+
+    def _set_live_kv_gauge(self, active: list) -> None:
+        """``serve.kv_read_mb_per_tok`` where the kernel reads: the mean
+        over active slots of their LIVE pages' bytes (pos + 1 rows, whole
+        pages), not the bucket's."""
+        from ..models.llama_paged import page_bytes
+        pages = self._pos[active] // self._ps + 1
+        metrics.gauge("serve.kv_read_mb_per_tok").set(
+            float(pages.mean()) * page_bytes(self._cfg, self._ps,
+                                             self._kv_dtype) / 1e6)
 
     def _install_admit(self, req: ServedRequest, slot: int) -> int:
         """Admit a kv_import request: allocate its live pages, write the
@@ -1171,7 +1209,9 @@ class ContinuousBatcher:
                     jnp.asarray(np.asarray(pages, np.int32)),
                     jnp.int32(tlen), sub, config=self._cfg,
                     temperature=self._temp, top_k=self._top_k,
-                    dequant=self._dequant, kv_dtype=self._kv_dtype)
+                    dequant=self._dequant, kv_dtype=self._kv_dtype,
+                    kv_read=self._kv_read, interpret=self._interpret,
+                    mesh=self._mesh)
                 self._note_admit_prefill(req, tlen)
             # pages past the real prompt hold only bucket-pad garbage the
             # mask never exposes — return them right away; the pre-burst
@@ -1381,8 +1421,7 @@ class ContinuousBatcher:
         table is always full width — the kernel reads live pages only, so
         there is no page bucket and no prompt bucket to compile against.
         Returns (old_pos, device futures) or None when nothing is active."""
-        from ..models.llama_paged import (llama_ragged_burst,
-                                          paged_kv_bytes_per_token)
+        from ..models.llama_paged import llama_ragged_burst
         active = [b for b, r in enumerate(self._slot_req) if r is not None]
         if not active:
             return None
@@ -1399,13 +1438,8 @@ class ContinuousBatcher:
             return None
         metrics.gauge("serve.pages_in_use").set(self._alloc.pages_in_use)
         # bytes/token follow LIVE context on the ragged path (the ISSUE-8
-        # over-reporting fix): mean over active slots of their live pages
-        live_bytes = [paged_kv_bytes_per_token(
-            self._cfg, 0, self._ps, live_tokens=int(self._pos[b]) + 1,
-            kv_dtype=self._kv_dtype)
-            for b in active]
-        metrics.gauge("serve.kv_read_mb_per_tok").set(
-            sum(live_bytes) / len(live_bytes) / 1e6)
+        # over-reporting fix)
+        self._set_live_kv_gauge(active)
 
         P = pages_for(self.S, self._ps)          # full width, always
         bt = np.full((self.B, P), SCRATCH_PAGE, np.int32)
@@ -1491,7 +1525,8 @@ class ContinuousBatcher:
         with _spans.span("serve.admit", cat="serve") as sp:
             staged = self._admit_ragged()
             sp.args = {"prefills": len(staged)}
-        with _spans.span("serve.dispatch_burst", cat="serve"):
+        with _spans.span("serve.dispatch_burst", cat="serve",
+                         kv_read=self._kv_read):
             inflight = self._dispatch_ragged(staged)
         emitted = self._sync_merge_ragged(inflight, staged)
         dt = _slo.now() - t0
@@ -1676,7 +1711,8 @@ class ContinuousBatcher:
                 self._step_ragged()
             elif self._layout == "paged":
                 t0 = _slo.now()     # the request-timing clock (lint O4)
-                with _spans.span("serve.dispatch_burst", cat="serve"):
+                with _spans.span("serve.dispatch_burst", cat="serve",
+                                 kv_read=self._kv_read):
                     inflight = self._dispatch_burst_paged()
                 with _spans.span("serve.admit", cat="serve") as sp:
                     real0, padded0 = self._pf_real.value, self._pf_padded.value
@@ -1716,7 +1752,8 @@ class ContinuousBatcher:
             sum(r is not None for r in self._slot_req))
         old_pos = self._pos.copy()
         t0 = _slo.now()
-        with _spans.span("serve.dispatch_burst", cat="serve"):
+        with _spans.span("serve.dispatch_burst", cat="serve",
+                         kv_read=self._kv_read):
             self._key, sub = jax.random.split(self._key)
             (self._cache, pos_d, tok_d, done_d, emitted) = \
                 llama_decode_burst(

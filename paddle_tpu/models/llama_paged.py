@@ -43,9 +43,23 @@ Ragged kernel (ISSUE 8): ``llama_ragged_burst`` below replaces the
 ``jnp.take`` gather with the Pallas ragged kernel
 (``ops/ragged_attention.py``) and folds ragged-length prompt prefill into
 the SAME executable as the decode scan — the bucket grid (and its
-executable inventory) disappears; bytes/token follow live context. The
-gather entry points stay as the fallback (PADDLE_RAGGED_ATTN=0) and
-equivalence baseline.
+executable inventory) disappears; bytes/token follow live context.
+
+Which read a decode step takes (ISSUE 28): ONE step function,
+``_paged_decode_step_slots``, serves both bursts; its ``kv_read`` scope is
+either the kernel (``_ragged_attn``: the decode-shaped body, live pages
+only) or the XLA gather + masked attention over the page bucket.
+``llama_ragged_burst`` always takes the kernel. ``llama_paged_decode_burst``
+takes what ``paged_kv_read`` says for the pool it is handed — the kernel
+for an unquantized pool with ``head_dim % 128 == 0`` on one device, the
+gather for everything else (int8/fp8 pages, head_dim 64, the tiny
+configurations of tier-1, a GSPMD-sharded pool) — the same choice on every
+backend, so the CPU's tests run the read the chip runs. The bucket still
+sets the block table's width; raggedness inside it rides in ``pos``. Where
+the read is the kernel's and a row of the pool is whole sublane tiles
+(``_kernel_write``), the fresh rows of a decode step and the pages of a
+bucketed prefill go into the pool through ``paged_kv_scatter``, one launch
+a layer, instead of the per-slot / per-page ``dynamic_update_slice`` loop.
 """
 from __future__ import annotations
 
@@ -57,9 +71,11 @@ import jax.numpy as jnp
 
 from .llama import LlamaConfig, _rmsnorm, _rope, lm_head_logits, \
     split_layer_params
+from ..ops.ragged_attention import decode_supported, paged_kv_scatter, \
+    ragged_paged_attention, scatter_supported
 from .llama_decode import _cached_attention_slots, _mlp, _qkv, _sample
 
-__all__ = ["init_paged_kv_cache", "llama_paged_prefill_slot",
+__all__ = ["init_paged_kv_cache", "paged_kv_read", "llama_paged_prefill_slot",
            "llama_paged_prefill_suffix", "llama_paged_decode_burst",
            "llama_ragged_burst", "llama_paged_verify",
            "paged_kv_bytes_per_token", "page_bytes",
@@ -236,38 +252,91 @@ def paged_kv_bytes_per_token(config: LlamaConfig, pages: int,
                * c.num_key_value_heads * _kv_row_head_bytes(c, kv_dtype))
 
 
+def paged_kv_read(config: LlamaConfig, page_size: int,
+                  kv_dtype: str | None = None, mesh=None) -> str:
+    """Which read ``llama_paged_decode_burst`` takes for a pool: "kernel"
+    (the decode body of ``ops/ragged_attention.py``: live pages only) or
+    "gather" (``jnp.take`` over the page bucket + masked attention). Decided
+    by the pool's geometry alone (``ragged_attention.decode_supported``),
+    the same on every backend; a GSPMD-sharded pool (``mesh``) keeps the
+    gather, which XLA partitions by itself."""
+    if mesh is None and decode_supported(
+            config.head_dim, config.num_key_value_heads, int(page_size),
+            kv_dtype):
+        return "kernel"
+    return "gather"
+
+
+def _kernel_write(config: LlamaConfig, page_size: int, kv_dtype, kv_read,
+                  mesh) -> bool:
+    """Do a program's K/V rows go into the pool through ONE
+    ``paged_kv_scatter`` launch a layer instead of two
+    ``dynamic_update_slice``s a slot (or page)? Where the read is the
+    kernel's (``kv_read`` None = ``paged_kv_read``'s choice) and a row of
+    the pool is whole sublane tiles (``scatter_supported``), on one device.
+    The same bytes either way; the loop is what the gather's pools keep."""
+    if kv_read is None:
+        kv_read = paged_kv_read(config, page_size, kv_dtype, mesh)
+    return (kv_read == "kernel" and mesh is None and scatter_supported(
+        config.head_dim, config.num_key_value_heads, int(page_size),
+        kv_dtype))
+
+
 def _paged_decode_step_slots(params, cache, block_table, pos, tok,
-                             config: LlamaConfig, kv_dtype: str | None = None):
+                             config: LlamaConfig, kv_dtype: str | None = None,
+                             kv_read: str | None = None,
+                             interpret: bool | None = None, mesh=None):
     """One single-token step over all slots, K/V through the block table.
 
     block_table [B, P] int32; pos/tok [B]. Slot b writes this token's K/V
     into physical page ``block_table[b, pos[b] // page_size]`` at row
-    ``pos[b] % page_size`` and attends the gathered [P*page_size] rows
-    under the same ``row <= pos`` mask as the dense path. Layers unrolled,
-    per-layer pool buffers, per-lane dynamic_update_slice — the measured
-    in-place discipline of llama_decode_step_slots carries over verbatim.
+    ``pos[b] % page_size`` and attends rows ``<= pos[b]`` of its pages.
+    Layers unrolled, per-layer pool buffers, written in place: per-lane
+    dynamic_update_slice (the measured discipline of
+    llama_decode_step_slots), or, where the read is the kernel's and the
+    pool allows (``_kernel_write``), one ``paged_kv_scatter`` launch a
+    layer: the same rows, 1 operation for 96 at 48 slots.
+
+    ``kv_read`` (static; None = what ``paged_kv_read`` says for this
+    pool): "kernel" reads through ``_ragged_attn`` — each slot's
+    ceil((pos+1)/page_size) live pages, chunk by chunk (quantized pools:
+    the kernel dequantizes each streamed page; ``interpret`` None = off
+    the TPU; ``mesh``: shard_map over the pool's KV heads). "gather"
+    gathers the [P*page_size] rows of the whole bucket and attends them
+    under the same ``row <= pos`` mask as the dense path, dequantizing
+    payload×scale right after the takes. The takes clip: the table is in
+    bounds by construction (unused entries hold SCRATCH_PAGE), and
+    ``jnp.take``'s default fill mode would write a second copy of the
+    gathered rows through a select (53 % of the batch cell's device time
+    before ISSUE 28).
 
     ``kv_dtype``: writes quantize the fresh K/V row (payload + per-head
-    scale land together), the gather dequantizes payload×scale right
-    after the two jnp.takes — same attention arithmetic downstream.
+    scale land together).
 
     Device-side names (``jax.named_scope``, read by the trace reducers):
     per layer ``kv_write`` (the per-slot row writes), ``kv_read`` (the
-    gather, its dequantize and reshape, and the masked attention over the
-    gathered rows), ``attn_out``, ``mlp``; ``head_sample`` the logits (and,
-    in the burst, the sampling). One scope a layer and phase, none inside
+    kernel, or the gather with its dequantize, reshape and masked
+    attention), ``attn_out``, ``mlp``; ``head_sample`` the logits (and,
+    in the bursts, the sampling). One scope a layer and phase, none inside
     the per-slot loop, whose 48 x 24 bodies are traced in every run.
     """
     c = config
     layer_p, other = split_layer_params(params)
     B = tok.shape[0]
-    ps = cache["k"][0].shape[1]
+    ps = int(cache["k"][0].shape[1])
+    if kv_read is None:
+        kv_read = paged_kv_read(c, ps, kv_dtype, mesh)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    kernel_write = _kernel_write(c, ps, kv_dtype, kv_read, mesh)
     x = jnp.take(other["embed_tokens"], tok[:, None], axis=0).astype(c.dtype)
     positions = pos[:, None].astype(jnp.int32)
     pos32 = pos.astype(jnp.int32)
-    page_of = pos32 // ps            # [B] logical page of the write
-    row_of = pos32 % ps              # [B] row within that page
+    page_of = pos32 // jnp.int32(ps)     # [B] logical page of the write
+    row_of = pos32 % jnp.int32(ps)       # [B] row within that page
+    wpage = jnp.take_along_axis(block_table, page_of[:, None], axis=1)[:, 0]
     z = jnp.int32(0)
+    one = jnp.ones((B,), jnp.int32)
 
     quant = kv_dtype is not None
     ks, vs = list(cache["k"]), list(cache["v"])
@@ -280,38 +349,49 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
         q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
         kp, vp = ks[l], vs[l]
         ku, vu = k[:, 0], v[:, 0]
+        ksp = vsp = None
         if quant:
             ku, ksr = _kv_encode(ku, kv_dtype)   # [B, KV, hd] + [B, KV]
             vu, vsr = _kv_encode(vu, kv_dtype)
             ksp, vsp = kss[l], vss[l]
         with jax.named_scope("kv_write"):
-            for b in range(B):
-                at = (block_table[b, page_of[b]], row_of[b], z, z)
-                kp = jax.lax.dynamic_update_slice(kp, ku[b][None, None], at)
-                vp = jax.lax.dynamic_update_slice(vp, vu[b][None, None], at)
-                if quant:
-                    ats = (block_table[b, page_of[b]], row_of[b], z)
-                    ksp = jax.lax.dynamic_update_slice(
-                        ksp, ksr[b][None, None], ats)
-                    vsp = jax.lax.dynamic_update_slice(
-                        vsp, vsr[b][None, None], ats)
+            if kernel_write:
+                kp, vp = paged_kv_scatter(kp, vp, ku[:, None], vu[:, None],
+                                          wpage, row_of, interpret=interpret)
+            else:
+                for b in range(B):
+                    at = (wpage[b], row_of[b], z, z)
+                    kp = jax.lax.dynamic_update_slice(
+                        kp, ku[b][None, None], at)
+                    vp = jax.lax.dynamic_update_slice(
+                        vp, vu[b][None, None], at)
+                    if quant:
+                        ats = (wpage[b], row_of[b], z)
+                        ksp = jax.lax.dynamic_update_slice(
+                            ksp, ksr[b][None, None], ats)
+                        vsp = jax.lax.dynamic_update_slice(
+                            vsp, vsr[b][None, None], ats)
         ks[l], vs[l] = kp, vp
         if quant:
             kss[l], vss[l] = ksp, vsp
         with jax.named_scope("kv_read"):
-            # gather the slot's pages into a [B, P*ps, KV, hd] view — THIS
-            # is the read whose bytes scale with the page bucket instead of
-            # S_max
-            kc = jnp.take(kp, block_table, axis=0)
-            vc = jnp.take(vp, block_table, axis=0)
-            if quant:
-                kc = _kv_decode(kc, jnp.take(ksp, block_table, axis=0),
-                                c.dtype)
-                vc = _kv_decode(vc, jnp.take(vsp, block_table, axis=0),
-                                c.dtype)
-            kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-            vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-            att = _cached_attention_slots(q, kc, vc, pos, c)
+            if kv_read == "kernel":
+                att = _ragged_attn(q, kp, vp, block_table, one, pos32 + 1,
+                                   page_size=ps, interpret=interpret,
+                                   mesh=mesh, ksc=ksp, vsc=vsp)
+            else:
+                # gather the slot's pages into a [B, P*ps, KV, hd] view:
+                # the read whose bytes scale with the page bucket
+                kc = _take_pages(kp, block_table)
+                vc = _take_pages(vp, block_table)
+                if quant:
+                    kc = _kv_decode(kc, _take_pages(ksp, block_table),
+                                    c.dtype)
+                    vc = _kv_decode(vc, _take_pages(vsp, block_table),
+                                    c.dtype)
+                kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
+                vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
+                att = _cached_attention_slots(q, kc, vc, pos, c)
         with jax.named_scope("attn_out"):
             y = x + (att.reshape(B, 1, -1) @ lp["wo"])
         with jax.named_scope("mlp"):
@@ -325,14 +405,22 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
     return logits, out
 
 
+def _take_pages(pool, table):
+    """``pool[table]`` over the page dim, clipping: no fill select over
+    the gathered shape (``jnp.take``'s default ``mode="fill"`` adds one)."""
+    return jnp.take(pool, table, axis=0, mode="clip")
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "config", "temperature", "top_k", "dequant", "kv_dtype"),
-    donate_argnums=(1,))
+    "config", "temperature", "top_k", "dequant", "kv_dtype", "kv_read",
+    "interpret", "mesh"), donate_argnums=(1,))
 @jax.named_scope("prefill")
 def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
                              config: LlamaConfig,
                              temperature: float = 0.0, top_k: int = 0,
-                             dequant=None, kv_dtype: str | None = None):
+                             dequant=None, kv_dtype: str | None = None,
+                             kv_read: str | None = None,
+                             interpret: bool | None = None, mesh=None):
     """Prefill ONE request's prompt into its allocated pages.
 
     tokens [Tb] int32 padded to a bucket length; page_ids [ceil(Tb/ps)]
@@ -348,13 +436,21 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     token is sampled from exact activations — the standard quantized-KV
     deployment shape); only the CACHE WRITES quantize, so quantization
     error enters at the first decode read, never the prefill compute.
+
+    ``kv_read`` / ``interpret`` / ``mesh`` (as the burst takes them): where
+    the engine's decode steps read through the kernel, the prompt's pages
+    are written by one ``paged_kv_scatter`` launch a layer instead of two
+    ``dynamic_update_slice``s a page (``_kernel_write``).
     """
     c = config
     if dequant is not None:
         params = dequant(params)
     layer_p, other = split_layer_params(params)
     T = tokens.shape[0]
-    ps = cache["k"][0].shape[1]
+    ps = int(cache["k"][0].shape[1])
+    kernel_write = _kernel_write(c, ps, kv_dtype, kv_read, mesh)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     n_pages = page_ids.shape[0]
     pad = n_pages * ps - T
     x = jnp.take(other["embed_tokens"], tokens[None, :],
@@ -387,18 +483,24 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
             vrows, vsrows = _kv_encode(vrows, kv_dtype)
             ksp, vsp = ksl[l], vsl[l]
         kp, vp = kl[l], vl[l]
-        for j in range(n_pages):
-            at = (page_ids[j], z, z, z)
-            kp = jax.lax.dynamic_update_slice(
-                kp, krows[j * ps:(j + 1) * ps][None], at)
-            vp = jax.lax.dynamic_update_slice(
-                vp, vrows[j * ps:(j + 1) * ps][None], at)
-            if quant:
-                ats = (page_ids[j], z, z)
-                ksp = jax.lax.dynamic_update_slice(
-                    ksp, ksrows[j * ps:(j + 1) * ps][None], ats)
-                vsp = jax.lax.dynamic_update_slice(
-                    vsp, vsrows[j * ps:(j + 1) * ps][None], ats)
+        if kernel_write:
+            paged = (n_pages, ps) + krows.shape[1:]
+            kp, vp = paged_kv_scatter(
+                kp, vp, krows.reshape(paged), vrows.reshape(paged), page_ids,
+                jnp.zeros(n_pages, jnp.int32), interpret=interpret)
+        else:
+            for j in range(n_pages):
+                at = (page_ids[j], z, z, z)
+                kp = jax.lax.dynamic_update_slice(
+                    kp, krows[j * ps:(j + 1) * ps][None], at)
+                vp = jax.lax.dynamic_update_slice(
+                    vp, vrows[j * ps:(j + 1) * ps][None], at)
+                if quant:
+                    ats = (page_ids[j], z, z)
+                    ksp = jax.lax.dynamic_update_slice(
+                        ksp, ksrows[j * ps:(j + 1) * ps][None], ats)
+                    vsp = jax.lax.dynamic_update_slice(
+                        vsp, vsrows[j * ps:(j + 1) * ps][None], ats)
         kl[l], vl[l] = kp, vp
         if quant:
             ksl[l], vsl[l] = ksp, vsp
@@ -541,14 +643,16 @@ def llama_paged_prefill_suffix(params, cache, tokens, page_ids,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "config", "n", "temperature", "top_k", "pad_id", "dequant", "kv_dtype"),
-    donate_argnums=(1,))
+    "config", "n", "temperature", "top_k", "pad_id", "dequant", "kv_dtype",
+    "kv_read", "interpret", "mesh"), donate_argnums=(1,))
 @jax.named_scope("burst")
 def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
                              limit, eos_id, key, config: LlamaConfig,
                              n: int, temperature: float = 0.0,
                              top_k: int = 0, pad_id: int = 0, dequant=None,
-                             kv_dtype: str | None = None):
+                             kv_dtype: str | None = None,
+                             kv_read: str | None = None,
+                             interpret: bool | None = None, mesh=None):
     """n scanned paged-decode steps — the paged serving hot loop.
 
     Same contract as llama_decode_burst plus block_table [B, P]: a slot
@@ -557,14 +661,16 @@ def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
     page 0 once the host retires them and zeroes their table row).
     Returns (cache, pos, tok, done, emitted [n, B]). One executable per
     (B, P, n) — P is the page-count bucket, so the inventory is
-    O(page buckets), not O(contexts).
+    O(page buckets), not O(contexts). ``kv_read`` / ``interpret`` /
+    ``mesh``: see ``_paged_decode_step_slots`` (None = ``paged_kv_read``'s
+    choice for this pool).
     """
     def step(carry, _):
         cache, pos, tok, done, key = carry
         p = dequant(params) if dequant is not None else params
-        logits, cache = _paged_decode_step_slots(p, cache, block_table,
-                                                 pos, tok, config,
-                                                 kv_dtype=kv_dtype)
+        logits, cache = _paged_decode_step_slots(
+            p, cache, block_table, pos, tok, config, kv_dtype=kv_dtype,
+            kv_read=kv_read, interpret=interpret, mesh=mesh)
         key, sub = jax.random.split(key)
         with jax.named_scope("head_sample"):
             nxt = _sample(logits, temperature, top_k, sub)
@@ -596,7 +702,6 @@ def _ragged_attn(q, kp, vp, block_table, q_lens, kv_lens, *, page_size,
     ``ksc``/``vsc`` (ISSUE 10): quantized pools' per-(page, row, head)
     scale pools, sharded along the SAME head axis — each chip streams only
     its own heads' scales next to its own heads' pages."""
-    from ..ops.ragged_attention import ragged_paged_attention
     if mesh is None:
         return ragged_paged_attention(q, kp, vp, block_table, q_lens,
                                       kv_lens, page_size=page_size,
@@ -631,68 +736,6 @@ def _ragged_attn(q, kp, vp, block_table, q_lens, kv_lens, *, page_size,
         in_specs=(heads, heads, heads, scales, scales, P(None, None),
                   P(None), P(None)),
         out_specs=heads)(q, kp, vp, ksc, vsc, block_table, q_lens, kv_lens)
-
-
-def _ragged_decode_step_slots(params, cache, block_table, pos, tok,
-                              config: LlamaConfig, interpret: bool,
-                              mesh=None, kv_dtype: str | None = None):
-    """_paged_decode_step_slots with the gather replaced by the ragged
-    kernel: K/V writes keep the per-lane dynamic_update_slice discipline;
-    the read DMAs only each slot's ceil((pos+1)/page_size) live pages.
-    ``kv_dtype``: rows quantize on write; the kernel dequantizes each
-    streamed page inside its DMA loop (ops/ragged_attention.py)."""
-    c = config
-    layer_p, other = split_layer_params(params)
-    B = tok.shape[0]
-    ps = cache["k"][0].shape[1]
-    x = jnp.take(other["embed_tokens"], tok[:, None], axis=0).astype(c.dtype)
-    positions = pos[:, None].astype(jnp.int32)
-    pos32 = pos.astype(jnp.int32)
-    page_of = pos32 // jnp.int32(ps)
-    row_of = pos32 % jnp.int32(ps)
-    z = jnp.int32(0)
-    one = jnp.ones((B,), jnp.int32)
-
-    quant = kv_dtype is not None
-    ks, vs = list(cache["k"]), list(cache["v"])
-    kss = list(cache["k_scale"]) if quant else None
-    vss = list(cache["v_scale"]) if quant else None
-    for l in range(c.num_hidden_layers):
-        lp = jax.tree.map(lambda a: a[l], layer_p)
-        h = _rmsnorm(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv(h, lp, c)
-        q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
-        kp, vp = ks[l], vs[l]
-        ku, vu = k[:, 0], v[:, 0]
-        if quant:
-            ku, ksr = _kv_encode(ku, kv_dtype)
-            vu, vsr = _kv_encode(vu, kv_dtype)
-            ksp, vsp = kss[l], vss[l]
-        for b in range(B):
-            at = (block_table[b, page_of[b]], row_of[b], z, z)
-            kp = jax.lax.dynamic_update_slice(kp, ku[b][None, None], at)
-            vp = jax.lax.dynamic_update_slice(vp, vu[b][None, None], at)
-            if quant:
-                ats = (block_table[b, page_of[b]], row_of[b], z)
-                ksp = jax.lax.dynamic_update_slice(
-                    ksp, ksr[b][None, None], ats)
-                vsp = jax.lax.dynamic_update_slice(
-                    vsp, vsr[b][None, None], ats)
-        ks[l], vs[l] = kp, vp
-        if quant:
-            kss[l], vss[l] = ksp, vsp
-        att = _ragged_attn(q, kp, vp, block_table, one, pos32 + 1,
-                           page_size=int(ps), interpret=interpret,
-                           mesh=mesh,
-                           ksc=ksp if quant else None,
-                           vsc=vsp if quant else None)
-        y = x + (att.reshape(B, 1, -1) @ lp["wo"])
-        x = _mlp(y, lp, c)
-
-    out = {"k": tuple(ks), "v": tuple(vs)}
-    if quant:
-        out["k_scale"], out["v_scale"] = tuple(kss), tuple(vss)
-    return lm_head_logits(x[:, 0, :], other, c), out
 
 
 def _ragged_prefill_phase(params, cache, block_table, new_tokens, new_lens,
@@ -836,10 +879,9 @@ def llama_ragged_burst(params, cache, block_table, pos, tok, done, limit,
     def step(carry, _):
         cache, pos, tok, done, key = carry
         pp = dequant(params) if dequant is not None else params
-        logits, cache = _ragged_decode_step_slots(pp, cache, block_table,
-                                                  pos, tok, config,
-                                                  interpret, mesh,
-                                                  kv_dtype=kv_dtype)
+        logits, cache = _paged_decode_step_slots(
+            pp, cache, block_table, pos, tok, config, kv_dtype=kv_dtype,
+            kv_read="kernel", interpret=interpret, mesh=mesh)
         key, sub = jax.random.split(key)
         nxt = _sample(logits, temperature, top_k, sub)
         emit = jnp.where(done, jnp.int32(pad_id), nxt)

@@ -92,6 +92,39 @@ def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases):
             assert facts["tokens_equal_llama_generate"] == "64/64", layout
 
 
+class TestServedLogitGap:
+    """A served token that differs from llama_generate's is held to the
+    benchmark's measure (ISSUE 28: the decode kernel's online softmax may
+    flip a near-tie); a wrong token reads far over the limit."""
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        from paddle_tpu.models.llama import llama_init_params
+        cfg = cs.model_config(True, 2)
+        params = llama_init_params(cfg, jax.random.PRNGKey(0))
+        requests = cs.make_requests(cfg, 0, True)[:2]
+        return cfg, params, requests, cs.reference_tokens(cfg, params,
+                                                          requests)
+
+    def test_equal_tokens_are_not_looked_at(self, served):
+        cfg, params, requests, ref = served
+        assert cs.served_logit_gap(cfg, params, requests, ref, ref) == 0.0
+
+    def test_greedy_tokens_read_zero_against_another_reference(self, served):
+        """The greedy tokens ARE the best at every position: held against
+        a reference that differs, their own gap is 0."""
+        cfg, params, requests, ref = served
+        other = [[(t + 1) % cfg.vocab_size for t in out] for out in ref]
+        assert cs.served_logit_gap(cfg, params, requests, ref, other) < 1e-5
+
+    def test_a_wrong_token_reads_over_the_limit(self, served):
+        cfg, params, requests, ref = served
+        wrong = [list(out) for out in ref]
+        wrong[1][3] = (wrong[1][3] + 7) % cfg.vocab_size
+        gap = cs.served_logit_gap(cfg, params, requests, wrong, ref)
+        assert gap > cs.SERVE_LOGIT_GAP_LIMIT
+
+
 class TestCompileCachePlacement:
     def test_env_places_the_cache_and_code_sets_no_path(
             self, monkeypatch, tmp_path):

@@ -1,9 +1,17 @@
 """Ragged paged-attention kernel + GSPMD-sharded page pool (ISSUE 8).
 
 The contracts under test:
-  * KERNEL PARITY — ops/ragged_attention.py (interpret mode on CPU) is
-    BITWISE equal to the XLA block-table gather for decode rows and to the
-    dense causal attention for ragged prefill rows.
+  * KERNEL PARITY — ops/ragged_attention.py (interpret mode on CPU): the
+    decode body (q_max == 1, online softmax over chunks of live pages)
+    agrees with a float32 full-softmax reference within rounding; the
+    q_max > 1 body is BITWISE equal to the XLA block-table gather at
+    q_max == 1 (the pools the decode body refuses) and matches the dense
+    causal attention for ragged prefill rows.
+  * PAGED ENGINE ON THE KERNEL (ISSUE 28) — a default ``kv_layout="paged"``
+    engine whose pool ``paged_kv_read`` takes reads through the decode
+    body (``stats["kv_read"] == "kernel"``), token-identical to
+    ``llama_generate`` through a preemption; quantized pages, head_dim 64
+    and a sharded pool keep the gather.
   * SERVING PARITY — a ``kv_layout="ragged"`` ContinuousBatcher is
     token-identical to the gather-paged, dense, and per-request
     ``llama_generate`` paths at temperature=0, across staggered admission
@@ -71,16 +79,156 @@ def _mixed_requests(cfg, seed, spec):
 
 
 # ----------------------------------------------------------------- kernel
+def _full_softmax_reference(q, kp, vp, bt, kv_lens):
+    """float32 attention of each slot's decode row over rows < kv_len of
+    its pages, one full-width softmax a head: no kernel, no chunks."""
+    B, _, H, hd = q.shape
+    KV = kp.shape[2]
+    q, kp, vp = (np.asarray(a, np.float32) for a in (q, kp, vp))
+    out = np.zeros((B, 1, H, hd), np.float32)
+    for b in range(B):
+        n = int(kv_lens[b])
+        rows_k = kp[np.asarray(bt[b])].reshape(-1, KV, hd)[:n]
+        rows_v = vp[np.asarray(bt[b])].reshape(-1, KV, hd)[:n]
+        for h in range(H):
+            kh = h // (H // KV)
+            logits = rows_k[:, kh] @ q[b, 0, h] / np.float32(np.sqrt(hd))
+            probs = np.exp(logits - logits.max())
+            out[b, 0, h] = (probs / probs.sum()) @ rows_v[:, kh]
+    return out
+
+
+# decode-body cases at page_size 8, a 4-page table (32 rows), chunks of
+# 2 pages: kv_len per slot. "done": a retired slot, its table row all
+# scratch and its frozen pos left behind, reads scratch rows harmlessly
+_PS, _PMAX = 8, 4
+DECODE_CASES = {
+    "one_row": [1],
+    "one_short_of_a_page": [_PS - 1],
+    "exactly_a_page": [_PS],
+    "a_page_plus_one": [_PS + 1],
+    "a_chunk_plus_one": [2 * _PS + 1],
+    "full_bucket": [_PS * _PMAX],
+    "mixed_lengths": [3, _PS * _PMAX, 2 * _PS, 19],
+    "done_slot_all_scratch": [13, 22],
+}
+
+
 class TestRaggedKernel:
-    def test_decode_rows_bitwise_equal_to_gather(self, small_model):
-        """q_len=1 rows: the kernel's per-page DMA + full-width masked
-        softmax is the SAME arithmetic as jnp.take + the grouped einsum —
-        bitwise, not approximately."""
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", sorted(DECODE_CASES))
+    def test_decode_rows_match_full_softmax_reference(self, case, dtype,
+                                                      monkeypatch):
+        """q_max == 1 at a geometry the decode body takes (head_dim 128):
+        chunks of live pages under an online softmax against ONE
+        full-width float32 softmax. Not bitwise (ISSUE 28 ended that: the
+        summation order differs); the tolerance is stated from the dtype:
+        float32 reassociates <= 32 terms of magnitude <= 1 a few times
+        (32 * eps, with room: 1e-5); bfloat16 also rounds K, V, q and
+        the probabilities to 8 bits (2^-8 each on O(1) values: 3e-2).
+        Dead rows of a live page are poisoned with NaN: they must not
+        leak through the mask."""
+        KV, H, hd, npool = 2, 4, 128, 24
+        lens = np.array(DECODE_CASES[case], np.int32)
+        B = len(lens)
+        rng = np.random.RandomState(len(case))
+        kp = rng.randn(npool, _PS, KV, hd).astype(np.float32)
+        vp = rng.randn(npool, _PS, KV, hd).astype(np.float32)
+        bt = np.zeros((B, _PMAX), np.int32)
+        pages = iter(rng.permutation(np.arange(1, npool)))
+        for b in range(B):
+            for j in range(-(-int(lens[b]) // _PS)):
+                bt[b, j] = next(pages)
+            last, row = bt[b, (lens[b] - 1) // _PS], lens[b] % _PS
+            if row:                      # rows past kv_len in a live page
+                kp[last, row:] = vp[last, row:] = np.nan
+        if case == "done_slot_all_scratch":
+            bt[1] = 0
+            kp[0], vp[0] = rng.randn(_PS, KV, hd), rng.randn(_PS, KV, hd)
+        dt = jnp.dtype(dtype)
+        q = jnp.asarray(rng.randn(B, 1, H, hd), dt)
+        kp, vp = jnp.asarray(kp, dt), jnp.asarray(vp, dt)
+        assert ra.decode_supported(hd, KV, _PS)
+        # 2 pages a chunk: the module constant is read where the launch
+        # is built, so call the launch itself (no jit cache in between)
+        monkeypatch.setattr(ra, "_DECODE_CHUNK_ROWS", 2 * _PS * KV)
+        out = np.asarray(ra._decode_attention(
+            q, kp, vp, jnp.asarray(bt), jnp.ones(B, jnp.int32),
+            jnp.asarray(lens), True), np.float32)
+        ref = _full_softmax_reference(q, kp, vp, bt, lens)
+        atol = 1e-5 if dtype == "float32" else 3e-2
+        np.testing.assert_allclose(out, ref, rtol=0, atol=atol)
+
+    @pytest.mark.parametrize("n,r,dtype", [(3, 1, "float32"),
+                                           (2, 4, "float32"),
+                                           (3, 1, "bfloat16")])
+    def test_scatter_writes_rows_in_place_of_update_slices(self, n, r,
+                                                           dtype):
+        """paged_kv_scatter == the dynamic_update_slice loop it replaces,
+        bitwise: r rows of item i into page pages[i] from row rows[i] on
+        (a decode step: r = 1; a prefill: whole pages), K and V in one
+        launch, every other row of the pool untouched."""
+        N, ps, KV, hd = 12, 4, 8, 128
+        rng = np.random.RandomState(n + r)
+        dt = jnp.dtype(dtype)
+        kp = jnp.asarray(rng.randn(N, ps, KV, hd), dt)
+        vp = jnp.asarray(rng.randn(N, ps, KV, hd), dt)
+        ks = jnp.asarray(rng.randn(n, r, KV, hd), dt)
+        vs = jnp.asarray(rng.randn(n, r, KV, hd), dt)
+        pages = np.array([5, 2, 9][:n], np.int32)
+        rows = np.array([3, 0, 1][:n] if r == 1 else [0] * n, np.int32)
+        assert ra.scatter_supported(hd, KV, ps)
+        ko, vo = ra.paged_kv_scatter(kp, vp, ks, vs, jnp.asarray(pages),
+                                     jnp.asarray(rows), interpret=True)
+        want_k, want_v = kp, vp
+        for i in range(n):
+            at = (int(pages[i]), int(rows[i]), 0, 0)
+            want_k = jax.lax.dynamic_update_slice(want_k, ks[i][None], at)
+            want_v = jax.lax.dynamic_update_slice(want_v, vs[i][None], at)
+        assert (np.asarray(ko) == np.asarray(want_k)).all()
+        assert (np.asarray(vo) == np.asarray(want_v)).all()
+
+    @pytest.mark.parametrize("geometry,takes", [
+        (dict(head_dim=128, kv_heads=8, page_size=16), True),
+        (dict(head_dim=128, kv_heads=32, page_size=16), True),
+        (dict(head_dim=128, kv_heads=4, page_size=16), False),
+        (dict(head_dim=128, kv_heads=1, page_size=8), False),
+        (dict(head_dim=64, kv_heads=8, page_size=16), False),
+        (dict(head_dim=128, kv_heads=8, page_size=16,
+              kv_dtype="fp8"), False)])
+    def test_scatter_supported(self, geometry, takes):
+        assert ra.scatter_supported(**geometry) is takes
+
+    def test_decode_body_skips_slots_without_a_query(self):
+        """q_len 0 (a slot that takes no query this launch): exact zeros,
+        and its neighbours are untouched by it."""
+        KV, H, hd, npool = 2, 4, 128, 12
+        rng = np.random.RandomState(5)
+        kp = jnp.asarray(rng.randn(npool, _PS, KV, hd), jnp.float32)
+        vp = jnp.asarray(rng.randn(npool, _PS, KV, hd), jnp.float32)
+        q = jnp.asarray(rng.randn(3, 1, H, hd), jnp.float32)
+        bt = rng.randint(1, npool, (3, _PMAX)).astype(np.int32)
+        lens = np.array([9, 17, 30], np.int32)
+        out = np.asarray(ra.ragged_paged_attention(
+            q, kp, vp, jnp.asarray(bt), jnp.asarray([1, 0, 1], jnp.int32),
+            jnp.asarray(lens), page_size=_PS, interpret=True))
+        ref = _full_softmax_reference(q, kp, vp, bt, lens)
+        assert (out[1] == 0).all()
+        np.testing.assert_allclose(out[[0, 2]], ref[[0, 2]], rtol=0,
+                                   atol=1e-5)
+
+    def test_wide_body_decode_rows_bitwise_equal_to_gather(self,
+                                                           small_model):
+        """q_len=1 rows through the q_max > 1 body (head_dim 16: the
+        decode body refuses this pool, as it does quantized ones): its
+        per-page DMA + full-width masked softmax is the SAME arithmetic
+        as jnp.take + the grouped einsum — bitwise, not approximately."""
         from paddle_tpu.models.llama_decode import _cached_attention_slots
         cfg, _ = small_model
         KV, H, hd = (cfg.num_key_value_heads, cfg.num_attention_heads,
                      cfg.head_dim)
         B, ps, pmax, npool = 3, 8, 5, 16
+        assert not ra.decode_supported(hd, KV, ps)
         rng = np.random.RandomState(0)
         kp = jnp.asarray(rng.randn(npool, ps, KV, hd).astype(np.float32))
         vp = jnp.asarray(rng.randn(npool, ps, KV, hd).astype(np.float32))
@@ -163,6 +311,24 @@ class TestRaggedKernel:
         assert ra.supported(interpret=False, **geometry) is compiles
 
 
+    @pytest.mark.parametrize("geometry,takes", [
+        (dict(head_dim=128, kv_heads=8, page_size=16), True),   # batch cell
+        (dict(head_dim=128, kv_heads=32, page_size=16), True),  # chip_smoke
+        (dict(head_dim=256, kv_heads=4, page_size=16), True),
+        (dict(head_dim=128, kv_heads=12, page_size=16), True),
+        (dict(head_dim=128, kv_heads=1, page_size=8), True),
+        (dict(head_dim=64, kv_heads=8, page_size=16), False),
+        (dict(head_dim=16, kv_heads=2, page_size=8), False),    # tier-1 tiny
+        (dict(head_dim=128, kv_heads=4, page_size=1), False),
+        (dict(head_dim=128, kv_heads=8, page_size=16,
+              kv_dtype="int8"), False)])
+    def test_decode_supported_says_what_the_compiler_says(self, geometry,
+                                                          takes):
+        """The decode body's rule has no backend and no max_len in it
+        (tests/test_tpu_compile.py compiles a case on each side)."""
+        assert ra.decode_supported(**geometry) is takes
+
+
 # ---------------------------------------------------------------- serving
 class TestRaggedServingParity:
     SPEC = [(5, 7), (13, 3), (29, 12), (8, 1), (20, 6), (11, 9), (4, 8)]
@@ -232,6 +398,185 @@ class TestRaggedServingParity:
         assert eng._ragged is False
         rid = eng.add_request(p, max_new_tokens=m)
         assert eng.run()[rid] == _reference_generate(cfg, params, p, m)
+
+
+# ------------------------------------------- paged engine on the kernel
+@pytest.fixture(scope="module")
+def wide_model():
+    """head_dim 128 with 8 KV heads (hidden 1024 over 8 heads), ONE layer:
+    the smallest model whose pool takes the decode body AND the one-launch
+    row write (a row of 8 heads is a whole sublane tile). The interpreted
+    kernels are slow on the CPU, so everything about it stays tiny."""
+    cfg = LlamaConfig.tiny(num_hidden_layers=1, hidden_size=1024,
+                           num_attention_heads=8, num_key_value_heads=8,
+                           max_position_embeddings=128)
+    return cfg, llama_init_params(cfg, jax.random.PRNGKey(3))
+
+
+@pytest.fixture(scope="module")
+def kernel_run(wide_model):
+    """ONE default-layout engine run on the kernel read, through a
+    mid-flight preemption (8 pages for two 35-row contexts); the tests
+    below each look at one side of it."""
+    from paddle_tpu.observability import metrics, spans
+    cfg, params = wide_model
+    reqs = _mixed_requests(cfg, 37, [(5, 30), (5, 30)])
+    eng = _engine(cfg, params, max_batch=2, prompt_buckets=(8,), burst=8,
+                  num_pages=8, page_buckets=(12,))
+    rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
+    seq0 = max((sp.seq for sp in spans.records()), default=0)
+    out = eng.run()
+    bursts = [sp for sp in spans.records(since=seq0)
+              if sp.name == "serve.dispatch_burst"]
+    return dict(eng=eng, cfg=cfg, params=params, reqs=reqs, rids=rids,
+                out=out, bursts=bursts,
+                gauge=metrics.gauge("serve.kv_read_mb_per_tok").value)
+
+
+class TestPagedEngineOnKernel:
+    def test_tokens_exact_through_preemption(self, kernel_run):
+        """float32, head_dim 128, kv_layout="paged" (the default): the
+        burst reads through the interpreted decode body and the greedy
+        tokens equal llama_generate's, across a preemption."""
+        r = kernel_run
+        assert r["eng"].stats["kv_read"] == "kernel"
+        assert r["eng"]._ragged is False         # the default layout
+        assert r["eng"].stats["preemptions"] >= 1
+        for rid, (p, m) in zip(r["rids"], r["reqs"]):
+            assert r["out"][rid] == _reference_generate(
+                r["cfg"], r["params"], p, m)
+        assert r["eng"].pages_in_use == 0
+
+    def test_rows_are_written_by_one_launch_a_layer(self, wide_model):
+        """Where the read is the kernel's and a row is whole tiles, the
+        burst and the prefill hold no per-slot / per-page
+        dynamic_update_slice of the pool: one paged_kv_scatter a layer."""
+        from paddle_tpu.models.llama_paged import (init_paged_kv_cache,
+                                                   llama_paged_decode_burst,
+                                                   llama_paged_prefill_slot)
+        cfg, params = wide_model
+        B, P, ps = 2, 4, 8
+        cache = init_paged_kv_cache(cfg, 12, ps)
+        vec = jnp.zeros(B, jnp.int32)
+        burst = llama_paged_decode_burst.lower(
+            params, cache, jnp.zeros((B, P), jnp.int32), vec, vec,
+            jnp.zeros(B, bool), vec + 9, jnp.int32(0),
+            jax.random.PRNGKey(0), config=cfg, n=2).as_text()
+        prefill = llama_paged_prefill_slot.lower(
+            params, cache, jnp.zeros(16, jnp.int32),
+            jnp.zeros(2, jnp.int32), jnp.int32(9), jax.random.PRNGKey(0),
+            config=cfg).as_text()
+        pool = "x".join(str(d) for d in cache["k"][0].shape)
+        for text in (burst, prefill):
+            assert "paged_kv_scatter" in text
+            assert not [ln for ln in text.splitlines()
+                        if "dynamic_update_slice" in ln and pool in ln]
+
+    def test_dispatch_span_says_which_read(self, kernel_run):
+        """The engagement counter's second home: every
+        serve.dispatch_burst span carries kv_read."""
+        assert kernel_run["bursts"]
+        assert all(sp.args.get("kv_read") == "kernel"
+                   for sp in kernel_run["bursts"])
+
+    def test_gauge_bills_live_rows_not_the_bucket(self, kernel_run):
+        """serve.kv_read_mb_per_tok follows the live pages where the
+        kernel reads: under the 12-page bucket's bill, and a whole number
+        of pages a slot at most max_len long."""
+        from paddle_tpu.models.llama_paged import (page_bytes,
+                                                   paged_kv_bytes_per_token)
+        cfg = kernel_run["cfg"]
+        bucket = paged_kv_bytes_per_token(cfg, 12, 8) / 1e6
+        one_page = page_bytes(cfg, 8) / 1e6
+        assert one_page <= kernel_run["gauge"] <= 5 * one_page < bucket
+
+    def test_the_tiny_configs_of_tier1_keep_the_gather(self, small_model):
+        cfg, params = small_model
+        eng = _engine(cfg, params)
+        assert eng.stats["kv_read"] == "gather"
+        eng.add_request([1, 2, 3], max_new_tokens=2)
+        from paddle_tpu.observability import spans
+        seq0 = max((sp.seq for sp in spans.records()), default=0)
+        eng.run()
+        last = [sp for sp in spans.records(since=seq0)
+                if sp.name == "serve.dispatch_burst"][-1]
+        assert last.args["kv_read"] == "gather"
+
+    @pytest.mark.parametrize("engine_kw,read", [
+        (dict(), "kernel"),
+        (dict(kv_dtype="int8"), "gather"),
+        (dict(kv_layout="dense"), "dense"),
+        (dict(kv_layout="ragged"), "kernel"),
+        (dict(kv_layout="ragged", env="0"), "gather")])
+    def test_engine_selection(self, wide_model, monkeypatch, engine_kw,
+                              read):
+        """Selection by what the engine can see in its pool; the env flag
+        keeps its meaning for kv_layout="ragged" only (an explicit ask
+        for the gather, whatever the geometry)."""
+        cfg, params = wide_model
+        kw = dict(engine_kw)
+        if kw.pop("env", None):
+            monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
+        assert _engine(cfg, params, **kw).stats["kv_read"] == read
+
+    def test_env_flag_does_not_reach_the_default_layout(self, wide_model,
+                                                        monkeypatch):
+        cfg, params = wide_model
+        monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
+        assert _engine(cfg, params).stats["kv_read"] == "kernel"
+
+    @pytest.mark.parametrize("cfg_kw,page_size,kv_dtype,mesh,read", [
+        # the batch cell's geometry (InternLM2-1.8B: 16 x 128, 8 KV heads)
+        (dict(hidden_size=2048, num_attention_heads=16,
+              num_key_value_heads=8), 16, None, None, "kernel"),
+        (dict(hidden_size=2048, num_attention_heads=16,
+              num_key_value_heads=8), 16, "int8", None, "gather"),
+        (dict(hidden_size=2048, num_attention_heads=16,
+              num_key_value_heads=8), 16, "fp8", None, "gather"),
+        (dict(hidden_size=2048, num_attention_heads=16,
+              num_key_value_heads=8), 16, None, "a mesh", "gather"),
+        (dict(hidden_size=1024, num_attention_heads=16,
+              num_key_value_heads=8), 16, None, None, "gather"),  # hd 64
+        (dict(), 8, None, None, "gather")])                      # hd 16
+    def test_paged_kv_read(self, cfg_kw, page_size, kv_dtype, mesh, read):
+        from paddle_tpu.models.llama_paged import paged_kv_read
+        cfg = LlamaConfig.tiny(**cfg_kw)
+        assert paged_kv_read(cfg, page_size, kv_dtype, mesh) == read
+
+
+def _selects_over(text: str, shape: tuple) -> list:
+    """stablehlo.select lines over operands whose shape starts with
+    `shape` (``stablehlo.select %p, %a, %b : tensor<..xi1>, tensor<..>``)."""
+    dims = "x".join(str(d) for d in shape) + "x"
+    return [ln for ln in text.splitlines()
+            if "stablehlo.select" in ln and f", tensor<{dims}" in ln]
+
+
+class TestGatherThatStays:
+    def test_int8_burst_holds_no_select_over_the_gathered_rows(
+            self, small_model):
+        """The gather keeps serving quantized pools; its takes clip (the
+        block table is in bounds by construction), so the lowered burst
+        has no fill select over the gathered payloads or scales — the
+        operation that was 53 % of the batch cell's device time."""
+        from paddle_tpu.models.llama_paged import (init_paged_kv_cache,
+                                                   llama_paged_decode_burst)
+        cfg, params = small_model
+        B, P, ps = 3, 4, 8
+        gathered = (B, P, ps, cfg.num_key_value_heads)
+        cache = init_paged_kv_cache(cfg, 16, ps, kv_dtype="int8")
+        vec = jnp.zeros(B, jnp.int32)
+        text = llama_paged_decode_burst.lower(
+            params, cache, jnp.zeros((B, P), jnp.int32), vec, vec,
+            jnp.zeros(B, bool), vec + 9, jnp.int32(0),
+            jax.random.PRNGKey(0), config=cfg, n=2,
+            kv_dtype="int8").as_text()
+        assert "stablehlo.gather" in text
+        assert _selects_over(text, gathered) == []
+        # the detector sees the select that the default mode adds
+        fill = jax.jit(lambda pool, t: jnp.take(pool, t, axis=0)).lower(
+            cache["k"][0], jnp.zeros((B, P), jnp.int32)).as_text()
+        assert _selects_over(fill, gathered)
 
 
 # -------------------------------------------------------------- inventory

@@ -319,7 +319,8 @@ def test_every_pallas_call_has_a_name():
             found[names[0]] = fn
     assert set(found) == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-        "ragged_paged_attention", "block_sparse_fwd", "block_sparse_bwd_dq",
+        "ragged_paged_attention", "paged_decode_attention",
+        "paged_kv_scatter", "block_sparse_fwd", "block_sparse_bwd_dq",
         "block_sparse_bwd_dkv"}
 
 

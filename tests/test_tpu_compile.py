@@ -31,25 +31,56 @@ TRAIN_QKV = (cs.TRAIN_BATCH, cs.TRAIN_SEQ, HEADS, HEAD_DIM)   # [B, T, H, D]
 PREFILL_BUCKETS = cs.SERVE_PROMPT_BUCKETS
 SERVE_BATCH, SERVE_MAX_LEN = cs.SERVE_MAX_BATCH, cs.SERVE_MAX_LEN
 
-# the ragged kernel at the serve phase's geometry (page_size 16 is the
-# batcher's default), then one case past each rule of ra.supported(): the
-# value is the compiler's message, None where it compiles
-_SERVE = dict(q_rows=1, kv_heads=HEADS, head_dim=HEAD_DIM, page_size=16,
+# the ragged kernel's q_rows > 1 body at the serve phase's geometry
+# (page_size 16 is the batcher's default), then one case past each rule of
+# ra.supported(): the value is the compiler's message, None where it
+# compiles. At q_rows == 1 the public entry takes the decode body
+# (DECODE_CASES below) unless the pool is quantized; 2 rows are the
+# smallest launch of this body (a speculative verify of one proposal)
+_SERVE = dict(q_rows=2, kv_heads=HEADS, head_dim=HEAD_DIM, page_size=16,
               kv_dtype=None)
 RAGGED_CASES = {
-    "decode": (_SERVE, None),
+    "verify": (_SERVE, None),
     "prefill": ({**_SERVE, "q_rows": PREFILL_BUCKETS[-1]}, None),
-    "decode_gqa": ({**_SERVE, "kv_heads": 8}, None),
-    "decode_kv_heads_4": ({**_SERVE, "kv_heads": 4}, None),
+    "verify_gqa": ({**_SERVE, "kv_heads": 8}, None),
+    "verify_kv_heads_4": ({**_SERVE, "kv_heads": 4}, None),
     "head_dim_64": ({**_SERVE, "head_dim": 64},
                     "Slice shape along dimension 3 must be aligned to "
                     "tiling (128), but is 64"),
     "kv_heads_12": ({**_SERVE, "kv_heads": 12},
                     "Slice shape along dimension 2 must be aligned to "
                     "tiling (8), but is 12"),
-    "int8_pages": ({**_SERVE, "kv_dtype": "int8"},
+    "int8_pages": ({**_SERVE, "q_rows": 1, "kv_dtype": "int8"},
                    "Slice shape along dimension 2 must be aligned to "
                    "tiling (128), but is 16"),
+}
+
+# the decode body (q_rows == 1, ISSUE 28) at the batch cell's geometry
+# (perfbench/traffic/longctx-batch.json: 48 slots, a 128-page table, pages
+# of 16; InternLM2-1.8B: 8 KV heads x 128, bf16), at chip_smoke's, and one
+# case past each rule of ra.decode_supported()
+_CELL = dict(slots=48, table_pages=128, page_size=16, kv_heads=8,
+             q_heads=16, head_dim=128, dtype="bfloat16")
+DECODE_CASES = {
+    "batch_cell": (_CELL, None),
+    "chip_smoke": ({**_CELL, "slots": SERVE_BATCH,
+                    "table_pages": SERVE_MAX_LEN // 16, "kv_heads": HEADS,
+                    "q_heads": HEADS}, None),
+    "chip_smoke_f32": ({**_CELL, "slots": SERVE_BATCH,
+                        "table_pages": SERVE_MAX_LEN // 16,
+                        "kv_heads": HEADS, "q_heads": HEADS,
+                        "dtype": "float32"}, None),
+    "table_of_2048_pages": ({**_CELL, "slots": 8, "table_pages": 2048},
+                            None),
+    "kv_heads_12": ({**_CELL, "slots": 8, "kv_heads": 12, "q_heads": 24},
+                    None),
+    "head_dim_64": ({**_CELL, "slots": 8, "head_dim": 64},
+                    "Slice shape along dimension 2 must be aligned to "
+                    "tiling (128), but is 64"),
+    "page_of_4_rows": ({**_CELL, "slots": 8, "page_size": 1, "kv_heads": 4,
+                        "q_heads": 8},
+                       "Slice shape along dimension 1 must be aligned to "
+                       "tiling (8), but is 4"),
 }
 
 
@@ -181,3 +212,85 @@ def test_ragged_kernel_verdict(one_chip, no_compile_cache, case):
         lowered.compile()
     assert refusal in str(err.value) and not says
     assert refusal in " ".join(ra.supported.__doc__.split())
+
+
+def _shapes(sharding, dtype="bfloat16"):
+    """shape -> ShapeDtypeStruct on `sharding`, `dtype` unless one is given."""
+    return lambda shape, dt=dtype: jax.ShapeDtypeStruct(
+        shape, jnp.dtype(dt), sharding=sharding)
+
+
+def _decode_lowered(sharding, slots, table_pages, page_size, kv_heads,
+                    q_heads, head_dim, dtype):
+    sds = _shapes(sharding, dtype)
+    pool = sds((slots * table_pages + 1, page_size, kv_heads, head_dim))
+    lens = sds((slots,), "int32")
+    return jax.jit(lambda *a: ra._decode_attention(*a, False)).lower(
+        sds((slots, 1, q_heads, head_dim)), pool, pool,
+        sds((slots, table_pages), "int32"), lens, lens)
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_body_verdict(one_chip, no_compile_cache, case):
+    """The compiler's verdict on the decode body, and decode_supported()
+    saying the same; the body holds two chunks whatever the table's
+    width, so no case knows a max_len."""
+    geometry, refusal = DECODE_CASES[case]
+    lowered = _decode_lowered(one_chip, **geometry)
+    says = ra.decode_supported(geometry["head_dim"], geometry["kv_heads"],
+                               geometry["page_size"])
+    if refusal is None:
+        assert _kernels(lowered.compile()) == 1 and says
+        return
+    with pytest.raises(Exception) as err:
+        lowered.compile()
+    assert refusal in str(err.value) and not says
+    assert refusal in " ".join(ra.decode_supported.__doc__.split())
+
+
+def test_public_entry_takes_the_decode_body_at_the_cell(one_chip,
+                                                        no_compile_cache):
+    """ragged_paged_attention at q_rows == 1 and the cell's geometry IS
+    the decode body: the kernel's name in the compiled program, and the
+    pool's [pages, rows, heads, hd] -> [pages, rows * heads, hd] view a
+    bitcast, not a copy of the pool."""
+    g = _CELL
+    sds = _shapes(one_chip)
+    pool = sds((4096, g["page_size"], g["kv_heads"], g["head_dim"]))
+    lens = sds((g["slots"],), "int32")
+    text = ra.ragged_paged_attention.lower(
+        sds((g["slots"], 1, g["q_heads"], g["head_dim"])), pool, pool,
+        sds((g["slots"], g["table_pages"]), "int32"), lens, lens,
+        page_size=g["page_size"], interpret=False).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "paged_decode_attention" in calls[0]
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "bf16[4096," in ln]
+
+
+@pytest.mark.parametrize("case,rows,kv_heads,compiles", [
+    ("decode_row_at_the_cell", 1, 8, True),
+    ("prefill_pages_at_the_cell", 16, 8, True),
+    ("decode_row_chip_smoke", 1, HEADS, True),
+    ("decode_row_of_4_heads", 1, 4, False)])
+def test_kv_scatter_verdict(one_chip, no_compile_cache, case, rows, kv_heads,
+                            compiles):
+    """paged_kv_scatter (the one-launch K/V row write) for a described
+    v5e, scatter_supported() saying the same, and the pools written in
+    place: no copy of a pool in the compiled program."""
+    n, ps, hd = 48, 16, 128
+    sds = _shapes(one_chip)
+    pool, src = sds((4096, ps, kv_heads, hd)), sds((n, rows, kv_heads, hd))
+    lowered = jax.jit(
+        lambda *a: ra.paged_kv_scatter(*a, interpret=False),
+        donate_argnums=(0, 1)).lower(pool, pool, src, src,
+                                     sds((n,), "int32"), sds((n,), "int32"))
+    assert ra.scatter_supported(hd, kv_heads, ps) is compiles
+    if not compiles:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            lowered.compile()
+        return
+    text = lowered.compile().as_text()
+    assert _kernels(lowered.compile()) == 1
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "bf16[4096," in ln]
