@@ -20,28 +20,23 @@ import statistics
 
 import numpy as np
 
-from . import gen, harness as hs
-from .ref import llama as ref
-from .weights import GAINS, make_weights
+from . import families, gen, harness as hs
+from .weights import make_weights
 
 NEGLIGIBLE_GRAD = 1e-3
 
 
 # ------------------------------------------------------- the program's side
 
-def _per_layer_norm(name, x):
-    import jax.numpy as jnp
-    x = x.astype(jnp.float32)
-    stacked = x.ndim == 3 or (x.ndim == 2 and name in GAINS)
-    axes = tuple(range(1, x.ndim)) if stacked else None
-    return jnp.sqrt(jnp.sum(x * x, axis=axes))
-
-
-def _norm_dict(tree) -> dict:
-    """{leaf: float}; a layer-stacked parameter gives one leaf a layer."""
+def _norm_dict(tree, fam) -> dict:
+    """{leaf: float}; a parameter the family stacks by layer gives one leaf
+    a layer."""
     import jax
+    import jax.numpy as jnp
     vals = jax.device_get(jax.jit(lambda t: {
-        k: _per_layer_norm(k, v) for k, v in t.items()})(tree))
+        k: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)),
+                            axis=fam.layer_axes(k, v.ndim)))
+        for k, v in t.items()})(tree))
     out = {}
     for k, v in vals.items():
         if np.ndim(v) == 0:
@@ -51,13 +46,13 @@ def _norm_dict(tree) -> dict:
     return out
 
 
-def first_grad_norms(opt_state: dict, beta1: float) -> dict:
+def first_grad_norms(opt_state: dict, beta1: float, fam) -> dict:
     """After one step Adam's first moment is (1 - beta1) * gradient."""
     m = {k: st["moment1"] for k, st in opt_state.items()}
-    return {k: v / (1.0 - beta1) for k, v in _norm_dict(m).items()}
+    return {k: v / (1.0 - beta1) for k, v in _norm_dict(m, fam).items()}
 
 
-def change_norms(params: dict, initial: dict) -> dict:
+def change_norms(params: dict, initial: dict, fam) -> dict:
     import jax
     import jax.numpy as jnp
     diff = jax.jit(lambda a, b: {
@@ -65,7 +60,7 @@ def change_norms(params: dict, initial: dict) -> dict:
     # leaf by leaf, so that no float32 copy of the model stands whole
     out = {}
     for k in params:
-        out.update(_norm_dict(diff({k: params[k]}, {k: initial[k]})))
+        out.update(_norm_dict(diff({k: params[k]}, {k: initial[k]}), fam))
     return out
 
 
@@ -83,6 +78,8 @@ def reference_trajectory(cfg: dict, job: dict, seed: int, batches,
     import jax.numpy as jnp
     o = job["optimizer"]
     hp = (o["lr"], o["beta1"], o["beta2"], o["eps"], o["weight_decay"])
+    fam = families.of(cfg)
+    ref = fam.reference()
     rcfg = ref.hashable(cfg)
     p = {k: v.astype(jnp.float32) for k, v in make_weights(cfg, seed).items()}
     out = {"loss": []}
@@ -97,14 +94,14 @@ def reference_trajectory(cfg: dict, job: dict, seed: int, batches,
         loss, g = ref.loss_and_grads(p, tokens, labels, cfg=rcfg, dot=dot)
         out["loss"].append(float(loss))
         if t == 1:
-            out["grad_norm"] = _norm_dict(g)
+            out["grad_norm"] = _norm_dict(g, fam)
         for k in list(p):
             gs = tuple(jnp.asarray(h[k]) for h in past) + (g[k],)
             p[k] = ref.adamw_leaf(p[k], gs, jnp.int32(t), hp=hp)
         if t < updates:
             past.append(jax.device_get(g))
         del g
-    out["change_norm"] = change_norms(p, make_weights(cfg, seed))
+    out["change_norm"] = change_norms(p, make_weights(cfg, seed), fam)
     del p, past
     gc.collect()
     return out
@@ -203,6 +200,7 @@ def served_gaps(weights: dict, cfg: dict, reqs: list[dict],
     `control`, also that of the tokens the lower precision puts first at
     the same positions of the same prompts and tokens."""
     import jax.numpy as jnp
+    ref = families.of(cfg).reference()
     rcfg = ref.hashable(cfg)
     worst = {"served": 0.0, "control": 0.0, "tokens": 0}
     for r in reqs:

@@ -3,7 +3,7 @@
 The program's spans come from its span API's public read in this process
 (`paddle_tpu.observability.spans.records()`, wall-clock ns); the device side
 from `env["trace"]`. Two things the harness's Trace does not keep are read
-here from the same `.xplane.pb`:
+here from the same `.xplane.pb`, whose path is `env["xplane_path"]`:
 
   profile_start_time   a stat of the plane "Task Environment", wall-clock
                        ns (through jax.profiler.ProfileData): the trace's
@@ -21,10 +21,7 @@ those planes gives None, and the metric is left out.
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import re
-import tempfile
 from collections import defaultdict
 
 from .. import trace as tracemod
@@ -179,35 +176,18 @@ def read_xplane_meta(path: str, window=None) -> dict | None:
     return {"profile_start_ns": start_ns, "op_names": read_op_names(path)}
 
 
-def run_xplanes(newest: int = 4) -> list[str]:
-    """Candidates for the trace this process has just written, newest
-    first: harness.traced_window makes its directory under the temporary
-    directory by this prefix, never removes it and does not pass it on, so
-    a neighbour's or an older run's may lie beside it."""
-    dirs = sorted(glob.glob(os.path.join(tempfile.gettempdir(),
-                                         "perfbench_trace_*")),
-                  key=os.path.getmtime, reverse=True)
-    paths = [tracemod.find_xplane(d) for d in dirs[:newest]]
-    return [p for p in paths if p]
-
-
 def meta(env) -> dict | None:
-    """read_xplane_meta of the run's trace, read once a run: of the file
-    whose window annotation is `env["trace"]`'s, or None."""
-    tr = env.get("trace")
+    """read_xplane_meta of the run's trace, `env["xplane_path"]`, read once
+    a run; None unless its window annotation is `env["trace"]`'s."""
+    tr, path = env.get("trace"), env.get("xplane_path")
     if tr is None:
         return None
     if "_xplane_meta" not in env:
-        env["_xplane_meta"] = None
-        given = env.get("xplane_path")
-        for path in [given] if given else run_xplanes():
-            try:
-                m = read_xplane_meta(path, tr.window())
-            except (OSError, ValueError, IndexError):
-                m = None
-            if m is not None:
-                env["_xplane_meta"] = m
-                break
+        try:
+            env["_xplane_meta"] = read_xplane_meta(path, tr.window()) \
+                if path else None
+        except (OSError, ValueError, IndexError):
+            env["_xplane_meta"] = None
     return env["_xplane_meta"]
 
 

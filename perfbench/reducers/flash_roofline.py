@@ -1,10 +1,11 @@
 """The flash kernels' share of their roofline: the least time the chip
-could take for the calls the traced window made (perfbench/arith.py's
-operations and bytes) over the device durations of the kernels' events,
-found in the trace by name inside the programs whose name matches `within`.
-Every train step runs the forward and the backward in every layer, and a
-forward recomputed by remat is time with no credit."""
+could take for the calls the traced window made (the family's attention
+calls a step, perfbench/arith.py's operations and bytes of each) over the
+device durations of the kernels' events, found in the trace by name inside
+the programs whose name matches `within`. Every call is a forward and a
+backward, and a forward recomputed by remat is time with no credit."""
 from .. import arith, harness as hs
+from .flash_kernel_roofline import credited
 
 
 def read(env, match, within=None):
@@ -15,13 +16,9 @@ def read(env, match, within=None):
     if not found:
         return None
     seconds, calls = found
-    d, rec = arith.dims(env["cfg"]), env["record"]
-    shape = (rec["batch"] // rec["chips"] or 1, d["H"], d["KV"],
-             rec["seq_len"], d["hd"])
-    f1, b1 = arith.flash_fwd_cost(*shape)
-    f2, b2 = arith.flash_bwd_cost(*shape)
-    n = len(rec["step_t"]) * d["L"]
-    least, bound = arith.roofline_seconds(n * (f1 + f2), n * (b1 + b2), peaks)
+    _, flops, byts = credited(env, [arith.flash_fwd_cost,
+                                    arith.flash_bwd_cost])
+    least, bound = arith.roofline_seconds(flops, byts, peaks)
     hs.say({"flash_roofline": within, "bound": bound, "kernel_calls": calls,
             "kernel_seconds": seconds, "least_seconds": least})
     return 100.0 * least / seconds

@@ -1,16 +1,15 @@
 """The served step's share of the chip's peak: the operations that every
 real (unpadded) prompt and output token of the traced window needs, over
 peak x the traced window."""
-from .. import arith
+from .. import families
 
 
 def window_flops(record, cfg):
+    fam = families.of(cfg)
     total = 0.0
     for s in record["steps"]:
-        total += sum(arith.prefill_flops(cfg, t) for t in s["prefills"])
-        for ctx0, n in s["decodes"]:
-            total += sum(arith.decode_flops(cfg, ctx0 + 1 + j)
-                         for j in range(n))
+        total += sum(fam.prefill_work(cfg, t)[0] for t in s["prefills"])
+        total += fam.burst_work(cfg, s["decode_steps"], s["decodes"])[0]
     return total
 
 

@@ -1,25 +1,24 @@
 """Least device time for the window's work over the device's busy time.
 
-Each prefill: max(operations / peak, bytes / bandwidth), bytes = the weights
-once + the prompt's KV written. Each burst: the weights once per executed
-decode step + the LIVE KV rows each emitted token attends + the rows
-written. It reads the same work whether a gather or a kernel does it."""
-from .. import arith
+Each prefill and each burst: max(operations / peak, bytes / bandwidth), the
+operations and bytes being what the configuration's family says that step
+needs (perfbench/families/: a prefill's weights once + what it writes; a
+burst's weights once per executed decode step + the LIVE cache and state its
+tokens read and write). It reads the same work whether a gather or a kernel
+does it."""
+from .. import arith, families
 
 
 def least_seconds(record, cfg, peaks):
-    w, kv = arith.weight_bytes(cfg), arith.kv_bytes_per_token(cfg)
+    fam = families.of(cfg)
     total = 0.0
     for s in record["steps"]:
         for t in s["prefills"]:
-            total += arith.roofline_seconds(
-                arith.prefill_flops(cfg, t), w + t * kv, peaks)[0]
+            total += arith.roofline_seconds(*fam.prefill_work(cfg, t),
+                                            peaks)[0]
         if s["decode_steps"]:
-            flops = sum(arith.decode_flops(cfg, c + 1 + j)
-                        for c, n in s["decodes"] for j in range(n))
-            rows = sum(arith.live_kv_rows(c, n) + n for c, n in s["decodes"])
-            total += arith.roofline_seconds(
-                flops, s["decode_steps"] * w + rows * kv, peaks)[0]
+            total += arith.roofline_seconds(*fam.burst_work(
+                cfg, s["decode_steps"], s["decodes"]), peaks)[0]
     return total
 
 

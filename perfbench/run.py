@@ -13,7 +13,9 @@ asks for, it exits non-zero and prints no result.
     --rehearse       tiny configurations from perfbench/configs/rehearse/ on
                      whatever backend JAX has; every number is marked as a
                      rehearsal and none is a device metric
-    --keep-trace D   copy the traced run's .xplane.pb into D
+    --keep-trace D   copy the traced run's .xplane.pb into D, and leave the
+                     profiler's own directory where it is (a run without it
+                     removes that directory once the readers have run)
     --control int8   the control of `correct`: the reference in the next
                      lower precision put where the program's answers stand;
                      it has to come out as not correct (never run by the
@@ -60,7 +62,8 @@ def parse(argv):
 def per_layer(ctx: dict, result: dict, dev: dict) -> tuple[dict, dict, dict]:
     """(metrics, device additions, breakdown) of a traced run: each metric's
     own reader takes it from the runner's record and the trace; one that
-    finds nothing to read is left out."""
+    finds nothing to read is left out, and so is every device-trace metric
+    of a trace that was cut short."""
     from . import arith, trace
     tr = None
     path = trace.find_xplane(result["trace_dir"]) if result["trace_dir"] \
@@ -72,10 +75,18 @@ def per_layer(ctx: dict, result: dict, dev: dict) -> tuple[dict, dict, dict]:
             shutil.copy(path, ctx["keep_trace"])
     peaks = None if ctx["rehearse"] else arith.load_peaks(dev["kind"])
     busy = tr.busy_seconds() if tr else None    # (busy_s, window_s)
+    cut = tr.cut_short() if tr else None
+    if cut:
+        hs.say({"trace_cut_short": f"the last device event ends {cut:.3f} s "
+                f"before the window's {busy[1]:.3f} s do: the profiler's "
+                "buffer was full, so the device-trace metrics are left out"})
     env = {"record": result["record"], "trace": tr, "busy": busy,
-           "cfg": ctx["cfg"], "traffic": ctx["traffic"], "peaks": peaks}
+           "xplane_path": path, "cfg": ctx["cfg"], "traffic": ctx["traffic"],
+           "peaks": peaks}
     out = {}
     for m in hs.metrics_of(ctx["bench"], ctx["cell"], "per_layer"):
+        if cut and m["source"] == "device_trace":
+            continue
         spec = hs.load_json("metrics", m["name"] + ".json")
         reader = importlib.import_module(
             f"{__package__}.reducers.{spec['reducer']}")
@@ -86,6 +97,8 @@ def per_layer(ctx: dict, result: dict, dev: dict) -> tuple[dict, dict, dict]:
     if busy:
         extra = {"busy_s": busy[0], "window_s": busy[1]}
         brk = trace.breakdown(tr)
+    if result["trace_dir"] and not ctx.get("keep_trace"):
+        shutil.rmtree(result["trace_dir"], ignore_errors=True)
     return out, extra, brk
 
 

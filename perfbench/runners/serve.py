@@ -1,5 +1,6 @@
-"""Traffic kinds `serve_closed` and `serve_open`: ContinuousBatcher under a
-request schedule from the seed.
+"""Traffic kinds `serve_closed` and `serve_open`: the serving engine of the
+configuration's family (perfbench/families/) under a request schedule from
+the seed.
 
 Closed: a backlog that never drains; set-up fills every slot, the window
 opens when every slot has been occupied once, and each finished request is
@@ -18,9 +19,8 @@ from __future__ import annotations
 import gc
 import time
 
-from .. import arith, check, faults, gen, harness as hs, stats
+from .. import check, families, faults, gen, harness as hs, stats
 from ..weights import make_weights
-from .train import llama_config
 
 
 class Tracker:
@@ -67,20 +67,6 @@ class Tracker:
         return {"prefills": prefills, "decodes": decodes}
 
 
-def build_engine(cfg: dict, traffic: dict, weights):
-    """The mix's `engine` settings are ContinuousBatcher's own arguments,
-    passed through as they stand (a list becomes a tuple, a null is left to
-    the engine's default), so a mix that sets another option of the engine
-    needs no edit here. Greedy unless the mix says otherwise: `correct`
-    compares greedy tokens."""
-    from paddle_tpu.inference import ContinuousBatcher
-    kw = {"temperature": 0.0}
-    for k, v in traffic["engine"].items():
-        if v is not None:
-            kw[k] = tuple(v) if isinstance(v, list) else v
-    return ContinuousBatcher(llama_config(cfg, kw["max_len"]), weights, **kw)
-
-
 def _top(spec: dict, default: int) -> int:
     return int(spec.get("hi", spec.get("value", default)))
 
@@ -125,14 +111,14 @@ def prepare(ctx: dict) -> dict:
     cfg, traffic, seed = ctx["cfg"], ctx["traffic"], ctx["seed"]
     t0 = hs.now()
     weights = make_weights(cfg, seed)
-    eng = build_engine(cfg, traffic, weights)
+    eng = families.of(cfg).engine(cfg, traffic, weights)
     jax.block_until_ready(weights)
     t1 = hs.now()
     took = warm_up(eng, traffic, cfg, seed)
     hs.say({"setup_phases_s": {"before_runner": t0 - ctx["t0"],
                                "weights_and_engine": t1 - t0,
                                "warm_up_requests": took}})
-    return {"eng": faults.plant("serve", ctx.get("fault"), eng),
+    return {"eng": faults.plant("serve", ctx.get("fault"), eng, cfg),
             "weights": weights}
 
 
@@ -208,8 +194,8 @@ def measure(state: dict, ctx: dict) -> dict:
         t_close = hs.now()
     window_s = t_close - t_open
     stats1 = dict(eng.stats)
-    live_rows = sum(r["prompt_len"] + r["seen"] for r in trk.active
-                    if r["seen"])        # the cache the engine holds now
+    live = [r["prompt_len"] + r["seen"] for r in trk.active
+            if r["seen"]]                # the cache the engine holds now
 
     drain_s = 0.0
     if not closed:          # the requests due in the window get their answer
@@ -237,8 +223,8 @@ def measure(state: dict, ctx: dict) -> dict:
             "engine_steps": len(steps_log), "requests": len(counted),
             "failed": failed, "compilations_in_window": counter.count,
             "page_buckets_used": stats1["page_buckets_used"],
-            "live_kv_bytes_at_close":
-                live_rows * arith.kv_bytes_per_token(cfg)}
+            "live_kv_bytes_at_close": families.of(cfg).held_bytes(
+                cfg, sum(live), len(live))}
     took = [s["t1"] - s["t0"] for s in steps_log if s["t1"] <= window_s + 1e-9]
     if took:        # a slow run shows here whether every step was slow
         info["engine_step_s"] = {

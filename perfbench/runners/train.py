@@ -1,8 +1,9 @@
 """Traffic kind `train`: the compiled train step on loader batches.
 
-Set-up builds ONE LlamaTrainStep, gives it weights made by the benchmark
-from the seed, and drives it through its first `check_steps` steps through
-the window's own call (`step(tokens, labels)` on batches from the running
+Set-up builds ONE train step of the configuration's family
+(perfbench/families/), with weights made by the benchmark from the seed in
+it, and drives it through its first `check_steps` steps through the
+window's own call (`step(tokens, labels)` on batches from the running
 TokenDataLoader); the same object then runs the window. After the window,
 with the peak read and the program's state freed, the plain reference
 follows those steps in float32 and the readings are compared (check.py).
@@ -17,23 +18,8 @@ import tempfile
 
 import numpy as np
 
-from .. import check, faults, gen, harness as hs
+from .. import check, families, faults, gen, harness as hs
 from ..weights import make_weights
-
-
-def llama_config(cfg: dict, seq_len: int):
-    import jax.numpy as jnp
-    from paddle_tpu.models import LlamaConfig
-    return LlamaConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_hidden_layers=cfg["num_hidden_layers"],
-        num_attention_heads=cfg["num_attention_heads"],
-        num_key_value_heads=cfg["num_key_value_heads"],
-        max_position_embeddings=max(seq_len, 128),
-        rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        dtype=jnp.dtype(cfg.get("dtype", "bfloat16")))
 
 
 def build_mesh(traffic: dict, chips: int):
@@ -47,35 +33,6 @@ def build_mesh(traffic: dict, chips: int):
     from paddle_tpu.distributed.process_mesh import ProcessMesh
     devs = np.asarray(jax.devices()[:chips]).reshape(spec["shape"])
     return ProcessMesh(Mesh(devs, tuple(spec["axes"])))
-
-
-def build_step(cfg: dict, job: dict, mesh, seed: int):
-    """The program under test with the benchmark's weights in it."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.models import LlamaTrainStep
-    from paddle_tpu.optimizer import AdamW
-
-    opt = job["optimizer"]
-    step = LlamaTrainStep(
-        llama_config(cfg, job["seq_len"]), mesh=mesh, remat=job["remat"],
-        seed=0, optimizer=AdamW(
-            learning_rate=opt["lr"], beta1=opt["beta1"], beta2=opt["beta2"],
-            epsilon=opt["eps"], weight_decay=opt["weight_decay"],
-            moment_dtype=jnp.dtype(opt["moment_dtype"])))
-    # the program's own initial state goes before the benchmark's is made,
-    # so that the two never stand on the device together
-    step.load_resilience_state({"params": None, "opt_state": None, "step": 0})
-    gc.collect()
-    weights = make_weights(cfg, seed)
-    if mesh is not None:
-        from paddle_tpu.models.llama import shard_llama_params
-        weights = shard_llama_params(weights, step.config, mesh)
-    step.load_resilience_state({"params": weights,
-                                "opt_state": step.optimizer.init_state(weights),
-                                "step": 0})
-    jax.block_until_ready(step.params)
-    return step
 
 
 @contextlib.contextmanager
@@ -123,9 +80,11 @@ def run(ctx: dict) -> dict:
     if ctx["control"]:
         return run_control(ctx, n_check)
 
+    fam = families.of(cfg)
     with open_loader(cfg, job, seed) as (loader, corpus):
-        step = build_step(cfg, job, build_mesh(traffic, chips), seed)
-        call = faults.plant("train", ctx.get("fault"), step)
+        step = fam.train_step(cfg, job, build_mesh(traffic, chips),
+                              lambda: make_weights(cfg, seed))
+        call = faults.plant("train", ctx.get("fault"), step, cfg)
 
         # the first steps, through the window's own call and feed
         batches, prog = [], {"loss": []}
@@ -137,10 +96,10 @@ def run(ctx: dict) -> dict:
             if i == 0:
                 prog["grad_norm"] = check.first_grad_norms(
                     step.resilience_state()["opt_state"],
-                    job["optimizer"]["beta1"])
+                    job["optimizer"]["beta1"], fam)
             if i == min(1, n_check - 1):
                 prog["change_norm"] = check.change_norms(
-                    step.params, make_weights(cfg, seed))
+                    step.params, make_weights(cfg, seed), fam)
 
         record = {"step_t": [], "data_wait_s": 0.0, "batch": B, "seq_len": T,
                   "chips": chips}

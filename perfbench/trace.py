@@ -13,6 +13,9 @@ plane, on the same clock.
            on the same line cover, so a loop is not counted beside its body
   gaps     idle intervals of the device inside the window, each given to the
            innermost harness span that covers its middle
+  cut      the profiler's buffer holds a fixed number of events; when it is
+           full the device's later operations are lost, and every share of
+           busy time is wrong (PERF.md section 6, PR 28)
 """
 from __future__ import annotations
 
@@ -26,6 +29,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 SPAN_PREFIX = "perfbench."
 WINDOW_SPAN = "perfbench.window"
+CUT_TAIL = (0.05, 4.0)      # of the window; times the longest gap before it
 
 
 def find_xplane(trace_dir: str) -> str | None:
@@ -133,6 +137,23 @@ class Trace:
             return None
         per_chip = [total(self.busy(c, *w)) for c in sorted(self.ops)]
         return sum(per_chip) / len(per_chip), w[1] - w[0]
+
+    def cut_short(self) -> float | None:
+        """The idle seconds at the window's end, where they are over 5 % of
+        the window and over four times the longest idle gap before them: the
+        last device event then ends well before the window does, as no
+        steady run's does. None where the trace is whole."""
+        w = self.window()
+        if w is None or not self.ops:
+            return None
+        busy = self.busy(sorted(self.ops)[0], *w)
+        if not busy:
+            return w[1] - w[0]
+        tail = w[1] - busy[-1][1]
+        inner = max([busy[0][0] - w[0]] + [b[0] - a[1] for a, b
+                                          in zip(busy, busy[1:])])
+        cut = tail > max(CUT_TAIL[0] * (w[1] - w[0]), CUT_TAIL[1] * inner)
+        return tail if cut else None
 
     # -- where the time goes ----------------------------------------------
     def op_seconds(self, line: str = "ops") -> dict:

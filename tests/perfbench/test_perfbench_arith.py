@@ -1,10 +1,13 @@
-"""The yardstick's arithmetic against hand-worked numbers."""
+"""The yardstick's arithmetic against hand-worked numbers: what is the same
+for every model (perfbench/arith.py) and what the Llama family's shape
+decides (perfbench/families/llama.py)."""
 import json
 import os
 
 import pytest
 
 from perfbench import arith
+from perfbench.families import llama
 
 CONFIGS = os.path.join(os.path.dirname(arith.__file__), "configs")
 
@@ -26,47 +29,47 @@ INTERN, MISTRAL = cfg("internlm2-1.8b"), cfg("mistral-7b.l4")
      1_134_596_096),
 ])
 def test_parameter_counts(c, layer, total):
-    assert arith.layer_params(c) == layer
-    assert arith.total_params(c) == total
-    assert arith.matmul_params(c) == total - c["vocab_size"] * c["hidden_size"]
+    assert llama.layer_params(c) == layer
+    assert llama.total_params(c) == total
+    assert llama.matmul_params(c) == total - c["vocab_size"] * c["hidden_size"]
 
 
 def test_issue_25_round_numbers():
-    assert round(arith.total_params(INTERN) / 1e9, 2) == 1.89
-    assert round(arith.total_params(MISTRAL) / 1e9, 3) == 1.135
-    assert round(24 * arith.layer_params(INTERN) / 1e9, 2) == 1.51
-    assert round(arith.layer_params(MISTRAL) / 1e6) == 218
+    assert round(llama.total_params(INTERN) / 1e9, 2) == 1.89
+    assert round(llama.total_params(MISTRAL) / 1e9, 3) == 1.135
+    assert round(24 * llama.layer_params(INTERN) / 1e9, 2) == 1.51
+    assert round(llama.layer_params(MISTRAL) / 1e6) == 218
 
 
 def test_kv_bytes_per_token():
-    assert arith.kv_bytes_per_token(INTERN) == 96 * 1024
+    assert llama.kv_bytes_per_token(INTERN) == 96 * 1024
     one_layer = dict(MISTRAL, num_hidden_layers=1)
-    assert arith.kv_bytes_per_token(one_layer) == 4 * 1024
+    assert llama.kv_bytes_per_token(one_layer) == 4 * 1024
     sixteen = dict(MISTRAL, num_hidden_layers=16)
-    assert arith.kv_bytes_per_token(sixteen) == 64 * 1024
+    assert llama.kv_bytes_per_token(sixteen) == 64 * 1024
 
 
 def test_weight_bytes_is_what_a_decode_step_reads():
-    n = arith.total_params(INTERN) - 92544 * 2048
-    assert arith.weight_bytes(INTERN) == 2 * n
-    assert 3.3e9 < arith.weight_bytes(INTERN) < 3.5e9
+    n = llama.total_params(INTERN) - 92544 * 2048
+    assert llama.weight_bytes(INTERN) == 2 * n
+    assert 3.3e9 < llama.weight_bytes(INTERN) < 3.5e9
 
 
 def test_train_flops_per_token_is_bench_py_arithmetic():
-    n, ne = arith.total_params(MISTRAL), 32000 * 4096
+    n, ne = llama.total_params(MISTRAL), 32000 * 4096
     want = 6.0 * (n - ne) + 6.0 * 4 * 32 * 128 * 2048
-    assert arith.train_flops_per_token(MISTRAL, 2048) == want
+    assert llama.train_flops_per_token(MISTRAL, 2048) == want
     assert round(want / 1e9, 1) == 6.2      # ISSUE 25: 6.2 GFLOP per token
 
 
 def test_prefill_and_decode_flops():
-    d = arith.dims(INTERN)
-    lin = 24 * (arith.layer_params(INTERN) - 2 * 2048)
-    assert arith.prefill_flops(INTERN, 1000) == pytest.approx(
+    d = llama.dims(INTERN)
+    lin = 24 * (llama.layer_params(INTERN) - 2 * 2048)
+    assert llama.prefill_flops(INTERN, 1000) == pytest.approx(
         2.0 * lin * 1000 + 2.0 * 2048 * 92544
         + 2.0 * 24 * 16 * 128 * 1000 * 1000)
-    assert arith.decode_flops(INTERN, 500) == pytest.approx(
-        2.0 * arith.matmul_params(INTERN) + 4.0 * 24 * 16 * 128 * 500)
+    assert llama.decode_flops(INTERN, 500) == pytest.approx(
+        2.0 * llama.matmul_params(INTERN) + 4.0 * 24 * 16 * 128 * 500)
     assert d["hd"] == 128 and d["KV"] == 8
 
 
@@ -81,7 +84,7 @@ def test_live_kv_rows_is_the_sum_over_tokens():
     for t0, m in [(10, 2), (16, 1), (1023, 64)]:
         assert arith.live_kv_rows(t0, m) == sum(
             t + 1 for t in range(t0, t0 + m))
-    assert arith.live_kv_rows(10, 2) * arith.kv_bytes_per_token(INTERN) \
+    assert arith.live_kv_rows(10, 2) * llama.kv_bytes_per_token(INTERN) \
         == (11 + 12) * 96 * 1024
 
 

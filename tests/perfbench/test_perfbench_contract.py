@@ -145,11 +145,56 @@ def test_whole_step_mfu_beside_every_kernel_roofline():
                        for o in BENCH["per_layer"]), m["name"]
 
 
+@pytest.mark.parametrize("conf", BENCH["configs"], ids=lambda c: c["name"])
+def test_configuration_names_a_family_whose_module_exists(conf):
+    from perfbench import families
+    real = os.path.join(ROOT, conf["file"])
+    tiny = os.path.join(os.path.dirname(real), "rehearse",
+                        os.path.basename(real))
+    for path in (real, tiny):
+        with open(path) as f:
+            cfg = json.load(f)
+        fam = families.of(cfg)      # no default: the file says what it is
+        assert fam.__name__ == "perfbench.families." + cfg["family"]
+        assert os.path.exists(os.path.join(hs.HERE, "families",
+                                           cfg["family"] + ".py"))
+        for name in ("shapes", "GAINS", "engine", "train_step", "reference",
+                     "layer_axes", "prefill_work", "burst_work",
+                     "train_flops_per_token", "held_bytes",
+                     "train_attention_calls"):
+            assert hasattr(fam, name), name
+
+
+def test_the_llamas_shape_is_named_in_its_two_files_only():
+    """Everything under perfbench/ that knows a Llama's shape is behind
+    families/llama.py and ref/llama.py: no other file imports the program's
+    models, names its Llama classes or its engine, or imports the Llama
+    reference (ISSUE 29, point 4)."""
+    llama = re.compile(r"paddle_tpu\.models|LlamaConfig|LlamaTrainStep|"
+                       r"shard_llama_params|ContinuousBatcher|llama_config|"
+                       r"ref\.llama|ref import llama")
+    seen = set()
+    for d, dirs, files in os.walk(hs.HERE):
+        dirs[:] = [x for x in dirs if x != "__pycache__"]
+        for f in files:
+            rel = os.path.relpath(os.path.join(d, f), hs.HERE)
+            if not f.endswith(".py"):
+                continue
+            with open(os.path.join(d, f)) as src:
+                if llama.search(src.read()):
+                    seen.add(rel)
+    assert seen == {os.path.join("families", "llama.py")}
+    with open(os.path.join(hs.HERE, "ref", "llama.py")) as src:
+        assert "paddle_tpu" not in src.read()       # nothing of the program
+
+
 def test_harness_does_not_import_jax_at_import_time():
     import subprocess
     import sys
     code = ("import sys; import perfbench.harness, perfbench.arith, "
-            "perfbench.gen, perfbench.stats, perfbench.trace; "
-            "sys.exit('jax' in sys.modules)")
+            "perfbench.gen, perfbench.stats, perfbench.trace, "
+            "perfbench.families, perfbench.families.llama; "
+            "perfbench.families.of({'family': 'llama'}); "
+            "sys.exit('jax' in sys.modules or 'paddle_tpu' in sys.modules)")
     assert subprocess.run([sys.executable, "-c", code], cwd=ROOT).returncode == 0
     assert perfbench.__doc__

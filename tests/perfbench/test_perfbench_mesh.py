@@ -18,16 +18,16 @@ def as_four_chip_cell(ctx):
 
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 virtual devices")
 def test_mesh_rehearsal_is_correct_and_sharded(monkeypatch):
-    from perfbench.runners import train
+    from perfbench.families import llama
     seen = {}
-    build = train.build_step
+    build = llama.train_step
 
     def spy(*a, **kw):
         step = build(*a, **kw)
         seen["devices"] = {d for d in step.params["wq"].sharding.device_set}
         seen["spec"] = str(step.params["wq"].sharding.spec)
         return step
-    monkeypatch.setattr(train, "build_step", spy)
+    monkeypatch.setattr(llama, "train_step", spy)
     line = drive(CELL, edit=as_four_chip_cell)
     assert len(seen["devices"]) == 4 and "tp" in seen["spec"]
     assert line["correct"] is True, line["compared"]

@@ -10,7 +10,7 @@ import os
 
 import pytest
 
-from perfbench import arith, harness as hs, trace
+from perfbench import arith, families, harness as hs, trace
 from perfbench.reducers import _program
 from paddle_tpu.observability.spans import Span
 
@@ -118,7 +118,8 @@ def test_device_share_by_program_on_the_fixture(env):
 def test_flash_kernel_roofline_on_the_fixture(env, tr, capsys):
     rd = reader("flash_kernel_roofline")
     env["peaks"] = arith.load_peaks("TPU v5 lite")
-    env["cfg"] = {"hidden_size": 512, "num_attention_heads": 4,
+    env["cfg"] = {"family": "llama", "hidden_size": 512,
+                  "num_attention_heads": 4,
                   "num_key_value_heads": 4, "num_hidden_layers": 2,
                   "intermediate_size": 1024, "vocab_size": 512}
     env["record"] = {"batch": 2, "chips": 1, "seq_len": 128,
@@ -127,7 +128,7 @@ def test_flash_kernel_roofline_on_the_fixture(env, tr, capsys):
     secs, calls = tr.matching_seconds(pat, "jit_step_fn")
     fwd = rd.read(env, kernels=pat, cost="fwd", within="jit_step_fn")
     bwd = rd.read(env, kernels=pat, cost="bwd", within="jit_step_fn")
-    d = arith.dims(env["cfg"])
+    d = families.of(env["cfg"]).dims(env["cfg"])
     shape = (2, d["H"], d["KV"], 128, d["hd"])
     f1, b1 = arith.flash_fwd_cost(*shape)
     least, _ = arith.roofline_seconds(24 * f1, 24 * b1, env["peaks"])
@@ -395,33 +396,15 @@ def test_program_records_is_the_rings_public_read():
     assert recs[-1].name == "serve.step" and recs[-1].t1_ns >= recs[-1].t0_ns
 
 
-def test_the_runs_xplane_is_the_one_whose_window_is_the_traces(
-        tmp_path, monkeypatch, tr):
-    """The harness leaves its trace directories in the temporary directory
-    and does not say which is this run's: a neighbour's or a stale one,
-    even a newer one, is passed over for the file whose own
-    `perfbench.window` annotation is the window of `env["trace"]`."""
-    import shutil
-    import tempfile
-    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
-    assert _program.run_xplanes() == []
+def test_the_runs_xplane_is_held_to_the_window_of_the_trace(tr):
+    """The harness hands the readers the path of the run's own trace
+    (`env["xplane_path"]`); a file whose `perfbench.window` annotation is
+    not the window of `env["trace"]` gives nothing, never a shifted number,
+    and neither does a run without a path."""
     assert _program.meta({"trace": tr}) is None
-
-    def lay(name, src, mtime):
-        d = tmp_path / ("perfbench_trace_" + name) / "plugins" / "profile" \
-            / "t0"
-        d.mkdir(parents=True)
-        shutil.copy(src, d / "host.xplane.pb")
-        os.utime(tmp_path / ("perfbench_trace_" + name), (mtime, mtime))
-        return str(d / "host.xplane.pb")
-
-    other = lay("neighbour", NAMED["train"], 2000)
-    assert _program.run_xplanes() == [other]
-    assert _program.meta({"trace": tr}) is None     # not this run's
-    mine = lay("mine", FIXTURE, 1000)               # older than the other
-    assert _program.run_xplanes() == [other, mine]
-    got = _program.meta({"trace": tr})
+    assert _program.meta({"trace": None, "xplane_path": FIXTURE}) is None
+    assert _program.meta({"trace": tr,
+                          "xplane_path": NAMED["train"]}) is None
+    got = _program.meta({"trace": tr, "xplane_path": FIXTURE})
     assert got["profile_start_ns"] == 1790772760547486691
     assert STEP in got["op_names"]
-    # and a path the harness hands over is held to the same check
-    assert _program.meta({"trace": tr, "xplane_path": other}) is None

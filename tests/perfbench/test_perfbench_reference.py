@@ -9,9 +9,10 @@ import numpy as np
 import pytest
 
 import perfbench
-from perfbench.ref import llama as ref
-from perfbench.runners.train import llama_config
+from perfbench.families import llama
 from perfbench.weights import make_weights
+
+ref = llama.reference()
 
 HERE = os.path.dirname(perfbench.__file__)
 
@@ -24,7 +25,7 @@ def tiny(name):
 @pytest.fixture(scope="module", params=["internlm2-1.8b", "mistral-7b.l4"])
 def model(request):
     cfg = tiny(request.param)
-    return cfg, make_weights(cfg, 5), llama_config(cfg, 64)
+    return cfg, make_weights(cfg, 5), llama.llama_config(cfg, 64)
 
 
 def test_reference_imports_nothing_of_the_program():

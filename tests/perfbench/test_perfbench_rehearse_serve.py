@@ -52,7 +52,7 @@ def test_open_loop_rehearsal(bench, capsys):
 def test_engine_settings_pass_through_as_they_stand(monkeypatch):
     """A mix may set any option of the engine: none is picked by name."""
     import paddle_tpu.inference as inf
-    from perfbench.runners import serve
+    from perfbench.families import llama
     seen = {}
 
     def engine(config, weights, **kw):
@@ -61,7 +61,7 @@ def test_engine_settings_pass_through_as_they_stand(monkeypatch):
     cell = prun.hs.load_cell(BATCH, True)
     mix = dict(cell["traffic"], engine=dict(
         cell["traffic"]["engine"], prefix_cache_pages=64, top_k=None))
-    serve.build_engine(cell["cfg"], mix, weights=None)
+    llama.engine(cell["cfg"], mix, weights=None)
     assert seen["prefix_cache_pages"] == 64 and seen["temperature"] == 0.0
     assert seen["prompt_buckets"] == (16, 32) and seen["page_buckets"] == (4, 8)
     assert "top_k" not in seen and "pool_hbm_bytes" not in seen   # nulls

@@ -95,3 +95,75 @@ def test_a_trace_without_a_tpu_plane_gives_nothing(tmp_path):
     t = trace.Trace(trace.find_xplane(str(tmp_path)))
     assert t.busy_seconds() is None and t.op_seconds() == {}
     assert t.idle_gaps() == {} and t.window() is not None
+
+
+# ------------------------------------------------ a trace that is cut short
+# PR 28: with 25 230 device operations a burst the profiler's buffer was full
+# after ~28 s of a 40 s window; busy time was cut there, and the roofline and
+# idle shares read 94.9 % and 32.5 %, both wrong (PERF.md section 6).
+
+def cut_in_the_middle(t):
+    """The fixture as a full buffer would have left it: no device event
+    past the middle of the window."""
+    lo, hi = t.window()
+    t.ops = {c: [ev for ev in evs if ev[1] <= (lo + hi) / 2]
+             for c, evs in t.ops.items()}
+    t._busy = {}
+    return t
+
+
+def test_a_whole_trace_is_not_cut_and_a_cut_one_says_by_how_much(tr):
+    assert tr.cut_short() is None
+    cut = cut_in_the_middle(trace.Trace(FIXTURE))
+    lo, hi = cut.window()
+    tail = cut.cut_short()
+    assert (hi - lo) / 2 <= tail < 0.6 * (hi - lo)
+    assert tail == pytest.approx(hi - max(e for _, e, _ in cut.ops[0]))
+    # a device that idles at the window's end as long as it did before is
+    # not cut: an open loop waits for its next arrival
+    lone = trace.Trace(FIXTURE)
+    lone.ops = {0: [(lo + 0.30 * (hi - lo), lo + 0.31 * (hi - lo), "%a"),
+                    (lo + 0.60 * (hi - lo), lo + 0.61 * (hi - lo), "%b")]}
+    lone._busy = {}
+    assert lone.cut_short() is None
+
+
+@pytest.mark.parametrize("whole,keep", [(True, False), (False, False),
+                                        (True, True)],
+                         ids=["whole", "cut", "kept"])
+def test_a_cut_trace_leaves_the_device_trace_metrics_out(
+        whole, keep, tmp_path, monkeypatch, capsys):
+    import shutil
+
+    import perfbench.run as prun
+    cell = "mistral-7b.train-packed-2k"
+    ctx = prun.context(cell, rehearse=True, trace=True)
+    ctx["rehearse"] = False         # the peaks of the chip that recorded it
+    ctx["keep_trace"] = str(tmp_path / "kept") if keep else None
+    prof = tmp_path / "perfbench_trace_x" / "plugins" / "profile" / "t0"
+    prof.mkdir(parents=True)
+    shutil.copy(FIXTURE, prof / "host.xplane.pb")
+    if not whole:
+        read = trace.Trace
+        monkeypatch.setattr(trace, "Trace",
+                            lambda path: cut_in_the_middle(read(path)))
+    result = {"trace_dir": str(tmp_path / "perfbench_trace_x"),
+              "record": {"step_t": [0.0] * 12, "batch": 2, "seq_len": 128,
+                         "chips": 1, "data_wait_s": 0.001, "window_s": 0.031}}
+    metrics, extra, brk = prun.per_layer(
+        ctx, result, {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    sources = {m["name"]: m["source"] for m in ctx["bench"]["per_layer"]}
+    said = capsys.readouterr().out
+    assert "train.data_wait_share" in metrics       # the host clock's
+    assert extra["busy_s"] > 0 and brk["device_ops"]
+    if whole:
+        assert "trace_cut_short" not in said
+        assert {"train.step_mfu", "device.idle_share.train",
+                "kernel.flash_roofline.train"} <= set(metrics)
+    else:
+        assert '{"trace_cut_short": "the last device event ends 0.01' in said
+        assert not [n for n in metrics if sources[n] == "device_trace"]
+    # the profiler's directory goes once the readers have run, unless the
+    # trace is kept
+    assert os.path.exists(result["trace_dir"]) == keep
+    assert os.path.exists(tmp_path / "kept" / "host.xplane.pb") == keep
