@@ -846,9 +846,14 @@ def _spec_config(spec: dict):
 
     from ..models.llama import LlamaConfig
 
+    # the model spec as JSON carries it: LlamaConfig's own field names, a
+    # dtype by name, a layer pattern (layer_types) as a list
     ckw = dict(spec.get("config") or {})
-    if "dtype" in ckw:
-        ckw["dtype"] = jnp.dtype(ckw["dtype"])
+    for key in ("dtype", "state_dtype"):
+        if key in ckw:
+            ckw[key] = jnp.dtype(ckw[key])
+    if ckw.get("layer_types") is not None:
+        ckw["layer_types"] = tuple(ckw["layer_types"])
     return LlamaConfig(**ckw)
 
 
@@ -867,6 +872,9 @@ def build_params(spec: dict):
 def build_batcher(spec: dict, params=None) -> ContinuousBatcher:
     """A batcher from a JSON-able spec: {"config": {LlamaConfig kwargs,
     "dtype": "float32"}, "seed": 0, "batcher": {ContinuousBatcher kwargs}}.
+    The config may state a layer pattern ("layer_types", with the linear
+    layers' sizes, "qk_norm", "norm_placement", "rope_theta": null): the
+    batcher then holds a recurrent state per slot beside the paged KV.
     Every replica of a fleet builds from the SAME spec, so weights are
     identical across replicas and a failover retry at temperature=0 is
     token-identical to the first attempt. ``params`` short-circuits the

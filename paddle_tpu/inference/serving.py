@@ -58,6 +58,22 @@ Three KV layouts share that scheduler:
     temperature=0, pinned by tests/test_serving_paged.py) and for tiny
     models where paging overhead isn't worth it.
 
+A model spec with LINEAR layers (``LlamaConfig.layer_types``, ISSUE 30:
+gated delta-rule layers among full-attention ones) is served by the
+default ``kv_layout="paged"`` alone: page pools for the FULL layers only
+(pages, ``page_bytes`` and ``pool_hbm_bytes`` count those), and for every
+LINEAR layer a recurrent state and a convolution tail per SLOT
+(``cache["state"]``, ``cache["conv"]``: sized by ``max_batch``, written
+whole by a slot's prefill, updated in place by every decode step, left as
+they are for a finished or free slot). Preemption restarts a request from
+scratch, so its next prefill simply overwrites the slot's rows. What
+cannot hold for a recurrent state raises a ``ValueError`` at construction
+that names the reason (prefix sharing, the ragged and dense layouts,
+quantized pages, speculation, a serving mesh) or at ``add_request``
+(``prefill_only`` / ``kv_import``). ``stats["state_bytes"]`` is the
+allocation; the gauge ``serve.state_mb_held`` and the ``state`` argument of
+every ``serve.dispatch_burst`` span the bytes of the slots in use.
+
 Prefix sharing (ISSUE 13, ``PADDLE_PREFIX_CACHE_PAGES`` /
 ``prefix_cache_pages=``): a page-granular prefix cache
 (``inference/prefix_cache.py``) over the paged pool lets shared-prompt
@@ -88,7 +104,9 @@ the gather does),
 Spans (observability.spans, on the device trace's clock): every ``step()``
 is one ``serve.step`` (args: burst number, live slots) whose children are
 ``serve.dispatch_burst`` (page growth, block table, transfers, the async
-launch; arg ``kv_read``: "kernel", "gather" or "dense"), ``serve.admit``
+launch; args ``kv_read``: "kernel", "gather" or "dense", and on the paged
+path ``state``: bytes of recurrent state the slots in use hold),
+``serve.admit``
 (pop, bucket, allocate, prefill dispatch; under the in-flight burst on the
 paged path; arg: prefills staged),
 ``serve.readback`` (the step's one blocking ``device_get``) and
@@ -242,6 +260,20 @@ class ContinuousBatcher:
 
         if kv_layout not in ("paged", "dense", "ragged"):
             raise ValueError(f"unknown kv_layout {kv_layout!r}")
+        # a model spec with LINEAR layers (LlamaConfig.layer_types) holds a
+        # recurrent state per SLOT beside the paged K/V of its FULL layers.
+        # What cannot hold for such a state is refused here, by name, and
+        # never served another way unasked.
+        recurrent = model_config.is_recurrent
+        from .speculative import ENV_SPEC_DECODE, spec_from_env
+        spec_on = (bool(spec_decode) if spec_decode is not None
+                   else _env_flags.get_bool(ENV_SPEC_DECODE))
+        if recurrent and kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={kv_layout!r} cannot serve a model with "
+                "recurrent (linear-attention) layers: only the default "
+                "kv_layout='paged' walks a layer pattern (the ragged burst "
+                "and the dense slot cache know one kind of layer)")
         # quantized KV pages (ISSUE 10): kv_dtype "int8"/"fp8" stores the
         # page pool through the paddle_tpu.quant block codecs (payload +
         # per-(row, head) scales); both read paths dequantize. Explicit
@@ -272,6 +304,11 @@ class ContinuousBatcher:
             raise ValueError("prefix sharing needs the paged pool "
                              "(kv_layout='paged' or 'ragged') — the dense "
                              "slot cache has no shareable page unit")
+        if recurrent and kv_dtype is not None:
+            raise ValueError(
+                f"kv_dtype={kv_dtype!r} cannot serve a model with recurrent "
+                "layers: quantized K/V pages beside a float32 recurrent "
+                "state are not supported")
         self._kv_dtype = kv_dtype
         # "ragged" = the paged pool read through the Pallas ragged kernel
         # (ops/ragged_attention.py) in ONE mixed prefill+decode executable:
@@ -282,6 +319,7 @@ class ContinuousBatcher:
         self._ragged = False
         self._interpret = jax.default_backend() != "tpu"
         self._mesh = None
+        self._pool_heads = self._cfg.num_key_value_heads
         # which read a decode step takes: stats["kv_read"] and the
         # serve.dispatch_burst span's argument ("dense" has no pool)
         self._kv_read = "dense"
@@ -326,11 +364,30 @@ class ContinuousBatcher:
         self._spt: float | None = None
 
         if self._layout == "paged":
-            from ..models.llama_paged import init_paged_kv_cache, page_bytes
+            from ..models.llama_paged import (init_paged_kv_cache,
+                                              page_bytes, pool_kv_heads)
+            from ..parallel.sharding import serving_mesh, shard_kv_pool
             self._ps = int(page_size)
             if self._ps < 1:
                 raise ValueError("page_size must be >= 1")
             slot_max_pages = pages_for(self.S, self._ps)
+            self._mesh = serving_mesh()
+            if self._mesh is not None and recurrent:
+                raise ValueError(
+                    "a serving mesh (PADDLE_SERVE_MESH_MODEL) cannot serve "
+                    "a model with recurrent layers: the per-slot state has "
+                    "no sharding rule yet")
+            # the heads a pool row holds are the model's, or padded to
+            # whole sublane tiles where that keeps the decode kernel's pool
+            # in one layout (llama_paged.pool_kv_heads: 30 -> 32). Only the
+            # two programs the default layout runs alone know a padded
+            # pool, so its other readers are refused by name
+            self._pool_heads = pool_kv_heads(model_config, self._kv_dtype,
+                                             self._mesh)
+            self._page_bytes = page_bytes(model_config, self._ps,
+                                          self._kv_dtype, self._mesh)
+            if self._ragged:
+                self._model_shaped_pool("kv_layout='ragged'")
             if pool_hbm_bytes is not None:
                 # explicit HBM budget: the pool is however many pages the
                 # bytes buy at this kv_dtype — the knob the quantized-page
@@ -341,9 +398,8 @@ class ContinuousBatcher:
                     raise ValueError(
                         "pass num_pages or pool_hbm_bytes, not both")
                 from .paging import pages_for_budget
-                num_pages = pages_for_budget(
-                    pool_hbm_bytes,
-                    page_bytes(model_config, self._ps, self._kv_dtype))
+                num_pages = pages_for_budget(pool_hbm_bytes,
+                                             self._page_bytes)
             elif num_pages is None:
                 # capacity parity with the dense layout (+1 scratch); size
                 # DOWN for real memory savings — admission degrades to
@@ -358,14 +414,14 @@ class ContinuousBatcher:
             self._page_buckets = pb
             self._cache = init_paged_kv_cache(model_config, num_pages,
                                               self._ps,
-                                              kv_dtype=self._kv_dtype)
+                                              kv_dtype=self._kv_dtype,
+                                              max_batch=self.B,
+                                              mesh=self._mesh)
             # GSPMD pool sharding (PADDLE_SERVE_MESH_MODEL): KV heads
             # spread over the "model" axis so one replica spans a pod
             # slice. The scheduler stays layout-agnostic — block tables
             # and slot state remain replicated host metadata; the gather
             # path partitions automatically, the ragged kernel shard_maps.
-            from ..parallel.sharding import serving_mesh, shard_kv_pool
-            self._mesh = serving_mesh()
             if self._mesh is not None:
                 kv = self._cfg.num_key_value_heads
                 if kv % self._mesh.size:
@@ -399,10 +455,16 @@ class ContinuousBatcher:
             # pre-sharing engine byte-for-byte (no index, no hash cost)
             cap = prefix_cache_pages
             if cap is None:
-                from ..utils import env_flags
                 from .prefix_cache import ENV_CACHE_PAGES
-                cap = env_flags.get_int(ENV_CACHE_PAGES)
+                cap = _env_flags.get_int(ENV_CACHE_PAGES)
+            if int(cap) > 0 and recurrent:
+                raise ValueError(
+                    f"prefix_cache_pages={int(cap)} cannot serve a model "
+                    "with recurrent layers: a shared page holds K/V rows "
+                    "and no recurrent state, so a request that maps it "
+                    "would start from a state nobody computed")
             if int(cap) > 0:
+                self._model_shaped_pool(f"prefix_cache_pages={int(cap)}")
                 from .prefix_cache import PrefixCache
                 self._prefix = PrefixCache(
                     self._alloc, self._ps,
@@ -415,7 +477,13 @@ class ContinuousBatcher:
         # greedy tokens per slot + ONE target verify launch per step.
         # None (off / unsupported) keeps the scheduler byte-for-byte the
         # plain engine — spec_from_env degrades silently by contract.
-        from .speculative import spec_from_env
+        if recurrent and spec_on:
+            raise ValueError(
+                "spec_decode cannot serve a model with recurrent layers: a "
+                "rejected draft token cannot be rewound out of a recurrent "
+                "state (pages rewind by resetting pos; a state does not)")
+        if spec_on:
+            self._model_shaped_pool("spec_decode")
         self._spec = spec_from_env(
             spec_src[0], spec_src[1], max_batch=self.B, max_len=self.S,
             prompt_buckets=self._buckets, temperature=self._temp,
@@ -452,10 +520,14 @@ class ContinuousBatcher:
         # the historical unbounded-queue behavior, unchanged.
         self._admission = admission
         self._draining = False
+        # recurrent state: sized by max_batch at construction (a slot's
+        # rows are overwritten by its next prefill), never by the pool
+        self._state_slot_bytes = model_config.state_bytes_per_request()
         self.stats = {"bursts": 0, "decode_steps": 0, "prefills": 0,
                       "admission_stalls": 0, "preemptions": 0,
                       "chaos_retired": 0, "max_concurrent": 0,
-                      "page_buckets_used": [], "kv_read": self._kv_read}
+                      "page_buckets_used": [], "kv_read": self._kv_read,
+                      "state_bytes": self.B * self._state_slot_bytes}
         # request-level SLO observability: lifecycle tracker + policy
         # (PADDLE_SLO_* env unless an explicit policy is given); pure
         # observation — no tracker call can change a served token
@@ -473,6 +545,16 @@ class ContinuousBatcher:
                               self.slo.policy.active
                               or os.environ.get("PADDLE_TRACE_DIR"))
                           else None)
+
+    def _model_shaped_pool(self, what: str) -> None:
+        """Refuse ``what`` by name where the pool's rows are padded."""
+        kv = self._cfg.num_key_value_heads
+        if self._pool_heads != kv:
+            raise ValueError(
+                f"{what} needs a pool of the model's own geometry; this one "
+                f"pads KV heads {kv} -> {self._pool_heads} "
+                "(llama_paged.pool_kv_heads), which only the default "
+                "kv_layout='paged' programs read")
 
     # ------------------------------------------------------------- intake
     def add_request(self, prompt_ids, max_new_tokens: int = 32,
@@ -517,6 +599,15 @@ class ContinuousBatcher:
             raise ValueError("disaggregated serving (prefill_only / "
                              "kv_import) needs the paged pool — the dense "
                              "slot cache has no transferable page unit")
+        if (prefill_only or kv_import is not None) \
+                and self._cfg.is_recurrent:
+            raise ValueError("disaggregated serving (prefill_only / "
+                             "kv_import) cannot serve a model with "
+                             "recurrent layers: the transfer carries K/V "
+                             "pages only, no recurrent state")
+        if prefill_only or kv_import is not None:
+            self._model_shaped_pool("disaggregated serving (prefill_only / "
+                                    "kv_import)")
         if prefill_only and kv_import is not None:
             raise ValueError("a request is prefill_only OR kv_import, "
                              "not both")
@@ -988,11 +1079,9 @@ class ContinuousBatcher:
         """``serve.kv_read_mb_per_tok`` where the kernel reads: the mean
         over active slots of their LIVE pages' bytes (pos + 1 rows, whole
         pages), not the bucket's."""
-        from ..models.llama_paged import page_bytes
         pages = self._pos[active] // self._ps + 1
         metrics.gauge("serve.kv_read_mb_per_tok").set(
-            float(pages.mean()) * page_bytes(self._cfg, self._ps,
-                                             self._kv_dtype) / 1e6)
+            float(pages.mean()) * self._page_bytes / 1e6)
 
     def _install_admit(self, req: ServedRequest, slot: int) -> int:
         """Admit a kv_import request: allocate its live pages, write the
@@ -1211,7 +1300,8 @@ class ContinuousBatcher:
                     temperature=self._temp, top_k=self._top_k,
                     dequant=self._dequant, kv_dtype=self._kv_dtype,
                     kv_read=self._kv_read, interpret=self._interpret,
-                    mesh=self._mesh)
+                    mesh=self._mesh,
+                    slot=jnp.int32(slot) if self._state_slot_bytes else None)
                 self._note_admit_prefill(req, tlen)
             # pages past the real prompt hold only bucket-pad garbage the
             # mask never exposes — return them right away; the pre-burst
@@ -1711,8 +1801,12 @@ class ContinuousBatcher:
                 self._step_ragged()
             elif self._layout == "paged":
                 t0 = _slo.now()     # the request-timing clock (lint O4)
+                state_live = self._state_slot_bytes * (
+                    self.B - self._slot_req.count(None))
+                if self._state_slot_bytes:
+                    metrics.gauge("serve.state_mb_held").set(state_live / 1e6)
                 with _spans.span("serve.dispatch_burst", cat="serve",
-                                 kv_read=self._kv_read):
+                                 kv_read=self._kv_read, state=state_live):
                     inflight = self._dispatch_burst_paged()
                 with _spans.span("serve.admit", cat="serve") as sp:
                     real0, padded0 = self._pf_real.value, self._pf_padded.value

@@ -51,17 +51,127 @@ class LlamaConfig:
     num_key_value_heads: int = 32
     max_position_embeddings: int = 4096
     rms_norm_eps: float = 1e-5
-    rope_theta: float = 10000.0
+    rope_theta: float | None = 10000.0
     tie_word_embeddings: bool = False
     dtype: Any = jnp.bfloat16
     # MoE variant (Mixtral/DeepSeekMoE class)
     num_experts: int = 0
     num_experts_per_tok: int = 2
     moe_intermediate_size: int | None = None
+    # --- what a model of another family states (all defaults: a Llama) ---
+    # stated head size (None: hidden_size / num_attention_heads)
+    head_dim: int | None = None
+    # (rope_theta None, above: no rotation is applied)
+    # RMSNorm over the whole q and k projections, before the split into heads
+    qk_norm: bool = False
+    # "pre": x + f(norm(x)) (Llama); "post": x + norm(f(x)) (the OLMo 2
+    # family's reordered norm), with the same ln1 / ln2 gains
+    norm_placement: str = "pre"
+    # ordered layer kinds, FULL or LINEAR (None: every layer FULL). A LINEAR
+    # layer's mixer is the gated delta rule (ops/gated_delta.py): it holds
+    # no K/V rows, but a state [value heads, value dim, key dim] and the
+    # last conv_kernel - 1 inputs of its convolution, for every request
+    layer_types: tuple | None = None
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False   # beta = 2 sigmoid, not sigmoid
+    state_dtype: Any = jnp.float32
+
+    FULL, LINEAR = "full_attention", "linear_attention"
+
+    def __post_init__(self):
+        set_ = lambda k, v: object.__setattr__(self, k, v)  # frozen
+        if self.head_dim is None:
+            set_("head_dim", self.hidden_size // self.num_attention_heads)
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement {self.norm_placement!r}: "
+                             "'pre' or 'post'")
+        if self.layer_types is None:
+            return
+        set_("layer_types", tuple(self.layer_types))
+        kinds = set(self.layer_types)
+        if len(self.layer_types) != self.num_hidden_layers \
+                or not kinds <= {self.FULL, self.LINEAR}:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers of kinds "
+                f"{sorted(kinds)}: it must give num_hidden_layers="
+                f"{self.num_hidden_layers} entries, each {self.FULL!r} or "
+                f"{self.LINEAR!r}")
+        if self.LINEAR in kinds:
+            if self.linear_num_key_heads != self.linear_num_value_heads:
+                raise ValueError(
+                    "a linear layer with more value heads than key heads "
+                    "(keys shared by a group) is not supported: "
+                    f"{self.linear_num_value_heads} value heads, "
+                    f"{self.linear_num_key_heads} key heads")
+            if min(self.linear_num_key_heads, self.linear_key_head_dim,
+                   self.linear_value_head_dim) < 1 \
+                    or self.linear_conv_kernel_dim < 2:
+                raise ValueError("a linear layer needs linear_num_key_heads, "
+                                 "linear_key_head_dim, linear_value_head_dim "
+                                 ">= 1 and linear_conv_kernel_dim >= 2")
+            if self.num_experts > 0:
+                raise ValueError("experts under a layer pattern are not "
+                                 "supported")
+
+    # what the pattern means for whoever holds per-request state
+    def kinds(self) -> tuple:
+        """The kind of every layer, in order."""
+        return self.layer_types or (self.FULL,) * self.num_hidden_layers
+
+    def kind_index(self, layer: int) -> tuple:
+        """(kind, the layer's place among the layers of its kind): where
+        its mixer's parameters and its per-request state are stacked."""
+        kinds = self.kinds()
+        return kinds[layer], kinds[:layer].count(kinds[layer])
 
     @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+    def num_kv_layers(self) -> int:
+        """Layers that hold K/V rows: the ones a KV page spans."""
+        return self.kinds().count(self.FULL)
+
+    @property
+    def num_linear_layers(self) -> int:
+        return self.kinds().count(self.LINEAR)
+
+    @property
+    def is_recurrent(self) -> bool:
+        """Does a request hold state that is no K/V row?"""
+        return self.num_linear_layers > 0
+
+    @property
+    def linear_conv_dim(self) -> int:
+        """Width of the convolution: q~, k~ and v~ side by side."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
+    def state_shapes(self, max_batch: int) -> dict:
+        """{leaf: (shape, dtype)} of ONE linear layer's per-request state
+        for `max_batch` slots."""
+        return {"state": ((max_batch, self.linear_num_value_heads,
+                           self.linear_value_head_dim,
+                           self.linear_key_head_dim), self.state_dtype),
+                "conv": ((max_batch, self.linear_conv_kernel_dim - 1,
+                          self.linear_conv_dim), self.dtype)}
+
+    def state_bytes_per_request(self) -> int:
+        """Bytes of recurrent state ONE request holds, whatever its length
+        (0 for a model of full-attention layers only)."""
+        per_layer = sum(int(np.prod(shape[1:])) * jnp.dtype(dt).itemsize
+                        for shape, dt in self.state_shapes(1).values())
+        return self.num_linear_layers * per_layer
+
+    def require_uniform(self, what: str) -> None:
+        """Paths that know one kind of layer say so by name."""
+        if self.is_recurrent or self.qk_norm \
+                or self.norm_placement != "pre" or self.rope_theta is None:
+            raise NotImplementedError(
+                f"{what} runs models of one layer kind (pre-norm, rope): "
+                "a layer pattern is served by ContinuousBatcher("
+                "kv_layout='paged') only (ROADMAP Queue 2(a) M2)")
 
     @classmethod
     def tiny(cls, **kw):
@@ -177,16 +287,37 @@ def llama_init_params(config: LlamaConfig, key=None, mesh=None):
     def init(k, shape):
         return (jax.random.normal(k, shape, jnp.float32) * std).astype(c.dtype)
 
+    # each kind of layer is stacked on a leading axis of its own: the
+    # attention matrices over the FULL layers, the linear mixer's (below)
+    # over the LINEAR ones, the FFN and the two norms over all of them
+    nF = c.num_kv_layers
     params = {
         "embed_tokens": init(ks[0], (V, D)),
-        "wq": init(ks[1], (L, D, H * hd)),
-        "wk": init(ks[2], (L, D, KV * hd)),
-        "wv": init(ks[3], (L, D, KV * hd)),
-        "wo": init(ks[4], (L, H * hd, D)),
+        "wq": init(ks[1], (nF, D, H * hd)),
+        "wk": init(ks[2], (nF, D, KV * hd)),
+        "wv": init(ks[3], (nF, D, KV * hd)),
+        "wo": init(ks[4], (nF, H * hd, D)),
         "ln1": jnp.ones((L, D), jnp.float32),
         "ln2": jnp.ones((L, D), jnp.float32),
         "norm": jnp.ones((D,), jnp.float32),
     }
+    if c.qk_norm:
+        params["q_norm"] = jnp.ones((nF, H * hd), jnp.float32)
+        params["k_norm"] = jnp.ones((nF, KV * hd), jnp.float32)
+    if c.is_recurrent:
+        nL, Hv = c.num_linear_layers, c.linear_num_value_heads
+        dv, kk = c.linear_value_head_dim, c.linear_conv_kernel_dim
+        params.update({
+            "lin_wqkv": init(ks[10], (nL, D, c.linear_conv_dim)),
+            "lin_wa": init(ks[11], (nL, D, Hv)),
+            "lin_wb": init(ks[12], (nL, D, Hv)),
+            "lin_wg": init(ks[13], (nL, D, Hv * dv)),
+            "lin_wo": init(ks[14], (nL, Hv * dv, D)),
+            "lin_conv": init(ks[15], (nL, kk, c.linear_conv_dim)),
+            "lin_A_log": jnp.zeros((nL, Hv), jnp.float32),
+            "lin_dt_bias": jnp.zeros((nL, Hv), jnp.float32),
+            "lin_norm": jnp.ones((nL, dv), jnp.float32),
+        })
     if c.num_experts > 0:
         E = c.num_experts
         Fm = c.moe_intermediate_size or F
@@ -413,6 +544,7 @@ def llama_trunk(x, stacked_layer_params, config, mesh=None, positions=None,
     remat: False | True (selective dots policy) | "full" (save nothing —
     the lowest-memory schedule) | "dots_noffn" (dots policy with the MLP
     nested-rematerialised: fits batch 8 on one 16 GB v5e)."""
+    config.require_uniform("llama_trunk (training, llama_forward)")
     if positions is None:
         positions = jnp.arange(x.shape[1])[None, :].astype(jnp.int32)
         positions = jnp.broadcast_to(positions, (x.shape[0], x.shape[1]))
@@ -434,14 +566,63 @@ def llama_trunk(x, stacked_layer_params, config, mesh=None, positions=None,
     return x, jnp.sum(auxes)
 
 
-_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "ln1", "ln2",
-               "gate_w", "moe_w_gate", "moe_w_up", "moe_w_down")
+_ATTN_KEYS = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+_LINEAR_KEYS = ("lin_wqkv", "lin_wa", "lin_wb", "lin_wg", "lin_wo", "lin_conv",
+                "lin_A_log", "lin_dt_bias", "lin_norm")
+_LAYER_KEYS = _ATTN_KEYS + _LINEAR_KEYS + (
+    "w_gate", "w_up", "w_down", "ln1", "ln2",
+    "gate_w", "moe_w_gate", "moe_w_up", "moe_w_down")
 
 
 def split_layer_params(params):
     layer = {k: v for k, v in params.items() if k in _LAYER_KEYS}
     other = {k: v for k, v in params.items() if k not in _LAYER_KEYS}
     return layer, other
+
+
+def layer_params_at(layer_p, config: LlamaConfig, layer: int) -> dict:
+    """One layer's parameters out of the stacked tree. Without a pattern
+    every leaf is stacked over all layers; with one, a mixer's leaves are
+    stacked over the layers of its kind (``kind_index``) and only the FFN
+    and the norms over all."""
+    if config.layer_types is None:
+        return jax.tree.map(lambda a: a[layer], layer_p)
+    kind, at = config.kind_index(layer)
+    mine = _ATTN_KEYS if kind == config.FULL else _LINEAR_KEYS
+    other = _LINEAR_KEYS if kind == config.FULL else _ATTN_KEYS
+    return {k: v[at if k in mine else layer] for k, v in layer_p.items()
+            if k not in other}
+
+
+def block_in(x, gain, config: LlamaConfig):
+    """What a mixer or an FFN is given of the stream ``x``."""
+    return _rmsnorm(x, gain, config.rms_norm_eps) \
+        if config.norm_placement == "pre" else x
+
+
+def block_out(y, gain, config: LlamaConfig):
+    """What the stream is given of a mixer's or an FFN's output ``y``."""
+    return y if config.norm_placement == "pre" \
+        else _rmsnorm(y, gain, config.rms_norm_eps)
+
+
+def attn_qkv(h, lp, config: LlamaConfig, positions):
+    """q [B, T, H, hd], k and v [B, T, KV, hd] of a full-attention layer
+    from its input h [B, T, D]: the projections, the QK-norm where the
+    spec has one (over the whole projection, before the heads are split),
+    the rotation where it has one."""
+    c = config
+    B, T, _ = h.shape
+    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+    if c.qk_norm:
+        q = _rmsnorm(q, lp["q_norm"], c.rms_norm_eps)
+        k = _rmsnorm(k, lp["k_norm"], c.rms_norm_eps)
+    q = q.reshape(B, T, c.num_attention_heads, c.head_dim)
+    k = k.reshape(B, T, c.num_key_value_heads, c.head_dim)
+    v = v.reshape(B, T, c.num_key_value_heads, c.head_dim)
+    if c.rope_theta is not None:
+        q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
+    return q, k, v
 
 
 def resolve_head(other):

@@ -39,8 +39,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .llama import (LlamaConfig, _moe_block, _rmsnorm, _rope, lm_head_logits,
-                    split_layer_params)
+from .llama import (LlamaConfig, _moe_block, _rmsnorm, _rope, block_in,
+                    block_out, lm_head_logits, split_layer_params)
 
 __all__ = ["init_kv_cache", "llama_prefill", "llama_decode_step",
            "llama_generate", "llama_prefill_slot", "llama_decode_step_slots",
@@ -58,6 +58,7 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int):
     per-layer buffers at B=8, S=512 on the 850M model; r4 serving work).
     """
     c = config
+    c.require_uniform("the dense slot cache (llama_decode)")
     shape = (batch, max_len, c.num_key_value_heads, c.head_dim)
     return {
         "k": tuple(jnp.zeros(shape, c.dtype)
@@ -76,18 +77,19 @@ def _qkv(h, lp, c):
 
 
 def _mlp(x, lp, c):
-    h2 = _rmsnorm(x, lp["ln2"], c.rms_norm_eps)
+    h2 = block_in(x, lp["ln2"], c)
     if c.num_experts > 0:
         out, _ = _moe_block(h2, lp["gate_w"], lp["moe_w_gate"],
                             lp["moe_w_up"], lp["moe_w_down"], c)
         return x + out
     ff = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
-    return x + (ff @ lp["w_down"])
+    return x + block_out(ff @ lp["w_down"], lp["ln2"], c)
 
 
 def _prefill_stacked(params, tokens, config: LlamaConfig):
     """Prompt forward: (logits [B,T,V], ks, vs stacked [L,B,T,KV,hd])."""
     c = config
+    c.require_uniform("llama_generate / llama_prefill")
     layer_p, other = split_layer_params(params)
     B, T = tokens.shape
     x = jnp.take(other["embed_tokens"], tokens, axis=0).astype(c.dtype)

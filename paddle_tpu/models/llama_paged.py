@@ -69,13 +69,15 @@ import math
 import jax
 import jax.numpy as jnp
 
-from .llama import LlamaConfig, _rmsnorm, _rope, lm_head_logits, \
-    split_layer_params
+from .linear_mixer import mixer_prefill, mixer_step
+from .llama import LlamaConfig, _rmsnorm, _rope, attn_qkv, block_in, \
+    block_out, layer_params_at, lm_head_logits, split_layer_params
 from ..ops.ragged_attention import decode_supported, paged_kv_scatter, \
     ragged_paged_attention, scatter_supported
 from .llama_decode import _cached_attention_slots, _mlp, _qkv, _sample
 
-__all__ = ["init_paged_kv_cache", "paged_kv_read", "llama_paged_prefill_slot",
+__all__ = ["init_paged_kv_cache", "paged_kv_read", "pool_kv_heads",
+           "llama_paged_prefill_slot",
            "llama_paged_prefill_suffix", "llama_paged_decode_burst",
            "llama_ragged_burst", "llama_paged_verify",
            "paged_kv_bytes_per_token", "page_bytes",
@@ -105,9 +107,49 @@ def _kv_decode(payload, scale, out_dtype):
     return dequantize_lastdim(payload, scale, out_dtype)
 
 
+def pool_kv_heads(config: LlamaConfig, kv_dtype: str | None = None,
+                  mesh=None) -> int:
+    """KV heads a row of this model's pool holds: the ONE place the pool's
+    geometry is decided (``init_paged_kv_cache``, ``page_bytes`` and
+    ``paged_kv_read`` ask here; the programs read it off the cache's
+    shape). The model's own, or, where only their number keeps the decode
+    kernel from reading the pool well, the next multiple of 8 (30 -> 32).
+    The kernel reads a page as ``[page_size * KV, head_dim]``; a KV count
+    that is no whole sublane tile makes that view of the tiled 4-D pool a
+    COPY of the whole pool, twice a layer and call (compiled for a
+    described v5e at 30 heads: 8 copies of 0.58 GB in one burst program,
+    which then does not fit), and keeps ``paged_kv_scatter`` off. The
+    padded heads hold zeros, cost 1/15 of the pool at 30 heads, and are
+    never read back: q is padded with zero heads to match and the output
+    sliced. Only ``llama_paged_prefill_slot`` and
+    ``llama_paged_decode_burst`` know a padded pool; the engine refuses
+    its other readers for such a model (``ContinuousBatcher``)."""
+    kv = int(config.num_key_value_heads)
+    if (kv > 8 and kv % 8 and kv_dtype is None and mesh is None
+            and config.head_dim % 128 == 0):
+        return -(-kv // 8) * 8
+    return kv
+
+
+def _pad_heads(rows, heads: int):
+    """rows [..., KV, hd] -> [..., heads, hd], zeros in the added heads."""
+    extra = heads - rows.shape[-2]
+    if not extra:
+        return rows
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 2) + ((0, extra), (0, 0)))
+
+
 def init_paged_kv_cache(config: LlamaConfig, num_pages: int, page_size: int,
-                        kv_dtype: str | None = None):
-    """Shared page pool: PER-LAYER tuples of [num_pages, page_size, KV, hd].
+                        kv_dtype: str | None = None, max_batch: int = 0,
+                        mesh=None):
+    """Shared page pool: PER-LAYER tuples of [num_pages, page_size, KV, hd],
+    one entry for every layer that holds K/V rows (``num_kv_layers``: all
+    of them without a layer pattern). A spec with LINEAR layers adds, for
+    each of those, ``state`` [max_batch, Hv, dv, dk] (``state_dtype``) and
+    ``conv`` [max_batch, K - 1, conv_dim] under the same names: per SLOT,
+    not per page, written whole by a slot's prefill and updated in place by
+    every decode step. A pool row holds ``pool_kv_heads`` heads (``mesh``:
+    the serving mesh a caller will shard the pool over, if any).
 
     Per-layer buffers for the same reason as the dense cache
     (llama_decode.init_kv_cache): XLA only updates a carried/donated leaf
@@ -120,27 +162,30 @@ def init_paged_kv_cache(config: LlamaConfig, num_pages: int, page_size: int,
     host allocator/block tables stay layout-agnostic.
     """
     c = config
-    shape = (int(num_pages), int(page_size), c.num_key_value_heads,
-             c.head_dim)
+    shape = (int(num_pages), int(page_size),
+             pool_kv_heads(c, kv_dtype, mesh), c.head_dim)
+    n_kv = c.num_kv_layers
     if kv_dtype is None:
-        return {
-            "k": tuple(jnp.zeros(shape, c.dtype)
-                       for _ in range(c.num_hidden_layers)),
-            "v": tuple(jnp.zeros(shape, c.dtype)
-                       for _ in range(c.num_hidden_layers)),
-        }
+        cache = {"k": tuple(jnp.zeros(shape, c.dtype) for _ in range(n_kv)),
+                 "v": tuple(jnp.zeros(shape, c.dtype) for _ in range(n_kv))}
+        if c.is_recurrent:
+            for name, (shp, dt) in c.state_shapes(int(max_batch)).items():
+                cache[name] = tuple(jnp.zeros(shp, dt)
+                                    for _ in range(c.num_linear_layers))
+        return cache
+    if c.is_recurrent:
+        raise ValueError("quantized KV pages beside a recurrent state are "
+                         "not supported")
     from ..quant.codec import SCALE_DTYPE, wire_dtype
     wire = wire_dtype(kv_dtype)
     sshape = shape[:-1]
     return {
-        "k": tuple(jnp.zeros(shape, wire)
-                   for _ in range(c.num_hidden_layers)),
-        "v": tuple(jnp.zeros(shape, wire)
-                   for _ in range(c.num_hidden_layers)),
+        "k": tuple(jnp.zeros(shape, wire) for _ in range(n_kv)),
+        "v": tuple(jnp.zeros(shape, wire) for _ in range(n_kv)),
         "k_scale": tuple(jnp.zeros(sshape, SCALE_DTYPE)
-                         for _ in range(c.num_hidden_layers)),
+                         for _ in range(n_kv)),
         "v_scale": tuple(jnp.zeros(sshape, SCALE_DTYPE)
-                         for _ in range(c.num_hidden_layers)),
+                         for _ in range(n_kv)),
     }
 
 
@@ -214,15 +259,16 @@ def _kv_row_head_bytes(config: LlamaConfig, kv_dtype: str | None) -> int:
 
 
 def page_bytes(config: LlamaConfig, page_size: int,
-               kv_dtype: str | None = None) -> int:
-    """HBM bytes one PAGE ID costs (K+V across all layers, scales
-    included) — the unit the pool budget is spent in. The serving
+               kv_dtype: str | None = None, mesh=None) -> int:
+    """HBM bytes one PAGE ID costs (K+V across all layers that hold K/V
+    rows, scales included) — the unit the pool budget is spent in. The serving
     engine's ``pool_hbm_bytes=`` sizing divides by this, which is how an
     int8/fp8 pool admits ~2× the live tokens of a bf16 pool at the same
     budget (pinned by tests/test_quant.py)."""
     c = config
-    return int(2 * c.num_hidden_layers * int(page_size)
-               * c.num_key_value_heads * _kv_row_head_bytes(c, kv_dtype))
+    return int(2 * c.num_kv_layers * int(page_size)
+               * pool_kv_heads(c, kv_dtype, mesh)
+               * _kv_row_head_bytes(c, kv_dtype))
 
 
 def paged_kv_bytes_per_token(config: LlamaConfig, pages: int,
@@ -248,7 +294,7 @@ def paged_kv_bytes_per_token(config: LlamaConfig, pages: int,
         live_tokens = int(live_tokens)
         pages = 0 if live_tokens <= 0 \
             else (live_tokens - 1) // int(page_size) + 1
-    return int(2 * c.num_hidden_layers * pages * page_size
+    return int(2 * c.num_kv_layers * pages * page_size
                * c.num_key_value_heads * _kv_row_head_bytes(c, kv_dtype))
 
 
@@ -261,8 +307,8 @@ def paged_kv_read(config: LlamaConfig, page_size: int,
     the same on every backend; a GSPMD-sharded pool (``mesh``) keeps the
     gather, which XLA partitions by itself."""
     if mesh is None and decode_supported(
-            config.head_dim, config.num_key_value_heads, int(page_size),
-            kv_dtype):
+            config.head_dim, pool_kv_heads(config, kv_dtype, mesh),
+            int(page_size), kv_dtype):
         return "kernel"
     return "gather"
 
@@ -278,14 +324,15 @@ def _kernel_write(config: LlamaConfig, page_size: int, kv_dtype, kv_read,
     if kv_read is None:
         kv_read = paged_kv_read(config, page_size, kv_dtype, mesh)
     return (kv_read == "kernel" and mesh is None and scatter_supported(
-        config.head_dim, config.num_key_value_heads, int(page_size),
-        kv_dtype))
+        config.head_dim, pool_kv_heads(config, kv_dtype, mesh),
+        int(page_size), kv_dtype))
 
 
 def _paged_decode_step_slots(params, cache, block_table, pos, tok,
                              config: LlamaConfig, kv_dtype: str | None = None,
                              kv_read: str | None = None,
-                             interpret: bool | None = None, mesh=None):
+                             interpret: bool | None = None, mesh=None,
+                             done=None):
     """One single-token step over all slots, K/V through the block table.
 
     block_table [B, P] int32; pos/tok [B]. Slot b writes this token's K/V
@@ -324,11 +371,13 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
     layer_p, other = split_layer_params(params)
     B = tok.shape[0]
     ps = int(cache["k"][0].shape[1])
+    pool_heads = int(cache["k"][0].shape[2])    # the model's, or padded
     if kv_read is None:
         kv_read = paged_kv_read(c, ps, kv_dtype, mesh)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     kernel_write = _kernel_write(c, ps, kv_dtype, kv_read, mesh)
+    q_heads = pool_heads * (c.num_attention_heads // c.num_key_value_heads)
     x = jnp.take(other["embed_tokens"], tok[:, None], axis=0).astype(c.dtype)
     positions = pos[:, None].astype(jnp.int32)
     pos32 = pos.astype(jnp.int32)
@@ -342,13 +391,23 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
     ks, vs = list(cache["k"]), list(cache["v"])
     kss = list(cache["k_scale"]) if quant else None
     vss = list(cache["v_scale"]) if quant else None
-    for l in range(c.num_hidden_layers):
-        lp = jax.tree.map(lambda a: a[l], layer_p)
-        h = _rmsnorm(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv(h, lp, c)
-        q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
+    states, tails = list(cache.get("state", ())), list(cache.get("conv", ()))
+    frozen = jnp.zeros((B,), bool) if done is None else done
+    for layer in range(c.num_hidden_layers):
+        lp = layer_params_at(layer_p, c, layer)
+        kind, l = c.kind_index(layer)   # l: its place among its kind
+        h = block_in(x, lp["ln1"], c)
+        if kind == c.LINEAR:
+            mix, states[l], tails[l] = mixer_step(
+                h[:, 0], lp, c, states[l], tails[l], frozen)
+            y = x + block_out(mix[:, None], lp["ln1"], c)
+            with jax.named_scope("mlp"):
+                x = _mlp(y, lp, c)
+            continue
+        q, k, v = attn_qkv(h, lp, c, positions)
         kp, vp = ks[l], vs[l]
-        ku, vu = k[:, 0], v[:, 0]
+        ku, vu = _pad_heads(k[:, 0], pool_heads), _pad_heads(v[:, 0],
+                                                             pool_heads)
         ksp = vsp = None
         if quant:
             ku, ksr = _kv_encode(ku, kv_dtype)   # [B, KV, hd] + [B, KV]
@@ -376,9 +435,11 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
             kss[l], vss[l] = ksp, vsp
         with jax.named_scope("kv_read"):
             if kv_read == "kernel":
-                att = _ragged_attn(q, kp, vp, block_table, one, pos32 + 1,
+                att = _ragged_attn(_pad_heads(q, q_heads), kp, vp,
+                                   block_table, one, pos32 + 1,
                                    page_size=ps, interpret=interpret,
                                    mesh=mesh, ksc=ksp, vsc=vsp)
+                att = att[:, :, :c.num_attention_heads]
             else:
                 # gather the slot's pages into a [B, P*ps, KV, hd] view:
                 # the read whose bytes scale with the page bucket
@@ -389,20 +450,35 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
                                     c.dtype)
                     vc = _kv_decode(vc, _take_pages(vsp, block_table),
                                     c.dtype)
-                kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-                vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-                att = _cached_attention_slots(q, kc, vc, pos, c)
+                kc = kc.reshape(B, -1, pool_heads, c.head_dim)
+                vc = vc.reshape(B, -1, pool_heads, c.head_dim)
+                att = _cached_attention_slots(
+                    q, kc[:, :, :c.num_key_value_heads],
+                    vc[:, :, :c.num_key_value_heads], pos, c)
         with jax.named_scope("attn_out"):
-            y = x + (att.reshape(B, 1, -1) @ lp["wo"])
+            y = x + block_out(att.reshape(B, 1, -1) @ lp["wo"], lp["ln1"], c)
         with jax.named_scope("mlp"):
             x = _mlp(y, lp, c)
 
     out = {"k": tuple(ks), "v": tuple(vs)}
     if quant:
         out["k_scale"], out["v_scale"] = tuple(kss), tuple(vss)
+    if states:
+        out["state"], out["conv"] = tuple(states), tuple(tails)
     with jax.named_scope("head_sample"):
         logits = lm_head_logits(x[:, 0, :], other, c)
     return logits, out
+
+
+def _one_kind_pool(cache, config: LlamaConfig, what: str) -> None:
+    """``what`` knows one kind of layer and a pool of the model's own
+    geometry: no layer pattern, no padded KV heads (``pool_kv_heads``)."""
+    config.require_uniform(what)
+    if int(cache["k"][0].shape[2]) != config.num_key_value_heads:
+        raise NotImplementedError(
+            f"{what} does not know a pool with padded KV heads "
+            f"({cache['k'][0].shape[2]} for the model's "
+            f"{config.num_key_value_heads}: pool_kv_heads)")
 
 
 def _take_pages(pool, table):
@@ -420,7 +496,8 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
                              temperature: float = 0.0, top_k: int = 0,
                              dequant=None, kv_dtype: str | None = None,
                              kv_read: str | None = None,
-                             interpret: bool | None = None, mesh=None):
+                             interpret: bool | None = None, mesh=None,
+                             slot=None):
     """Prefill ONE request's prompt into its allocated pages.
 
     tokens [Tb] int32 padded to a bucket length; page_ids [ceil(Tb/ps)]
@@ -441,6 +518,14 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     the engine's decode steps read through the kernel, the prompt's pages
     are written by one ``paged_kv_scatter`` launch a layer instead of two
     ``dynamic_update_slice``s a page (``_kernel_write``).
+
+    A layer pattern (``config.layer_types``): the layers are walked one by
+    one (no scan: they are not alike). A LINEAR layer runs the chunk scan
+    from a zero state over the ``tlen`` real tokens
+    (``linear_mixer.mixer_prefill``) and writes the state and the
+    convolution's tail into row ``slot`` (traced; the engine's slot) of its
+    ``state`` / ``conv`` buffers, whatever the slot's last request left
+    there; pages are written for the FULL layers only.
     """
     c = config
     if dequant is not None:
@@ -448,6 +533,7 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     layer_p, other = split_layer_params(params)
     T = tokens.shape[0]
     ps = int(cache["k"][0].shape[1])
+    pool_heads = int(cache["k"][0].shape[2])    # the model's, or padded
     kernel_write = _kernel_write(c, ps, kv_dtype, kv_read, mesh)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -460,24 +546,45 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     from .llama import _attention
 
     def body(carry, lp):
-        h = _rmsnorm(carry, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv(h, lp, c)
-        q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
+        h = block_in(carry, lp["ln1"], c)
+        q, k, v = attn_qkv(h, lp, c, positions)
         att = _attention(q, k, v, c)
-        y = carry + (att.reshape(1, T, -1) @ lp["wo"])
+        y = carry + block_out(att.reshape(1, T, -1) @ lp["wo"], lp["ln1"], c)
         y = _mlp(y, lp, c)
         return y, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(body, x, layer_p)  # ks [L, 1, T, KV, hd]
+    states, tails = list(cache.get("state", ())), list(cache.get("conv", ()))
+    if c.layer_types is None:
+        x, (ks, vs) = jax.lax.scan(body, x, layer_p)  # ks [L, 1, T, KV, hd]
+    else:
+        ks, vs = [], []
+        for layer in range(c.num_hidden_layers):
+            lp = layer_params_at(layer_p, c, layer)
+            kind, l = c.kind_index(layer)
+            if kind == c.FULL:
+                x, (k, v) = body(x, lp)
+                ks.append(k)
+                vs.append(v)
+                continue
+            mix, state, tail = mixer_prefill(
+                block_in(x, lp["ln1"], c)[0], lp, c, tlen)
+            x = _mlp(x + block_out(mix[None], lp["ln1"], c), lp, c)
+            states[l] = jax.lax.dynamic_update_slice(
+                states[l], state[None].astype(states[l].dtype),
+                (slot,) + (jnp.int32(0),) * 3)
+            tails[l] = jax.lax.dynamic_update_slice(
+                tails[l], tail[None].astype(tails[l].dtype),
+                (slot,) + (jnp.int32(0),) * 2)
 
     quant = kv_dtype is not None
     z = jnp.int32(0)
     kl, vl = list(cache["k"]), list(cache["v"])
     ksl = list(cache["k_scale"]) if quant else None
     vsl = list(cache["v_scale"]) if quant else None
-    for l in range(c.num_hidden_layers):
-        krows = jnp.pad(ks[l][0], ((0, pad), (0, 0), (0, 0)))
-        vrows = jnp.pad(vs[l][0], ((0, pad), (0, 0), (0, 0)))
+    heads_pad = pool_heads - c.num_key_value_heads
+    for l in range(c.num_kv_layers):
+        krows = jnp.pad(ks[l][0], ((0, pad), (0, heads_pad), (0, 0)))
+        vrows = jnp.pad(vs[l][0], ((0, pad), (0, heads_pad), (0, 0)))
         if quant:
             krows, ksrows = _kv_encode(krows, kv_dtype)  # + [T+pad, KV]
             vrows, vsrows = _kv_encode(vrows, kv_dtype)
@@ -507,6 +614,8 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     cache = {"k": tuple(kl), "v": tuple(vl)}
     if quant:
         cache["k_scale"], cache["v_scale"] = tuple(ksl), tuple(vsl)
+    if states:
+        cache["state"], cache["conv"] = tuple(states), tuple(tails)
 
     last = jax.lax.dynamic_slice_in_dim(x[0], tlen - 1, 1, axis=0)  # [1, D]
     logits = lm_head_logits(last, other, c)
@@ -566,6 +675,7 @@ def llama_paged_prefill_suffix(params, cache, tokens, page_ids,
     generated token at suffix position tlen-1; returns (first, cache).
     One executable per (suffix bucket, prefix page bucket)."""
     c = config
+    _one_kind_pool(cache, c, "the prefix-shared suffix prefill")
     if dequant is not None:
         params = dequant(params)
     layer_p, other = split_layer_params(params)
@@ -670,7 +780,7 @@ def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
         p = dequant(params) if dequant is not None else params
         logits, cache = _paged_decode_step_slots(
             p, cache, block_table, pos, tok, config, kv_dtype=kv_dtype,
-            kv_read=kv_read, interpret=interpret, mesh=mesh)
+            kv_read=kv_read, interpret=interpret, mesh=mesh, done=done)
         key, sub = jax.random.split(key)
         with jax.named_scope("head_sample"):
             nxt = _sample(logits, temperature, top_k, sub)
@@ -761,6 +871,7 @@ def _ragged_prefill_phase(params, cache, block_table, new_tokens, new_lens,
     from ..inference.paging import SCRATCH_PAGE
 
     c = config
+    _one_kind_pool(cache, c, "the ragged layout's mixed burst")
     layer_p, other = split_layer_params(params)
     B, Tmax = new_tokens.shape
     ps = int(cache["k"][0].shape[1])
@@ -969,6 +1080,7 @@ def llama_paged_verify(params, cache, block_table, start, tokens, n_tok,
     from ..inference.paging import SCRATCH_PAGE
 
     c = config
+    _one_kind_pool(cache, c, "speculative verification")
     p = dequant(params) if dequant is not None else params
     layer_p, other = split_layer_params(p)
     B, Tv = tokens.shape

@@ -294,3 +294,59 @@ def test_kv_scatter_verdict(one_chip, no_compile_cache, case, rows, kv_heads,
     assert _kernels(lowered.compile()) == 1
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and "bf16[4096," in ln]
+
+
+# --------------------------------------------- a model of 30 KV heads (PR 30)
+
+def _pool_copies(text, pages) -> list:
+    return [ln for ln in text.splitlines()
+            if " copy(" in ln and f"bf16[{pages}," in ln]
+
+
+def test_pool_of_30_kv_heads_is_padded_to_whole_tiles(one_chip,
+                                                      no_compile_cache):
+    """Olmo-Hybrid's full layers have 30 KV heads x 128. The decode kernel
+    compiles for such a pool, but its [pages, rows * heads, hd] view of the
+    tiled 4-D pool is then a COPY of the whole pool, K and V, every call;
+    with the rows padded to 32 heads (llama_paged.pool_kv_heads) it is a
+    bitcast again, and paged_kv_scatter takes the pool."""
+    from paddle_tpu.models.llama_paged import pool_kv_heads
+    cfg = LlamaConfig(hidden_size=3840, num_attention_heads=30,
+                      num_key_value_heads=30, head_dim=128)
+    assert pool_kv_heads(cfg) == 32 and pool_kv_heads(cfg, "int8") == 30
+    assert pool_kv_heads(cfg, None, mesh=object()) == 30    # a sharded pool
+    assert pool_kv_heads(_CFG) == HEADS                     # 32: as it is
+    assert pool_kv_heads(LlamaConfig.tiny()) == 2           # the tiny ones
+    sds = _shapes(one_chip)
+    lens = sds((24,), "int32")
+
+    def text(heads):
+        pool = sds((1024, 16, heads, 128))
+        return ra.ragged_paged_attention.lower(
+            sds((24, 1, heads, 128)), pool, pool, sds((24, 216), "int32"),
+            lens, lens, page_size=16, interpret=False).compile().as_text()
+
+    assert _pool_copies(text(30), 1024)                     # why it is padded
+    assert not _pool_copies(text(32), 1024)
+    assert ra.scatter_supported(128, 32, 16) \
+        and not ra.scatter_supported(128, 30, 16)
+
+
+def test_gated_delta_rule_compiles_at_the_cells_shapes(one_chip,
+                                                       no_compile_cache):
+    """ops/gated_delta.py at Olmo-Hybrid's widths (30 heads, key 96, value
+    192) for a described v5e: one token of 24 slots, and the chunk scan
+    over the longer prompt bucket of perfbench/traffic/longdoc-batch.json."""
+    from paddle_tpu.ops.gated_delta import gdn_chunk_scan, gdn_step
+    sds = _shapes(one_chip)
+    B, T, H, dk, dv = 24, 3072, 30, 96, 192
+    step = jax.jit(gdn_step).lower(
+        sds((B, H, dk)), sds((B, H, dk)), sds((B, H, dv)),
+        sds((B, H), "float32"), sds((B, H), "float32"),
+        sds((B, H, dv, dk), "float32")).compile()
+    assert step.memory_analysis().temp_size_in_bytes < 2 * B * H * dv * 128 * 4
+    scan = jax.jit(gdn_chunk_scan).lower(
+        sds((T, H, dk)), sds((T, H, dk)), sds((T, H, dv)),
+        sds((T, H), "float32"), sds((T, H), "float32"),
+        sds((H, dv, dk), "float32"), sds((), "int32")).compile()
+    assert scan.memory_analysis().temp_size_in_bytes < 2 ** 30
