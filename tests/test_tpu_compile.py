@@ -29,6 +29,9 @@ _CFG = LlamaConfig.llama2_7b()
 HEADS, HEAD_DIM = _CFG.num_attention_heads, _CFG.head_dim
 TRAIN_QKV = (cs.TRAIN_BATCH, cs.TRAIN_SEQ, HEADS, HEAD_DIM)   # [B, T, H, D]
 PREFILL_BUCKETS = cs.SERVE_PROMPT_BUCKETS
+# the shapes the benchmark's cells hand the flash kernel (ISSUE 31), from the
+# microbenchmark that times them so that they cannot drift
+from tools.flash_microbench import GEOMETRIES as CELL_GEOMETRIES  # noqa: E402
 SERVE_BATCH, SERVE_MAX_LEN = cs.SERVE_MAX_BATCH, cs.SERVE_MAX_LEN
 
 # the ragged kernel's q_rows > 1 body at the serve phase's geometry
@@ -153,6 +156,40 @@ class TestFlashCompiles:
         c = jax.jit(_flash).lower(
             *_qkv((1, bucket, HEADS, HEAD_DIM), one_chip)).compile()
         assert _kernels(c) == 1
+
+    @pytest.mark.parametrize("name,shape,grad", CELL_GEOMETRIES,
+                             ids=[g[0] for g in CELL_GEOMETRIES])
+    def test_at_the_cells_geometries(self, one_chip, no_compile_cache, name,
+                                     shape, grad):
+        """The five shapes the benchmark's cells run (ISSUE 31): the train
+        cell's forward and gradient, the serving cells' prefill buckets
+        forward only, each with K and V held whole in VMEM."""
+        assert fa._major_block(fa._fit_block(512, shape[1]), shape[1],
+                               2 * shape[3]) == shape[1]
+        c = jax.jit(_flash).lower(*_qkv(shape, one_chip)).compile()
+        assert _kernels(c) == 1
+        if grad:
+            g = jax.grad(_flash_loss, argnums=(0, 1, 2))
+            c = jax.jit(g).lower(*_qkv(shape, one_chip)).compile()
+            assert _kernels(c) == 3
+
+    @pytest.mark.parametrize("rows,dtype,major", [
+        (8192, jnp.bfloat16, 8192),     # the longest sequence held whole
+        (16384, jnp.bfloat16, 8192),    # past it: two major blocks a head,
+        (8192, jnp.float32, 4096),      # and float32 rows cost twice
+    ])
+    def test_on_each_side_of_the_major_block_rule(self, one_chip,
+                                                  no_compile_cache, rows,
+                                                  dtype, major):
+        """K and V (q and dout in the dk/dv kernel) are held in VMEM up to
+        8 MiB, double-buffered; a longer sequence brings the second grid
+        axis and its clamped index maps in."""
+        assert fa._major_block(512, rows,
+                               128 * jnp.dtype(dtype).itemsize) == major
+        g = jax.grad(_flash_loss, argnums=(0, 1, 2))
+        c = jax.jit(g).lower(*_qkv((1, rows, 2, 128), one_chip,
+                                   dtype)).compile()
+        assert _kernels(c) == 3
 
     def test_sharded_step_runs_kernel_per_shard(self, topo, no_compile_cache,
                                                 monkeypatch):
