@@ -19,20 +19,9 @@ the two numbers the paged design is FOR:
 
     python benchmarks/decode_bench.py --paged [N_REQ] [MAX_BATCH] [BURST]
 
-``--ragged`` (implies --paged) additionally serves the same workload
-through the Pallas ragged kernel (``kv_layout="ragged"``,
-ops/ragged_attention.py) and emits a ``ragged`` sub-object: bytes/token
-that follow LIVE context (the kernel DMAs only live pages — next to the
-HBM roofline, i.e. the exact live K/V bytes a perfect reader would move),
-the measured executable inventory (O(1): {prefill-carrying, decode-only}
-vs the gather path's bucket grid), and a parity bit against the gather
-outputs. Works on the CPU fallback (interpret mode) and TPU alike.
-
-    python benchmarks/decode_bench.py --paged --ragged [N_REQ] [MB] [BURST]
-
 On CPU both modes drop to the tiny config automatically (the 850M flagship
 sizing stays TPU-only) — that is what the tier-1 smokes
-(tests/test_serving_paged.py, tests/test_ragged_attention.py) run to pin
+(tests/test_serving_paged.py, tests/test_speculative.py) run to pin
 the compile-count bounds. The JSON line is emitted on EVERY exit path
 (bench contract): failures print an ``error`` payload before re-raising.
 """
@@ -104,11 +93,11 @@ def _dense_main(args) -> dict:
 
 def ragged_read_bytes(cfg, reqs, page_size):
     """(page-granular mean, exact-live mean) K/V bytes per emitted token
-    for a ragged serve of `reqs` [(prompt, max_new), ...]: token t of a
-    request reads ceil((t+1)/page_size) pages; the HBM roofline reads
-    exactly t+1 rows. This is the live-length accounting the ISSUE-8 fix
-    added to paged_kv_bytes_per_token — the bucket-width bill the gather
-    path pays does not apply to the kernel's per-page DMA loop."""
+    for a serve of `reqs` [(prompt, max_new), ...] that reads live pages
+    only (the decode kernel): token t of a request reads
+    ceil((t+1)/page_size) pages; the HBM roofline reads exactly t+1 rows.
+    The bucket-width bill the gather pays does not apply to the kernel's
+    per-page copies."""
     from paddle_tpu.inference.paging import pages_for
     from paddle_tpu.models.llama_paged import paged_kv_bytes_per_token
     row_bytes = paged_kv_bytes_per_token(cfg, 1, 1)  # one K+V row, all layers
@@ -123,7 +112,7 @@ def ragged_read_bytes(cfg, reqs, page_size):
     return row_bytes * rows_paged // ntok, row_bytes * rows_exact // ntok
 
 
-def _paged_main(args, ragged: bool = False) -> dict:
+def _paged_main(args) -> dict:
     n_req = int(args[0]) if len(args) > 0 else 16
     max_batch = int(args[1]) if len(args) > 1 else 8
     burst = int(args[2]) if len(args) > 2 else 16
@@ -153,7 +142,7 @@ def _paged_main(args, ragged: bool = False) -> dict:
                             rng.choice(budgets, n_req))]
     total_new = sum(m for _, m in reqs)
 
-    def serve(layout="paged", kv_dtype="", spec=False):
+    def serve(kv_dtype="", spec=False):
         # kv_dtype="" pins the baseline passes to full-precision pages
         # even when PADDLE_SERVE_KV_DTYPE is set fleet-wide — the quant
         # sub-object below is a COMPARISON, not a global override; the
@@ -161,8 +150,8 @@ def _paged_main(args, ragged: bool = False) -> dict:
         # for the same reason (their sub-objects own those comparisons)
         eng = ContinuousBatcher(cfg, params, max_batch=max_batch,
                                 max_len=max_len, prompt_buckets=buckets,
-                                burst=burst, kv_layout=layout,
-                                page_size=page_size, kv_dtype=kv_dtype,
+                                burst=burst, page_size=page_size,
+                                kv_dtype=kv_dtype,
                                 prefix_cache_pages=0, spec_decode=spec)
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run()
@@ -228,51 +217,18 @@ def _paged_main(args, ragged: bool = False) -> dict:
         payload["spec"] = spec_subobject(
             seng, total_new, spec_s=spec_s, plain_s=dt,
             parity=spec_out == gather_out, accept_hist_count0=ar0)
-    if not ragged:
-        return payload
-
-    # ---- ragged mode: same workload through the Pallas kernel path
-    from paddle_tpu.models.llama_paged import llama_ragged_burst
-    b0 = llama_ragged_burst._cache_size()
-    serve("ragged")  # compile pass
-    t0 = time.perf_counter()
-    reng, ragged_out = serve("ragged")
-    rdt = time.perf_counter() - t0
-    live_bytes, roofline_bytes = ragged_read_bytes(cfg, reqs, page_size)
-    payload["ragged"] = {
-        "tokens_per_sec": round(total_new / rdt, 1),
-        # bytes the kernel's per-page DMA loop actually moves per token
-        # (live pages) vs the exact-live-rows HBM roofline it approaches
-        # from above by < one page
-        "kv_read_bytes_per_token": int(live_bytes),
-        "hbm_roofline_bytes_per_token": int(roofline_bytes),
-        "roofline_ratio": round(live_bytes / max(roofline_bytes, 1), 3),
-        # measured executable inventory: O(1) — at most the
-        # {prefill-carrying, decode-only} pair, never a bucket grid
-        "executables": {
-            "ragged_burst": llama_ragged_burst._cache_size(),
-            "ragged_burst_delta": llama_ragged_burst._cache_size() - b0,
-        },
-        # the engine really took the kernel path (False means
-        # PADDLE_RAGGED_ATTN=0 asked for the gather)
-        "kernel_active": bool(reng._ragged),
-        "parity": ragged_out == gather_out,
-    }
     return payload
 
 
 def main(argv=None) -> dict:
     argv = sys.argv[1:] if argv is None else list(argv)
     paged = "--paged" in argv
-    ragged = "--ragged" in argv          # implies --paged
     args = [a for a in argv if not a.startswith("--")]
     try:
-        payload = _paged_main(args, ragged=ragged) if (paged or ragged) \
-            else _dense_main(args)
+        payload = _paged_main(args) if paged else _dense_main(args)
     except BaseException as e:  # bench contract: never exit JSON-less
         print(json.dumps({"metric": "llama_paged_decode_tokens_per_sec"
-                          if (paged or ragged)
-                          else "llama_decode_tokens_per_sec",
+                          if paged else "llama_decode_tokens_per_sec",
                           "error": f"{type(e).__name__}: {e}"}))
         raise
     print(json.dumps(payload))

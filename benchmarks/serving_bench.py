@@ -6,11 +6,7 @@ request mix, several ways on the real chip:
   sequential — one llama_generate per request (B=1, the old LLMPredictor
                serving mode);
   continuous — the slot-pool ContinuousBatcher (inference/serving.py),
-               timed for BOTH KV layouts (paged gather and dense slots)
-               AND the ragged Pallas-kernel path (`kv_layout="ragged"`,
-               ISSUE 8) — the JSON line carries a `ragged` sub-object
-               (tokens/s, live-length bytes/token, executable count,
-               parity bit vs the gather outputs).
+               timed for BOTH KV layouts (paged pool and dense slots).
 
     python benchmarks/serving_bench.py [n_requests] [max_batch] [burst]
 
@@ -700,26 +696,6 @@ def _main():
     _, dense_rids, dense_out = serve("dense")
     dense_s = time.perf_counter() - t0
 
-    # ---- ragged Pallas-kernel path (ISSUE 8): same mixed prefill/decode
-    # request mix, ONE mixed-burst executable instead of the bucket grid
-    from benchmarks.decode_bench import ragged_read_bytes
-    from paddle_tpu.models.llama_paged import llama_ragged_burst
-    serve("ragged")  # compile pass
-    t0 = time.perf_counter()
-    reng, ragged_rids, ragged_out = serve("ragged")
-    ragged_s = time.perf_counter() - t0
-    ragged_vs_paged = sum(ragged_out[r] != out[p]
-                          for r, p in zip(ragged_rids, rids))
-    live_bytes, roofline_bytes = ragged_read_bytes(cfg, reqs, page_size)
-    ragged_obj = {
-        "tokens_per_sec": round(total_new / ragged_s, 1),
-        "kv_read_bytes_per_token": int(live_bytes),
-        "hbm_roofline_bytes_per_token": int(roofline_bytes),
-        "executables": {"ragged_burst": llama_ragged_burst._cache_size()},
-        "kernel_active": bool(reng._ragged),
-        "parity": ragged_vs_paged == 0,
-    }
-
     # ---- quantized KV pages (ISSUE 10): the same workload once more with
     # int8/fp8 pages through the gather path — the `quant` sub-object
     # reports what the quantized pool buys (bytes/token + capacity at an
@@ -739,7 +715,7 @@ def _main():
 
     # ---- speculative decoding (ISSUE 14): PADDLE_SPEC_DECODE=1 serves
     # the same workload once more through draft-propose + one-launch
-    # verify on the ragged engine and reports the `spec` sub-object
+    # verify on the paged engine and reports the `spec` sub-object
     # (accept rate, tokens per slot-launch, draft overhead, spec-vs-plain
     # ratio); null otherwise — off must be distinguishable from
     # zero-accepts. A failure lands as spec.error (never JSON-less).
@@ -749,16 +725,16 @@ def _main():
     spec_divergent = 0
     if spec_enabled():
         try:
-            serve("ragged", spec=True)  # compile pass
+            serve("paged", spec=True)  # compile pass
             ar0 = _metrics.histogram("serve.spec_accept_rate") \
                 .stats()["count"]
             t0 = time.perf_counter()
-            seng, spec_rids, spec_out = serve("ragged", spec=True)
+            seng, spec_rids, spec_out = serve("paged", spec=True)
             spec_s = time.perf_counter() - t0
-            spec_divergent = sum(spec_out[s] != ragged_out[r]
-                                 for s, r in zip(spec_rids, ragged_rids))
+            spec_divergent = sum(spec_out[s] != out[r]
+                                 for s, r in zip(spec_rids, rids))
             spec_obj = spec_subobject(seng, total_new, spec_s=spec_s,
-                                      plain_s=ragged_s,
+                                      plain_s=cont_s,
                                       parity=spec_divergent == 0,
                                       accept_hist_count0=ar0)
         except BaseException as e:
@@ -860,7 +836,6 @@ def _main():
         "disagg": disagg_obj,
         "prefix": prefix_obj,
         "spec": spec_obj,
-        "ragged": ragged_obj,
         "quant": quant_obj,
         "vs_sequential_b1": round(seq_s / cont_s, 2),
         "vs_dense_slots": round(dense_s / cont_s, 2),
@@ -885,11 +860,9 @@ def _main():
     # hard parity gate AFTER the JSON line: the measured throughputs must
     # never be discarded by the failure they diagnose (cf. bench.py
     # _record_latest rationale). Plain `if` — `assert` dies under -O.
-    if train_steps and (mismatch or paged_vs_dense or ragged_vs_paged
-                        or spec_divergent):
+    if train_steps and (mismatch or paged_vs_dense or spec_divergent):
         print(f"# FAIL: {mismatch}/{n_req} paged-vs-sequential, "
-              f"{paged_vs_dense}/{n_req} paged-vs-dense, "
-              f"{ragged_vs_paged}/{n_req} ragged-vs-paged and "
+              f"{paged_vs_dense}/{n_req} paged-vs-dense and "
               f"{spec_divergent}/{n_req} spec-vs-plain requests diverged "
               f"WITH TRAINED WEIGHTS — a real numerics bug, not a bf16 "
               f"tiebreak", file=sys.stderr)

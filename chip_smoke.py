@@ -179,8 +179,8 @@ def make_requests(cfg, seed, rehearse):
             for n, m in spec]
 
 
-def serve(cfg, params, requests, on_tpu, kv_layout, **engine_kw):
-    """Serve `requests` through ContinuousBatcher(kv_layout); returns each
+def serve(cfg, params, requests, on_tpu, **engine_kw):
+    """Serve `requests` through the default ContinuousBatcher; returns each
     request's greedy tokens and the facts of the pass."""
     import jax
     import jax.numpy as jnp
@@ -188,39 +188,30 @@ def serve(cfg, params, requests, on_tpu, kv_layout, **engine_kw):
     from paddle_tpu.models.llama_paged import llama_paged_prefill_slot
     from paddle_tpu.observability import metrics
 
-    eng = ContinuousBatcher(cfg, params, kv_layout=kv_layout,
+    eng = ContinuousBatcher(cfg, params,
                             max_batch=SERVE_MAX_BATCH, max_len=SERVE_MAX_LEN,
                             prompt_buckets=SERVE_PROMPT_BUCKETS, burst=8,
                             **engine_kw)
     ps = eng.page_size
-    if kv_layout == "ragged":
-        # prefill and decode both read through the ragged kernel, and on a
-        # TPU the burst is traced with interpret=False: Mosaic compiles it
-        kernel = {"ragged_kernel": eng._ragged,
-                  "interpreted": eng._interpret}
-        if not eng._ragged or eng._interpret == on_tpu:
-            raise AssertionError(f"the ragged engine is not on the compiled "
-                                 f"kernel: {kernel}")
-    else:
-        bucket = SERVE_PROMPT_BUCKETS[0]
-        # Llama-2-7B's pool (32 KV heads x 128, bf16 or float32) is one the
-        # decode kernel takes: on a TPU the burst must read through it
-        if on_tpu and eng.stats["kv_read"] != "kernel":
-            raise AssertionError(f"the paged engine's decode steps read "
-                                 f"through {eng.stats['kv_read']!r}, not "
-                                 f"the kernel")
-        kernel = {"kv_read": eng.stats["kv_read"],
-                  "flash_tpu_custom_calls_in_prefill": custom_calls(
-            llama_paged_prefill_slot.lower(
-                params, eng._cache, jnp.zeros(bucket, jnp.int32),
-                jnp.zeros(-(-bucket // ps), jnp.int32), jnp.int32(1),
-                jax.random.PRNGKey(0), config=cfg, temperature=0.0, top_k=0,
-                dequant=None, kv_dtype=None,
-                # the page writes as a loop: every kernel counted is flash
-                kv_read="gather"))}
-        if on_tpu and kernel["flash_tpu_custom_calls_in_prefill"] < 1:
-            raise AssertionError("the Pallas flash kernel is not in the "
-                                 "bucketed-prefill program")
+    bucket = SERVE_PROMPT_BUCKETS[0]
+    # Llama-2-7B's pool (32 KV heads x 128, bf16 or float32) is one the
+    # decode kernel takes: on a TPU the burst must read through it
+    if on_tpu and eng.stats["kv_read"] != "kernel":
+        raise AssertionError(f"the paged engine's decode steps read "
+                             f"through {eng.stats['kv_read']!r}, not "
+                             f"the kernel")
+    kernel = {"kv_read": eng.stats["kv_read"],
+              "flash_tpu_custom_calls_in_prefill": custom_calls(
+        llama_paged_prefill_slot.lower(
+            params, eng._cache, jnp.zeros(bucket, jnp.int32),
+            jnp.zeros(-(-bucket // ps), jnp.int32), jnp.int32(1),
+            jax.random.PRNGKey(0), config=cfg, temperature=0.0, top_k=0,
+            dequant=None, kv_dtype=None,
+            # the page writes as a loop: every kernel counted is flash
+            kv_read="gather"))}
+    if on_tpu and kernel["flash_tpu_custom_calls_in_prefill"] < 1:
+        raise AssertionError("the Pallas flash kernel is not in the "
+                             "bucketed-prefill program")
 
     t0 = time.perf_counter()
     rids = [eng.add_request(p, max_new_tokens=m) for p, m in requests]
@@ -288,31 +279,23 @@ def served_logit_gap(cfg, params, requests, served, ref) -> float:
     return gap
 
 
-def serve_both_layouts(cfg, params, requests, on_tpu, **engine_kw):
-    """The default gather layout and the ragged kernel layout against
-    per-request llama_generate. Returns the facts and whether all three
-    agree on every token."""
-    paged, facts_p = serve(cfg, params, requests, on_tpu, "paged",
-                           **engine_kw)
-    gc.collect()
-    ragged, facts_r = serve(cfg, params, requests, on_tpu, "ragged",
-                            **engine_kw)
+def serve_against_reference(cfg, params, requests, on_tpu, **engine_kw):
+    """The default paged layout against per-request llama_generate.
+    Returns the facts and whether the two agree on every token."""
+    paged, facts_p = serve(cfg, params, requests, on_tpu, **engine_kw)
     gc.collect()
     t0 = time.perf_counter()
     ref = reference_tokens(cfg, params, requests)
     facts_p["tokens_equal_llama_generate"] = agreement(paged, ref)
-    facts_r["tokens_equal_llama_generate"] = agreement(ragged, ref)
-    facts_r["tokens_equal_paged"] = agreement(ragged, paged)
-    equal = paged == ref and ragged == ref
-    facts = {"paged": facts_p, "ragged": facts_r,
+    equal = paged == ref
+    facts = {"paged": facts_p,
              "reference_s_with_compile": round(time.perf_counter() - t0, 2)}
     if not equal:
         # the kernel's online softmax sums in another order than
         # llama_generate's full-width one: hold what differs to the
         # benchmark's measure instead of to equality
-        facts["served_logit_gap_max"] = max(
-            served_logit_gap(cfg, params, requests, out, ref)
-            for out in (paged, ragged))
+        facts["served_logit_gap_max"] = served_logit_gap(
+            cfg, params, requests, paged, ref)
     return facts, equal
 
 
@@ -327,7 +310,8 @@ def phase_serve(args, dev) -> dict:
     requests = make_requests(cfg, args.seed, args.rehearse)
     params = llama_init_params(cfg, jax.random.PRNGKey(args.seed))
     pool = {} if args.rehearse else {"pool_hbm_bytes": SERVE_POOL_BYTES}
-    facts, equal = serve_both_layouts(cfg, params, requests, on_tpu, **pool)
+    facts, equal = serve_against_reference(cfg, params, requests, on_tpu,
+                                            **pool)
     result = {"config": {**width_summary(cfg),
                          "prompt_lens": [len(p) for p, _ in requests],
                          "prompt_buckets": list(SERVE_PROMPT_BUCKETS),
@@ -351,12 +335,12 @@ def phase_serve(args, dev) -> dict:
                              dtype=jnp.float32)
         params32 = llama_init_params(cfg32, jax.random.PRNGKey(args.seed))
         with jax.default_matmul_precision("highest"):
-            facts32, equal32 = serve_both_layouts(cfg32, params32, requests,
-                                                  on_tpu)
+            facts32, equal32 = serve_against_reference(cfg32, params32,
+                                                       requests, on_tpu)
         result.update(float32=facts32, compared_in="float32",
                       float32_config=width_summary(cfg32))
         result["reduced"].append(
-            f"token equality of both layouts with llama_generate decided in "
+            f"token equality with llama_generate decided in "
             f"float32 at {cfg32.num_hidden_layers} layers (a token that "
             f"still differs there is held to served_logit_gap_max < "
             f"{SERVE_LOGIT_GAP_LIMIT})")

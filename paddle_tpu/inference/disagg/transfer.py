@@ -30,7 +30,7 @@ before it can stream tokens. This module is the wire in between:
     per-page-coarser carry-over): ``scale_gran="page"`` re-blocks the
     quantization to ONE scale per (page, head) — ``~page_size×`` fewer
     scale bytes on the wire. The POOL keeps its per-(row, head) layout
-    on both sides (read paths and the ragged kernel untouched); the
+    on both sides (the read paths untouched); the
     coarser blocks exist only in flight, at the cost of one
     requantization whose greedy-agreement impact is measured and pinned
     by tests/test_disagg_serving.py. Rows past the live length are

@@ -127,11 +127,12 @@ class RoutedRequest:
     kv: dict | None = None
     # request-lifecycle reliability (ISSUE 19): absolute deadline on the
     # router clock (None = unbounded), the dispatch timestamp the hedge
-    # delay measures from, the replica running the hedge copy (None = not
-    # hedged), and a once-per-request latch so a blocked hedge counts
-    # retry_budget_exhausted once, not once per tick
+    # delay measures from (None = not dispatched: the clock's zero is
+    # arbitrary, so no reading of it can say that), the replica running
+    # the hedge copy (None = not hedged), and a once-per-request latch so
+    # a blocked hedge counts retry_budget_exhausted once, not once per tick
     t_deadline: float | None = None
-    t_dispatch: float = 0.0
+    t_dispatch: float | None = None
     hedge_replica: str | None = None
     budget_blocked: bool = False
     # where the prefilled result physically came from (ISSUE 14
@@ -637,7 +638,7 @@ class Router:
         for rid, req in list(self._inflight.items()):
             if req.hedge_replica is not None or req.last_faulted:
                 continue
-            if req.t_dispatch <= 0 or now - req.t_dispatch < delay:
+            if req.t_dispatch is None or now - req.t_dispatch < delay:
                 continue
             if req.t_deadline is not None and now >= req.t_deadline:
                 continue   # expired: the replica's own budget check

@@ -14,20 +14,7 @@ different prompt lengths and generation budgets share every MXU step
     slot retires on EOS or its length budget and emits padding until the
     host swaps a new request in between bursts.
 
-Three KV layouts share that scheduler:
-
-  * ``kv_layout="ragged"`` (ISSUE 8) — the paged pool below, read through
-    the Pallas ragged kernel (``ops/ragged_attention.py``) in ONE mixed
-    prefill+decode executable per burst (``llama_ragged_burst``):
-    admissions prefill their ragged-length prompts and join the same
-    launch's decode steps, the block table rides full-width (the kernel
-    DMAs only live pages), and the executable inventory collapses to the
-    {prefill-carrying, decode-only} pair — O(1) in the request mix.
-    ``PADDLE_RAGGED_ATTN=0`` asks for the gather-paged path instead,
-    token-identical; a pool the compiled kernel cannot take on a TPU
-    (``ops.ragged_attention.supported``: quantized pages, head_dim not a
-    lane multiple, a KV head count the page DMA cannot tile) RAISES at
-    construction, it is never served another way unasked.
+Two KV layouts share that scheduler:
 
   * ``kv_layout="paged"`` (default) — a shared ``[num_pages, page_size,
     KV, hd]`` pool per layer with per-slot block tables
@@ -68,8 +55,8 @@ whole by a slot's prefill, updated in place by every decode step, left as
 they are for a finished or free slot). Preemption restarts a request from
 scratch, so its next prefill simply overwrites the slot's rows. What
 cannot hold for a recurrent state raises a ``ValueError`` at construction
-that names the reason (prefix sharing, the ragged and dense layouts,
-quantized pages, speculation, a serving mesh) or at ``add_request``
+that names the reason (prefix sharing, the dense layout, quantized
+pages, speculation, a serving mesh) or at ``add_request``
 (``prefill_only`` / ``kv_import``). ``stats["state_bytes"]`` is the
 allocation; the gauge ``serve.state_mb_held`` and the ``state`` argument of
 every ``serve.dispatch_burst`` span the bytes of the slots in use.
@@ -83,7 +70,7 @@ page in a burst's write window private before dispatch) and prefill ONLY
 the unshared suffix — a full-prefix hit skips prefill entirely and
 resumes decode at the last prompt token. Near-zero marginal HBM and
 TTFT for a common system prompt; temp=0 token-identical to an unshared
-serve on both read paths (pinned by tests/test_prefix_cache.py).
+serve on both KV reads (pinned by tests/test_prefix_cache.py).
 
 Chaos sites (PADDLE_CHAOS, ROADMAP PR 1 follow-up): ``serve.admit`` fails
 one admission (that request retires with partial output), ``serve.burst``
@@ -110,8 +97,8 @@ path ``state``: bytes of recurrent state the slots in use hold),
 (pop, bucket, allocate, prefill dispatch; under the in-flight burst on the
 paged path; arg: prefills staged),
 ``serve.readback`` (the step's one blocking ``device_get``) and
-``serve.merge`` (the host bookkeeping after it). The ragged and dense loops
-use the same four names. Construction is one ``serve.init``.
+``serve.merge`` (the host bookkeeping after it). The dense loop uses the
+same four names. Construction is one ``serve.init``.
 
 Request-level SLO observability (ISSUE 6 tentpole): every request gets a
 process-unique trace id at enqueue and its lifecycle edges
@@ -258,7 +245,7 @@ class ContinuousBatcher:
         self._temp, self._top_k = float(temperature), int(top_k)
         self._key = jax.random.PRNGKey(seed)
 
-        if kv_layout not in ("paged", "dense", "ragged"):
+        if kv_layout not in ("paged", "dense"):
             raise ValueError(f"unknown kv_layout {kv_layout!r}")
         # a model spec with LINEAR layers (LlamaConfig.layer_types) holds a
         # recurrent state per SLOT beside the paged K/V of its FULL layers.
@@ -272,8 +259,8 @@ class ContinuousBatcher:
             raise ValueError(
                 f"kv_layout={kv_layout!r} cannot serve a model with "
                 "recurrent (linear-attention) layers: only the default "
-                "kv_layout='paged' walks a layer pattern (the ragged burst "
-                "and the dense slot cache know one kind of layer)")
+                "kv_layout='paged' walks a layer pattern (the dense slot "
+                "cache knows one kind of layer)")
         # quantized KV pages (ISSUE 10): kv_dtype "int8"/"fp8" stores the
         # page pool through the paddle_tpu.quant block codecs (payload +
         # per-(row, head) scales); both read paths dequantize. Explicit
@@ -293,8 +280,8 @@ class ContinuousBatcher:
             # only reachable with an explicit argument — env-derived
             # dtypes were never consulted for the dense baseline above
             raise ValueError("kv_dtype quantization needs the paged pool "
-                             "(kv_layout='paged' or 'ragged'); the dense "
-                             "slot cache is the full-precision baseline")
+                             "(kv_layout='paged'); the dense slot cache is "
+                             "the full-precision baseline")
         if pool_hbm_bytes is not None and kv_layout == "dense":
             raise ValueError("pool_hbm_bytes sizes the paged page pool; "
                              "the dense slot cache is sized by "
@@ -302,46 +289,18 @@ class ContinuousBatcher:
                              "budget would hide a misconfiguration")
         if prefix_cache_pages and kv_layout == "dense":
             raise ValueError("prefix sharing needs the paged pool "
-                             "(kv_layout='paged' or 'ragged') — the dense "
-                             "slot cache has no shareable page unit")
+                             "(kv_layout='paged') — the dense slot cache "
+                             "has no shareable page unit")
         if recurrent and kv_dtype is not None:
             raise ValueError(
                 f"kv_dtype={kv_dtype!r} cannot serve a model with recurrent "
                 "layers: quantized K/V pages beside a float32 recurrent "
                 "state are not supported")
         self._kv_dtype = kv_dtype
-        # "ragged" = the paged pool read through the Pallas ragged kernel
-        # (ops/ragged_attention.py) in ONE mixed prefill+decode executable:
-        # compiled on a TPU, interpreted elsewhere. PADDLE_RAGGED_ATTN=0 is
-        # the one explicit way to ask for the XLA gather instead; a pool
-        # the kernel cannot take RAISES — a caller who asked for the
-        # kernel must never be served through another path unasked.
-        self._ragged = False
+        # off the TPU the Pallas kernels of the paged programs are interpreted
         self._interpret = jax.default_backend() != "tpu"
         self._mesh = None
         self._pool_heads = self._cfg.num_key_value_heads
-        # which read a decode step takes: stats["kv_read"] and the
-        # serve.dispatch_burst span's argument ("dense" has no pool)
-        self._kv_read = "dense"
-        if kv_layout == "ragged":
-            from ..ops import ragged_attention as _ra
-            self._ragged = _ra.enabled()
-            # PADDLE_RAGGED_ATTN=0: the gather, whatever the pool's shape
-            self._kv_read = "kernel" if self._ragged else "gather"
-            kv_heads = self._cfg.num_key_value_heads
-            if self._ragged and not _ra.supported(
-                    self._cfg.head_dim, kv_heads, self.S, self._interpret,
-                    kv_dtype=self._kv_dtype):
-                raise ValueError(
-                    f"kv_layout='ragged' cannot run the ragged kernel on "
-                    f"platform {jax.default_backend()!r} at head_dim="
-                    f"{self._cfg.head_dim}, page_size={int(page_size)}, "
-                    f"kv_heads={kv_heads}, max_len={self.S}, kv_dtype="
-                    f"{self._kv_dtype!r}: the compiler refuses it (see "
-                    f"ops.ragged_attention.supported). Use kv_layout="
-                    f"'paged', or set {_ra.ENV_RAGGED_ATTN}=0 to ask for "
-                    f"the XLA gather explicitly.")
-            kv_layout = "paged"
         self._layout = kv_layout
         # Slot state lives HOST-side as numpy and is uploaded per burst
         # call (four tiny [B] arrays + the block table). The alternative —
@@ -386,8 +345,6 @@ class ContinuousBatcher:
                                              self._mesh)
             self._page_bytes = page_bytes(model_config, self._ps,
                                           self._kv_dtype, self._mesh)
-            if self._ragged:
-                self._model_shaped_pool("kv_layout='ragged'")
             if pool_hbm_bytes is not None:
                 # explicit HBM budget: the pool is however many pages the
                 # bytes buy at this kv_dtype — the knob the quantized-page
@@ -421,7 +378,7 @@ class ContinuousBatcher:
             # spread over the "model" axis so one replica spans a pod
             # slice. The scheduler stays layout-agnostic — block tables
             # and slot state remain replicated host metadata; the gather
-            # path partitions automatically, the ragged kernel shard_maps.
+            # path partitions automatically.
             if self._mesh is not None:
                 kv = self._cfg.num_key_value_heads
                 if kv % self._mesh.size:
@@ -435,20 +392,12 @@ class ContinuousBatcher:
             self._admit_seq = [0] * self.B
             self._seq = 0
             self._kv_read_bucket = None  # page bucket the gauge was set at
-            if self._kv_read == "dense":
-                # the default layout takes the read its pool's geometry
-                # allows (ISSUE 28): the decode kernel over live pages, or
-                # the XLA gather over the page bucket
-                from ..models.llama_paged import paged_kv_read
-                self._kv_read = paged_kv_read(
-                    model_config, self._ps, self._kv_dtype, self._mesh)
-            if self._ragged:
-                # decode-only bursts (the steady state) reuse these
-                # device-resident empty-admission inputs instead of
-                # rebuilding and re-uploading a [B, Tmax] buffer per burst
-                self._no_prompts = jnp.full(
-                    (self.B, self._buckets[-1]), jnp.int32(self.pad_id))
-                self._no_lens = jnp.zeros(self.B, jnp.int32)
+            # which read a decode step takes (ISSUE 28; stats["kv_read"]
+            # and the serve.dispatch_burst span's argument): the decode
+            # kernel over live pages, or the XLA gather over the page bucket
+            from ..models.llama_paged import paged_kv_read
+            self._kv_read = paged_kv_read(
+                model_config, self._ps, self._kv_dtype, self._mesh)
             # prefix cache (ISSUE 13): page-granular prefix-hash index
             # over THIS pool. Explicit argument wins; None consults
             # PADDLE_PREFIX_CACHE_PAGES; 0 (the default) keeps the
@@ -472,6 +421,7 @@ class ContinuousBatcher:
         else:
             from ..models.llama_decode import init_kv_cache
             self._cache = init_kv_cache(model_config, self.B, self.S)
+            self._kv_read = "dense"   # no pool to read
 
         # speculative decoding (ISSUE 14): a draft model proposing k
         # greedy tokens per slot + ONE target verify launch per step.
@@ -492,7 +442,7 @@ class ContinuousBatcher:
         del spec_src
 
         # what a prompt bucket pads: real and padded prompt tokens of
-        # every bucketed prefill dispatched (the ragged path has no bucket)
+        # every bucketed prefill dispatched
         self._pf_real = metrics.counter("serve.prefill_tokens_real")
         self._pf_padded = metrics.counter("serve.prefill_tokens_padded")
         self._queue: deque[ServedRequest] = deque()
@@ -768,8 +718,8 @@ class ContinuousBatcher:
         with this prefix shares instead of recomputing. Called only once
         the pages' content has LANDED (the prefill's first-token readback
         at merge, or a kv_import's synchronous install) — an admit-time
-        insert would let a same-pass resume COW-copy a page the ragged
-        burst's in-flight prefill phase had not written yet."""
+        insert would let a same-pass resume COW-copy a page the in-flight
+        prefill had not written yet."""
         if self._prefix is not None:
             self._prefix.insert(req.prompt, self._page_tbl[slot])
 
@@ -1148,10 +1098,9 @@ class ContinuousBatcher:
         return first
 
     def _admit_kv_import(self, req: ServedRequest, slot: int) -> int | None:
-        """The ONE kv_import admit epilogue (gather and ragged paths
-        share it): install-or-terminal-error, stat bump, and the
-        immediate retire when the transferred first token already
-        satisfies the budget (or ended the stream). Returns the first
+        """The kv_import admit epilogue: install-or-terminal-error, stat
+        bump, and the immediate retire when the transferred first token
+        already satisfies the budget (or ended the stream). Returns the first
         token while the slot decodes on, None when the request retired
         here (installed fine but needed no decode, or the install failed
         as ONE terminal error result — never a dead serve loop)."""
@@ -1320,8 +1269,8 @@ class ContinuousBatcher:
         return staged, installed
 
     def _drain_burst(self, old_pos, done, emitted, skip=frozenset()) -> int:
-        """The ONE burst drain loop (dense, gather-paged and ragged steps
-        all end here): extend each live slot's output by its
+        """The ONE burst drain loop (dense and paged steps both end
+        here): extend each live slot's output by its
         ``pos - old_pos`` scan emissions, report them to the SLO tracker,
         and finish+retire slots the device marked done. ``skip`` holds
         slots whose readback is stale this step (gather path: slots staged
@@ -1413,217 +1362,6 @@ class ContinuousBatcher:
             sum(r is not None for r in self._slot_req))
         return emitted_total
 
-    # ----------------------------------------------------- ragged (ISSUE 8)
-    def _admit_ragged(self):
-        """Pop + allocate + stage admissions for the MIXED burst. No
-        bucketing: pages are reserved for the ACTUAL prompt length and the
-        prompt rides into the burst as a (token row, length) pair — the
-        prefill happens inside the same executable as the decode steps, so
-        a freshly admitted request's first token lands this very burst.
-        A prefix-cache hit (ISSUE 13) maps the shared pages and its row
-        carries ONLY the unshared suffix (prefill_start > 0); a
-        full-prefix hit stages nothing — it joins the burst's decode rows
-        resuming at the last prompt token."""
-        staged = []  # (req, slot, suffix_len, prefill_start)
-        stalled = False
-        while self._queue and None in self._slot_req:
-            req = self._queue[0]
-            tlen = len(req.prompt)
-            if req.kv_import is not None:
-                if not self._reclaim_to(self._kv_need(req)):
-                    stalled = True
-                    break
-                self._queue.popleft()
-                self._kv_acct(req, -1)
-                try:
-                    chaos.hit("serve.admit")
-                except chaos.ChaosError:
-                    self.stats["chaos_retired"] += 1
-                    metrics.counter("serve.chaos_retired").inc()
-                    self._finish(req, reason="chaos serve.admit")
-                    continue
-                self.slo.on_admit(req.rid)
-                slot = self._slot_req.index(None)
-                # transferred pages install now; the slot joins THIS
-                # burst's decode rows (new_lens stays 0 — no prefill)
-                self._admit_kv_import(req, slot)
-                continue
-            shared, matched = self._prefix_match(req)
-            resume = bool(shared) and matched >= tlen
-            need = 0 if resume else pages_for(tlen - matched, self._ps)
-            if not self._reclaim_to(need):
-                if shared:
-                    self._alloc.free(shared)
-                stalled = True  # stays queued; pages free as slots retire
-                break
-            self._queue.popleft()
-            self._kv_acct(req, -1)
-            try:
-                chaos.hit("serve.admit")
-            except chaos.ChaosError:
-                if shared:
-                    self._alloc.free(shared)
-                self.stats["chaos_retired"] += 1
-                metrics.counter("serve.chaos_retired").inc()
-                # partial (empty) output, queue moves on
-                self._finish(req, reason="chaos serve.admit")
-                continue
-            self.slo.on_admit(req.rid)
-            if shared:
-                self._prefix_hit_account(shared, matched)
-            slot = self._slot_req.index(None)
-            if resume:
-                # no prefill row at all: decode resumes at the last
-                # prompt token (growth COWs the shared tail page before
-                # this burst's first write)
-                self._admit_resume(req, slot, shared)
-                continue
-            self._page_tbl[slot] = shared + self._alloc.alloc(need)
-            self._slot_req[slot] = req
-            self._admit_seq[slot] = self._seq = self._seq + 1
-            # host slot state for the burst: the device's prefill phase
-            # re-derives pos/tok/done for staged slots (where(is_new, ...))
-            # — pos=tlen here is the growth loop's and the merge's truth
-            self._pos[slot] = tlen
-            self._tok[slot] = self.pad_id
-            self._done[slot] = False
-            # a prefill_only slot stops at its first token: limit == tlen
-            # makes the in-burst prefill mark it done before any decode
-            # step emits, so the burst's scan adds nothing to its output
-            self._limit[slot] = (tlen if req.prefill_only
-                                 else min(tlen + req.max_new_tokens - 1,
-                                          self.S - 1))
-            self.stats["prefills"] += 1
-            if shared:
-                self.stats["prefix_marginal_pages"] = \
-                    self.stats.get("prefix_marginal_pages", 0) + need
-            else:
-                self._note_admit_prefill(req, tlen)
-            staged.append((req, slot, tlen - matched, matched))
-        if stalled:
-            self.stats["admission_stalls"] += 1
-            metrics.counter("serve.admission_stalls").inc()
-        return staged
-
-    def _dispatch_ragged(self, staged):
-        """ONE async launch covering this burst's admissions (ragged
-        prefill) AND every decoding slot (llama_ragged_burst). The block
-        table is always full width — the kernel reads live pages only, so
-        there is no page bucket and no prompt bucket to compile against.
-        Returns (old_pos, device futures) or None when nothing is active."""
-        from ..models.llama_paged import llama_ragged_burst
-        active = [b for b, r in enumerate(self._slot_req) if r is not None]
-        if not active:
-            return None
-        try:
-            chaos.hit("serve.burst")
-        except chaos.ChaosError:
-            self._retire_all_active("chaos serve.burst")
-            staged.clear()
-            return None
-        active = self._grow_for_burst(active)
-        # growth may have preempted a just-staged slot back to the queue
-        staged[:] = [s for s in staged if self._slot_req[s[1]] is s[0]]
-        if not active:
-            return None
-        metrics.gauge("serve.pages_in_use").set(self._alloc.pages_in_use)
-        # bytes/token follow LIVE context on the ragged path (the ISSUE-8
-        # over-reporting fix)
-        self._set_live_kv_gauge(active)
-
-        P = pages_for(self.S, self._ps)          # full width, always
-        bt = np.full((self.B, P), SCRATCH_PAGE, np.int32)
-        for b in active:
-            ids = self._page_tbl[b]
-            bt[b, :len(ids)] = ids
-        if staged:
-            t_max = self._buckets[-1]            # the ONE static width
-            new_tokens = np.full((self.B, t_max), self.pad_id, np.int32)
-            new_lens = np.zeros(self.B, np.int32)
-            starts = np.zeros(self.B, np.int32)
-            for req, slot, sl, start in staged:
-                # the row carries ONLY the unshared suffix; the shared
-                # prefix (prefill_start tokens) is already in the pool
-                new_tokens[slot, :sl] = req.prompt[start:]
-                new_lens[slot] = sl
-                starts[slot] = start
-            new_tokens, new_lens, starts = jnp.asarray(new_tokens), \
-                jnp.asarray(new_lens), jnp.asarray(starts)
-        else:
-            new_tokens, new_lens, starts = self._no_prompts, \
-                self._no_lens, self._no_lens
-
-        old_pos = self._pos.copy()
-        self._key, sub = jax.random.split(self._key)
-        (self._cache, pos_d, tok_d, done_d, emitted_d, firsts_d) = \
-            llama_ragged_burst(
-                self._params, self._cache, jnp.asarray(bt),
-                jnp.asarray(self._pos), jnp.asarray(self._tok),
-                jnp.asarray(self._done), jnp.asarray(self._limit),
-                new_tokens, new_lens, starts,
-                jnp.int32(self.eos_id), sub, config=self._cfg,
-                n=self.burst, has_prefill=bool(staged),
-                temperature=self._temp, top_k=self._top_k,
-                pad_id=self.pad_id, dequant=self._dequant,
-                interpret=self._interpret, mesh=self._mesh,
-                kv_dtype=self._kv_dtype)
-        self.stats["bursts"] += 1
-        self.stats["decode_steps"] += self.burst
-        return old_pos, pos_d, tok_d, done_d, emitted_d, firsts_d
-
-    def _sync_merge_ragged(self, inflight, staged) -> int:
-        """The one blocking point of a ragged step: read back the merged
-        burst (slot state + scan emissions + prefill first tokens), then
-        pure host bookkeeping."""
-        if inflight is None:
-            return 0
-        with _spans.span("serve.readback", cat="serve"):
-            vals = jax.device_get(inflight[1:])
-        with _spans.span("serve.merge", cat="serve"):
-            return self._merge_ragged(inflight[0], staged, *vals)
-
-    def _merge_ragged(self, old_pos, staged, pos, tok, done, emitted,
-                      firsts) -> int:
-        self._pos = np.array(pos)    # device_get views are read-only;
-        self._tok = np.array(tok)    # admissions write these in place
-        self._done = np.array(done)
-        emitted_total = 0
-        for req, slot, *_ in staged:
-            # the prefill token, sampled inside the same burst; the drain
-            # below appends this slot's scan emissions AFTER it
-            req.out.append(int(firsts[slot]))
-            emitted_total += 1
-            self._observe_first(req)
-            # the burst is read back: the prompt pages' content landed in
-            # the pool — NOW they are indexable (an admit-time insert
-            # would let a same-pass hit copy/read unwritten pages)
-            self._prefix_insert(req, slot)
-        emitted_total += self._drain_burst(old_pos, done,
-                                           np.asarray(emitted))
-        metrics.counter("serve.tokens").inc(emitted_total)
-        self.stats["max_concurrent"] = max(
-            self.stats["max_concurrent"],
-            sum(r is not None for r in self._slot_req))
-        return emitted_total
-
-    def _step_ragged(self):
-        """One ragged scheduling iteration: admissions join the SAME
-        launch as the decode steps (prefill-to-first-token inside one
-        executable — lower TTFT than the overlap schedule's next-burst
-        landing), and the single blocking readback follows the dispatch."""
-        t0 = _slo.now()
-        with _spans.span("serve.admit", cat="serve") as sp:
-            staged = self._admit_ragged()
-            sp.args = {"prefills": len(staged)}
-        with _spans.span("serve.dispatch_burst", cat="serve",
-                         kv_read=self._kv_read):
-            inflight = self._dispatch_ragged(staged)
-        emitted = self._sync_merge_ragged(inflight, staged)
-        dt = _slo.now() - t0
-        metrics.histogram("serve.burst_time_s").observe(dt)
-        if emitted and dt > 0:
-            metrics.gauge("serve.tokens_per_s").set(emitted / dt)
-
     # -------------------------------------------------- speculative (14)
     def _spec_applicable(self) -> bool:
         """Speculative steps run when there is decode work and no
@@ -1686,11 +1424,8 @@ class ContinuousBatcher:
             tokens[b, :len(row)] = row
             n_tok[b] = len(row)
             start[b] = self._pos[b]
-        if self._ragged:
-            P = pages_for(self.S, self._ps)      # full width, one program
-        else:
-            width = max(len(self._page_tbl[b]) for b in active)
-            P = next(p for p in self._page_buckets if p >= width)
+        width = max(len(self._page_tbl[b]) for b in active)
+        P = next(p for p in self._page_buckets if p >= width)
         bt = np.full((self.B, P), SCRATCH_PAGE, np.int32)
         for b in active:
             ids = self._page_tbl[b]
@@ -1699,9 +1434,8 @@ class ContinuousBatcher:
         targets_d, self._cache = llama_paged_verify(
             self._params, self._cache, jnp.asarray(bt),
             jnp.asarray(start), jnp.asarray(tokens), jnp.asarray(n_tok),
-            config=self._cfg, ragged=self._ragged,
-            interpret=self._interpret, mesh=self._mesh,
-            dequant=self._dequant, kv_dtype=self._kv_dtype)
+            config=self._cfg, dequant=self._dequant,
+            kv_dtype=self._kv_dtype)
         targets = np.asarray(jax.device_get(targets_d))
         self.stats["bursts"] += 1
         self.stats["spec_steps"] = self.stats.get("spec_steps", 0) + 1
@@ -1797,8 +1531,6 @@ class ContinuousBatcher:
                     self.shed_newest(len(self._queue) - cap)
             if self._spec_applicable() and self._try_step_spec():
                 pass                      # spec step served this iteration
-            elif self._ragged:
-                self._step_ragged()
             elif self._layout == "paged":
                 t0 = _slo.now()     # the request-timing clock (lint O4)
                 state_live = self._state_slot_bytes * (
@@ -2147,7 +1879,6 @@ class ContinuousBatcher:
         return {
             "layout": self._layout,
             "kv_dtype": self._kv_dtype or "native",
-            "ragged": self._ragged,
             "sharded_devices": (self._mesh.size if self._mesh is not None
                                 else 1),
             "queue_depth": len(self._queue),

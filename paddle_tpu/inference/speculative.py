@@ -1,12 +1,9 @@
 """Speculative decoding on the paged serving engine (ISSUE 14).
 
 Decode throughput is bounded by one target-model launch per token per
-slot. The ragged paged-attention path already executes short
-prefill-carrying rows mixed with decode rows in one executable — which is
-exactly the shape of a speculative VERIFICATION pass — so the trade this
-module makes is: a small DRAFT model proposes up to ``k`` greedy tokens
-per live slot (k cheap launches of a model a fraction of the target's
-size), then the target verifies all of them in ONE launch
+slot. The trade this module makes is: a small DRAFT model proposes up to
+``k`` greedy tokens per live slot (k cheap launches of a model a fraction
+of the target's size), then the target verifies all of them in ONE launch
 (``models.llama_paged.llama_paged_verify``: each slot's row carries
 [current_tok, d_1..d_k] as a q_len = k+1 segment at prefill_start = pos
 and returns per-position greedy targets). Accept-prefix semantics keep

@@ -39,18 +39,12 @@ model-parallel chips unchanged; the block table is replicated host
 metadata (``parallel/sharding.py:shard_kv_pool`` applies it; the serving
 engine reads ``PADDLE_SERVE_MESH_MODEL``).
 
-Ragged kernel (ISSUE 8): ``llama_ragged_burst`` below replaces the
-``jnp.take`` gather with the Pallas ragged kernel
-(``ops/ragged_attention.py``) and folds ragged-length prompt prefill into
-the SAME executable as the decode scan — the bucket grid (and its
-executable inventory) disappears; bytes/token follow live context.
-
-Which read a decode step takes (ISSUE 28): ONE step function,
-``_paged_decode_step_slots``, serves both bursts; its ``kv_read`` scope is
-either the kernel (``_ragged_attn``: the decode-shaped body, live pages
-only) or the XLA gather + masked attention over the page bucket.
-``llama_ragged_burst`` always takes the kernel. ``llama_paged_decode_burst``
-takes what ``paged_kv_read`` says for the pool it is handed — the kernel
+Which read a decode step takes (ISSUE 28): the ``kv_read`` scope of
+``_paged_decode_step_slots`` is either the kernel
+(``ops/ragged_attention.paged_decode_attention``: live pages only) or the
+XLA gather + masked attention over the page bucket.
+``llama_paged_decode_burst`` takes what ``paged_kv_read`` says for the pool
+it is handed — the kernel
 for an unquantized pool with ``head_dim % 128 == 0`` on one device, the
 gather for everything else (int8/fp8 pages, head_dim 64, the tiny
 configurations of tier-1, a GSPMD-sharded pool) — the same choice on every
@@ -72,14 +66,14 @@ import jax.numpy as jnp
 from .linear_mixer import mixer_prefill, mixer_step
 from .llama import LlamaConfig, _rmsnorm, _rope, attn_qkv, block_in, \
     block_out, layer_params_at, lm_head_logits, split_layer_params
-from ..ops.ragged_attention import decode_supported, paged_kv_scatter, \
-    ragged_paged_attention, scatter_supported
+from ..ops.ragged_attention import decode_supported, \
+    paged_decode_attention, paged_kv_scatter, scatter_supported
 from .llama_decode import _cached_attention_slots, _mlp, _qkv, _sample
 
 __all__ = ["init_paged_kv_cache", "paged_kv_read", "pool_kv_heads",
            "llama_paged_prefill_slot",
            "llama_paged_prefill_suffix", "llama_paged_decode_burst",
-           "llama_ragged_burst", "llama_paged_verify",
+           "llama_paged_verify",
            "paged_kv_bytes_per_token", "page_bytes",
            "gather_pages", "scatter_pages", "copy_pages"]
 
@@ -89,11 +83,10 @@ __all__ = ["init_paged_kv_cache", "paged_kv_read", "pool_kv_heads",
 # codecs: the payload pools keep the [num_pages, page_size, KV, hd] layout
 # in the wire dtype and a per-(row, kv-head) float32 scale rides in
 # parallel [num_pages, page_size, KV] pools (block = the head_dim vector).
-# Writes quantize (prefill rows and per-step decode rows alike); BOTH read
-# paths dequantize — the XLA gather right after its jnp.take, the Pallas
-# ragged kernel per streamed page inside its double-buffered DMA loop
-# (ops/ragged_attention.py). kv_dtype=None is byte-for-byte the pre-quant
-# code: no scale pools exist and no branch below runs.
+# Writes quantize (prefill rows and per-step decode rows alike); the reads
+# dequantize right after their jnp.take (a quantized pool never takes the
+# decode kernel: paged_kv_read). kv_dtype=None is byte-for-byte the
+# pre-quant code: no scale pools exist and no branch below runs.
 
 
 def _kv_encode(rows, kv_dtype: str):
@@ -281,11 +274,9 @@ def paged_kv_bytes_per_token(config: LlamaConfig, pages: int,
     active context) × page_size rows — pass the bucket width (dense reads
     the same expression with pages*page_size == max_len, always).
 
-    Ragged kernel path: the per-page DMA loop stops at the slot's LIVE
-    pages, so bytes follow the live context, not the bucket — pass
-    ``live_tokens`` and `pages` is ignored in favor of
-    ``ceil(live_tokens / page_size)`` (the ISSUE-8 over-reporting fix:
-    decode_bench must not bill the ragged path at bucket width).
+    Kernel read: the per-page copies stop at the slot's LIVE pages, so
+    bytes follow the live context, not the bucket — pass ``live_tokens``
+    and `pages` is ignored in favor of ``ceil(live_tokens / page_size)``.
 
     ``kv_dtype`` (ISSUE 10): quantized pages bill wire-dtype payload plus
     the per-(row, head) scale reads — roughly half the bf16 bill."""
@@ -345,10 +336,10 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
     layer: the same rows, 1 operation for 96 at 48 slots.
 
     ``kv_read`` (static; None = what ``paged_kv_read`` says for this
-    pool): "kernel" reads through ``_ragged_attn`` — each slot's
-    ceil((pos+1)/page_size) live pages, chunk by chunk (quantized pools:
-    the kernel dequantizes each streamed page; ``interpret`` None = off
-    the TPU; ``mesh``: shard_map over the pool's KV heads). "gather"
+    pool): "kernel" reads through ``paged_decode_attention`` — each slot's
+    ceil((pos+1)/page_size) live pages, chunk by chunk (``interpret`` None
+    = off the TPU; never a quantized or sharded pool: ``paged_kv_read``
+    says "gather" for both). "gather"
     gathers the [P*page_size] rows of the whole bucket and attends them
     under the same ``row <= pos`` mask as the dense path, dequantizing
     payload×scale right after the takes. The takes clip: the table is in
@@ -435,10 +426,9 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
             kss[l], vss[l] = ksp, vsp
         with jax.named_scope("kv_read"):
             if kv_read == "kernel":
-                att = _ragged_attn(_pad_heads(q, q_heads), kp, vp,
-                                   block_table, one, pos32 + 1,
-                                   page_size=ps, interpret=interpret,
-                                   mesh=mesh, ksc=ksp, vsc=vsp)
+                att = paged_decode_attention(
+                    _pad_heads(q, q_heads), kp, vp, block_table, one,
+                    pos32 + 1, interpret=interpret)
                 att = att[:, :, :c.num_attention_heads]
             else:
                 # gather the slot's pages into a [B, P*ps, KV, hd] view:
@@ -795,217 +785,6 @@ def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
     return cache, pos, tok, done, emitted
 
 
-# ------------------------------------------------------------------ ragged
-# ISSUE 8 tentpole: the same paged pool read through the Pallas ragged
-# kernel (ops/ragged_attention.py) instead of the XLA block-table gather.
-# Raggedness moves from SHAPES (page buckets, prompt buckets — one
-# executable each) into scalar-prefetched lengths, so ONE executable per
-# {prefill-carrying, decode-only} covers every request mix.
-
-
-def _ragged_attn(q, kp, vp, block_table, q_lens, kv_lens, *, page_size,
-                 interpret, mesh, ksc=None, vsc=None):
-    """Dispatch the ragged kernel, shard_map'd over the "model" axis when
-    the pool is GSPMD-sharded along KV heads: kernel programs are
-    independent per (slot, kv-head), so each shard runs the SAME kernel
-    over its local heads — no collective, no re-gather of the pool.
-    ``ksc``/``vsc`` (ISSUE 10): quantized pools' per-(page, row, head)
-    scale pools, sharded along the SAME head axis — each chip streams only
-    its own heads' scales next to its own heads' pages."""
-    if mesh is None:
-        return ragged_paged_attention(q, kp, vp, block_table, q_lens,
-                                      kv_lens, page_size=page_size,
-                                      interpret=interpret,
-                                      k_scale=ksc, v_scale=vsc)
-    from jax.sharding import PartitionSpec as P
-
-    from ..utils.jax_compat import shard_map
-
-    axis = mesh.axis_names[0]
-    heads = P(None, None, axis, None)
-    scales = P(None, None, axis)
-    if ksc is None:
-        def local(q_, kp_, vp_, bt_, ql_, kl_):
-            return ragged_paged_attention(q_, kp_, vp_, bt_, ql_, kl_,
-                                          page_size=page_size,
-                                          interpret=interpret)
-
-        return shard_map(
-            local, mesh,
-            in_specs=(heads, heads, heads, P(None, None), P(None), P(None)),
-            out_specs=heads)(q, kp, vp, block_table, q_lens, kv_lens)
-
-    def local_q(q_, kp_, vp_, ks_, vs_, bt_, ql_, kl_):
-        return ragged_paged_attention(q_, kp_, vp_, bt_, ql_, kl_,
-                                      page_size=page_size,
-                                      interpret=interpret,
-                                      k_scale=ks_, v_scale=vs_)
-
-    return shard_map(
-        local_q, mesh,
-        in_specs=(heads, heads, heads, scales, scales, P(None, None),
-                  P(None), P(None)),
-        out_specs=heads)(q, kp, vp, ksc, vsc, block_table, q_lens, kv_lens)
-
-
-def _ragged_prefill_phase(params, cache, block_table, new_tokens, new_lens,
-                          prefill_start,
-                          config: LlamaConfig, interpret: bool, mesh=None,
-                          kv_dtype: str | None = None):
-    """Ragged prompt forward for EVERY newly admitted slot at once.
-
-    new_tokens [B, Tmax] (Tmax = the engine's widest prompt bucket, the
-    ONE static width), new_lens [B] (0 = slot not prefilling — its lanes
-    are dead compute, not corruption). ``prefill_start`` [B] (ISSUE 13,
-    prefix sharing): the absolute position the slot's prompt ROW begins
-    at — 0 for an ordinary admission, a page-aligned shared-prefix length
-    for a prefix-cache hit, whose row then carries ONLY the unshared
-    suffix. Per layer: K/V rows land in the slot's pages starting at
-    logical page ``prefill_start // page_size`` (non-prefilling slots'
-    writes are redirected to the scratch page so a decoding neighbour's
-    context is never touched), then the ragged kernel reads them back
-    causally (q_len = new_lens, kv_len = prefill_start + new_lens — the
-    kernel's decode-style offset mask covers suffix rows attending the
-    shared prefix) — the same paged read path decode uses, per the RPA
-    paper. Returns (last-position logits [B, V], cache)."""
-    from ..inference.paging import SCRATCH_PAGE
-
-    c = config
-    _one_kind_pool(cache, c, "the ragged layout's mixed burst")
-    layer_p, other = split_layer_params(params)
-    B, Tmax = new_tokens.shape
-    ps = int(cache["k"][0].shape[1])
-    t_pages = (Tmax - 1) // ps + 1
-    pad = t_pages * ps - Tmax
-    P = block_table.shape[1]
-    is_new = new_lens > 0
-    start32 = prefill_start.astype(jnp.int32)
-    off_pages = start32 // jnp.int32(ps)
-    # prefill slots write through their block table at a page offset of
-    # their shared prefix; everyone else (rows past the slot's allocation
-    # — already SCRATCH in the table — and column overhangs past the
-    # table's width) to scratch
-    idx = off_pages[:, None] + jnp.arange(t_pages, dtype=jnp.int32)[None, :]
-    gathered = jnp.take_along_axis(block_table,
-                                   jnp.minimum(idx, jnp.int32(P - 1)),
-                                   axis=1)
-    wt = jnp.where(is_new[:, None] & (idx < jnp.int32(P)), gathered,
-                   jnp.int32(SCRATCH_PAGE))
-    x = jnp.take(other["embed_tokens"], new_tokens, axis=0).astype(c.dtype)
-    positions = start32[:, None] + jnp.broadcast_to(
-        jnp.arange(Tmax, dtype=jnp.int32)[None, :], (B, Tmax))
-    z = jnp.int32(0)
-    lens32 = new_lens.astype(jnp.int32)
-
-    quant = kv_dtype is not None
-    ks, vs = list(cache["k"]), list(cache["v"])
-    kss = list(cache["k_scale"]) if quant else None
-    vss = list(cache["v_scale"]) if quant else None
-    for l in range(c.num_hidden_layers):
-        lp = jax.tree.map(lambda a: a[l], layer_p)
-        h = _rmsnorm(x, lp["ln1"], c.rms_norm_eps)
-        q, k, v = _qkv(h, lp, c)
-        q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
-        kp, vp = ks[l], vs[l]
-        krows = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vrows = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        if quant:
-            krows, ksrows = _kv_encode(krows, kv_dtype)  # + [B, T+pad, KV]
-            vrows, vsrows = _kv_encode(vrows, kv_dtype)
-            ksp, vsp = kss[l], vss[l]
-        for b in range(B):
-            for j in range(t_pages):
-                at = (wt[b, j], z, z, z)
-                kp = jax.lax.dynamic_update_slice(
-                    kp, krows[b, j * ps:(j + 1) * ps][None], at)
-                vp = jax.lax.dynamic_update_slice(
-                    vp, vrows[b, j * ps:(j + 1) * ps][None], at)
-                if quant:
-                    ats = (wt[b, j], z, z)
-                    ksp = jax.lax.dynamic_update_slice(
-                        ksp, ksrows[b, j * ps:(j + 1) * ps][None], ats)
-                    vsp = jax.lax.dynamic_update_slice(
-                        vsp, vsrows[b, j * ps:(j + 1) * ps][None], ats)
-        ks[l], vs[l] = kp, vp
-        if quant:
-            kss[l], vss[l] = ksp, vsp
-        att = _ragged_attn(q, kp, vp, block_table, lens32, start32 + lens32,
-                           page_size=ps, interpret=interpret, mesh=mesh,
-                           ksc=ksp if quant else None,
-                           vsc=vsp if quant else None)
-        y = x + (att.reshape(B, Tmax, -1) @ lp["wo"])
-        x = _mlp(y, lp, c)
-
-    last = x[jnp.arange(B), jnp.maximum(lens32 - 1, 0)]       # [B, D]
-    cache = {"k": tuple(ks), "v": tuple(vs)}
-    if quant:
-        cache["k_scale"], cache["v_scale"] = tuple(kss), tuple(vss)
-    return lm_head_logits(last, other, c), cache
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "config", "n", "has_prefill", "temperature", "top_k", "pad_id",
-    "dequant", "interpret", "mesh", "kv_dtype"), donate_argnums=(1,))
-def llama_ragged_burst(params, cache, block_table, pos, tok, done, limit,
-                       new_tokens, new_lens, prefill_start, eos_id, key,
-                       config: LlamaConfig, n: int, has_prefill: bool,
-                       temperature: float = 0.0, top_k: int = 0,
-                       pad_id: int = 0, dequant=None, *, interpret: bool,
-                       mesh=None, kv_dtype: str | None = None):
-    """ONE executable for a mixed prefill+decode burst (ISSUE 8).
-
-    Same contract as llama_paged_decode_burst plus the admission inputs:
-    slots with ``new_lens[b] > 0`` first prefill their prompt (ragged —
-    any length ≤ Tmax in the same launch), sample their first token and
-    join the n decode steps alongside the already-decoding slots.
-    ``prefill_start`` [B] (ISSUE 13): a prefix-cache hit maps its shared
-    pages into the block table and its prompt row carries ONLY the
-    unshared suffix — the prefill phase writes/attends at the offset, so
-    a shared system prompt pays no prefill FLOPs here. The block table is
-    always FULL WIDTH (slot_max_pages): the ragged kernel reads only live
-    pages, so no page bucketing and no prompt bucketing — the executable
-    inventory is exactly {prefill-carrying, decode-only}, O(1) in the
-    request mix (pinned by tests/test_ragged_attention.py).
-
-    Returns (cache, pos, tok, done, emitted [n, B], firsts [B]) — firsts
-    holds each newly admitted slot's prefill token (pad_id elsewhere);
-    scan emissions for those slots start AFTER it.
-    """
-    p = dequant(params) if dequant is not None else params
-    B = tok.shape[0]
-    firsts = jnp.full((B,), jnp.int32(pad_id))
-    if has_prefill:
-        key, sub = jax.random.split(key)
-        logits, cache = _ragged_prefill_phase(
-            p, cache, block_table, new_tokens, new_lens, prefill_start,
-            config, interpret, mesh, kv_dtype=kv_dtype)
-        first = _sample(logits, temperature, top_k, sub)
-        is_new = new_lens > 0
-        firsts = jnp.where(is_new, first, firsts)
-        tok = jnp.where(is_new, first, tok)
-        pos = jnp.where(is_new,
-                        (prefill_start + new_lens).astype(pos.dtype), pos)
-        done = jnp.where(is_new, (first == eos_id) | (pos >= limit), done)
-
-    def step(carry, _):
-        cache, pos, tok, done, key = carry
-        pp = dequant(params) if dequant is not None else params
-        logits, cache = _paged_decode_step_slots(
-            pp, cache, block_table, pos, tok, config, kv_dtype=kv_dtype,
-            kv_read="kernel", interpret=interpret, mesh=mesh)
-        key, sub = jax.random.split(key)
-        nxt = _sample(logits, temperature, top_k, sub)
-        emit = jnp.where(done, jnp.int32(pad_id), nxt)
-        new_pos = jnp.where(done, pos, pos + 1)
-        new_tok = jnp.where(done, tok, nxt)
-        new_done = done | (nxt == eos_id) | (new_pos >= limit)
-        return (cache, new_pos, new_tok, new_done, key), emit
-
-    (cache, pos, tok, done, _), emitted = jax.lax.scan(
-        step, (cache, pos, tok, done, key), None, length=n)
-    return cache, pos, tok, done, emitted, firsts
-
-
 # ------------------------------------------------------- verify (ISSUE 14)
 # Speculative decoding's target half: each verifying slot's row carries
 # [current_tok, d_1 .. d_np] — its np draft proposals behind the token the
@@ -1023,13 +802,11 @@ def llama_ragged_burst(params, cache, block_table, pos, tok, done, limit,
 
 
 def _verify_attention(q, kc, vc, start, config: LlamaConfig):
-    """Verify-segment attention for the GATHER read path: q [B, Tv, H, hd]
-    queries at absolute positions ``start[b] + j`` over the block-table-
-    gathered rows kc/vc [B, R, KV, hd] (R = page_bucket × page_size, row
-    r = logical position r). Query j attends rows ≤ start + j — the
-    decode-style offset mask the ragged kernel computes from (q_len,
-    kv_len). Same arithmetic family as ``_cached_attention_slots``
-    (grouped einsum, f32 logits, -1e30 mask, softmax rounded to q.dtype)
+    """Verify-segment attention: q [B, Tv, H, hd] queries at absolute
+    positions ``start[b] + j`` over the block-table-gathered rows kc/vc
+    [B, R, KV, hd] (R = page_bucket × page_size, row r = logical position
+    r). Query j attends rows ≤ start + j. Same arithmetic family as
+    ``_cached_attention_slots`` (grouped einsum, f32 logits, -1e30 mask, softmax rounded to q.dtype)
     so greedy targets match the plain decode step's token for token."""
     c = config
     H, KV = c.num_attention_heads, c.num_key_value_heads
@@ -1051,11 +828,9 @@ def _verify_attention(q, kc, vc, start, config: LlamaConfig):
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "config", "ragged", "interpret", "mesh", "dequant", "kv_dtype"),
-    donate_argnums=(1,))
+    "config", "dequant", "kv_dtype"), donate_argnums=(1,))
 def llama_paged_verify(params, cache, block_table, start, tokens, n_tok,
-                       config: LlamaConfig, ragged: bool = False, *,
-                       interpret: bool, mesh=None, dequant=None,
+                       config: LlamaConfig, *, dequant=None,
                        kv_dtype: str | None = None):
     """ONE launch verifying every slot's speculative segment (ISSUE 14).
 
@@ -1065,11 +840,10 @@ def llama_paged_verify(params, cache, block_table, start, tokens, n_tok,
     the host ignores); start [B] = the slot's pos (row j lands at
     absolute position start+j, NOT page-aligned — writes are per-row).
     K/V rows are written through the block table exactly like a decode
-    step would write them one launch at a time, then read back with the
-    slot's own read path: the Pallas ragged kernel (``ragged=True``,
-    q_len = n_tok, kv_len = start + n_tok) or the XLA gather +
-    ``_verify_attention``. Rows past the accepted prefix become stale
-    pool garbage the validity masks hide — rewind is free (the host just
+    step would write them one launch at a time, then read back through
+    the XLA gather + ``_verify_attention`` (whichever read the engine's
+    decode steps take: the decode kernel reads one row a slot). Rows past
+    the accepted prefix become stale pool garbage the validity masks hide — rewind is free (the host just
     resets pos and frees trailing pages; shared pages were COW'd by the
     growth sweep BEFORE these writes could touch them).
 
@@ -1129,23 +903,14 @@ def llama_paged_verify(params, cache, block_table, start, tokens, n_tok,
         ks[l], vs[l] = kp, vp
         if quant:
             kss[l], vss[l] = ksp, vsp
-        if ragged:
-            att = _ragged_attn(q, kp, vp, block_table, lens32,
-                               start32 + lens32, page_size=ps,
-                               interpret=interpret, mesh=mesh,
-                               ksc=ksp if quant else None,
-                               vsc=vsp if quant else None)
-        else:
-            kc = jnp.take(kp, block_table, axis=0)
-            vc = jnp.take(vp, block_table, axis=0)
-            if quant:
-                kc = _kv_decode(kc, jnp.take(ksp, block_table, axis=0),
-                                c.dtype)
-                vc = _kv_decode(vc, jnp.take(vsp, block_table, axis=0),
-                                c.dtype)
-            kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-            vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
-            att = _verify_attention(q, kc, vc, start32, c)
+        kc = jnp.take(kp, block_table, axis=0)
+        vc = jnp.take(vp, block_table, axis=0)
+        if quant:
+            kc = _kv_decode(kc, jnp.take(ksp, block_table, axis=0), c.dtype)
+            vc = _kv_decode(vc, jnp.take(vsp, block_table, axis=0), c.dtype)
+        kc = kc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
+        vc = vc.reshape(B, -1, c.num_key_value_heads, c.head_dim)
+        att = _verify_attention(q, kc, vc, start32, c)
         y = x + (att.reshape(B, Tv, -1) @ lp["wo"])
         x = _mlp(y, lp, c)
 

@@ -25,10 +25,9 @@ ENV_SERVE_MESH = "PADDLE_SERVE_MESH_MODEL"
 # its third axis ([num_pages, page_size, KV, hd]), so one NamedSharding
 # spreads a serving replica's cache across a pod slice with NO layout
 # change — each chip holds every page's slice of ITS heads, the block
-# table stays replicated host metadata, and both the XLA gather path
-# (GSPMD partitions the take+einsum automatically) and the Pallas ragged
-# kernel (shard_map'd per shard — programs are independent per
-# (slot, kv-head)) read only local bytes.
+# table stays replicated host metadata, and the XLA gather (which a
+# sharded pool always reads through: llama_paged.paged_kv_read) is
+# partitioned by GSPMD automatically, so every chip reads only local bytes.
 
 
 def kv_pool_pspec(axis: str = "model"):

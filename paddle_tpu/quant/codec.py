@@ -79,7 +79,7 @@ def normalize_kv_dtype(raw) -> str | None:
 # (page, head) — ~page_size× fewer scale bytes on the wire, paid for with
 # a requantization pass whose accuracy cost is measured and pinned in
 # tests/test_disagg_serving.py. The POOL layout never changes — this is a
-# wire format, so both read paths and the ragged kernel are untouched.
+# wire format, so the read paths are untouched.
 SCALE_GRANS = ("row", "page")
 
 

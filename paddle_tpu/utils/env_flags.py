@@ -289,11 +289,6 @@ declare("PADDLE_PREFIX_CACHE_PAGES", "0",
         "page-granular prefix-hash index: shared-prompt admissions map "
         "cached pages copy-on-write and prefill only their suffix; "
         "0 = off, the pre-sharing engine byte-for-byte)")
-declare("PADDLE_RAGGED_ATTN", "1",
-        "'0' asks a kv_layout='ragged' engine for the XLA block-table "
-        "gather instead of the ragged Pallas kernel — token-identical, "
-        "bucket-bound; the one explicit way to the gather (an "
-        "uncompilable pool raises)")
 declare("PADDLE_SERVE_MESH_MODEL", "0",
         "shard the serving KV page pool over this many devices along the "
         "'model' mesh axis (GSPMD; 0/1 = single-chip)")

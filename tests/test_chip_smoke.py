@@ -87,9 +87,8 @@ def test_rehearsal_walks_every_phase_and_never_passes(capsys, argv, phases):
     if "serve" in phases:
         serve = lines[2]
         assert serve["compared_in"] == "float32"    # both passes walked
-        for layout in ("paged", "ragged"):
-            facts = serve["float32"][layout]
-            assert facts["tokens_equal_llama_generate"] == "64/64", layout
+        facts = serve["float32"]["paged"]
+        assert facts["tokens_equal_llama_generate"] == "64/64"
 
 
 class TestServedLogitGap:

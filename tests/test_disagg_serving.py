@@ -78,7 +78,7 @@ def small_model():
 
 
 @pytest.fixture(scope="module")
-def wide_model():
+def hd32_model():
     import jax.numpy as jnp
     cfg = LlamaConfig(dtype=jnp.float32, **WIDE_CFG_KW)
     params = llama_init_params(cfg, jax.random.PRNGKey(3))
@@ -105,12 +105,13 @@ def _prompts(n, seed=0, lo=4, hi=20):
             for m in rng.randint(lo, hi, n)]
 
 
-def _handoff(cfg, params, reqs, layout="paged", kv_dtype=None,
-             scale_gran=None, **kw):
+def _handoff(cfg, params, reqs, kv_dtype=None, scale_gran=None,
+             kv_read="gather", **kw):
     """prefill_only on engine A → export → kv_import on engine B →
     decoded outputs, in request order."""
-    pre = _engine(cfg, params, kv_layout=layout, kv_dtype=kv_dtype, **kw)
-    dec = _engine(cfg, params, kv_layout=layout, kv_dtype=kv_dtype, **kw)
+    pre = _engine(cfg, params, kv_dtype=kv_dtype, **kw)
+    dec = _engine(cfg, params, kv_dtype=kv_dtype, **kw)
+    assert pre.stats["kv_read"] == dec.stats["kv_read"] == kv_read
     rids = [pre.add_request(p, max_new_tokens=m, prefill_only=True)
             for p, m in reqs]
     pre.run()
@@ -287,11 +288,11 @@ class TestBinaryFrame:
 # ------------------------------------------------------------ wire format
 
 class TestTransferWire:
-    def test_quantized_wire_ratio_both_grans(self, wide_model):
+    def test_quantized_wire_ratio_both_grans(self, hd32_model):
         """Acceptance: the quantized page transfer ships ≤ 0.30× the f32
         byte count for the same live tokens, at BOTH scale
         granularities (payload itemsize + scale overhead)."""
-        cfg, _ = wide_model
+        cfg, _ = hd32_model
         for dt in ("int8", "fp8"):
             for gran in ("row", "page"):
                 r = wire_ratio_vs_f32(cfg, 8, dt, gran)
@@ -300,8 +301,8 @@ class TestTransferWire:
         assert wire_ratio_vs_f32(cfg, 8, "fp8", "page") \
             < wire_ratio_vs_f32(cfg, 8, "fp8", "row")
 
-    def test_page_gran_scale_bytes_page_size_x_fewer(self, wide_model):
-        cfg, _ = wide_model
+    def test_page_gran_scale_bytes_page_size_x_fewer(self, hd32_model):
+        cfg, _ = hd32_model
         row = wire_breakdown(cfg, 4, 8, "fp8", "row")
         page = wire_breakdown(cfg, 4, 8, "fp8", "page")
         assert row["scale_bytes"] == 8 * page["scale_bytes"]  # page_size×
@@ -359,9 +360,9 @@ class TestTransferWire:
                     np.asarray(g).view(np.uint8),
                     np.asarray(w).view(np.uint8))
 
-    def test_geometry_mismatch_refused(self, small_model, wide_model):
+    def test_geometry_mismatch_refused(self, small_model, hd32_model):
         cfg, params = small_model
-        wcfg, _ = wide_model
+        wcfg, _ = hd32_model
         eng = _engine(cfg, params)
         rid = eng.add_request([5, 6, 7, 8], max_new_tokens=4,
                               prefill_only=True)
@@ -375,24 +376,29 @@ class TestTransferWire:
 # --------------------------------------------------------- engine handoff
 
 class TestBatcherHandoff:
-    @pytest.mark.parametrize("layout,kv_dtype", [
-        ("paged", None), ("ragged", None), ("paged", "int8")])
-    def test_handoff_token_identical(self, small_model, layout, kv_dtype):
+    @pytest.mark.parametrize("read,kv_dtype", [
+        ("paged", None), ("kernel", None), ("paged", "int8")])
+    def test_handoff_token_identical(self, small_model, wide_model, read,
+                                     kv_dtype):
         """The disagg core invariant: prefill on engine A + decode on
-        engine B from transferred pages == llama_generate, on the gather
-        AND ragged read paths, full-precision AND quantized pools
-        (bit-exact row-granular wire)."""
-        cfg, params = small_model
+        engine B from transferred pages == llama_generate, where the
+        decode steps read through the gather AND through the kernel
+        (conftest.wide_model: the pages a prefill's paged_kv_scatter
+        wrote, installed into another pool), full-precision AND quantized
+        pools (bit-exact row-granular wire)."""
+        cfg, params = wide_model if read == "kernel" else small_model
         reqs = list(zip(_prompts(4, seed=1), (6, 9, 5, 12)))
-        outs, blobs = _handoff(cfg, params, reqs, layout=layout,
-                               kv_dtype=kv_dtype)
+        outs, blobs = _handoff(
+            cfg, params, reqs, kv_dtype=kv_dtype,
+            kv_read="kernel" if read == "kernel" else "gather")
         for out, (p, m) in zip(outs, reqs):
             assert out == _reference(cfg, params, p, m)
         assert all(b["kv_dtype"] == kv_dtype for b in blobs.values())
 
-    def test_prefilled_reason_and_parking(self, small_model):
-        cfg, params = small_model
+    def test_prefilled_reason_and_parking(self, served):
+        cfg, params, read = served
         eng = _engine(cfg, params)
+        assert eng.stats["kv_read"] == read
         p = _prompts(1, seed=2)[0]
         rid = eng.add_request(p, max_new_tokens=8, prefill_only=True)
         out = eng.run()
@@ -405,11 +411,12 @@ class TestBatcherHandoff:
         with pytest.raises(KeyError):
             eng.export_kv(rid)                      # one exit per park
 
-    def test_prefill_only_no_decode_needed_completes(self, small_model):
+    def test_prefill_only_no_decode_needed_completes(self, served):
         """mnt == 1: the prefill token IS the whole request — reason
         "complete", nothing parks (the router skips the decode stage)."""
-        cfg, params = small_model
+        cfg, params, read = served
         eng = _engine(cfg, params)
+        assert eng.stats["kv_read"] == read
         rid = eng.add_request(_prompts(1, seed=3)[0], max_new_tokens=1,
                               prefill_only=True)
         out = eng.run()
@@ -445,13 +452,13 @@ class TestBatcherHandoff:
         with pytest.raises(ValueError, match="prompt"):
             eng.add_request([1, 2, 3], max_new_tokens=4, kv_import=blob)
 
-    def test_page_gran_cost_measured_and_pinned(self, wide_model):
+    def test_page_gran_cost_measured_and_pinned(self, hd32_model):
         """The ISSUE 11 satellite's accuracy pin: the page-granular wire
         re-quantizes (row scales → page blocks → row scales), so its
         decode may diverge from the bit-exact row wire — measured here
         and bounded. Row-granular transfer is the exact baseline: its
         outputs equal a never-transferred quantized serve."""
-        cfg, params = wide_model
+        cfg, params = hd32_model
         reqs = list(zip(_prompts(4, seed=6, lo=5, hi=16), (8, 8, 8, 8)))
         row_out, _ = _handoff(cfg, params, reqs, kv_dtype="fp8",
                               scale_gran="row")

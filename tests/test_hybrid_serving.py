@@ -164,7 +164,7 @@ def test_state_is_reported_on_the_span_and_the_gauge(params):
 
 REFUSED = [
     (dict(prefix_cache_pages=4), "a shared page holds K/V rows"),
-    (dict(kv_layout="ragged"), "only the default kv_layout='paged'"),
+    (dict(kv_layout="ragged"), "unknown kv_layout 'ragged'"),
     (dict(kv_layout="dense"), "only the default kv_layout='paged'"),
     (dict(kv_dtype="int8"), "quantized K/V pages beside"),
     (dict(spec_decode=True), "cannot be rewound out of a recurrent state"),
@@ -303,8 +303,7 @@ def test_a_pool_of_12_kv_heads_is_padded_where_the_kernel_reads():
         assert list(got[rid]) == [int(t) for t in want]
     with pytest.raises(ValueError, match="pads KV heads 12 -> 16"):
         eng.add_request([1, 2, 3], max_new_tokens=4, prefill_only=True)
-    for refused in (dict(prefix_cache_pages=8), dict(spec_decode=True),
-                    dict(kv_layout="ragged")):
+    for refused in (dict(prefix_cache_pages=8), dict(spec_decode=True)):
         with pytest.raises(ValueError, match="pads KV heads 12 -> 16"):
             ContinuousBatcher(cfg, p, **refused, **kw)
     assert ContinuousBatcher(cfg, p, kv_dtype="int8",

@@ -8,8 +8,9 @@ The contracts under test:
     chained blake2b hashes, verifies tokens on match, LRU-evicts idle
     entries under its capacity, and reclaims them on allocator pressure.
   * PARITY — a prefix-shared serve is temp=0 token-identical to an
-    unshared serve AND ``llama_generate`` on BOTH read paths (gather and
-    ragged), through suffix-only prefill, full-prefix decode-resume,
+    unshared serve AND ``llama_generate`` on BOTH reads of the default
+    layout (the gather, and the decode kernel on ``conftest.wide_model``),
+    through suffix-only prefill, full-prefix decode-resume,
     COW-triggering writes, and mid-flight preemption of a sharing slot.
   * CAPACITY — a common system prompt admits ≥2× the concurrent
     requests at equal ``pool_hbm_bytes`` vs ``PADDLE_PREFIX_CACHE_PAGES=0``,
@@ -221,16 +222,15 @@ class TestPrefixCacheUnit:
 
 # ------------------------------------------------------------------- parity
 class TestPrefixParity:
-    @pytest.mark.parametrize("layout", ["paged", "ragged"])
-    def test_shared_matches_unshared_and_generate(self, small_model, layout):
+    def test_shared_matches_unshared_and_generate(self, served):
         """The acceptance pin: shared-prompt traffic (suffix hits AND a
         full-prefix resume) is token-identical to an unshared serve and
         to llama_generate, staggered admissions included."""
-        cfg, params = small_model
+        cfg, params, read = served
         _, reqs = _sys_reqs(cfg)
-        base = _serve(_engine(cfg, params, kv_layout=layout), reqs,
-                      stagger=True)
-        eng = _engine(cfg, params, kv_layout=layout, prefix_cache_pages=64)
+        base = _serve(_engine(cfg, params), reqs, stagger=True)
+        eng = _engine(cfg, params, prefix_cache_pages=64)
+        assert eng.stats["kv_read"] == read
         shared = _serve(eng, reqs, stagger=True)
         assert shared == base
         assert eng.stats["prefix_hits"] >= 3
@@ -239,20 +239,20 @@ class TestPrefixParity:
         for out, (p, m) in zip(shared, reqs):
             assert out == _reference_generate(cfg, params, p, m)
 
-    @pytest.mark.parametrize("layout", ["paged", "ragged"])
-    def test_preemption_of_sharing_slot_is_exact(self, small_model, layout):
+    def test_preemption_of_sharing_slot_is_exact(self, served):
         """Pool runs dry mid-flight while slots share a prefix: the
         youngest sharing slot preempts back to the queue, re-matches on
         re-admit, and its regenerated output is exact."""
-        cfg, params = small_model
+        cfg, params, read = served
         rng = np.random.RandomState(41)
         sysp = rng.randint(1, cfg.vocab_size, 2 * PS).tolist()
         reqs = [(sysp + rng.randint(1, cfg.vocab_size, 3).tolist(), 26)
                 for _ in range(2)]
         # each grows to ceil((19+26)/8) = 6 pages; 2 shared + 2×4 private
         # at peak > usable 8 → someone preempts
-        eng = _engine(cfg, params, kv_layout=layout, num_pages=9, burst=8,
+        eng = _engine(cfg, params, num_pages=9, burst=8,
                       prefix_cache_pages=64)
+        assert eng.stats["kv_read"] == read
         warm = (sysp + [5], 4)             # populate the index first
         outs = _serve(eng, [warm] + reqs)
         assert eng.stats["preemptions"] >= 1
@@ -260,15 +260,16 @@ class TestPrefixParity:
         for out, (p, m) in zip(outs, [warm] + reqs):
             assert out == _reference_generate(cfg, params, p, m)
 
-    def test_cow_write_leaves_sharers_untouched(self, small_model):
+    def test_cow_write_leaves_sharers_untouched(self, served):
         """Two identical full-page prompts decode concurrently: the
         second resumes on shared pages, COWs its tail page, and BOTH
         streams stay exact — the write never leaks into the shared
         original."""
-        cfg, params = small_model
+        cfg, params, read = served
         rng = np.random.RandomState(43)
         p = rng.randint(1, cfg.vocab_size, 2 * PS).tolist()
         eng = _engine(cfg, params, prefix_cache_pages=64)
+        assert eng.stats["kv_read"] == read
         ref = _reference_generate(cfg, params, p, 10)
         r1 = eng.add_request(p, max_new_tokens=10)
         eng.run()
@@ -281,20 +282,20 @@ class TestPrefixParity:
         fin = {**{r1: ref}, **out}
         assert fin[r2] == ref and fin[r3] == ref
 
-    def test_exact_fit_resume_drops_cache_ref_not_livelock(self,
-                                                           small_model):
+    def test_exact_fit_resume_drops_cache_ref_not_livelock(self, served):
         """A worst-case-sized pool (usable == the request's page bill)
         with a full-prefix resume: the COW copy has NO free page to land
         in and the shared pages' only other holder is the cache itself —
         the zero-copy fallback drops the cache reference (page becomes
         private, entry evicted) instead of preempting the slot forever."""
-        cfg, params = small_model
+        cfg, params, read = served
         rng = np.random.RandomState(67)
         p = rng.randint(1, cfg.vocab_size, 2 * PS).tolist()
         ref = _reference_generate(cfg, params, p, 8)
         # worst = pages_for(16 + 8) = 3 == usable (num_pages 4)
         eng = _engine(cfg, params, num_pages=4, burst=8,
                       prefix_cache_pages=8)
+        assert eng.stats["kv_read"] == read
         r1 = eng.add_request(p, max_new_tokens=8)
         out1 = eng.run()[r1]
         r2 = eng.add_request(p, max_new_tokens=8)
@@ -318,13 +319,14 @@ class TestPrefixParity:
             got = _serve(eng, reqs)
         assert got == base
 
-    def test_ragged_chaos_hash_fault_free(self, small_model):
-        cfg, params = small_model
+    def test_kernel_read_chaos_hash_fault_free(self, wide_model):
+        cfg, params = wide_model
         _, reqs = _sys_reqs(cfg, seed=17)
-        base = _serve(_engine(cfg, params, kv_layout="ragged"), reqs)
+        base = _serve(_engine(cfg, params), reqs)
         with chaos.inject("serve.prefix_hash:1+"):
-            got = _serve(_engine(cfg, params, kv_layout="ragged",
-                                 prefix_cache_pages=16), reqs)
+            eng = _engine(cfg, params, prefix_cache_pages=16)
+            assert eng.stats["kv_read"] == "kernel"
+            got = _serve(eng, reqs)
         assert got == base
 
 

@@ -14,9 +14,9 @@ The contracts under test:
     for int8 AND fp8 — with chaos at ``quant.allreduce`` (per-call
     fallback to full precision) inside the same envelope.
   * QUANTIZED KV PAGES — ``kv_dtype=int8|fp8`` serving on TRAINED
-    weights: greedy token agreement ≥99% vs the full-precision engine on
-    BOTH read paths (XLA gather and ragged Pallas kernel), across
-    staggered admission and mid-flight preemption; one-step decode
+    weights: greedy token agreement ≥99% vs the full-precision engine
+    (a quantized pool reads through the XLA gather), across staggered
+    admission and mid-flight preemption; one-step decode
     logits within a bounded δ; the fp path is byte-identical (no scale
     pools, tokens == llama_generate); and an equal page-pool HBM budget
     admits ≥1.8× the live tokens of bf16 pages (the capacity
@@ -36,7 +36,7 @@ import paddle_tpu.distributed.collective as coll
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.resilience import chaos
 from paddle_tpu.inference import ContinuousBatcher
-from paddle_tpu.models.llama import LlamaConfig, llama_init_params
+from paddle_tpu.models.llama import LlamaConfig
 from paddle_tpu.models.llama_decode import llama_generate
 from paddle_tpu.observability import metrics
 from paddle_tpu.quant import codec as qcodec
@@ -99,8 +99,8 @@ def _corpus_requests(corpus, n, seed):
     return reqs
 
 
-def _serve(cfg, params, reqs, layout, kv_dtype="", **kw):
-    eng = _engine(cfg, params, kv_layout=layout, kv_dtype=kv_dtype, **kw)
+def _serve(cfg, params, reqs, kv_dtype="", **kw):
+    eng = _engine(cfg, params, kv_dtype=kv_dtype, **kw)
     rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
     out = eng.run()
     return eng, [out[r] for r in rids]
@@ -135,10 +135,10 @@ class TestCodec:
         rt = np.asarray(qcodec.dequantize_lastdim(q, s))
         assert (rt == x).all()
 
-    def test_zero_blocks_roundtrip_exact(self):
-        for mode in ("int8", "fp8"):
-            q, s = qcodec.quantize_lastdim(jnp.zeros((3, 16)), mode)
-            assert (np.asarray(qcodec.dequantize_lastdim(q, s)) == 0).all()
+    @pytest.mark.parametrize("mode", ["int8", "fp8"])
+    def test_zero_blocks_roundtrip_exact(self, mode):
+        q, s = qcodec.quantize_lastdim(jnp.zeros((3, 16)), mode)
+        assert (np.asarray(qcodec.dequantize_lastdim(q, s)) == 0).all()
 
     def test_fp8_saturates_never_nan(self):
         # a bare float8 astype maps overflow to NaN on this jax; the
@@ -375,42 +375,20 @@ class TestDataParallelLossTrajectory:
 
 # --------------------------------------------------- quantized KV pages
 class TestQuantKVPages:
-    def test_greedy_agreement_both_read_paths(self, trained_model):
+    @pytest.mark.parametrize("dt", ["int8", "fp8"])
+    def test_greedy_agreement(self, trained_model, dt):
         """int8 and fp8 pages vs the full-precision engine on TRAINED
         weights, staggered admission (6 requests over 3 slots): ≥99%
-        greedy token agreement on BOTH read paths, and gather == ragged
-        token-identically (they dequantize the same pool to the same f32
-        values)."""
+        greedy token agreement."""
         cfg, params, corpus = trained_model
         reqs = _corpus_requests(corpus, 6, seed=11)
-        _, base = _serve(cfg, params, reqs, "paged")
-        for dt in ("int8", "fp8"):
-            _, gather = _serve(cfg, params, reqs, "paged", kv_dtype=dt)
-            reng, ragged = _serve(cfg, params, reqs, "ragged", kv_dtype=dt)
-            assert reng._ragged, "kernel path must be active on CPU"
-            assert _agreement(gather, base) >= 0.99, dt
-            assert _agreement(ragged, base) >= 0.99, dt
-            assert gather == ragged, dt
+        _, base = _serve(cfg, params, reqs)
+        eng, quant = _serve(cfg, params, reqs, kv_dtype=dt)
+        assert eng.stats["kv_read"] == "gather"
+        assert _agreement(quant, base) >= 0.99
 
-    def test_bf16_model_gather_ragged_token_identical(self):
-        """The dtype-rounding contract: the quantized kernel mirrors the
-        gather path's dequantize→round-to-model-dtype arithmetic, so the
-        two read paths stay token-identical for a BF16 model too (the
-        supported() fallback claim) — not just for the f32 tier-1
-        config where rounding is the identity."""
-        cfg = LlamaConfig.tiny(num_hidden_layers=2,
-                               max_position_embeddings=128,
-                               dtype=jnp.bfloat16)
-        params = llama_init_params(cfg, jax.random.PRNGKey(3))
-        rng = np.random.RandomState(7)
-        reqs = [(rng.randint(1, 256, n).tolist(), m)
-                for n, m in [(5, 8), (11, 6)]]
-        for dt in ("int8", "fp8"):
-            _, gather = _serve(cfg, params, reqs, "paged", kv_dtype=dt)
-            _, ragged = _serve(cfg, params, reqs, "ragged", kv_dtype=dt)
-            assert gather == ragged, dt
-
-    def test_midflight_preemption_quantized(self, trained_model):
+    @pytest.mark.parametrize("dt", ["int8", "fp8"])
+    def test_midflight_preemption_quantized(self, trained_model, dt):
         """A pool sized to force mid-flight preemption (the PR-8 recipe:
         two 30-token budgets over 7 usable pages) with quantized pages:
         preemption fires, everything completes, agreement holds —
@@ -419,15 +397,15 @@ class TestQuantKVPages:
         cfg, params, corpus = trained_model
         reqs = [([int(t) or 1 for t in corpus[o:o + 5]], 30)
                 for o in (40, 200)]
-        _, base = _serve(cfg, params, reqs, "paged", num_pages=8, burst=8)
-        for layout in ("paged", "ragged"):
-            eng, outs = _serve(cfg, params, reqs, layout, kv_dtype="int8",
-                               num_pages=8, burst=8)
-            assert eng.stats["preemptions"] >= 1, layout
-            assert _agreement(outs, base) >= 0.99, layout
-            assert eng.pages_in_use == 0   # clean drain
+        _, base = _serve(cfg, params, reqs, num_pages=8, burst=8)
+        eng, outs = _serve(cfg, params, reqs, kv_dtype=dt, num_pages=8,
+                           burst=8)
+        assert eng.stats["preemptions"] >= 1
+        assert _agreement(outs, base) >= 0.99
+        assert eng.pages_in_use == 0   # clean drain
 
-    def test_bounded_logit_delta_one_step(self, trained_model):
+    @pytest.mark.parametrize("quant,bound", [("int8", 1e-2), ("fp8", 5e-2)])
+    def test_bounded_logit_delta_one_step(self, trained_model, quant, bound):
         """Prefill the same prompt into quantized and full-precision
         pools, take ONE decode step: max |Δlogit| bounded (measured:
         int8 ≈ 8e-4, fp8 ≈ 5e-3 on a ~1.1 logit range — bounds ~10×)."""
@@ -437,7 +415,7 @@ class TestQuantKVPages:
         cfg, params, corpus = trained_model
         prompt = np.asarray([int(t) or 1 for t in corpus[100:116]], np.int32)
         outs = {}
-        for dt in (None, "int8", "fp8"):
+        for dt in (None, quant):
             cache = init_paged_kv_cache(cfg, 13, 8, kv_dtype=dt)
             first, cache = llama_paged_prefill_slot(
                 params, cache, jnp.asarray(prompt),
@@ -450,10 +428,9 @@ class TestQuantKVPages:
                 jnp.asarray([16], jnp.int32),
                 jnp.asarray([int(first)], jnp.int32), cfg, kv_dtype=dt)
             outs[dt] = np.asarray(logits)
-        for dt, bound in (("int8", 1e-2), ("fp8", 5e-2)):
-            d = np.abs(outs[dt] - outs[None]).max()
-            assert 0 < d <= bound, (dt, d)
-            assert outs[dt].argmax() == outs[None].argmax()
+        d = np.abs(outs[quant] - outs[None]).max()
+        assert 0 < d <= bound, d
+        assert outs[quant].argmax() == outs[None].argmax()
 
     def test_fp_path_byte_identical_when_off(self, trained_model,
                                              monkeypatch):
@@ -463,7 +440,7 @@ class TestQuantKVPages:
         monkeypatch.delenv("PADDLE_SERVE_KV_DTYPE", raising=False)
         cfg, params, corpus = trained_model
         reqs = _corpus_requests(corpus, 3, seed=31)
-        eng, outs = _serve(cfg, params, reqs, "paged")
+        eng, outs = _serve(cfg, params, reqs)
         assert eng._kv_dtype is None
         assert "k_scale" not in eng._cache
         assert eng._cache["k"][0].dtype == cfg.dtype
@@ -511,23 +488,23 @@ class TestCapacityAtEqualHBM:
     CFG = dict(hidden_size=64, num_attention_heads=1, num_key_value_heads=1,
                num_hidden_layers=2, dtype=jnp.bfloat16)  # head_dim 64
 
-    def test_equal_budget_admits_1p8x_live_tokens(self):
+    @pytest.mark.parametrize("dt", ["int8", "fp8"])
+    def test_equal_budget_admits_1p8x_live_tokens(self, dt):
         from paddle_tpu.models.llama_paged import page_bytes
         cfg = LlamaConfig.tiny(**self.CFG)
         ps = 8
         budget = 48 * page_bytes(cfg, ps)      # a 48-page bf16 pool
         bf16 = _engine(cfg, params=None, kv_layout="paged",
                        pool_hbm_bytes=budget)
-        for dt in ("int8", "fp8"):
-            quant = _engine(cfg, params=None, kv_layout="paged",
-                            kv_dtype=dt, pool_hbm_bytes=budget)
-            ratio = (quant._alloc.usable * ps) / (bf16._alloc.usable * ps)
-            assert ratio >= 1.8, (dt, ratio)
-            # and in admitted-request terms: concurrent 16-token contexts
-            from paddle_tpu.inference.paging import pages_for
-            per_req = pages_for(16, ps)
-            assert quant._alloc.usable // per_req \
-                >= 1.8 * (bf16._alloc.usable // per_req), dt
+        quant = _engine(cfg, params=None, kv_layout="paged",
+                        kv_dtype=dt, pool_hbm_bytes=budget)
+        ratio = (quant._alloc.usable * ps) / (bf16._alloc.usable * ps)
+        assert ratio >= 1.8, ratio
+        # and in admitted-request terms: concurrent 16-token contexts
+        from paddle_tpu.inference.paging import pages_for
+        per_req = pages_for(16, ps)
+        assert quant._alloc.usable // per_req \
+            >= 1.8 * (bf16._alloc.usable // per_req)
 
     def test_pool_budget_knob_validation(self):
         cfg = LlamaConfig.tiny(**self.CFG)

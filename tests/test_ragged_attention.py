@@ -1,37 +1,29 @@
-"""Ragged paged-attention kernel + GSPMD-sharded page pool (ISSUE 8).
+"""The paged pool's Pallas kernels (ops/ragged_attention.py) and the default
+``kv_layout="paged"`` engine on them (ISSUE 28; one paged layout: ISSUE 32).
 
 The contracts under test:
-  * KERNEL PARITY — ops/ragged_attention.py (interpret mode on CPU): the
-    decode body (q_max == 1, online softmax over chunks of live pages)
-    agrees with a float32 full-softmax reference within rounding; the
-    q_max > 1 body is BITWISE equal to the XLA block-table gather at
-    q_max == 1 (the pools the decode body refuses) and matches the dense
-    causal attention for ragged prefill rows.
-  * PAGED ENGINE ON THE KERNEL (ISSUE 28) — a default ``kv_layout="paged"``
-    engine whose pool ``paged_kv_read`` takes reads through the decode
-    body (``stats["kv_read"] == "kernel"``), token-identical to
-    ``llama_generate`` through a preemption; quantized pages, head_dim 64
-    and a sharded pool keep the gather.
-  * SERVING PARITY — a ``kv_layout="ragged"`` ContinuousBatcher is
-    token-identical to the gather-paged, dense, and per-request
-    ``llama_generate`` paths at temperature=0, across staggered admission
-    (mixed prefill+decode bursts), mid-flight preemption, and chaos; and
-    ``PADDLE_RAGGED_ATTN=0`` falls back to the gather path, still
-    token-identical (parity gated both ways).
-  * INVENTORY — the ragged path compiles O(1) decode executables (at most
-    the {prefill-carrying, decode-only} pair) where the gather path
-    compiles one per prompt bucket × page bucket used (jit-cache deltas
-    on a cold config).
-  * BENCH CONTRACT — ``decode_bench --paged --ragged`` and
-    ``serving_bench`` JSON lines carry the ``ragged`` sub-object
-    (bytes/token, executable count, parity bit), never exit JSON-less.
-  * SHARDING — a pool sharded P(None, None, "model", None) over 2 forced
-    CPU host devices serves token-identically on both read paths
-    (subprocess drill: tests/mp_runners/ragged_sharded_serve.py).
+  * KERNEL PARITY (interpret mode on CPU): the decode read
+    (``paged_decode_attention``, online softmax over chunks of live pages)
+    agrees with a float32 full-softmax reference within rounding;
+    ``paged_kv_scatter`` is bitwise the ``dynamic_update_slice`` loop it
+    replaces.
+  * PAGED ENGINE ON THE KERNEL: a default-layout engine whose pool
+    ``paged_kv_read`` takes reads through the decode kernel
+    (``stats["kv_read"] == "kernel"``), token-identical to its gather
+    twin, the dense layout and ``llama_generate`` at temperature=0,
+    through a preemption and chaos; quantized pages, head_dim 64 and a
+    sharded pool keep the gather. ``kv_layout="ragged"`` is an unknown
+    layout and ``PADDLE_RAGGED_ATTN`` is not a flag.
+  * INVENTORY: the default layout compiles one prefill per prompt bucket
+    used and one burst per page bucket used (jit-cache deltas on a cold
+    config).
+  * BENCH CONTRACT: ``decode_bench --paged`` and ``serving_bench`` JSON
+    lines carry their sub-objects, never exit JSON-less.
+  * SHARDING: a pool sharded P(None, None, "model", None) over 2 of the
+    forced CPU host devices serves token-identically to the unsharded
+    pool, through the gather, with the pool really on both devices.
 """
 import json
-import os
-import subprocess
 import sys
 
 import jax
@@ -45,14 +37,12 @@ from paddle_tpu.models.llama import LlamaConfig, llama_init_params
 from paddle_tpu.models.llama_decode import llama_generate
 from paddle_tpu.ops import ragged_attention as ra
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(scope="module")
 def small_model():
     # deliberately the same config/params/engine geometry as
     # tests/test_serving_paged.py: the gather/dense/generate executables
-    # are shared across the two files, so only the ragged path compiles
+    # are shared across the two files
     cfg = LlamaConfig.tiny(num_hidden_layers=2, max_position_embeddings=128)
     params = llama_init_params(cfg, jax.random.PRNGKey(3))
     return cfg, params
@@ -114,12 +104,12 @@ DECODE_CASES = {
 }
 
 
-class TestRaggedKernel:
+class TestPagedKernels:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("case", sorted(DECODE_CASES))
     def test_decode_rows_match_full_softmax_reference(self, case, dtype,
                                                       monkeypatch):
-        """q_max == 1 at a geometry the decode body takes (head_dim 128):
+        """A geometry the decode kernel takes (head_dim 128):
         chunks of live pages under an online softmax against ONE
         full-width float32 softmax. Not bitwise (ISSUE 28 ended that: the
         summation order differs); the tolerance is stated from the dtype:
@@ -152,9 +142,9 @@ class TestRaggedKernel:
         # 2 pages a chunk: the module constant is read where the launch
         # is built, so call the launch itself (no jit cache in between)
         monkeypatch.setattr(ra, "_DECODE_CHUNK_ROWS", 2 * _PS * KV)
-        out = np.asarray(ra._decode_attention(
+        out = np.asarray(ra.paged_decode_attention.__wrapped__(
             q, kp, vp, jnp.asarray(bt), jnp.ones(B, jnp.int32),
-            jnp.asarray(lens), True), np.float32)
+            jnp.asarray(lens), interpret=True), np.float32)
         ref = _full_softmax_reference(q, kp, vp, bt, lens)
         atol = 1e-5 if dtype == "float32" else 3e-2
         np.testing.assert_allclose(out, ref, rtol=0, atol=atol)
@@ -209,107 +199,13 @@ class TestRaggedKernel:
         q = jnp.asarray(rng.randn(3, 1, H, hd), jnp.float32)
         bt = rng.randint(1, npool, (3, _PMAX)).astype(np.int32)
         lens = np.array([9, 17, 30], np.int32)
-        out = np.asarray(ra.ragged_paged_attention(
+        out = np.asarray(ra.paged_decode_attention(
             q, kp, vp, jnp.asarray(bt), jnp.asarray([1, 0, 1], jnp.int32),
-            jnp.asarray(lens), page_size=_PS, interpret=True))
+            jnp.asarray(lens), interpret=True))
         ref = _full_softmax_reference(q, kp, vp, bt, lens)
         assert (out[1] == 0).all()
         np.testing.assert_allclose(out[[0, 2]], ref[[0, 2]], rtol=0,
                                    atol=1e-5)
-
-    def test_wide_body_decode_rows_bitwise_equal_to_gather(self,
-                                                           small_model):
-        """q_len=1 rows through the q_max > 1 body (head_dim 16: the
-        decode body refuses this pool, as it does quantized ones): its
-        per-page DMA + full-width masked softmax is the SAME arithmetic
-        as jnp.take + the grouped einsum — bitwise, not approximately."""
-        from paddle_tpu.models.llama_decode import _cached_attention_slots
-        cfg, _ = small_model
-        KV, H, hd = (cfg.num_key_value_heads, cfg.num_attention_heads,
-                     cfg.head_dim)
-        B, ps, pmax, npool = 3, 8, 5, 16
-        assert not ra.decode_supported(hd, KV, ps)
-        rng = np.random.RandomState(0)
-        kp = jnp.asarray(rng.randn(npool, ps, KV, hd).astype(np.float32))
-        vp = jnp.asarray(rng.randn(npool, ps, KV, hd).astype(np.float32))
-        q = jnp.asarray(rng.randn(B, 1, H, hd).astype(np.float32))
-        bt = jnp.asarray(rng.randint(1, npool, (B, pmax)).astype(np.int32))
-        pos = jnp.asarray(np.array([3, 17, 39], np.int32))
-        kc = jnp.take(kp, bt, axis=0).reshape(B, -1, KV, hd)
-        vc = jnp.take(vp, bt, axis=0).reshape(B, -1, KV, hd)
-        ref = np.asarray(_cached_attention_slots(q, kc, vc, pos, cfg))
-        out = np.asarray(ra.ragged_paged_attention(
-            q, kp, vp, bt, jnp.ones(B, jnp.int32), pos + 1,
-            page_size=ps, interpret=True))
-        assert (ref == out).all()
-
-    def test_prefill_rows_match_dense_causal(self, small_model):
-        """Ragged q_len>1 rows read back through the pool == the dense
-        causal attention over each slot's own rows; q_len=0 slots emit
-        exact zeros (dead lanes, never NaN)."""
-        from paddle_tpu.models.llama import _attention
-        cfg, _ = small_model
-        KV, H, hd = (cfg.num_key_value_heads, cfg.num_attention_heads,
-                     cfg.head_dim)
-        B, ps, pmax, q_max = 3, 8, 4, 16
-        rng = np.random.RandomState(1)
-        qlens = np.array([5, 12, 0], np.int32)   # slot 2 skipped
-        qp = jnp.asarray(rng.randn(B, q_max, H, hd).astype(np.float32))
-        ks = rng.randn(B, q_max, KV, hd).astype(np.float32)
-        vs = rng.randn(B, q_max, KV, hd).astype(np.float32)
-        npool = 1 + B * pmax
-        kp = np.full((npool, ps, KV, hd), np.nan, np.float32)  # poison
-        vp = kp.copy()
-        bt = np.zeros((B, pmax), np.int32)
-        page = 1
-        for b in range(B):
-            for j in range(-(-int(qlens[b]) // ps)):
-                bt[b, j] = page
-                rows = ks[b, j * ps:(j + 1) * ps]
-                kp[page, :rows.shape[0]] = rows
-                vp[page, :rows.shape[0]] = vs[b, j * ps:(j + 1) * ps]
-                page += 1
-        out = np.asarray(ra.ragged_paged_attention(
-            qp, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-            jnp.asarray(qlens), jnp.asarray(qlens), page_size=ps,
-            interpret=True))
-        # NOT bitwise, unlike the decode test above: there both sides
-        # reduce over the same padded width, here the kernel's softmax sum
-        # and probs@V contraction run over max_pages*ps = 32 lanes (dead
-        # lanes exact zeros) while the dense reference runs over the T live
-        # ones. XLA's CPU backend (jaxlib 0.9.0) vectorizes a row reduction
-        # by its width, so the same numbers are summed in another order:
-        # 1-ulp differences (measured max 1.2e-7 on O(1) outputs). The
-        # bound is reassociation of <= 32 f32 terms of magnitude <= 1,
-        # twice: 32 * eps, set from the dtype and not from the measurement.
-        atol = pmax * ps * np.finfo(np.float32).eps
-        for b in range(2):
-            T = int(qlens[b])
-            ref = _attention(qp[b:b + 1, :T], jnp.asarray(ks[b:b + 1, :T]),
-                             jnp.asarray(vs[b:b + 1, :T]), cfg,
-                             use_flash=False)
-            np.testing.assert_allclose(out[b, :T], np.asarray(ref)[0],
-                                       rtol=0, atol=atol, err_msg=str(b))
-        assert (out[2] == 0).all()               # skipped slot: zeros
-        assert np.isfinite(out[:2, :12]).all()   # NaN pool never leaked
-
-    @pytest.mark.parametrize("geometry,compiles", [
-        (dict(head_dim=128, kv_heads=32, max_len=1024), True),
-        (dict(head_dim=128, kv_heads=8, max_len=4096), True),
-        (dict(head_dim=256, kv_heads=4, max_len=512), True),
-        (dict(head_dim=64, kv_heads=32, max_len=1024), False),
-        (dict(head_dim=128, kv_heads=12, max_len=1024), False),
-        (dict(head_dim=128, kv_heads=1, max_len=1024), False),
-        (dict(head_dim=128, kv_heads=32, max_len=8192), False),
-        (dict(head_dim=128, kv_heads=32, max_len=1024,
-              kv_dtype="int8"), False)])
-    def test_supported_says_what_the_compiler_says(self, geometry, compiles):
-        """Interpret mode always can; the compiled path follows the rules
-        Mosaic was seen to enforce (tests/test_tpu_compile.py compiles a
-        case on each side of every rule and must agree with this)."""
-        assert ra.supported(interpret=True, **geometry)
-        assert ra.supported(interpret=False, **geometry) is compiles
-
 
     @pytest.mark.parametrize("geometry,takes", [
         (dict(head_dim=128, kv_heads=8, page_size=16), True),   # batch cell
@@ -324,95 +220,43 @@ class TestRaggedKernel:
               kv_dtype="int8"), False)])
     def test_decode_supported_says_what_the_compiler_says(self, geometry,
                                                           takes):
-        """The decode body's rule has no backend and no max_len in it
+        """The decode kernel's rule has no backend and no max_len in it
         (tests/test_tpu_compile.py compiles a case on each side)."""
         assert ra.decode_supported(**geometry) is takes
 
 
 # ---------------------------------------------------------------- serving
-class TestRaggedServingParity:
+class TestServingParity:
     SPEC = [(5, 7), (13, 3), (29, 12), (8, 1), (20, 6), (11, 9), (4, 8)]
 
-    def test_ragged_matches_gather_dense_and_generate(self, small_model):
-        """7 mixed requests through 3 slots: admissions land inside
-        decoding bursts by construction (mixed prefill+decode launches).
-        ragged == gather == dense == llama_generate, token for token."""
-        cfg, params = small_model
+    def test_paged_matches_dense_and_generate(self, served, monkeypatch):
+        """7 mixed requests through 3 slots, admissions landing between
+        decoding bursts: the default layout on its read == the dense
+        layout == llama_generate, token for token; and where the read is
+        the kernel's, == its gather twin (the same model and pool, the
+        choice of ``paged_kv_read`` overruled here in the test)."""
+        from paddle_tpu.models import llama_paged
+        cfg, params, read = served
         reqs = _mixed_requests(cfg, 11, self.SPEC)
-        outs = {}
-        for layout in ("ragged", "paged", "dense"):
-            eng = _engine(cfg, params, kv_layout=layout)
+
+        def serve(want, **kw):
+            eng = _engine(cfg, params, **kw)
+            assert eng.stats["kv_read"] == want
             rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
             res = eng.run()
-            outs[layout] = [res[r] for r in rids]
-            if layout == "ragged":
-                assert eng._ragged is True
-                assert eng.admin_summary()["ragged"] is True
-        for (p, m), rag, pg, dn in zip(reqs, outs["ragged"], outs["paged"],
-                                       outs["dense"]):
-            ref = _reference_generate(cfg, params, p, m)
-            assert rag == ref, (len(p), m)
-            assert pg == ref and dn == ref, (len(p), m)
+            return [res[r] for r in rids]
 
-    def test_midflight_preemption_is_exact(self, small_model):
-        """Pool runs dry mid-flight under the ragged scheduler: youngest
-        slot preempted back to the queue, output still exact."""
-        cfg, params = small_model
-        reqs = _mixed_requests(cfg, 37, [(5, 30), (5, 30)])
-        eng = _engine(cfg, params, num_pages=8, burst=8, kv_layout="ragged")
-        rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
-        out = eng.run()
-        assert eng.stats["preemptions"] >= 1
-        for rid, (p, m) in zip(rids, reqs):
-            assert out[rid] == _reference_generate(cfg, params, p, m)
-        assert eng.pages_in_use == 0
-
-    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
-    def test_uncompilable_pool_raises_on_tpu(self, small_model, monkeypatch,
-                                             kv_dtype):
-        """On a TPU the kernel must be compiled, and the compiler refuses
-        this pool (head_dim 16; quantized pages): an explicit
-        kv_layout="ragged" raises, naming the geometry — it never serves
-        through the gather or the interpreter unasked.
-        PADDLE_RAGGED_ATTN=0 stays the one explicit way to the gather."""
-        cfg, params = small_model
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        with pytest.raises(ValueError) as err:
-            _engine(cfg, params, kv_layout="ragged", kv_dtype=kv_dtype)
-        for part in (f"head_dim={cfg.head_dim}", "page_size=8", "max_len=96",
-                     f"kv_heads={cfg.num_key_value_heads}",
-                     f"kv_dtype={kv_dtype!r}", "PADDLE_RAGGED_ATTN=0"):
-            assert part in str(err.value)
-        monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
-        eng = _engine(cfg, params, kv_layout="ragged", kv_dtype=kv_dtype)
-        assert eng._ragged is False and eng._interpret is False
-
-    def test_env_flag_falls_back_to_gather(self, small_model, monkeypatch):
-        """PADDLE_RAGGED_ATTN=0: a ragged engine serves through the gather
-        path because it was asked to — token-identical, parity gated both
-        ways."""
-        cfg, params = small_model
-        p, m = _mixed_requests(cfg, 41, [(9, 6)])[0]
-        monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
-        eng = _engine(cfg, params, kv_layout="ragged")
-        assert eng._ragged is False
-        rid = eng.add_request(p, max_new_tokens=m)
-        assert eng.run()[rid] == _reference_generate(cfg, params, p, m)
+        outs = [serve(read), serve("dense", kv_layout="dense")]
+        if read == "kernel":
+            monkeypatch.setattr(llama_paged, "paged_kv_read",
+                                lambda *a, **kw: "gather")
+            outs.append(serve("gather"))
+        ref = [_reference_generate(cfg, params, p, m) for p, m in reqs]
+        for out in outs:
+            assert out == ref
 
 
 # ------------------------------------------- paged engine on the kernel
-@pytest.fixture(scope="module")
-def wide_model():
-    """head_dim 128 with 8 KV heads (hidden 1024 over 8 heads), ONE layer:
-    the smallest model whose pool takes the decode body AND the one-launch
-    row write (a row of 8 heads is a whole sublane tile). The interpreted
-    kernels are slow on the CPU, so everything about it stays tiny."""
-    cfg = LlamaConfig.tiny(num_hidden_layers=1, hidden_size=1024,
-                           num_attention_heads=8, num_key_value_heads=8,
-                           max_position_embeddings=128)
-    return cfg, llama_init_params(cfg, jax.random.PRNGKey(3))
-
-
 @pytest.fixture(scope="module")
 def kernel_run(wide_model):
     """ONE default-layout engine run on the kernel read, through a
@@ -440,7 +284,6 @@ class TestPagedEngineOnKernel:
         tokens equal llama_generate's, across a preemption."""
         r = kernel_run
         assert r["eng"].stats["kv_read"] == "kernel"
-        assert r["eng"]._ragged is False         # the default layout
         assert r["eng"].stats["preemptions"] >= 1
         for rid, (p, m) in zip(r["rids"], r["reqs"]):
             assert r["out"][rid] == _reference_generate(
@@ -505,22 +348,26 @@ class TestPagedEngineOnKernel:
     @pytest.mark.parametrize("engine_kw,read", [
         (dict(), "kernel"),
         (dict(kv_dtype="int8"), "gather"),
-        (dict(kv_layout="dense"), "dense"),
-        (dict(kv_layout="ragged"), "kernel"),
-        (dict(kv_layout="ragged", env="0"), "gather")])
-    def test_engine_selection(self, wide_model, monkeypatch, engine_kw,
-                              read):
-        """Selection by what the engine can see in its pool; the env flag
-        keeps its meaning for kv_layout="ragged" only (an explicit ask
-        for the gather, whatever the geometry)."""
+        (dict(kv_layout="dense"), "dense")])
+    def test_engine_selection(self, wide_model, engine_kw, read):
+        """Selection by what the engine can see in its pool."""
         cfg, params = wide_model
-        kw = dict(engine_kw)
-        if kw.pop("env", None):
-            monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
-        assert _engine(cfg, params, **kw).stats["kv_read"] == read
+        assert _engine(cfg, params, **engine_kw).stats["kv_read"] == read
 
-    def test_env_flag_does_not_reach_the_default_layout(self, wide_model,
-                                                        monkeypatch):
+    def test_ragged_is_an_unknown_layout(self, wide_model):
+        """One paged layout (ISSUE 32): the name raises like any other."""
+        cfg, params = wide_model
+        with pytest.raises(ValueError, match="unknown kv_layout 'ragged'"):
+            _engine(cfg, params, kv_layout="ragged")
+        with pytest.raises(ValueError, match="unknown kv_layout 'pagd'"):
+            _engine(cfg, params, kv_layout="pagd")
+
+    def test_the_gather_switch_is_not_declared(self, wide_model,
+                                               monkeypatch):
+        """The read is chosen from the pool's geometry alone: no flag
+        (the name was PADDLE_RAGGED_ATTN) is declared, none is read."""
+        from paddle_tpu.utils import env_flags
+        assert not env_flags.declared("PADDLE_RAGGED_ATTN")
         cfg, params = wide_model
         monkeypatch.setenv("PADDLE_RAGGED_ATTN", "0")
         assert _engine(cfg, params).stats["kv_read"] == "kernel"
@@ -580,80 +427,85 @@ class TestGatherThatStays:
 
 
 # -------------------------------------------------------------- inventory
-class TestRaggedExecutableInventory:
-    def test_o1_executables_vs_gather_bucket_grid(self):
-        """COLD config (unique to this test): the same mixed workload
-        compiles one gather executable per prompt/page bucket used, but at
-        most the {prefill-carrying, decode-only} PAIR on the ragged path —
-        the inventory no longer scales with the bucket grid."""
+class TestExecutableInventory:
+    def test_one_program_a_bucket_used(self):
+        """COLD config (unique to this test): a mixed workload compiles
+        one prefill per prompt bucket used and one burst per page bucket
+        used, whatever the request count, prompt mix and admission order;
+        a second engine on the same config compiles nothing."""
         from paddle_tpu.models.llama_paged import (llama_paged_decode_burst,
-                                                   llama_paged_prefill_slot,
-                                                   llama_ragged_burst)
+                                                   llama_paged_prefill_slot)
         cfg = LlamaConfig.tiny(num_hidden_layers=2, vocab_size=250,
                                max_position_embeddings=128)
         params = llama_init_params(cfg, jax.random.PRNGKey(7))
         spec = [(4, 5), (14, 6), (28, 10), (9, 4), (20, 8), (6, 9)]
         reqs = _mixed_requests(cfg, 43, spec)
+        prompt_buckets_used = {min(b for b in (8, 16, 32) if b >= n)
+                               for n, _ in spec}
 
-        r0 = llama_ragged_burst._cache_size()
-        eng = _engine(cfg, params, kv_layout="ragged")
-        rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
-        ragged_out = eng.run()
-        ragged_delta = llama_ragged_burst._cache_size() - r0
+        def serve():
+            b0 = llama_paged_decode_burst._cache_size()
+            p0 = llama_paged_prefill_slot._cache_size()
+            eng = _engine(cfg, params)
+            rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
+            out = eng.run()
+            return (eng, [out[r] for r in rids],
+                    llama_paged_decode_burst._cache_size() - b0,
+                    llama_paged_prefill_slot._cache_size() - p0)
 
-        b0 = llama_paged_decode_burst._cache_size()
-        p0 = llama_paged_prefill_slot._cache_size()
-        geng = _engine(cfg, params, kv_layout="paged")
-        grids = [geng.add_request(p, max_new_tokens=m) for p, m in reqs]
-        gather_out = geng.run()
-        gather_delta = (llama_paged_decode_burst._cache_size() - b0
-                        + llama_paged_prefill_slot._cache_size() - p0)
-
-        # O(1) vs the bucket grid — the acceptance bound, measured
-        assert ragged_delta <= 2
-        assert gather_delta >= 4    # >= 2 prompt buckets + >= 2 page buckets
-        assert ragged_delta < gather_delta
-        # and the outputs stayed identical while we were counting
-        assert [ragged_out[r] for r in rids] == [gather_out[g] for g in grids]
+        eng, out, bursts, prefills = serve()
+        assert len(prompt_buckets_used) == 3
+        assert prefills == len(prompt_buckets_used)
+        assert bursts == len(eng.stats["page_buckets_used"]) >= 2
+        _, again, bursts, prefills = serve()
+        assert (bursts, prefills) == (0, 0) and again == out
 
 
 # ------------------------------------------------------------------ chaos
-class TestRaggedChaos:
-    def test_admit_fault_retires_request_not_scheduler(self, small_model):
-        cfg, params = small_model
-        reqs = _mixed_requests(cfg, 51, [(6, 5), (10, 7), (15, 4)])
-        eng = _engine(cfg, params, kv_layout="ragged")
+class TestChaosOnKernel:
+    """Faults that land MID-SERVE where the bursts read through the decode
+    kernel and write through ``paged_kv_scatter``: slots are decoding, rows
+    of several bursts are in the pool (tests/test_serving_paged.py holds
+    the first-admission and first-burst faults on every read)."""
+
+    def test_later_admit_fault_retires_that_request_only(self, wide_model):
+        cfg, params = wide_model
+        reqs = _mixed_requests(cfg, 51, [(6, 5), (10, 7), (15, 4), (9, 6)])
+        eng = _engine(cfg, params, max_batch=2)
+        assert eng.stats["kv_read"] == "kernel"
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
-        with chaos.inject("serve.admit:1"):
+        with chaos.inject("serve.admit:3"):     # a slot freed mid-serve
             out = eng.run()
-        assert out[rids[0]] == [] and eng.stats["chaos_retired"] == 1
-        for rid, (p, m) in zip(rids[1:], reqs[1:]):
-            assert out[rid] == _reference_generate(cfg, params, p, m)
+        assert out[rids[2]] == [] and eng.stats["chaos_retired"] == 1
+        for i in (0, 1, 3):
+            p, m = reqs[i]
+            assert out[rids[i]] == _reference_generate(cfg, params, p, m)
         assert eng.pages_in_use == 0
 
-    def test_burst_fault_retires_active_with_partial_output(self,
-                                                            small_model):
-        cfg, params = small_model
-        reqs = _mixed_requests(cfg, 53, [(6, 8), (10, 8), (15, 5), (8, 6)])
-        eng = _engine(cfg, params, max_batch=2, kv_layout="ragged")
+    def test_later_burst_fault_keeps_what_was_decoded(self, wide_model):
+        cfg, params = wide_model
+        reqs = _mixed_requests(cfg, 53, [(6, 12), (10, 12), (15, 5), (8, 6)])
+        eng = _engine(cfg, params, max_batch=2)
+        assert eng.stats["kv_read"] == "kernel"
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
-        with chaos.inject("serve.burst:1"):
+        with chaos.inject("serve.burst:2"):     # the second burst
             out = eng.run()
         assert len(out) == 4 and eng.stats["chaos_retired"] >= 1
-        exact = 0
-        for rid, (p, m) in zip(rids, reqs):
-            ref = _reference_generate(cfg, params, p, m)
+        refs = [_reference_generate(cfg, params, p, m) for p, m in reqs]
+        for rid, ref in zip(rids, refs):
             assert out[rid] == ref[:len(out[rid])], rid
-            exact += out[rid] == ref
-        assert exact >= 1
+        # the first burst's tokens survived the fault, the later
+        # requests were served whole
+        assert 1 < len(out[rids[0]]) < 12 and 1 < len(out[rids[1]]) < 12
+        assert [out[r] for r in rids[2:]] == refs[2:]
         assert eng.pages_in_use == 0
 
 
 # ---------------------------------------------------------- bench contract
-class TestRaggedBenchContract:
+class TestBenchContract:
     def test_paged_kv_bytes_live_length_fix(self, small_model):
-        """bytes follow LIVE length on the ragged path, bucket width on
-        the gather path — the decode_bench over-reporting fix."""
+        """bytes follow LIVE length where the kernel reads, bucket width
+        where the gather does."""
         from paddle_tpu.models.llama_paged import paged_kv_bytes_per_token
         cfg, _ = small_model
         bucket = paged_kv_bytes_per_token(cfg, 8, 8)          # 64 rows
@@ -662,27 +514,20 @@ class TestRaggedBenchContract:
         assert live < bucket
         assert paged_kv_bytes_per_token(cfg, 8, 8, live_tokens=0) == 0
 
-    def test_decode_bench_ragged_subobject(self):
-        """decode_bench --paged --ragged always lands the ragged
-        sub-object with bytes/token + executable inventory + parity, on
-        the CPU fallback path (tier-1) exactly as on TPU."""
+    def test_decode_bench_paged_line(self):
+        """decode_bench --paged lands its line with the measured
+        executable inventory and the quant sub-object, on the CPU
+        fallback path (tier-1) exactly as on TPU."""
         from benchmarks import decode_bench
-        payload = decode_bench.main(["--paged", "--ragged", "6", "3", "8"])
+        payload = decode_bench.main(["--paged", "6", "3", "8"])
         # ISSUE 14: spec sub-object is null with PADDLE_SPEC_DECODE off
         # (the populated schema is pinned in tests/test_speculative.py)
         assert payload["spec"] is None
-        r = payload["ragged"]
-        assert set(r) >= {"tokens_per_sec", "kv_read_bytes_per_token",
-                          "hbm_roofline_bytes_per_token", "executables",
-                          "kernel_active", "parity"}
-        assert r["parity"] is True and r["kernel_active"] is True
-        # live-length accounting: under the gather path's bucket bill,
-        # within one page of the roofline
-        assert r["kv_read_bytes_per_token"] <= \
-            payload["kv_read_bytes_per_token"]
-        assert r["hbm_roofline_bytes_per_token"] <= \
-            r["kv_read_bytes_per_token"]
-        assert r["executables"]["ragged_burst_delta"] <= 2
+        assert "ragged" not in payload
+        assert payload["kv_read_bytes_per_token"] <= \
+            payload["kv_read_bytes_per_token_dense"]
+        assert payload["executables"]["paged_burst"] >= 1
+        assert payload["executables"]["paged_prefill"] >= 1
         # ISSUE 10: the quant sub-object rides the same JSON line
         q = payload["quant"]
         assert set(q) >= {"kv_dtype", "kv_read_bytes_per_token",
@@ -693,9 +538,9 @@ class TestRaggedBenchContract:
         assert q["capacity_ratio_vs_bf16"] > 1.0
         assert 0.0 <= q["token_agreement"] <= 1.0
 
-    def test_serving_bench_ragged_subobject(self, monkeypatch, capsys):
-        """serving_bench's JSON line carries the ragged sub-object and the
-        hard parity gate covers the ragged path (rc 0 == no divergence)."""
+    def test_serving_bench_line(self, monkeypatch, capsys):
+        """serving_bench's JSON line: every feature's sub-object null
+        while its flag is off, the quant sub-object always there."""
         from benchmarks import serving_bench
         monkeypatch.setenv("SERVING_TRAIN_STEPS", "0")
         monkeypatch.delenv("PADDLE_SERVE_REPLICAS", raising=False)
@@ -721,11 +566,8 @@ class TestRaggedBenchContract:
         # dashboards must distinguish 'off' from 'zero accepts' (the
         # populated schema is pinned in tests/test_speculative.py)
         assert doc["spec"] is None
-        r = doc["ragged"]
-        assert set(r) >= {"tokens_per_sec", "kv_read_bytes_per_token",
-                          "hbm_roofline_bytes_per_token", "executables",
-                          "kernel_active", "parity"}
-        assert r["kernel_active"] is True and r["parity"] is True
+        assert "ragged" not in doc
+        assert doc["paged_vs_dense_divergent_requests"] == 0
         # ISSUE 10: quant sub-object (kv_dtype, bytes vs bf16, capacity
         # ratio, agreement rate) always present on the serving line
         q = doc["quant"]
@@ -752,19 +594,28 @@ class TestShardedPagePool:
         assert tuple(kv_pool_pspec()) == (None, None, "model", None)
         assert serving_mesh(0) is None and serving_mesh(1) is None
 
-    def test_sharded_serve_drill(self):
-        """2 forced CPU host devices, pool sharded along KV heads: gather
-        AND ragged serves are token-identical to their unsharded runs, and
-        the pool buffers really live on both devices (subprocess — the
-        device count must be forced before jax initializes)."""
-        r = subprocess.run(
-            [sys.executable,
-             os.path.join(ROOT, "tests", "mp_runners",
-                          "ragged_sharded_serve.py")],
-            capture_output=True, text=True, timeout=300, cwd=ROOT)
-        assert r.returncode == 0, r.stderr[-2000:]
-        doc = json.loads(r.stdout.strip().splitlines()[-1])
-        assert doc["gather_parity"] and doc["ragged_parity"] \
-            and doc["cross_parity"], doc
-        assert doc["pool_devices"] == [1, 2, 2], doc
-        assert doc["ragged_active"] is True
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    def test_sharded_pool_serves_token_identical(self, small_model,
+                                                 monkeypatch, kv_dtype):
+        """The pool (and a quantized pool's scales) sharded along KV heads
+        over 2 of the forced CPU host devices: the default layout reads
+        through the gather, which GSPMD partitions, token-identical to the
+        unsharded run, and the pool's buffers really live on both."""
+        cfg, params = small_model           # 2 KV heads
+        reqs = _mixed_requests(cfg, 5, [(5, 6), (13, 4)])
+
+        def serve(shard):
+            monkeypatch.setenv("PADDLE_SERVE_MESH_MODEL", "2" if shard
+                               else "0")
+            eng = _engine(cfg, params, kv_dtype=kv_dtype)
+            assert eng.stats["kv_read"] == "gather"
+            rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
+            res = eng.run()
+            pools = [eng._cache[k][0] for k in sorted(eng._cache)]
+            return ([res[r] for r in rids],
+                    {len(a.sharding.device_set) for a in pools})
+
+        base, base_devices = serve(False)
+        sharded, sharded_devices = serve(True)
+        assert sharded == base
+        assert (base_devices, sharded_devices) == ({1}, {2})

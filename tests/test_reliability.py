@@ -158,10 +158,10 @@ class TestBatcherDeadline:
         assert eng.pages_in_use == 0
         assert eng.slo.summary()["inflight"] == 0   # measured, once
 
-    def test_in_slot_expiry_keeps_partial_and_frees_pages(self,
-                                                          small_model):
-        cfg, params = small_model
+    def test_in_slot_expiry_keeps_partial_and_frees_pages(self, served):
+        cfg, params, read = served
         eng = _engine(cfg, params)
+        assert eng.stats["kv_read"] == read
         p = _prompt(5)
         rid = eng.add_request(p, 40, deadline_s=600.0)
         eng.step()                               # prefill + first decode
@@ -193,11 +193,12 @@ class TestBatcherCancel:
         assert metrics.counter("serve.cancelled").value == c0 + 1
         assert eng.pages_in_use == 0 and eng.pending == 0
 
-    def test_cancel_in_slot_partial_output_pages_freed(self, small_model):
+    def test_cancel_in_slot_partial_output_pages_freed(self, served):
         """Acceptance: cancelling a decoding request frees its pages
         within one step — the pool gauge returns to baseline."""
-        cfg, params = small_model
+        cfg, params, read = served
         eng = _engine(cfg, params)
+        assert eng.stats["kv_read"] == read
         c0 = metrics.counter("serve.cancelled").value
         rid = eng.add_request(_prompt(7), 40)
         eng.step()
@@ -436,6 +437,31 @@ class TestHedgedRedispatch:
             assert s["hedge_wins"] in (0, 1)
             assert router.slo.summary()["inflight"] == 0
             assert _wait_pages_baseline([h.batcher(0), h.batcher(1)])
+        finally:
+            h.stop()
+
+    def test_hedge_fires_whatever_the_clock_reads(
+            self, small_model, tmp_path, monkeypatch):
+        """slo.now() is monotonic with an arbitrary zero (a machine up for
+        under an hour reads less than the stall below): a dispatch time
+        at or under zero is a dispatch time, "not dispatched" is None."""
+        cfg, params = small_model
+        monkeypatch.setenv("PADDLE_HEDGE_DELAY_S", "0.01")
+        monkeypatch.setenv("PADDLE_RETRY_BUDGET_PCT", "100")
+        t0 = time.perf_counter() - 100.0         # a clock that reads ~100 s
+        monkeypatch.setattr(slo_mod, "now",
+                            lambda: time.perf_counter() - t0)
+        h = _Replicas(tmp_path, cfg, params, n=2)
+        try:
+            router = Router(h.registry)
+            assert all(r.t_dispatch is None
+                       for r in router._requests.values())
+            rid = router.submit(_prompt(19), 12)
+            self._stalled(router, rid)
+            assert router._requests[rid].t_dispatch < 0
+            router.tick()
+            assert router.summary()["hedges"] == 1
+            assert router.wait([rid], timeout=90)[rid]
         finally:
             h.stop()
 

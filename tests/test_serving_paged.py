@@ -108,25 +108,32 @@ class TestPagedEquivalence:
             assert paged == ref, (len(p), m)
             assert dense == ref, (len(p), m)
 
-    def test_eos_retirement_paged(self, small_model):
-        cfg, params = small_model
+    def test_eos_retirement_paged(self, served):
+        """EOS inside a burst: the slot freezes for the burst's remaining
+        steps (its frozen row write lands in its own page, through the
+        per-slot loop or, on the kernel read, paged_kv_scatter)."""
+        cfg, params, read = served
         rng = np.random.RandomState(3)
         prompt = rng.randint(1, cfg.vocab_size, 6).tolist()
         ref = _reference_generate(cfg, params, prompt, 20)
-        eos = ref[2]
-        eng = _engine(cfg, params, eos_id=eos)
+        # the first token past the prefill's that the stream has not
+        # emitted before (random weights repeat themselves)
+        k = next(i for i in range(2, 20) if ref[i] not in ref[:i])
+        eng = _engine(cfg, params, eos_id=ref[k])
+        assert eng.stats["kv_read"] == read
         rid = eng.add_request(prompt, max_new_tokens=20)
         out = eng.run()
-        assert out[rid] == ref[:3]
+        assert out[rid] == ref[:k + 1]
         # pages freed with the slot: pool is empty again
         assert eng.pages_in_use == 0
 
-    def test_slot_and_page_reuse_after_retire(self, small_model):
+    def test_slot_and_page_reuse_after_retire(self, served):
         """One slot forces full reuse; the second prompt is shorter, so its
         block table must not expose the previous occupant's pages."""
-        cfg, params = small_model
+        cfg, params, read = served
         rng = np.random.RandomState(7)
         eng = _engine(cfg, params, max_batch=1)
+        assert eng.stats["kv_read"] == read
         long_p = rng.randint(1, cfg.vocab_size, 30).tolist()
         short_p = rng.randint(1, cfg.vocab_size, 4).tolist()
         r1 = eng.add_request(long_p, max_new_tokens=8)
@@ -255,16 +262,27 @@ class TestExecutableInventory:
 
 
 # ------------------------------------------------------------------ chaos
+def _chaos_engine(layout, small_model, wide_model, **kw):
+    """An engine for a chaos case: ``"kernel"`` is the default layout on
+    conftest's wide_model, whose bursts read through the decode kernel and
+    write through paged_kv_scatter."""
+    cfg, params = wide_model if layout == "kernel" else small_model
+    eng = _engine(cfg, params, **kw,
+                  kv_layout="dense" if layout == "dense" else "paged")
+    assert eng.stats["kv_read"] == ("gather" if layout == "paged"
+                                    else layout)
+    return cfg, params, eng
+
+
 class TestServingChaos:
-    @pytest.mark.parametrize("layout", ["paged", "dense"])
+    @pytest.mark.parametrize("layout", ["paged", "kernel", "dense"])
     def test_admit_fault_retires_request_not_scheduler(self, small_model,
-                                                       layout):
+                                                       wide_model, layout):
         """serve.admit:1 — the FIRST admission faults: that request
         finishes with empty (partial) output; every other request is
         exact; the queue fully drains."""
-        cfg, params = small_model
+        cfg, params, eng = _chaos_engine(layout, small_model, wide_model)
         reqs = _mixed_requests(cfg, 51, [(6, 5), (10, 7), (15, 4)])
-        eng = _engine(cfg, params, kv_layout=layout)
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         with chaos.inject("serve.admit:1"):
             out = eng.run()
@@ -273,18 +291,19 @@ class TestServingChaos:
         assert eng.stats["chaos_retired"] == 1
         for rid, (p, m) in zip(rids[1:], reqs[1:]):
             assert out[rid] == _reference_generate(cfg, params, p, m)
-        if layout == "paged":
+        if layout != "dense":
             assert eng.pages_in_use == 0
 
-    @pytest.mark.parametrize("layout", ["paged", "dense"])
+    @pytest.mark.parametrize("layout", ["paged", "kernel", "dense"])
     def test_burst_fault_retires_active_with_partial_output(self, small_model,
+                                                            wide_model,
                                                             layout):
         """serve.burst:1 — the first burst faults: the active requests
         retire with whatever tokens they have (at least the prefill
         token), later requests serve exactly, nothing wedges."""
-        cfg, params = small_model
+        cfg, params, eng = _chaos_engine(layout, small_model, wide_model,
+                                         max_batch=2)
         reqs = _mixed_requests(cfg, 53, [(6, 8), (10, 8), (15, 5), (8, 6)])
-        eng = _engine(cfg, params, max_batch=2, kv_layout=layout)
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         with chaos.inject("serve.burst:1"):
             out = eng.run()
@@ -298,7 +317,7 @@ class TestServingChaos:
             assert out[rid] == ref[:len(out[rid])], rid
             exact += out[rid] == ref
         assert exact >= 1
-        if layout == "paged":
+        if layout != "dense":
             assert eng.pages_in_use == 0
 
 

@@ -319,8 +319,7 @@ def test_every_pallas_call_has_a_name():
             found[names[0]] = fn
     assert set(found) == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
-        "ragged_paged_attention", "paged_decode_attention",
-        "paged_kv_scatter", "block_sparse_fwd", "block_sparse_bwd_dq",
+        "paged_decode_attention", "paged_kv_scatter", "block_sparse_fwd", "block_sparse_bwd_dq",
         "block_sparse_bwd_dkv"}
 
 
@@ -365,8 +364,8 @@ def test_record_event_goes_through_the_span_api(monkeypatch):
 class TestEngineSpans:
     LENS = (5, 9, 12, 7, 3)
 
-    def _run(self, new_tokens=6, **kw):
-        cfg, params = _tiny()
+    def _run(self, new_tokens=6, model=None, **kw):
+        cfg, params = model or _tiny()
         eng = _engine(cfg, params, **kw)
         for p in _prompts(cfg, self.LENS):
             eng.add_request(p, max_new_tokens=new_tokens)
@@ -374,9 +373,15 @@ class TestEngineSpans:
         out = eng.run()
         return eng, out, spans.records(since=seq0)
 
-    @pytest.mark.parametrize("layout", ["paged", "ragged", "dense"])
-    def test_children_tile_the_step(self, layout):
-        eng, out, recs = self._run(kv_layout=layout)
+    @pytest.mark.parametrize("layout,read", [
+        ("paged", "gather"), ("paged", "kernel"), ("dense", "dense")],
+        ids=["paged", "kernel", "dense"])
+    def test_children_tile_the_step(self, wide_model, layout, read):
+        eng, out, recs = self._run(
+            kv_layout=layout, model=wide_model if read == "kernel" else None)
+        assert eng.stats["kv_read"] == read
+        assert all(r.args["kv_read"] == read for r in recs
+                   if r.name == "serve.dispatch_burst")
         steps = [r for r in recs if r.name == "serve.step"]
         assert steps and all(len(t) == 6 for t in out.values())
         phases = ("serve.dispatch_burst", "serve.admit", "serve.readback",

@@ -6,7 +6,8 @@ The contracts under test:
     reject, eos/limit freeze mid-segment.
   * PARITY — a spec-enabled ContinuousBatcher is token-identical to the
     plain engine and to per-request ``llama_generate`` at temperature 0
-    on BOTH read paths (gather and ragged), across staggered admission,
+    on BOTH reads of the default layout (the gather, and the decode kernel
+    on ``conftest.wide_model``), across staggered admission,
     mid-flight preemption, and prefix-cache-shared pages (the verify
     write COWs a shared tail page, never truncates it in place).
   * THROUGHPUT SHAPE — the self-draft (draft == target) accepts 100%
@@ -42,8 +43,8 @@ from paddle_tpu.models.llama_decode import llama_generate
 @pytest.fixture(scope="module")
 def small_model():
     # same config/params/engine geometry as tests/test_ragged_attention.py
-    # so the gather/dense/generate/ragged executables are shared across
-    # files — only the draft and verify executables are new compiles here
+    # so the gather/dense/generate executables are shared across files —
+    # only the draft and verify executables are new compiles here
     cfg = LlamaConfig.tiny(num_hidden_layers=2, max_position_embeddings=128)
     params = llama_init_params(cfg, jax.random.PRNGKey(3))
     return cfg, params
@@ -140,35 +141,35 @@ class TestDraftModel:
 class TestSpecServingParity:
     SPEC = [(5, 7), (13, 3), (29, 12), (8, 1), (20, 6), (11, 9), (4, 8)]
 
-    @pytest.mark.parametrize("layout", ["ragged", "paged"])
-    def test_spec_matches_plain_and_generate(self, small_model, layout):
+    def test_spec_matches_plain_and_generate(self, served):
         """7 mixed requests through 3 slots with a REAL (weaker,
         1-layer) draft: rejections and corrections happen, tokens don't
         change — spec == plain == llama_generate."""
-        cfg, params = small_model
+        cfg, params, read = served
         reqs = _mixed_requests(cfg, 11, self.SPEC)
-        eng = _engine(cfg, params, kv_layout=layout, spec_decode=True,
-                      spec_k=3, spec_draft_layers=1)
-        assert eng._spec is not None
+        eng = _engine(cfg, params, spec_decode=True, spec_k=3,
+                      spec_draft_layers=1)
+        assert eng._spec is not None and eng.stats["kv_read"] == read
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run()
         assert eng.stats.get("spec_steps", 0) >= 1
         for rid, (p, m) in zip(rids, reqs):
             assert out[rid] == _reference_generate(cfg, params, p, m), \
-                (layout, len(p), m)
+                (read, len(p), m)
+        assert eng.stats["spec_accepted"] < eng.stats["spec_proposed"]
         assert eng.pages_in_use == 0
         assert eng.admin_summary()["spec"]["k"] == 3
 
-    def test_self_draft_full_accept(self, small_model):
+    def test_self_draft_full_accept(self, served):
         """draft == target proposes exactly the target's continuation:
         acceptance is 100% deterministically and every verify launch
         emits its whole segment — tokens per (slot, launch) > 1, the
         speculation win in launch units."""
-        cfg, params = small_model
+        cfg, params, read = served
         reqs = _mixed_requests(cfg, 23, [(6, 12), (9, 16), (14, 10)])
-        eng = _engine(cfg, params, kv_layout="ragged", spec_decode=True,
-                      spec_k=3,
+        eng = _engine(cfg, params, spec_decode=True, spec_k=3,
                       spec_draft_layers=cfg.num_hidden_layers)
+        assert eng.stats["kv_read"] == read
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run()
         st = eng.stats
@@ -178,15 +179,15 @@ class TestSpecServingParity:
         for rid, (p, m) in zip(rids, reqs):
             assert out[rid] == _reference_generate(cfg, params, p, m)
 
-    @pytest.mark.parametrize("layout", ["ragged", "paged"])
-    def test_midflight_preemption_is_exact(self, small_model, layout):
+    def test_midflight_preemption_is_exact(self, served):
         """Pool runs dry mid-flight under speculation: youngest slot
         preempted back to the queue (draft state invalidated with it),
         output still exact."""
-        cfg, params = small_model
+        cfg, params, read = served
         reqs = _mixed_requests(cfg, 37, [(5, 30), (5, 30)])
-        eng = _engine(cfg, params, num_pages=8, burst=8, kv_layout=layout,
-                      spec_decode=True, spec_k=3, spec_draft_layers=1)
+        eng = _engine(cfg, params, num_pages=8, burst=8, spec_decode=True,
+                      spec_k=3, spec_draft_layers=1)
+        assert eng.stats["kv_read"] == read
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run()
         assert eng.stats["preemptions"] >= 1
@@ -195,20 +196,19 @@ class TestSpecServingParity:
             assert out[rid] == _reference_generate(cfg, params, p, m)
         assert eng.pages_in_use == 0
 
-    @pytest.mark.parametrize("layout", ["ragged", "paged"])
-    def test_cow_on_prefix_shared_page(self, small_model, layout):
+    def test_cow_on_prefix_shared_page(self, served):
         """The reject-on-COW-shared-page case: a full-prefix cache hit
         resumes decode INSIDE a shared tail page, so the verify's first
         write would land in a page other holders map — the growth sweep
         copies it private first (cow_copies moves), the cache entry
         survives, and a THIRD serve of the same prompt still hits.
         Tokens exact throughout, including the rejected-tail rewind."""
-        cfg, params = small_model
+        cfg, params, read = served
         rng = np.random.RandomState(61)
         prompt = rng.randint(1, cfg.vocab_size, 16).tolist()  # 2 pages
-        eng = _engine(cfg, params, kv_layout=layout, spec_decode=True,
-                      spec_k=3, spec_draft_layers=1,
-                      prefix_cache_pages=16)
+        eng = _engine(cfg, params, spec_decode=True, spec_k=3,
+                      spec_draft_layers=1, prefix_cache_pages=16)
+        assert eng.stats["kv_read"] == read
         ref = _reference_generate(cfg, params, prompt, 8)
         r1 = eng.add_request(prompt, max_new_tokens=8)
         assert eng.run()[r1] == ref
@@ -229,9 +229,9 @@ class TestSpecServingParity:
         reqs = _mixed_requests(cfg, 43, [(6, 8), (12, 6), (9, 10)])
         outs = {}
         for spec_on in (False, True):
-            eng = _engine(cfg, params, kv_layout="ragged",
-                          kv_dtype="int8", spec_decode=spec_on,
-                          spec_k=3, spec_draft_layers=1)
+            eng = _engine(cfg, params, kv_dtype="int8",
+                          spec_decode=spec_on, spec_k=3,
+                          spec_draft_layers=1)
             rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
             out = eng.run()
             outs[spec_on] = [out[r] for r in rids]
@@ -250,14 +250,12 @@ class TestSpecGates:
 
     def test_temperature_degrades_silently(self, small_model):
         cfg, params = small_model
-        eng = _engine(cfg, params, kv_layout="ragged", temperature=0.7,
-                      spec_decode=True)
+        eng = _engine(cfg, params, temperature=0.7, spec_decode=True)
         assert eng._spec is None
 
     def test_bad_k_degrades_silently(self, small_model):
         cfg, params = small_model
-        eng = _engine(cfg, params, kv_layout="ragged", spec_decode=True,
-                      spec_k=0)
+        eng = _engine(cfg, params, spec_decode=True, spec_k=0)
         assert eng._spec is None
 
     def test_env_flag_enables(self, small_model, monkeypatch):
@@ -265,11 +263,11 @@ class TestSpecGates:
         monkeypatch.setenv("PADDLE_SPEC_DECODE", "1")
         monkeypatch.setenv("PADDLE_SPEC_K", "2")
         monkeypatch.setenv("PADDLE_SPEC_DRAFT_LAYERS", "1")
-        eng = _engine(cfg, params, kv_layout="ragged")
+        eng = _engine(cfg, params)
         assert eng._spec is not None and eng._spec.k == 2
         assert eng._spec.draft_layers == 1
         monkeypatch.setenv("PADDLE_SPEC_DECODE", "0")
-        assert _engine(cfg, params, kv_layout="ragged")._spec is None
+        assert _engine(cfg, params)._spec is None
 
 
 # -------------------------------------------------------------- inventory
@@ -277,9 +275,9 @@ class TestSpecExecutableInventory:
     def test_verify_is_one_executable(self):
         """COLD config (unique to this test): a whole spec serve with
         mixed prompt lengths, budgets, limit-capped tails, full accepts
-        and rejections compiles at most ONE verify and ONE draft-burst
-        executable on the ragged path — per-slot proposal counts ride in
-        traced q_lens, not shapes (the no-per-k-bucket-grid bound)."""
+        and rejections compiles at most ONE verify executable a page
+        bucket and ONE draft burst — per-slot proposal counts ride in the
+        traced n_tok, not shapes (the no-per-k-bucket-grid bound)."""
         from paddle_tpu.inference.speculative import draft_spec_burst
         from paddle_tpu.models.llama_paged import llama_paged_verify
         cfg = LlamaConfig.tiny(num_hidden_layers=2, vocab_size=249,
@@ -290,17 +288,18 @@ class TestSpecExecutableInventory:
                                          (5, 12)])
         v0 = llama_paged_verify._cache_size()
         d0 = draft_spec_burst._cache_size()
-        eng = _engine(cfg, params, kv_layout="ragged", spec_decode=True,
-                      spec_k=3, spec_draft_layers=1)
+        eng = _engine(cfg, params, spec_decode=True, spec_k=3,
+                      spec_draft_layers=1)
         rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
         out = eng.run()
         assert eng.stats.get("spec_steps", 0) >= 2
-        assert llama_paged_verify._cache_size() - v0 <= 1
+        assert llama_paged_verify._cache_size() - v0 \
+            <= len(eng._page_buckets)
         assert draft_spec_burst._cache_size() - d0 <= 1
         # a second engine, same config+k: everything is already compiled
         v1 = llama_paged_verify._cache_size()
-        eng2 = _engine(cfg, params, kv_layout="ragged", spec_decode=True,
-                      spec_k=3, spec_draft_layers=1)
+        eng2 = _engine(cfg, params, spec_decode=True, spec_k=3,
+                       spec_draft_layers=1)
         r2 = [eng2.add_request(p, max_new_tokens=m) for p, m in reqs]
         out2 = eng2.run()
         assert llama_paged_verify._cache_size() == v1
@@ -309,17 +308,17 @@ class TestSpecExecutableInventory:
 
 # ------------------------------------------------------------------ chaos
 class TestSpecChaos:
-    @pytest.mark.parametrize("layout", ["ragged", "paged"])
-    def test_chaos_on_equals_fault_free(self, small_model, layout):
+    def test_chaos_on_equals_fault_free(self, served):
         """serve.spec_verify faulted: that burst serves through the
         plain path — degraded throughput, identical tokens, fallback
         counted, scheduler never wedges."""
-        cfg, params = small_model
+        cfg, params, read = served
         reqs = _mixed_requests(cfg, 51, [(6, 8), (12, 6), (9, 10)])
 
         def serve(chaos_spec):
-            eng = _engine(cfg, params, kv_layout=layout, spec_decode=True,
-                          spec_k=3, spec_draft_layers=1)
+            eng = _engine(cfg, params, spec_decode=True, spec_k=3,
+                          spec_draft_layers=1)
+            assert eng.stats["kv_read"] == read
             rids = [eng.add_request(p, max_new_tokens=m) for p, m in reqs]
             if chaos_spec:
                 with chaos.inject(chaos_spec):

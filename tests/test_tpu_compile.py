@@ -1,9 +1,11 @@
 """The compiler's verdict on the main path's kernels, without a chip.
 
 The TPU compiler is installed here and compiles for a chip that is
-described, not attached (on-chip-measurement guide, section 2). Every case
-compiles one kernel at the real shapes of ``chip_smoke.py`` — Llama-2-7B
-widths: 32 heads x 128, bf16 — for one described v5e chip, in this
+described, not attached (on-chip-measurement guide, section 2). The kernel
+cases compile one kernel at the real shapes of ``chip_smoke.py`` —
+Llama-2-7B widths: 32 heads x 128, bf16 — or of a benchmark cell, the
+program cases the two paged programs of each serving cell at one layer
+with the kernels inside them, for one described v5e chip, in this
 process. A compile that passes is not a chip run; it guards every later
 PR against what interpret mode cannot see (tiling, alignment, VMEM).
 
@@ -34,31 +36,7 @@ PREFILL_BUCKETS = cs.SERVE_PROMPT_BUCKETS
 from tools.flash_microbench import GEOMETRIES as CELL_GEOMETRIES  # noqa: E402
 SERVE_BATCH, SERVE_MAX_LEN = cs.SERVE_MAX_BATCH, cs.SERVE_MAX_LEN
 
-# the ragged kernel's q_rows > 1 body at the serve phase's geometry
-# (page_size 16 is the batcher's default), then one case past each rule of
-# ra.supported(): the value is the compiler's message, None where it
-# compiles. At q_rows == 1 the public entry takes the decode body
-# (DECODE_CASES below) unless the pool is quantized; 2 rows are the
-# smallest launch of this body (a speculative verify of one proposal)
-_SERVE = dict(q_rows=2, kv_heads=HEADS, head_dim=HEAD_DIM, page_size=16,
-              kv_dtype=None)
-RAGGED_CASES = {
-    "verify": (_SERVE, None),
-    "prefill": ({**_SERVE, "q_rows": PREFILL_BUCKETS[-1]}, None),
-    "verify_gqa": ({**_SERVE, "kv_heads": 8}, None),
-    "verify_kv_heads_4": ({**_SERVE, "kv_heads": 4}, None),
-    "head_dim_64": ({**_SERVE, "head_dim": 64},
-                    "Slice shape along dimension 3 must be aligned to "
-                    "tiling (128), but is 64"),
-    "kv_heads_12": ({**_SERVE, "kv_heads": 12},
-                    "Slice shape along dimension 2 must be aligned to "
-                    "tiling (8), but is 12"),
-    "int8_pages": ({**_SERVE, "q_rows": 1, "kv_dtype": "int8"},
-                   "Slice shape along dimension 2 must be aligned to "
-                   "tiling (128), but is 16"),
-}
-
-# the decode body (q_rows == 1, ISSUE 28) at the batch cell's geometry
+# the decode read (ISSUE 28) at the batch cell's geometry
 # (perfbench/traffic/longctx-batch.json: 48 slots, a 128-page table, pages
 # of 16; InternLM2-1.8B: 8 KV heads x 128, bf16), at chip_smoke's, and one
 # case past each rule of ra.decode_supported()
@@ -211,46 +189,6 @@ class TestFlashCompiles:
         assert _kernels(c) == 3
 
 
-def _ragged_lowered(sharding, q_rows, kv_heads, head_dim, page_size, kv_dtype):
-    max_pages = SERVE_MAX_LEN // page_size
-    pool_pages = SERVE_BATCH * max_pages + 1
-    q_heads = max(HEADS // kv_heads, 1) * kv_heads
-
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    pool = sds((pool_pages, page_size, kv_heads, head_dim),
-               jnp.bfloat16 if kv_dtype is None else jnp.dtype(kv_dtype))
-    scales = {}
-    if kv_dtype is not None:
-        scale = sds((pool_pages, page_size, kv_heads), jnp.float32)
-        scales = dict(k_scale=scale, v_scale=scale)
-    lens = sds((SERVE_BATCH,), jnp.int32)
-    return ra.ragged_paged_attention.lower(
-        sds((SERVE_BATCH, q_rows, q_heads, head_dim), jnp.bfloat16), pool,
-        pool, sds((SERVE_BATCH, max_pages), jnp.int32), lens, lens,
-        page_size=page_size, interpret=False, **scales)
-
-
-@pytest.mark.parametrize("case", sorted(RAGGED_CASES))
-def test_ragged_kernel_verdict(one_chip, no_compile_cache, case):
-    """The compiler's verdict on the ragged kernel, and supported() saying
-    the same: what it accepts compiles to one kernel, what it refuses is
-    refused with the message supported() records."""
-    geometry, refusal = RAGGED_CASES[case]
-    lowered = _ragged_lowered(one_chip, **geometry)
-    says = ra.supported(geometry["head_dim"], geometry["kv_heads"],
-                        SERVE_MAX_LEN, interpret=False,
-                        kv_dtype=geometry["kv_dtype"])
-    if refusal is None:
-        assert _kernels(lowered.compile()) == 1 and says
-        return
-    with pytest.raises(Exception) as err:
-        lowered.compile()
-    assert refusal in str(err.value) and not says
-    assert refusal in " ".join(ra.supported.__doc__.split())
-
-
 def _shapes(sharding, dtype="bfloat16"):
     """shape -> ShapeDtypeStruct on `sharding`, `dtype` unless one is given."""
     return lambda shape, dt=dtype: jax.ShapeDtypeStruct(
@@ -262,15 +200,15 @@ def _decode_lowered(sharding, slots, table_pages, page_size, kv_heads,
     sds = _shapes(sharding, dtype)
     pool = sds((slots * table_pages + 1, page_size, kv_heads, head_dim))
     lens = sds((slots,), "int32")
-    return jax.jit(lambda *a: ra._decode_attention(*a, False)).lower(
+    return ra.paged_decode_attention.lower(
         sds((slots, 1, q_heads, head_dim)), pool, pool,
-        sds((slots, table_pages), "int32"), lens, lens)
+        sds((slots, table_pages), "int32"), lens, lens, interpret=False)
 
 
 @pytest.mark.parametrize("case", sorted(DECODE_CASES))
-def test_decode_body_verdict(one_chip, no_compile_cache, case):
-    """The compiler's verdict on the decode body, and decode_supported()
-    saying the same; the body holds two chunks whatever the table's
+def test_decode_read_verdict(one_chip, no_compile_cache, case):
+    """The compiler's verdict on the decode read, and decode_supported()
+    saying the same; its body holds two chunks whatever the table's
     width, so no case knows a max_len."""
     geometry, refusal = DECODE_CASES[case]
     lowered = _decode_lowered(one_chip, **geometry)
@@ -285,20 +223,19 @@ def test_decode_body_verdict(one_chip, no_compile_cache, case):
     assert refusal in " ".join(ra.decode_supported.__doc__.split())
 
 
-def test_public_entry_takes_the_decode_body_at_the_cell(one_chip,
-                                                        no_compile_cache):
-    """ragged_paged_attention at q_rows == 1 and the cell's geometry IS
-    the decode body: the kernel's name in the compiled program, and the
-    pool's [pages, rows, heads, hd] -> [pages, rows * heads, hd] view a
-    bitcast, not a copy of the pool."""
+def test_decode_read_at_the_cell_is_one_named_kernel(one_chip,
+                                                     no_compile_cache):
+    """paged_decode_attention at the cell's geometry: the kernel's name in
+    the compiled program, and the pool's [pages, rows, heads, hd] ->
+    [pages, rows * heads, hd] view a bitcast, not a copy of the pool."""
     g = _CELL
     sds = _shapes(one_chip)
     pool = sds((4096, g["page_size"], g["kv_heads"], g["head_dim"]))
     lens = sds((g["slots"],), "int32")
-    text = ra.ragged_paged_attention.lower(
+    text = ra.paged_decode_attention.lower(
         sds((g["slots"], 1, g["q_heads"], g["head_dim"])), pool, pool,
         sds((g["slots"], g["table_pages"]), "int32"), lens, lens,
-        page_size=g["page_size"], interpret=False).compile().as_text()
+        interpret=False).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == 1 and "paged_decode_attention" in calls[0]
     assert not [ln for ln in text.splitlines()
@@ -359,9 +296,9 @@ def test_pool_of_30_kv_heads_is_padded_to_whole_tiles(one_chip,
 
     def text(heads):
         pool = sds((1024, 16, heads, 128))
-        return ra.ragged_paged_attention.lower(
+        return ra.paged_decode_attention.lower(
             sds((24, 1, heads, 128)), pool, pool, sds((24, 216), "int32"),
-            lens, lens, page_size=16, interpret=False).compile().as_text()
+            lens, lens, interpret=False).compile().as_text()
 
     assert _pool_copies(text(30), 1024)                     # why it is padded
     assert not _pool_copies(text(32), 1024)
@@ -387,3 +324,85 @@ def test_gated_delta_rule_compiles_at_the_cells_shapes(one_chip,
         sds((T, H), "float32"), sds((T, H), "float32"),
         sds((H, dv, dk), "float32"), sds((), "int32")).compile()
     assert scan.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+# ------------------- the serving cells' two programs, the kernels inside them
+
+def _cell_model(workload):
+    """(one layer of the cell's model as the program's LlamaConfig, the
+    cell's engine settings): the widths from the benchmark's own files
+    through its own family modules, so that they cannot drift. The hybrid's
+    one layer is a FULL layer: 30 KV heads, a pool padded to 32."""
+    import dataclasses
+    from paddle_tpu.inference.replica import _spec_config
+    from perfbench import harness
+    from perfbench.families import llama, olmo_hybrid
+    cell = harness.load_cell(workload, rehearse=False)
+    cfg, settings = cell["cfg"], cell["traffic"]["engine"]
+    if cfg["family"] == "llama":
+        model = llama.llama_config(cfg, settings["max_len"])
+        kinds = None
+    else:
+        model = _spec_config({"config": olmo_hybrid.model_spec(
+            cfg, settings["max_len"])})
+        kinds = (model.FULL,)
+    return dataclasses.replace(model, num_hidden_layers=1,
+                               layer_types=kinds), settings
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_slot"])
+@pytest.mark.parametrize("workload", ["internlm2-1.8b.longctx-batch",
+                                      "olmo-hybrid-7b.longdoc-batch"])
+def test_paged_program_of_a_cell_holds_its_kernels(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch, workload,
+                                                   program):
+    """What both serving cells run, compiled whole for a described v5e at
+    one layer of the cell's widths, slots, page bucket, pool and longest
+    prompt bucket: ``llama_paged_decode_burst`` holds the decode read and
+    the one-launch row write, ``llama_paged_prefill_slot`` the flash
+    forward and the page write. Kernels that compile alone have been
+    refused inside a program (a pool view that became a copy, PR 30). The
+    flash kernel's platform gate asks jax.default_backend(), which is this
+    CPU: steered here, in the test."""
+    monkeypatch.setattr(fa, "flash_attention_tpu_available", lambda: True)
+    from paddle_tpu.inference.paging import pages_for_budget
+    from paddle_tpu.models import llama_init_params
+    from paddle_tpu.models.llama_paged import (
+        init_paged_kv_cache, llama_paged_decode_burst,
+        llama_paged_prefill_slot, page_bytes, paged_kv_read)
+    cfg, eng = _cell_model(workload)
+    ps, B = eng["page_size"], eng["max_batch"]
+    assert paged_kv_read(cfg, ps) == "kernel"
+    # the cell's pool: the pages its byte budget buys one layer of
+    pages = pages_for_budget(eng["pool_hbm_bytes"], page_bytes(cfg, ps))
+    pages = min(pages, B * eng["max_len"] // ps + 1)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: llama_init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, pages, ps, max_batch=B)))
+    sds = _shapes(one_chip, "int32")
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    if program == "decode_burst":
+        text = llama_paged_decode_burst.lower(
+            params, cache, sds((B, eng["page_buckets"][-1])), sds((B,)),
+            sds((B,)), sds((B,), "bool"), sds((B,)), sds(()), key,
+            config=cfg, n=eng["burst"], kv_read="kernel",
+            interpret=False).compile().as_text()
+        want = ("paged_decode_attention", "paged_kv_scatter")
+    else:
+        bucket = eng["prompt_buckets"][-1]
+        text = llama_paged_prefill_slot.lower(
+            params, cache, sds((bucket,)), sds((bucket // ps,)), sds(()),
+            key, config=cfg, kv_read="kernel",
+            interpret=False).compile().as_text()
+        want = ("flash_fwd", "paged_kv_scatter")
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name in want:
+        assert [ln for ln in calls if name in ln], (name, len(calls))
+    assert not _pool_copies(text, pages)
