@@ -10,13 +10,15 @@ bucket's width. The two kernels here are what a decode step of the default
 (``llama_paged.paged_kv_read`` decides, from ``decode_supported``).
 
 The read, ``paged_decode_attention`` (ISSUE 28): one query row a slot. One
-program per slot walks the slot's LIVE pages in chunks, the next chunk's
-page copies in flight while the current one is computed, with a running
-max / sum / accumulator in f32 (the flash kernels' online softmax): bytes
-AND compute follow ``ceil(kv_len / page_size)`` pages, VMEM holds two
-chunks whatever the context, and the kernel compiles in seconds at any
-``max_len``. Raggedness rides in scalar-prefetched block tables and lengths,
-not in shapes. A page is read as ``[page_size * KV, head_dim]`` (a bitcast
+grid step per slot walks the slot's LIVE pages in chunks, the next chunk's
+page copies in flight while the current one is computed (since ISSUE 33
+also across slots: under a slot's last chunk the next slot's first one is
+in flight, so the copy pipeline drains once a launch, not once a slot),
+with a running max / sum / accumulator in f32 (the flash kernels' online
+softmax): bytes AND compute follow ``ceil(kv_len / page_size)`` pages, VMEM
+holds two chunks whatever the context, and the kernel compiles in seconds
+at any ``max_len``. Raggedness rides in scalar-prefetched block tables and
+lengths, not in shapes. A page is read as ``[page_size * KV, head_dim]`` (a bitcast
 of the pool: the rows of all KV heads interleaved, as they lie in HBM), ALL
 query heads take one MXU product against the chunk and each keeps its own
 KV head's columns under the mask, so every K and V element passes the MXU
@@ -26,10 +28,14 @@ is f32, probabilities round to the model dtype before probs @ V: the
 arithmetic the configuration states; only the summation order differs from
 the gather's full-width softmax, so the two agree to rounding, not bitwise
 (``tests/test_ragged_attention.py`` holds both to an f32 reference).
-Measured on a v5e at the batch cell's geometry (48 slots, 128-page table,
-contexts 512-2048, bf16; PERF.md, PR 28): 0.45 ms a layer, 73 % of HBM
-bandwidth over the live pages; the fill-mode gather + masked attention took
-4.30 ms.
+Measured on a v5e by ``tools/paged_decode_microbench.py`` (PERF.md, PR 33)
+at the batch cell's geometry (48 slots, 128-page table, contexts 512-2048,
+bf16): 0.354 / 0.365 ms a layer on two draws of contexts, 85 / 86 % of HBM
+bandwidth over the live rows (0.393 / 0.407 ms, 77 %, while every slot
+started its copies cold; 92 % where every context is whole chunks); at the
+hybrid cell's (24 slots, 216-page table, 32 KV heads, contexts 1024-3456)
+91 % (90 %). The fill-mode gather + masked attention took 4.30 ms at the
+batch cell's geometry (PERF.md, PR 28).
 
 The write beside the read (ISSUE 28): ``paged_kv_scatter`` puts a decode
 step's fresh K/V rows (or a prefill's pages) into the pool with ONE launch
@@ -65,9 +71,14 @@ _i0 = np.int32(0)
 
 # flat rows ([row, kv-head] pairs) of one chunk of the decode body: the
 # logits of all query heads against a chunk are [H, _DECODE_CHUNK_ROWS] f32
-# (32 vregs at 16 heads). Measured on a v5e at the batch cell's geometry
-# (PERF.md, PR 28): 512 flat rows a chunk 0.64 ms a layer, 1024 0.48 ms,
-# 2048 0.45 ms
+# (32 vregs at 16 heads). Measured on a v5e with the copies chained across
+# slots (tools/paged_decode_microbench.py; PERF.md, PR 33), 512 / 1024 /
+# 2048 / 4096 flat rows a chunk: 0.551 / 0.403 / 0.354 / 0.356 ms a layer at
+# the batch cell's geometry, 2.06 / 1.48 / 1.29 / 1.29 ms at the hybrid
+# cell's. A chunk costs the same scalar work whatever its size (a
+# predicated start and wait a page copy), so fewer, larger chunks win until
+# the half-empty last one and the logits' registers take it back: one size
+# for both pools
 _DECODE_CHUNK_ROWS = 2048
 
 
@@ -96,23 +107,36 @@ def decode_supported(head_dim: int, kv_heads: int, page_size: int,
 
 
 def _decode_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
-                 kbuf, vbuf, sem, *, page_size, kv_heads, groups,
+                 kbuf, vbuf, sem, chain, *, page_size, kv_heads, groups,
                  chunk_pages, table_pages, scale):
-    """One slot's decode row against its live pages.
+    """One slot's decode row against its live pages; the slots in order.
 
     Scalar prefetch (SMEM): bt_ref [B, P], qlen_ref / kvlen_ref [B]. q_ref
     / o_ref block [1, H, hd] (head h = kv_head * groups + gi, the gather
     path's order). kp/vp_ref: the WHOLE pool in HBM viewed as [num_pages,
     page_size * KV, hd]: flat row f of a page is (row f // KV, kv-head
     f % KV). kbuf/vbuf [2, chunk_pages * page_size * KV, hd]: two chunks.
+    chain (SMEM scratch, carried from slot to slot): [the buffer half the
+    next chunk lands in, whether the slot before started this slot's
+    first chunk].
 
-    Per chunk: only the slot's live pages are copied (the next chunk's
-    copies start before this one is awaited); logits = q @ chunk^T for all
-    H heads at once, [H, flat rows] f32; a head keeps the columns of its
-    own KV head at rows < kv_len (``-1e30`` elsewhere, which underflows to
-    an exact zero probability); running max / sum / accumulator in f32.
-    Rows the copies did not write are stale VMEM: their probabilities are
-    exact zeros, but 0 * NaN is NaN, so the V rows themselves are zeroed.
+    Per chunk: only the slot's live pages are copied; logits = q @ chunk^T
+    for all H heads at once, [H, flat rows] f32; a head keeps the columns
+    of its own KV head at rows < kv_len (``-1e30`` elsewhere, which
+    underflows to an exact zero probability); running max / sum /
+    accumulator in f32. Rows the copies did not write are stale VMEM:
+    their probabilities are exact zeros, but 0 * NaN is NaN, so the V rows
+    themselves are zeroed.
+
+    The copies run one chunk ahead of the products ACROSS slots (ISSUE
+    33): before a chunk is awaited, the slot's next chunk is started into
+    the other half, and under a slot's LAST chunk, chunk 0 of slot b + 1.
+    So the half of a chunk is the launch's running chunk count, not the
+    slot's own. A slot without context (kv_len 0, or q_len 0: it reads
+    nothing) has no chunks: it is handed none and starts none, and the
+    slot after it starts cold, as the first slot does. Every started copy
+    is awaited by the slot that computes it, and the last slot starts
+    nothing it does not compute.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -126,43 +150,59 @@ def _decode_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
     flat = CP * page_rows                # flat rows of one chunk
     chunk_rows = CP * ps                 # context rows of one chunk
     b = pl.program_id(0)
-    kv_len = kvlen_ref[b]
-    n_pages = (kv_len + i32(ps - 1)) // i32(ps)
-    n_chunks = (kv_len + i32(chunk_rows - 1)) // i32(chunk_rows)
 
-    def page_copies(c, half):
-        """(is the page live, its K copy, its V copy) for chunk c."""
+    def context(slot):
+        """Rows slot ``slot`` reads in this launch."""
+        return jnp.where(qlen_ref[slot] > 0, kvlen_ref[slot], i32(0))
+
+    kv_len = context(b)
+    n_chunks = (kv_len + i32(chunk_rows - 1)) // i32(chunk_rows)
+    last = pl.num_programs(0) - i32(1)
+    nxt = jnp.minimum(b + i32(1), last)
+    next_len = jnp.where(b < last, context(nxt), i32(0))
+
+    @pl.when(b == 0)
+    def _unchained():
+        chain[0] = i32(0)
+        chain[1] = i32(0)
+
+    first_half, handed = chain[0], chain[1]
+
+    def page_copies(slot, rows, c, half):
+        """(is the page live, its K copy, its V copy) for chunk c of a
+        slot that reads ``rows`` rows."""
+        n_pages = (rows + i32(ps - 1)) // i32(ps)
         out = []
         for j in range(CP):
             pg = c * i32(CP) + i32(j)
-            page = bt_ref[b, jnp.minimum(pg, i32(table_pages - 1))]
-            rows = pl.ds(j * page_rows, page_rows)
+            page = bt_ref[slot, jnp.minimum(pg, i32(table_pages - 1))]
+            at = pl.ds(j * page_rows, page_rows)
             out.append((pg < n_pages,
                         pltpu.make_async_copy(kp_ref.at[page],
-                                              kbuf.at[half, rows],
+                                              kbuf.at[half, at],
                                               sem.at[half, i32(0)]),
                         pltpu.make_async_copy(vp_ref.at[page],
-                                              vbuf.at[half, rows],
+                                              vbuf.at[half, at],
                                               sem.at[half, i32(1)])))
         return out
 
-    def start(c, half):
-        for live, kc, vc in page_copies(c, half):
+    def start(slot, rows, c, half):
+        for live, kc, vc in page_copies(slot, rows, c, half):
             @pl.when(live)
             def _go():
                 kc.start()
                 vc.start()
 
-    def wait(c, half):
-        for live, kc, vc in page_copies(c, half):
+    def wait(slot, rows, c, half):
+        for live, kc, vc in page_copies(slot, rows, c, half):
             @pl.when(live)
             def _done():
                 kc.wait()
                 vc.wait()
 
-    @pl.when(n_chunks > 0)
-    def _first():
-        start(i32(0), i32(0))
+    @pl.when((n_chunks > 0) & (handed == 0))
+    def _cold():
+        start(b, kv_len, i32(0), first_half)
 
     # column f of a chunk's logits is (row f // KV, kv-head f % KV); query
     # head h reads kv-head h // groups. The same for every chunk
@@ -178,13 +218,14 @@ def _decode_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
 
     def chunk(c, carry):
         m, l, acc = carry
-        half = c & i32(1)
-
-        @pl.when(c + i32(1) < n_chunks)
-        def _next():
-            start(c + i32(1), i32(1) - half)
-
-        wait(c, half)
+        half = (first_half + c) & i32(1)
+        # in flight under this chunk's products: the slot's next chunk,
+        # or under its last one chunk 0 of the next slot (no page of it
+        # is live where that slot has no context or there is none)
+        more = c + i32(1) < n_chunks
+        start(jnp.where(more, b, nxt), jnp.where(more, kv_len, next_len),
+              jnp.where(more, c + i32(1), i32(0)), i32(1) - half)
+        wait(b, kv_len, c, half)
         left = kv_len - c * i32(chunk_rows)     # live rows from this chunk on
         k, v = kbuf[half], vbuf[half]                          # [flat, hd]
         s = jax.lax.dot_general(
@@ -208,9 +249,11 @@ def _decode_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
         i32(0), n_chunks, chunk,
         (jnp.full((H, 1), -1e30, jnp.float32), jnp.zeros((H, 1), jnp.float32),
          jnp.zeros((H, hd), jnp.float32)))
-    # a slot that takes no query this launch (q_len 0) or has no context
-    # writes zeros, never NaN residue
-    out = jnp.where(qlen_ref[b] > 0, acc / jnp.maximum(l, jnp.float32(1e-30)),
+    chain[0] = (first_half + n_chunks) & i32(1)
+    chain[1] = ((n_chunks > 0) & (next_len > 0)).astype(i32)
+    # a slot without context (no query this launch, or no rows) writes
+    # zeros, never NaN residue
+    out = jnp.where(kv_len > 0, acc / jnp.maximum(l, jnp.float32(1e-30)),
                     jnp.float32(0))
     o_ref[0] = out.astype(o_ref.dtype)
 
@@ -219,7 +262,10 @@ def _decode_body(bt_ref, qlen_ref, kvlen_ref, q_ref, kp_ref, vp_ref, o_ref,
 def paged_decode_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
                            *, interpret: bool):
     """One decode row a slot over a shared page pool: grid over slots,
-    ``_decode_body``.
+    ``_decode_body``. The grid is ``"arbitrary"``: the slots run in order
+    on one core, each handing the next its first chunk in flight, so the
+    order is part of the kernel's contract on any chip (a v5e has one
+    TensorCore; a chip with two would not split this grid).
 
     q           [B, 1, H, hd]: slot b's query at absolute position
                 kv_lens[b] - 1.
@@ -253,12 +299,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, q_lens, kv_lens,
         in_specs=[q_block, hbm, hbm], out_specs=q_block,
         scratch_shapes=[pltpu.VMEM((2, flat, hd), k_pool.dtype),
                         pltpu.VMEM((2, flat, hd), v_pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, 2))])
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((2,), jnp.int32)])
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         compiler_params=(None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel",))),
+            dimension_semantics=("arbitrary",))),
         interpret=interpret,
         name="paged_decode_attention",
     )(block_table.astype(jnp.int32), q_lens.astype(jnp.int32),

@@ -103,6 +103,61 @@ DECODE_CASES = {
     "done_slot_all_scratch": [13, 22],
 }
 
+# the copy pipeline carried across slots (ISSUE 33), at page_size 8, an
+# 8-page table (64 rows), chunks of 2 pages = 16 rows: (q_len, kv_len) per
+# slot. kv_len 1-16 is one chunk, 17-32 two, 33-48 three, 49-64 four; a
+# slot's first chunk lands in the buffer half the chunks before it left
+_CHAIN_PMAX, _CHAIN_KV = 8, 2
+CHAIN_CASES = {
+    "odd_then_even_chunk_counts": [(1, 40), (1, 30), (1, 20), (1, 64)],
+    "even_then_odd_chunk_counts": [(1, 30), (1, 40), (1, 10), (1, 50)],
+    "odd_counts_throughout": [(1, 5), (1, 40), (1, 16), (1, 33)],
+    "one_chunk_slot_between_long_ones": [(1, 64), (1, 9), (1, 60)],
+    "one_chunk_slots_only": [(1, 3), (1, 16), (1, 9), (1, 1)],
+    "no_context_first": [(1, 0), (1, 40), (1, 20)],
+    "no_context_in_the_middle": [(1, 40), (1, 0), (1, 20)],
+    "no_context_last": [(1, 40), (1, 20), (1, 0)],
+    "two_without_context_in_a_row": [(1, 40), (1, 0), (1, 0), (1, 20)],
+    "no_query_first": [(0, 33), (1, 40), (1, 20)],
+    "no_query_in_the_middle": [(1, 40), (0, 33), (1, 20)],
+    "no_query_last": [(1, 40), (1, 20), (0, 33)],
+    "no_query_after_a_one_chunk_slot": [(1, 7), (0, 64), (1, 48)],
+    "exact_multiples_of_a_chunk": [(1, 16), (1, 32), (1, 48), (1, 64)],
+    "every_slot_without_context": [(1, 0), (1, 0), (1, 0)],
+    "every_slot_without_a_query": [(0, 40), (0, 9), (0, 64)],
+    "a_single_slot": [(1, 50)],
+}
+
+
+def _chain_launch(case, dtype, interpret, monkeypatch):
+    """(out, reference, live slots) of one launch of CHAIN_CASES[case]:
+    every slot's pages its own, rows past kv_len in a live page poisoned
+    with NaN; 2 pages a chunk, so the launch itself (no jit cache: the
+    chunk's size is read where the launch is built)."""
+    KV, H, hd = _CHAIN_KV, 4, 128
+    monkeypatch.setattr(ra, "_DECODE_CHUNK_ROWS", 2 * _PS * KV)
+    rng = np.random.RandomState(len(case))
+    q_lens, lens = (np.array(a, np.int32) for a in zip(*CHAIN_CASES[case]))
+    B = len(lens)
+    npool = B * _CHAIN_PMAX + 1
+    kp = rng.randn(npool, _PS, KV, hd).astype(np.float32)
+    vp = rng.randn(npool, _PS, KV, hd).astype(np.float32)
+    bt = rng.permutation(np.arange(1, npool)).reshape(
+        B, _CHAIN_PMAX).astype(np.int32)
+    for b in range(B):
+        if lens[b] % _PS:
+            last = bt[b, (lens[b] - 1) // _PS]
+            kp[last, lens[b] % _PS:] = vp[last, lens[b] % _PS:] = np.nan
+    dt = jnp.dtype(dtype)
+    q = jnp.asarray(rng.randn(B, 1, H, hd), dt)
+    kp, vp = jnp.asarray(kp, dt), jnp.asarray(vp, dt)
+    out = np.asarray(ra.paged_decode_attention.__wrapped__(
+        q, kp, vp, jnp.asarray(bt), jnp.asarray(q_lens), jnp.asarray(lens),
+        interpret=interpret), np.float32)
+    live = (q_lens > 0) & (lens > 0)
+    ref = _full_softmax_reference(q, kp, vp, bt, np.where(live, lens, 1))
+    return out, ref, live
+
 
 class TestPagedKernels:
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -149,6 +204,47 @@ class TestPagedKernels:
         atol = 1e-5 if dtype == "float32" else 3e-2
         np.testing.assert_allclose(out, ref, rtol=0, atol=atol)
 
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", sorted(CHAIN_CASES))
+    def test_chain_across_slots_matches_full_softmax_reference(
+            self, case, dtype, monkeypatch):
+        """The copies run one chunk ahead ACROSS slots (ISSUE 33): a slot's
+        last chunk is computed with the next slot's first in flight, in
+        the buffer half the launch's running chunk count says. Every way a
+        boundary can fall (odd and even chunk counts in both orders, a
+        one-chunk slot, slots without context or without a query first,
+        last and in between, whole chunks, nothing to read at all) against
+        the float32 reference at the tolerances of the test above; a slot
+        that reads nothing returns exact zeros."""
+        out, ref, live = _chain_launch(case, dtype, True, monkeypatch)
+        assert (out[~live] == 0).all()
+        atol = 1e-5 if dtype == "float32" else 3e-2
+        np.testing.assert_allclose(out[live], ref[live], rtol=0, atol=atol)
+
+    @pytest.mark.parametrize("mode", ["eager", "on_wait"])
+    @pytest.mark.parametrize("case", sorted(CHAIN_CASES))
+    def test_chain_pairs_every_started_copy_with_a_wait(self, case, mode,
+                                                        monkeypatch, capfd):
+        """Under the TPU interpreter, which keeps the DMA semaphores'
+        counts and fills fresh VMEM with NaN. ``eager``: a copy signals its
+        semaphore when it is started, so a chunk started twice (by the
+        slot before AND by its own slot) or never awaited leaves a count
+        behind, which the interpreter reports at the kernel's exit.
+        ``on_wait``: a copy moves its bytes only when it is awaited, so a
+        chunk that is computed was awaited by the slot that computes it."""
+        from jax.experimental.pallas import tpu as pltpu
+        for interpret in (True,          # a wait that nothing started makes
+                          # the TPU interpreter wait for ever: the plain one,
+                          # where it reads a chunk nobody copied, goes first
+                          pltpu.InterpretParams(dma_execution_mode=mode)):
+            pltpu.reset_tpu_interpret_mode_state()
+            out, ref, live = _chain_launch(case, "float32", interpret,
+                                           monkeypatch)
+            assert "non-zero count" not in capfd.readouterr().out
+            assert (out[~live] == 0).all()
+            np.testing.assert_allclose(out[live], ref[live], rtol=0,
+                                       atol=1e-5)
+
     @pytest.mark.parametrize("n,r,dtype", [(3, 1, "float32"),
                                            (2, 4, "float32"),
                                            (3, 1, "bfloat16")])
@@ -189,23 +285,28 @@ class TestPagedKernels:
     def test_scatter_supported(self, geometry, takes):
         assert ra.scatter_supported(**geometry) is takes
 
-    def test_decode_body_skips_slots_without_a_query(self):
+    def test_decode_body_skips_slots_without_a_query(self, monkeypatch):
         """q_len 0 (a slot that takes no query this launch): exact zeros,
-        and its neighbours are untouched by it."""
+        and its neighbours are untouched by it, also where it follows a
+        slot of two chunks (which then hands it no chunk: the slot after
+        the skipped one starts its own)."""
         KV, H, hd, npool = 2, 4, 128, 12
         rng = np.random.RandomState(5)
         kp = jnp.asarray(rng.randn(npool, _PS, KV, hd), jnp.float32)
         vp = jnp.asarray(rng.randn(npool, _PS, KV, hd), jnp.float32)
-        q = jnp.asarray(rng.randn(3, 1, H, hd), jnp.float32)
-        bt = rng.randint(1, npool, (3, _PMAX)).astype(np.int32)
-        lens = np.array([9, 17, 30], np.int32)
-        out = np.asarray(ra.paged_decode_attention(
-            q, kp, vp, jnp.asarray(bt), jnp.asarray([1, 0, 1], jnp.int32),
-            jnp.asarray(lens), interpret=True))
+        q = jnp.asarray(rng.randn(4, 1, H, hd), jnp.float32)
+        bt = rng.randint(1, npool, (4, _PMAX)).astype(np.int32)
+        lens = np.array([9, 17, 30, 32], np.int32)
         ref = _full_softmax_reference(q, kp, vp, bt, lens)
-        assert (out[1] == 0).all()
-        np.testing.assert_allclose(out[[0, 2]], ref[[0, 2]], rtol=0,
-                                   atol=1e-5)
+        monkeypatch.setattr(ra, "_DECODE_CHUNK_ROWS", 2 * _PS * KV)
+        for q_lens in ([1, 0, 1, 1], [1, 1, 0, 1], [1, 1, 1, 0]):
+            out = np.asarray(ra.paged_decode_attention.__wrapped__(
+                q, kp, vp, jnp.asarray(bt), jnp.asarray(q_lens, jnp.int32),
+                jnp.asarray(lens), interpret=True))
+            took = np.array(q_lens) > 0
+            assert (out[~took] == 0).all()
+            np.testing.assert_allclose(out[took], ref[took], rtol=0,
+                                       atol=1e-5)
 
     @pytest.mark.parametrize("geometry,takes", [
         (dict(head_dim=128, kv_heads=8, page_size=16), True),   # batch cell

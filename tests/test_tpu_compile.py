@@ -36,14 +36,20 @@ PREFILL_BUCKETS = cs.SERVE_PROMPT_BUCKETS
 from tools.flash_microbench import GEOMETRIES as CELL_GEOMETRIES  # noqa: E402
 SERVE_BATCH, SERVE_MAX_LEN = cs.SERVE_MAX_BATCH, cs.SERVE_MAX_LEN
 
-# the decode read (ISSUE 28) at the batch cell's geometry
-# (perfbench/traffic/longctx-batch.json: 48 slots, a 128-page table, pages
-# of 16; InternLM2-1.8B: 8 KV heads x 128, bf16), at chip_smoke's, and one
-# case past each rule of ra.decode_supported()
-_CELL = dict(slots=48, table_pages=128, page_size=16, kv_heads=8,
-             q_heads=16, head_dim=128, dtype="bfloat16")
+# the decode read (ISSUE 28; the slots in order on an "arbitrary" grid with
+# an SMEM carry since ISSUE 33) at the two serving cells' geometries, from
+# the microbenchmark that times them so that they cannot drift (the batch
+# cell: perfbench/traffic/longctx-batch.json, 48 slots, a 128-page table,
+# pages of 16; InternLM2-1.8B: 8 KV heads x 128, bf16; the hybrid cell: 24
+# slots, a 216-page table, 30 KV heads padded to 32), at chip_smoke's, and
+# one case past each rule of ra.decode_supported()
+from tools.paged_decode_microbench import GEOMETRIES as _READ  # noqa: E402
+_CELL, _HYBRID_CELL = (
+    {**{k: v for k, v in _READ[name].items() if k != "contexts"},
+     "dtype": "bfloat16"} for name in ("batch", "hybrid"))
 DECODE_CASES = {
     "batch_cell": (_CELL, None),
+    "hybrid_cell": (_HYBRID_CELL, None),
     "chip_smoke": ({**_CELL, "slots": SERVE_BATCH,
                     "table_pages": SERVE_MAX_LEN // 16, "kv_heads": HEADS,
                     "q_heads": HEADS}, None),

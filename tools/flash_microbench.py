@@ -47,15 +47,15 @@ def causal_blocks(shape) -> int:
     return b * h * n * (n + 1) // 2
 
 
-def kernel_seconds(fn, args, iters: int = 10) -> dict:
-    """name -> (device seconds a call, calls a run of `fn`) for every kernel
-    of KERNELS that ran: `fn` jitted, warmed, then run `iters` times under
+def traced(fn, args, iters: int):
+    """(the trace of `iters` runs of `fn`, None where there is no device
+    plane; the warm-up run's result): `fn` jitted, warmed, then run under
     the profiler; the trace is read as the benchmark reads its own."""
     import jax
     from perfbench.trace import Trace, find_xplane
 
     run = jax.jit(fn)
-    jax.block_until_ready(run(*args))
+    first = jax.block_until_ready(run(*args))
     with tempfile.TemporaryDirectory() as d:
         jax.profiler.start_trace(d)
         for _ in range(iters):
@@ -63,7 +63,13 @@ def kernel_seconds(fn, args, iters: int = 10) -> dict:
         jax.block_until_ready(out)
         jax.profiler.stop_trace()
         path = find_xplane(d)
-        tr = Trace(path) if path else None
+        return (Trace(path) if path else None), first
+
+
+def kernel_seconds(fn, args, iters: int = 10) -> dict:
+    """name -> (device seconds a call, calls a run of `fn`) for every kernel
+    of KERNELS that ran in `traced(fn, args, iters)`."""
+    tr, _ = traced(fn, args, iters)
     got = {}
     for kern in KERNELS if tr is not None else ():
         # jax.vjp at top level prefixes the instruction with its
