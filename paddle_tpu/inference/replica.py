@@ -847,13 +847,16 @@ def _spec_config(spec: dict):
     from ..models.llama import LlamaConfig
 
     # the model spec as JSON carries it: LlamaConfig's own field names, a
-    # dtype by name, a layer pattern (layer_types) as a list
+    # dtype by name, a pattern (layer kinds, FFN kinds, the kinds that
+    # rotate, the experts held) as a list
     ckw = dict(spec.get("config") or {})
     for key in ("dtype", "state_dtype"):
         if key in ckw:
             ckw[key] = jnp.dtype(ckw[key])
-    if ckw.get("layer_types") is not None:
-        ckw["layer_types"] = tuple(ckw["layer_types"])
+    for key in ("layer_types", "mlp_layer_types", "rope_layer_types",
+                "experts_held"):
+        if ckw.get(key) is not None:
+            ckw[key] = tuple(ckw[key])
     return LlamaConfig(**ckw)
 
 
@@ -872,9 +875,16 @@ def build_params(spec: dict):
 def build_batcher(spec: dict, params=None) -> ContinuousBatcher:
     """A batcher from a JSON-able spec: {"config": {LlamaConfig kwargs,
     "dtype": "float32"}, "seed": 0, "batcher": {ContinuousBatcher kwargs}}.
-    The config may state a layer pattern ("layer_types", with the linear
-    layers' sizes, "qk_norm", "norm_placement", "rope_theta": null): the
-    batcher then holds a recurrent state per slot beside the paged KV.
+    The config may state a layer pattern ("layer_types": full_attention /
+    linear_attention / sliding_attention, with the linear layers' sizes or
+    "sliding_window"; "qk_norm" / "qk_norm_per_head", "norm_placement",
+    "rope_theta": null, "rope_layer_types") and FFN kinds
+    ("mlp_layer_types": dense / sparse, with "num_experts",
+    "num_experts_per_tok", "moe_intermediate_size", "num_shared_experts",
+    "scoring_func", "norm_topk_prob", "routed_scaling_factor",
+    "experts_held": [first, count]): the batcher then holds a recurrent
+    state or a ring per slot beside the paged KV and runs the dropless
+    expert layer over the experts held.
     Every replica of a fleet builds from the SAME spec, so weights are
     identical across replicas and a failover retry at temperature=0 is
     token-identical to the first attempt. ``params`` short-circuits the

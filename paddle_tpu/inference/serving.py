@@ -61,6 +61,22 @@ pages, speculation, a serving mesh) or at ``add_request``
 allocation; the gauge ``serve.state_mb_held`` and the ``state`` argument of
 every ``serve.dispatch_burst`` span the bytes of the slots in use.
 
+SLIDING layers (ISSUE 34: window attention among full-attention layers) are
+a second fixed-size per-slot state in the same machinery: a RING of the K/V
+rows of a slot's last ``sliding_window`` positions a window layer
+(``cache["win_k"]``, ``cache["win_v"]``: ``[max_batch, window, KV, hd]``,
+allocated at construction beside ``cache["state"]``, written whole by a
+slot's prefill, one row a decode step), counted in the same
+``stats["state_bytes"]``, gauge and span argument, and refused the same
+things by name. A spec with SPARSE FFN layers (``mlp_layer_types``) runs the
+dropless expert layer (``ops/moe_dropless.py``) over the experts this device
+holds; the assignments are counted on the device (``cache["moe_counts"]``)
+and read back with the step's one ``device_get``:
+``stats["moe_expert_tokens"]`` (per held expert, cumulative), the counters
+``serve.moe_assignments_local`` / ``serve.moe_assignments_total``, and on
+``serve.dispatch_burst`` the arguments ``moe_local`` / ``moe_max`` (that
+burst's assignments on held experts: all, and the busiest expert's).
+
 Prefix sharing (ISSUE 13, ``PADDLE_PREFIX_CACHE_PAGES`` /
 ``prefix_cache_pages=``): a page-granular prefix cache
 (``inference/prefix_cache.py``) over the paged pool lets shared-prompt
@@ -91,8 +107,10 @@ the gather does),
 Spans (observability.spans, on the device trace's clock): every ``step()``
 is one ``serve.step`` (args: burst number, live slots) whose children are
 ``serve.dispatch_burst`` (page growth, block table, transfers, the async
-launch; args ``kv_read``: "kernel", "gather" or "dense", and on the paged
-path ``state``: bytes of recurrent state the slots in use hold),
+launch; args ``kv_read``: "kernel", "gather" or "dense", on the paged
+path ``state``: bytes of per-slot state (recurrent state, rings) the slots
+in use hold, and for a spec with expert layers ``moe_local`` / ``moe_max``,
+set when the step's readback has come),
 ``serve.admit``
 (pop, bucket, allocate, prefill dispatch; under the in-flight burst on the
 paged path; arg: prefills staged),
@@ -247,20 +265,25 @@ class ContinuousBatcher:
 
         if kv_layout not in ("paged", "dense"):
             raise ValueError(f"unknown kv_layout {kv_layout!r}")
-        # a model spec with LINEAR layers (LlamaConfig.layer_types) holds a
-        # recurrent state per SLOT beside the paged K/V of its FULL layers.
-        # What cannot hold for such a state is refused here, by name, and
-        # never served another way unasked.
-        recurrent = model_config.is_recurrent
+        # a model spec with LINEAR or SLIDING layers (LlamaConfig.layer_types)
+        # holds state per SLOT beside the paged K/V of its FULL layers: a
+        # recurrent state, a ring of the window's K/V rows. What cannot hold
+        # for such a state is refused here, by name, and never served
+        # another way unasked; so is what only the two default programs
+        # walk (a per-layer FFN kind: the dropless expert layer).
+        slot_state = model_config.slot_state
+        walked = self._walked = slot_state or (
+            "a per-layer FFN kind (mlp_layer_types)"
+            if model_config.mlp_layer_types is not None else "")
         from .speculative import ENV_SPEC_DECODE, spec_from_env
         spec_on = (bool(spec_decode) if spec_decode is not None
                    else _env_flags.get_bool(ENV_SPEC_DECODE))
-        if recurrent and kv_layout != "paged":
+        if walked and kv_layout != "paged":
             raise ValueError(
                 f"kv_layout={kv_layout!r} cannot serve a model with "
-                "recurrent (linear-attention) layers: only the default "
-                "kv_layout='paged' walks a layer pattern (the dense slot "
-                "cache knows one kind of layer)")
+                f"{walked}: only the default kv_layout='paged' walks a "
+                "layer pattern (the dense slot cache knows one kind of "
+                "layer)")
         # quantized KV pages (ISSUE 10): kv_dtype "int8"/"fp8" stores the
         # page pool through the paddle_tpu.quant block codecs (payload +
         # per-(row, head) scales); both read paths dequantize. Explicit
@@ -291,11 +314,11 @@ class ContinuousBatcher:
             raise ValueError("prefix sharing needs the paged pool "
                              "(kv_layout='paged') — the dense slot cache "
                              "has no shareable page unit")
-        if recurrent and kv_dtype is not None:
+        if slot_state and kv_dtype is not None:
             raise ValueError(
-                f"kv_dtype={kv_dtype!r} cannot serve a model with recurrent "
-                "layers: quantized K/V pages beside a float32 recurrent "
-                "state are not supported")
+                f"kv_dtype={kv_dtype!r} cannot serve a model with "
+                f"{slot_state}: quantized K/V pages beside a per-slot state "
+                "in full precision are not supported")
         self._kv_dtype = kv_dtype
         # off the TPU the Pallas kernels of the paged programs are interpreted
         self._interpret = jax.default_backend() != "tpu"
@@ -331,11 +354,11 @@ class ContinuousBatcher:
                 raise ValueError("page_size must be >= 1")
             slot_max_pages = pages_for(self.S, self._ps)
             self._mesh = serving_mesh()
-            if self._mesh is not None and recurrent:
+            if self._mesh is not None and walked:
                 raise ValueError(
                     "a serving mesh (PADDLE_SERVE_MESH_MODEL) cannot serve "
-                    "a model with recurrent layers: the per-slot state has "
-                    "no sharding rule yet")
+                    f"a model with {walked}: the per-slot state has no "
+                    "sharding rule yet, nor have held experts")
             # the heads a pool row holds are the model's, or padded to
             # whole sublane tiles where that keeps the decode kernel's pool
             # in one layout (llama_paged.pool_kv_heads: 30 -> 32). Only the
@@ -406,12 +429,13 @@ class ContinuousBatcher:
             if cap is None:
                 from .prefix_cache import ENV_CACHE_PAGES
                 cap = _env_flags.get_int(ENV_CACHE_PAGES)
-            if int(cap) > 0 and recurrent:
+            if int(cap) > 0 and walked:
                 raise ValueError(
                     f"prefix_cache_pages={int(cap)} cannot serve a model "
-                    "with recurrent layers: a shared page holds K/V rows "
-                    "and no recurrent state, so a request that maps it "
-                    "would start from a state nobody computed")
+                    f"with {walked}: a shared page holds K/V rows of the "
+                    "full-attention layers and no per-slot state, so a "
+                    "request that maps it (a suffix prefill, a full-prefix "
+                    "resume) would start from a state nobody computed")
             if int(cap) > 0:
                 self._model_shaped_pool(f"prefix_cache_pages={int(cap)}")
                 from .prefix_cache import PrefixCache
@@ -427,11 +451,17 @@ class ContinuousBatcher:
         # greedy tokens per slot + ONE target verify launch per step.
         # None (off / unsupported) keeps the scheduler byte-for-byte the
         # plain engine — spec_from_env degrades silently by contract.
-        if recurrent and spec_on:
+        if walked and spec_on:
+            why = [w for w, on in (
+                ("a rejected draft token cannot be rewound out of a "
+                 "recurrent state", model_config.is_recurrent),
+                ("a rejected draft token's row has overwritten the oldest "
+                 "row of a ring", model_config.has_ring)) if on] \
+                or ["the verify program knows one kind of layer"]
             raise ValueError(
-                "spec_decode cannot serve a model with recurrent layers: a "
-                "rejected draft token cannot be rewound out of a recurrent "
-                "state (pages rewind by resetting pos; a state does not)")
+                f"spec_decode cannot serve a model with {walked}: "
+                + " and ".join(why)
+                + " (pages rewind by resetting pos; a state does not)")
         if spec_on:
             self._model_shaped_pool("spec_decode")
         self._spec = spec_from_env(
@@ -470,14 +500,25 @@ class ContinuousBatcher:
         # the historical unbounded-queue behavior, unchanged.
         self._admission = admission
         self._draining = False
-        # recurrent state: sized by max_batch at construction (a slot's
-        # rows are overwritten by its next prefill), never by the pool
+        # per-slot state (recurrent state, rings): sized by max_batch at
+        # construction (a slot's rows are overwritten by its next prefill),
+        # never by the pool
         self._state_slot_bytes = model_config.state_bytes_per_request()
         self.stats = {"bursts": 0, "decode_steps": 0, "prefills": 0,
                       "admission_stalls": 0, "preemptions": 0,
                       "chaos_retired": 0, "max_concurrent": 0,
                       "page_buckets_used": [], "kv_read": self._kv_read,
                       "state_bytes": self.B * self._state_slot_bytes}
+        # the dropless expert layers' assignments, counted on the device
+        # (cache["moe_counts"]: burst and prefill rows, one column a held
+        # expert and one for the experts held elsewhere) and read back with
+        # the step's one device_get
+        self._moe_seen = None
+        if "moe_counts" in self._cache:
+            self._moe_seen = np.zeros(self._cache["moe_counts"][0].shape,
+                                      np.int64)
+            self.stats["moe_expert_tokens"] = [0] * (
+                self._moe_seen.shape[1] - 1)
         # request-level SLO observability: lifecycle tracker + policy
         # (PADDLE_SLO_* env unless an explicit policy is given); pure
         # observation — no tracker call can change a served token
@@ -549,12 +590,11 @@ class ContinuousBatcher:
             raise ValueError("disaggregated serving (prefill_only / "
                              "kv_import) needs the paged pool — the dense "
                              "slot cache has no transferable page unit")
-        if (prefill_only or kv_import is not None) \
-                and self._cfg.is_recurrent:
+        if (prefill_only or kv_import is not None) and self._walked:
             raise ValueError("disaggregated serving (prefill_only / "
                              "kv_import) cannot serve a model with "
-                             "recurrent layers: the transfer carries K/V "
-                             "pages only, no recurrent state")
+                             f"{self._walked}: the transfer carries K/V "
+                             "pages only, no per-slot state")
         if prefill_only or kv_import is not None:
             self._model_shaped_pool("disaggregated serving (prefill_only / "
                                     "kv_import)")
@@ -1293,7 +1333,8 @@ class ContinuousBatcher:
                 self._park_or_finish(slot, req)
         return total
 
-    def _sync_merge_paged(self, inflight, staged, installed=()) -> int:
+    def _sync_merge_paged(self, inflight, staged, installed=(),
+                          dispatch_span=None) -> int:
         """THE one blocking point per step: a single device_get covering
         the burst readback and every staged first token, then pure host
         bookkeeping (drain outputs, retire, install admissions).
@@ -1304,12 +1345,34 @@ class ContinuousBatcher:
         if inflight is None and not staged and not installed:
             return 0
         with _spans.span("serve.readback", cat="serve"):
-            burst_vals, firsts = jax.device_get(
+            burst_vals, firsts, moe = jax.device_get(
                 (inflight[1:] if inflight else (),
-                 [f for *_, f in staged]))
+                 [f for *_, f in staged],
+                 self._cache["moe_counts"][0]
+                 if self._moe_seen is not None else None))
         with _spans.span("serve.merge", cat="serve"):
+            if moe is not None:
+                self._count_moe(moe, dispatch_span)
             return self._merge_paged(inflight, staged, installed,
                                      burst_vals, firsts)
+
+    def _count_moe(self, moe, dispatch_span) -> None:
+        """The expert layers' assignments since the last readback: the
+        cumulative ``stats["moe_expert_tokens"]`` (per held expert, bursts
+        and prefills, all layers), the counters of assignments on held
+        experts and of all the router made, and on the step's
+        ``serve.dispatch_burst`` span what ITS burst put on held experts:
+        the total and the busiest expert's."""
+        moe = np.asarray(moe, np.int64)
+        new = moe - self._moe_seen
+        self._moe_seen = moe
+        self.stats["moe_expert_tokens"] = moe[:, :-1].sum(0).tolist()
+        metrics.counter("serve.moe_assignments_local").inc(
+            int(new[:, :-1].sum()))
+        metrics.counter("serve.moe_assignments_total").inc(int(new.sum()))
+        if dispatch_span is not None:
+            dispatch_span.args.update(moe_local=int(new[0, :-1].sum()),
+                                      moe_max=int(new[0, :-1].max()))
 
     def _merge_paged(self, inflight, staged, installed, burst_vals,
                      firsts) -> int:
@@ -1538,7 +1601,8 @@ class ContinuousBatcher:
                 if self._state_slot_bytes:
                     metrics.gauge("serve.state_mb_held").set(state_live / 1e6)
                 with _spans.span("serve.dispatch_burst", cat="serve",
-                                 kv_read=self._kv_read, state=state_live):
+                                 kv_read=self._kv_read,
+                                 state=state_live) as dispatched:
                     inflight = self._dispatch_burst_paged()
                 with _spans.span("serve.admit", cat="serve") as sp:
                     real0, padded0 = self._pf_real.value, self._pf_padded.value
@@ -1546,7 +1610,8 @@ class ContinuousBatcher:
                     sp.args = {"prefills": len(staged),
                                "real": self._pf_real.value - real0,
                                "padded": self._pf_padded.value - padded0}
-                emitted = self._sync_merge_paged(inflight, staged, installed)
+                emitted = self._sync_merge_paged(inflight, staged, installed,
+                                                 dispatched)
                 dt = _slo.now() - t0
                 metrics.histogram("serve.burst_time_s").observe(dt)
                 if emitted and dt > 0:

@@ -67,10 +67,12 @@ class LlamaConfig:
     # "pre": x + f(norm(x)) (Llama); "post": x + norm(f(x)) (the OLMo 2
     # family's reordered norm), with the same ln1 / ln2 gains
     norm_placement: str = "pre"
-    # ordered layer kinds, FULL or LINEAR (None: every layer FULL). A LINEAR
-    # layer's mixer is the gated delta rule (ops/gated_delta.py): it holds
-    # no K/V rows, but a state [value heads, value dim, key dim] and the
-    # last conv_kernel - 1 inputs of its convolution, for every request
+    # ordered layer kinds, FULL, LINEAR or SLIDING (None: every layer FULL).
+    # A LINEAR layer's mixer is the gated delta rule (ops/gated_delta.py): it
+    # holds no K/V rows, but a state [value heads, value dim, key dim] and
+    # the last conv_kernel - 1 inputs of its convolution, for every request.
+    # A SLIDING layer is attention over the last `sliding_window` positions:
+    # it holds no page, but a ring of that many K/V rows for every request
     layer_types: tuple | None = None
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
@@ -79,8 +81,28 @@ class LlamaConfig:
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False   # beta = 2 sigmoid, not sigmoid
     state_dtype: Any = jnp.float32
+    sliding_window: int = 0
+    # per-head QK-norm: RMSNorm of every q and k head over its head_dim,
+    # one gain vector of head_dim each (qk_norm above: the whole projection)
+    qk_norm_per_head: bool = False
+    # the layer kinds whose q and k are rotated (None: every attention layer,
+    # where rope_theta is set)
+    rope_layer_types: tuple | None = None
+    # ordered FFN kinds, DENSE or SPARSE (None: every layer alike, the
+    # training block `_moe_block` where num_experts > 0). A SPARSE layer is
+    # the dropless expert layer of ops/moe_dropless.py: the router scores
+    # all `num_experts`, this device computes the experts it holds
+    # (`experts_held`: (first, count); None: all) and the shared experts
+    mlp_layer_types: tuple | None = None
+    num_shared_experts: int = 0
+    scoring_func: str = "softmax"           # or "sigmoid"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    experts_held: tuple | None = None
 
     FULL, LINEAR = "full_attention", "linear_attention"
+    SLIDING = "sliding_attention"
+    DENSE, SPARSE = "dense", "sparse"
 
     def __post_init__(self):
         set_ = lambda k, v: object.__setattr__(self, k, v)  # frozen
@@ -89,17 +111,59 @@ class LlamaConfig:
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(f"norm_placement {self.norm_placement!r}: "
                              "'pre' or 'post'")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func {self.scoring_func!r}: "
+                             "'softmax' or 'sigmoid'")
+        if self.mlp_layer_types is not None:
+            set_("mlp_layer_types", tuple(self.mlp_layer_types))
+            ffn = set(self.mlp_layer_types)
+            if len(self.mlp_layer_types) != self.num_hidden_layers \
+                    or not ffn <= {self.DENSE, self.SPARSE}:
+                raise ValueError(
+                    f"mlp_layer_types names {len(self.mlp_layer_types)} "
+                    f"layers of kinds {sorted(ffn)}: it must give "
+                    f"num_hidden_layers={self.num_hidden_layers} entries, "
+                    f"each {self.DENSE!r} or {self.SPARSE!r}")
+            if self.SPARSE in ffn and not (
+                    1 <= self.num_experts_per_tok <= self.num_experts):
+                raise ValueError(
+                    f"a {self.SPARSE!r} layer routes each token to "
+                    f"num_experts_per_tok={self.num_experts_per_tok} of "
+                    f"num_experts={self.num_experts}")
+        if self.experts_held is not None:
+            first, count = (int(v) for v in self.experts_held)
+            set_("experts_held", (first, count))
+            if first < 0 or count < 1 or first + count > self.num_experts:
+                raise ValueError(
+                    f"experts_held=(first {first}, count {count}) is no "
+                    f"range of the {self.num_experts} experts")
+        if self.rope_layer_types is not None:
+            set_("rope_layer_types", tuple(self.rope_layer_types))
         if self.layer_types is None:
+            if self.rope_layer_types is not None or self.sliding_window:
+                raise ValueError("rope_layer_types and sliding_window go "
+                                 "with a layer pattern (layer_types)")
             return
         set_("layer_types", tuple(self.layer_types))
         kinds = set(self.layer_types)
+        known = (self.FULL, self.LINEAR, self.SLIDING)
         if len(self.layer_types) != self.num_hidden_layers \
-                or not kinds <= {self.FULL, self.LINEAR}:
+                or not kinds <= set(known):
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers of kinds "
                 f"{sorted(kinds)}: it must give num_hidden_layers="
-                f"{self.num_hidden_layers} entries, each {self.FULL!r} or "
-                f"{self.LINEAR!r}")
+                f"{self.num_hidden_layers} entries, each {known[0]!r}, "
+                f"{known[1]!r} or {known[2]!r}")
+        if self.rope_layer_types is not None \
+                and not set(self.rope_layer_types) <= {self.FULL,
+                                                       self.SLIDING}:
+            raise ValueError(
+                f"rope_layer_types {self.rope_layer_types}: the kinds that "
+                f"rotate q and k are {self.FULL!r} and {self.SLIDING!r} (a "
+                f"{self.LINEAR!r} layer has no rotation)")
+        if self.SLIDING in kinds and self.sliding_window < 1:
+            raise ValueError(f"a {self.SLIDING!r} layer needs "
+                             "sliding_window >= 1")
         if self.LINEAR in kinds:
             if self.linear_num_key_heads != self.linear_num_value_heads:
                 raise ValueError(
@@ -113,9 +177,12 @@ class LlamaConfig:
                 raise ValueError("a linear layer needs linear_num_key_heads, "
                                  "linear_key_head_dim, linear_value_head_dim "
                                  ">= 1 and linear_conv_kernel_dim >= 2")
-            if self.num_experts > 0:
-                raise ValueError("experts under a layer pattern are not "
-                                 "supported")
+        if self.num_experts > 0 and self.mlp_layer_types is None:
+            raise ValueError(
+                f"experts under a layer pattern ({known[0]!r} / {known[1]!r} "
+                f"/ {known[2]!r}) are served by the dropless layer only: "
+                "state mlp_layer_types (the training block `_moe_block` "
+                "drops tokens over capacity and knows no pattern)")
 
     # what the pattern means for whoever holds per-request state
     def kinds(self) -> tuple:
@@ -124,23 +191,84 @@ class LlamaConfig:
 
     def kind_index(self, layer: int) -> tuple:
         """(kind, the layer's place among the layers of its kind): where
-        its mixer's parameters and its per-request state are stacked."""
+        its per-request state is stacked (FULL: the page pools; LINEAR:
+        state and conv; SLIDING: the rings) and, for a LINEAR layer, its
+        mixer's parameters (``attn_index`` for the other two)."""
         kinds = self.kinds()
         return kinds[layer], kinds[:layer].count(kinds[layer])
 
+    def attn_index(self, layer: int) -> int:
+        """An attention layer's (FULL or SLIDING) place among the attention
+        layers: where wq, wk, wv, wo and the QK-norm gains are stacked."""
+        return sum(k != self.LINEAR for k in self.kinds()[:layer])
+
+    def ffn_index(self, layer: int) -> tuple:
+        """(FFN kind, the layer's place among the layers of that FFN kind):
+        where its FFN's parameters are stacked. Without mlp_layer_types
+        every layer is alike and stacked over all."""
+        if self.mlp_layer_types is None:
+            return (self.SPARSE if self.num_experts > 0 else self.DENSE), layer
+        kinds = self.mlp_layer_types
+        return kinds[layer], kinds[:layer].count(kinds[layer])
+
+    def rotates(self, kind: str) -> bool:
+        """Are q and k of a layer of this kind rotated?"""
+        return self.rope_theta is not None and (
+            self.rope_layer_types is None or kind in self.rope_layer_types)
+
     @property
     def num_kv_layers(self) -> int:
-        """Layers that hold K/V rows: the ones a KV page spans."""
+        """Layers that hold K/V rows for every token: the ones a KV page
+        spans (FULL; not SLIDING, whose rows live in a ring)."""
         return self.kinds().count(self.FULL)
+
+    @property
+    def num_attn_layers(self) -> int:
+        return self.num_hidden_layers - self.num_linear_layers
 
     @property
     def num_linear_layers(self) -> int:
         return self.kinds().count(self.LINEAR)
 
     @property
+    def num_sliding_layers(self) -> int:
+        return self.kinds().count(self.SLIDING)
+
+    @property
+    def num_sparse_layers(self) -> int:
+        """Layers whose FFN is the dropless expert layer."""
+        return (self.mlp_layer_types or ()).count(self.SPARSE)
+
+    @property
+    def num_dense_layers(self) -> int:
+        if self.mlp_layer_types is None:
+            return 0 if self.num_experts > 0 else self.num_hidden_layers
+        return self.mlp_layer_types.count(self.DENSE)
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the experts this device holds."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
     def is_recurrent(self) -> bool:
-        """Does a request hold state that is no K/V row?"""
+        """Does a request hold a recurrent state (LINEAR layers)?"""
         return self.num_linear_layers > 0
+
+    @property
+    def has_ring(self) -> bool:
+        """Does a request hold a ring of K/V rows (SLIDING layers)?"""
+        return self.num_sliding_layers > 0
+
+    @property
+    def slot_state(self) -> str:
+        """What a request holds beside its K/V pages, as the engine's
+        refusals name it ("" where it holds pages only)."""
+        return " and ".join(
+            n for n, on in (("a recurrent state (linear-attention layers)",
+                             self.is_recurrent),
+                            ("a ring of K/V rows (window layers)",
+                             self.has_ring)) if on)
 
     @property
     def linear_conv_dim(self) -> int:
@@ -157,21 +285,38 @@ class LlamaConfig:
                 "conv": ((max_batch, self.linear_conv_kernel_dim - 1,
                           self.linear_conv_dim), self.dtype)}
 
+    def ring_shapes(self, max_batch: int) -> dict:
+        """{leaf: (shape, dtype)} of ONE window layer's ring for `max_batch`
+        slots: the K and V rows of a request's last `sliding_window`
+        positions, position p in row p % sliding_window. The shape of a
+        page pool with one page of `sliding_window` rows a slot, so the
+        pools' kernels read and write it."""
+        shape = (max_batch, self.sliding_window, self.num_key_value_heads,
+                 self.head_dim)
+        return {"win_k": (shape, self.dtype), "win_v": (shape, self.dtype)}
+
     def state_bytes_per_request(self) -> int:
-        """Bytes of recurrent state ONE request holds, whatever its length
-        (0 for a model of full-attention layers only)."""
-        per_layer = sum(int(np.prod(shape[1:])) * jnp.dtype(dt).itemsize
-                        for shape, dt in self.state_shapes(1).values())
-        return self.num_linear_layers * per_layer
+        """Bytes ONE request holds beside its K/V pages, whatever its
+        length: the recurrent state of its LINEAR layers and the rings of
+        its SLIDING ones (0 for a model of full-attention layers only)."""
+        def one(shapes):
+            return sum(int(np.prod(shape[1:])) * jnp.dtype(dt).itemsize
+                       for shape, dt in shapes.values())
+        return (self.num_linear_layers * one(self.state_shapes(1))
+                + self.num_sliding_layers * one(self.ring_shapes(1)))
 
     def require_uniform(self, what: str) -> None:
         """Paths that know one kind of layer say so by name."""
-        if self.is_recurrent or self.qk_norm \
+        if self.layer_types is not None or self.mlp_layer_types is not None \
+                or self.qk_norm or self.qk_norm_per_head \
                 or self.norm_placement != "pre" or self.rope_theta is None:
             raise NotImplementedError(
-                f"{what} runs models of one layer kind (pre-norm, rope): "
-                "a layer pattern is served by ContinuousBatcher("
-                "kv_layout='paged') only (ROADMAP Queue 2(a) M2)")
+                f"{what} runs models of one layer kind (pre-norm, rope, "
+                f"every layer {self.FULL!r} with one kind of FFN): a layer "
+                f"pattern ({self.FULL!r}, {self.LINEAR!r}, {self.SLIDING!r}; "
+                f"FFNs {self.DENSE!r} / {self.SPARSE!r}) is served by "
+                "ContinuousBatcher(kv_layout='paged') only (ROADMAP Queue "
+                "2(a) M1-M3)")
 
     @classmethod
     def tiny(cls, **kw):
@@ -288,22 +433,26 @@ def llama_init_params(config: LlamaConfig, key=None, mesh=None):
         return (jax.random.normal(k, shape, jnp.float32) * std).astype(c.dtype)
 
     # each kind of layer is stacked on a leading axis of its own: the
-    # attention matrices over the FULL layers, the linear mixer's (below)
-    # over the LINEAR ones, the FFN and the two norms over all of them
-    nF = c.num_kv_layers
+    # attention matrices over the attention layers (FULL and SLIDING), the
+    # linear mixer's (below) over the LINEAR ones, the two norms over all of
+    # them, and with mlp_layer_types each kind of FFN over its own layers
+    nA = c.num_attn_layers
     params = {
         "embed_tokens": init(ks[0], (V, D)),
-        "wq": init(ks[1], (nF, D, H * hd)),
-        "wk": init(ks[2], (nF, D, KV * hd)),
-        "wv": init(ks[3], (nF, D, KV * hd)),
-        "wo": init(ks[4], (nF, H * hd, D)),
+        "wq": init(ks[1], (nA, D, H * hd)),
+        "wk": init(ks[2], (nA, D, KV * hd)),
+        "wv": init(ks[3], (nA, D, KV * hd)),
+        "wo": init(ks[4], (nA, H * hd, D)),
         "ln1": jnp.ones((L, D), jnp.float32),
         "ln2": jnp.ones((L, D), jnp.float32),
         "norm": jnp.ones((D,), jnp.float32),
     }
-    if c.qk_norm:
-        params["q_norm"] = jnp.ones((nF, H * hd), jnp.float32)
-        params["k_norm"] = jnp.ones((nF, KV * hd), jnp.float32)
+    if c.qk_norm_per_head:
+        params["q_norm"] = jnp.ones((nA, hd), jnp.float32)
+        params["k_norm"] = jnp.ones((nA, hd), jnp.float32)
+    elif c.qk_norm:
+        params["q_norm"] = jnp.ones((nA, H * hd), jnp.float32)
+        params["k_norm"] = jnp.ones((nA, KV * hd), jnp.float32)
     if c.is_recurrent:
         nL, Hv = c.num_linear_layers, c.linear_num_value_heads
         dv, kk = c.linear_value_head_dim, c.linear_conv_kernel_dim
@@ -318,17 +467,32 @@ def llama_init_params(config: LlamaConfig, key=None, mesh=None):
             "lin_dt_bias": jnp.zeros((nL, Hv), jnp.float32),
             "lin_norm": jnp.ones((nL, dv), jnp.float32),
         })
-    if c.num_experts > 0:
+    nS, nD = c.num_sparse_layers, c.num_dense_layers
+    Fm = c.moe_intermediate_size or F
+    if nS:      # the dropless layer: the router over all experts, the held
+        E, Eh = c.num_experts, c.held[1]
+        kx = jax.random.split(ks[8], 6)
+        params["gate_w"] = init(ks[5], (nS, D, E)).astype(jnp.float32)
+        params["gate_bias"] = jnp.zeros((nS, E), jnp.float32)
+        params["moe_w_gate"] = init(kx[0], (nS, Eh, D, Fm))
+        params["moe_w_up"] = init(kx[1], (nS, Eh, D, Fm))
+        params["moe_w_down"] = init(kx[2], (nS, Eh, Fm, D))
+        if c.num_shared_experts:
+            Fs = c.num_shared_experts * Fm
+            params["shared_w_gate"] = init(kx[3], (nS, D, Fs))
+            params["shared_w_up"] = init(kx[4], (nS, D, Fs))
+            params["shared_w_down"] = init(kx[5], (nS, Fs, D))
+    elif c.num_experts > 0:
         E = c.num_experts
-        Fm = c.moe_intermediate_size or F
         params["gate_w"] = init(ks[5], (L, D, E)).astype(jnp.float32)
         params["moe_w_gate"] = init(ks[6], (L, E, D, Fm))
         params["moe_w_up"] = init(ks[7], (L, E, D, Fm))
         params["moe_w_down"] = init(ks[8], (L, E, Fm, D))
-    else:
-        params["w_gate"] = init(ks[5], (L, D, F))
-        params["w_up"] = init(ks[6], (L, D, F))
-        params["w_down"] = init(ks[7], (L, F, D))
+    if nD:      # the keys a Llama's FFN always had; others beside experts
+        kd = ks[5:8] if not nS else jax.random.split(ks[7], 3)
+        params["w_gate"] = init(kd[0], (nD, D, F))
+        params["w_up"] = init(kd[1], (nD, D, F))
+        params["w_down"] = init(kd[2], (nD, F, D))
     if not c.tie_word_embeddings:
         params["lm_head"] = init(ks[9], (D, V))
     if mesh is not None:
@@ -394,10 +558,11 @@ def _expand_gqa(k, v, config):
     return k, v
 
 
-def _attention(q, k, v, config, use_flash=True, mesh=None):
+def _attention(q, k, v, config, use_flash=True, mesh=None, window=None):
     """q:[B,T,H,hd] k,v:[B,T,KV,hd] causal. `mesh` (a jax Mesh): the
     program is GSPMD-partitioned over it, so the flash kernel runs per
-    shard of (batch, heads) — see flash_attention_raw."""
+    shard of (batch, heads) — see flash_attention_raw. `window` (a SLIDING
+    layer's): query i sees keys i - window < j <= i; forward only."""
     k, v = _expand_gqa(k, v, config)
     if use_flash:
         # Pallas kernel on TPU, XLA reference otherwise — the predicate
@@ -410,18 +575,30 @@ def _attention(q, k, v, config, use_flash=True, mesh=None):
             spec = P(*(a if a is None or n % mesh.shape[a] == 0 else None
                        for a, n in zip(_act_spec(set(mesh.axis_names),
                                                  "bthd"), q.shape)))
-        return flash_attention_raw(q, k, v, causal=True, mesh=mesh, spec=spec)
+        return flash_attention_raw(q, k, v, causal=True, mesh=mesh, spec=spec,
+                                   window=window)
     scale = 1.0 / math.sqrt(config.head_dim)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     T, S_ = logits.shape[-2], logits.shape[-1]
     mask = jnp.tril(jnp.ones((T, S_), bool), k=S_ - T)
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((T, S_), bool), k=S_ - T - window)
     logits = jnp.where(mask, logits, jnp.float32(-1e30))
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
 def _moe_block(x, gate_w, w_gate, w_up, w_down, config):
-    """x:[B,T,D]; expert weights [E,...]. GShard top-k dense dispatch."""
+    """x:[B,T,D]; expert weights [E,...]. GShard top-k dense dispatch.
+
+    The TRAINING block only (``llama_trunk``): softmax scores, one-hot
+    dispatch into ``capacity = 1.25 n k / E`` places an expert, and a token
+    past an expert's capacity is DROPPED (it passes through residually), so
+    its output differs from the layer's equations wherever routing is
+    uneven. Serving runs ``ops/moe_dropless.py`` instead (a SPARSE layer of
+    ``LlamaConfig.mlp_layer_types``: no capacity, no dropped token, the
+    experts this device holds); training through that layer is ROADMAP
+    Queue 2(a) M1."""
     B, T, D = x.shape
     E, k = config.num_experts, config.num_experts_per_tok
     tokens = x.reshape(-1, D)
@@ -569,9 +746,11 @@ def llama_trunk(x, stacked_layer_params, config, mesh=None, positions=None,
 _ATTN_KEYS = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
 _LINEAR_KEYS = ("lin_wqkv", "lin_wa", "lin_wb", "lin_wg", "lin_wo", "lin_conv",
                 "lin_A_log", "lin_dt_bias", "lin_norm")
-_LAYER_KEYS = _ATTN_KEYS + _LINEAR_KEYS + (
-    "w_gate", "w_up", "w_down", "ln1", "ln2",
-    "gate_w", "moe_w_gate", "moe_w_up", "moe_w_down")
+_DENSE_KEYS = ("w_gate", "w_up", "w_down")
+_SPARSE_KEYS = ("gate_w", "gate_bias", "moe_w_gate", "moe_w_up", "moe_w_down",
+                "shared_w_gate", "shared_w_up", "shared_w_down")
+_LAYER_KEYS = _ATTN_KEYS + _LINEAR_KEYS + _DENSE_KEYS + _SPARSE_KEYS + (
+    "ln1", "ln2")
 
 
 def split_layer_params(params):
@@ -583,15 +762,23 @@ def split_layer_params(params):
 def layer_params_at(layer_p, config: LlamaConfig, layer: int) -> dict:
     """One layer's parameters out of the stacked tree. Without a pattern
     every leaf is stacked over all layers; with one, a mixer's leaves are
-    stacked over the layers of its kind (``kind_index``) and only the FFN
-    and the norms over all."""
-    if config.layer_types is None:
+    stacked over the layers that have such a mixer (``attn_index``: FULL
+    and SLIDING together; ``kind_index``: LINEAR), an FFN's over the layers
+    of its FFN kind (``ffn_index``), and only the norms over all."""
+    c = config
+    if c.layer_types is None and c.mlp_layer_types is None:
         return jax.tree.map(lambda a: a[layer], layer_p)
-    kind, at = config.kind_index(layer)
-    mine = _ATTN_KEYS if kind == config.FULL else _LINEAR_KEYS
-    other = _LINEAR_KEYS if kind == config.FULL else _ATTN_KEYS
-    return {k: v[at if k in mine else layer] for k, v in layer_p.items()
-            if k not in other}
+    kind, at = c.kind_index(layer)
+    linear = kind == c.LINEAR
+    ffn, fi = c.ffn_index(layer)
+    place = {}
+    place.update(dict.fromkeys(_ATTN_KEYS,
+                               None if linear else c.attn_index(layer)))
+    place.update(dict.fromkeys(_LINEAR_KEYS, at if linear else None))
+    place.update(dict.fromkeys(_DENSE_KEYS, fi if ffn == c.DENSE else None))
+    place.update(dict.fromkeys(_SPARSE_KEYS, fi if ffn == c.SPARSE else None))
+    return {k: v[place.get(k, layer)] for k, v in layer_p.items()
+            if place.get(k, layer) is not None}
 
 
 def block_in(x, gain, config: LlamaConfig):
@@ -606,21 +793,25 @@ def block_out(y, gain, config: LlamaConfig):
         else _rmsnorm(y, gain, config.rms_norm_eps)
 
 
-def attn_qkv(h, lp, config: LlamaConfig, positions):
-    """q [B, T, H, hd], k and v [B, T, KV, hd] of a full-attention layer
-    from its input h [B, T, D]: the projections, the QK-norm where the
-    spec has one (over the whole projection, before the heads are split),
-    the rotation where it has one."""
+def attn_qkv(h, lp, config: LlamaConfig, positions, kind: str | None = None):
+    """q [B, T, H, hd], k and v [B, T, KV, hd] of an attention layer of
+    ``kind`` (FULL, or SLIDING) from its input h [B, T, D]: the
+    projections, the QK-norm where the spec has one (over the whole
+    projection before the heads are split, or per head over head_dim), the
+    rotation where the spec rotates this kind of layer."""
     c = config
     B, T, _ = h.shape
     q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
-    if c.qk_norm:
+    if c.qk_norm and not c.qk_norm_per_head:
         q = _rmsnorm(q, lp["q_norm"], c.rms_norm_eps)
         k = _rmsnorm(k, lp["k_norm"], c.rms_norm_eps)
     q = q.reshape(B, T, c.num_attention_heads, c.head_dim)
     k = k.reshape(B, T, c.num_key_value_heads, c.head_dim)
     v = v.reshape(B, T, c.num_key_value_heads, c.head_dim)
-    if c.rope_theta is not None:
+    if c.qk_norm_per_head:
+        q = _rmsnorm(q, lp["q_norm"], c.rms_norm_eps)
+        k = _rmsnorm(k, lp["k_norm"], c.rms_norm_eps)
+    if c.rotates(kind or c.FULL):
         q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
     return q, k, v
 

@@ -78,7 +78,9 @@ def _qkv(h, lp, c):
 
 def _mlp(x, lp, c):
     h2 = block_in(x, lp["ln2"], c)
-    if c.num_experts > 0:
+    if c.num_experts > 0 and c.mlp_layer_types is None:
+        # one kind of FFN, the training block; a SPARSE layer of
+        # mlp_layer_types is llama_paged._ffn's (the dropless layer)
         out, _ = _moe_block(h2, lp["gate_w"], lp["moe_w_gate"],
                             lp["moe_w_up"], lp["moe_w_down"], c)
         return x + out

@@ -66,6 +66,7 @@ import jax.numpy as jnp
 from .linear_mixer import mixer_prefill, mixer_step
 from .llama import LlamaConfig, _rmsnorm, _rope, attn_qkv, block_in, \
     block_out, layer_params_at, lm_head_logits, split_layer_params
+from ..ops.moe_dropless import moe_dropless
 from ..ops.ragged_attention import decode_supported, \
     paged_decode_attention, paged_kv_scatter, scatter_supported
 from .llama_decode import _cached_attention_slots, _mlp, _qkv, _sample
@@ -165,9 +166,15 @@ def init_paged_kv_cache(config: LlamaConfig, num_pages: int, page_size: int,
             for name, (shp, dt) in c.state_shapes(int(max_batch)).items():
                 cache[name] = tuple(jnp.zeros(shp, dt)
                                     for _ in range(c.num_linear_layers))
+        if c.has_ring:
+            for name, (shp, dt) in c.ring_shapes(int(max_batch)).items():
+                cache[name] = tuple(jnp.zeros(shp, dt)
+                                    for _ in range(c.num_sliding_layers))
+        if c.num_sparse_layers:
+            cache["moe_counts"] = (jnp.zeros((2, c.held[1] + 1), jnp.int32),)
         return cache
-    if c.is_recurrent:
-        raise ValueError("quantized KV pages beside a recurrent state are "
+    if c.is_recurrent or c.has_ring:
+        raise ValueError(f"quantized KV pages beside {c.slot_state} are "
                          "not supported")
     from ..quant.codec import SCALE_DTYPE, wire_dtype
     wire = wire_dtype(kv_dtype)
@@ -319,6 +326,68 @@ def _kernel_write(config: LlamaConfig, page_size: int, kv_dtype, kv_read,
         int(page_size), kv_dtype))
 
 
+def _ffn(x, lp, config: LlamaConfig, valid, interpret, stacked, layer: int):
+    """The FFN of layer ``layer`` on the stream x [B, T, D], by its kind:
+    the SwiGLU FFN (``_mlp``), or for a SPARSE layer of ``mlp_layer_types``
+    the dropless expert layer (``valid`` [B, T] bool: the tokens that are
+    real; ``stacked``: the layer-stacked tree ``lp`` was taken from, whose
+    expert weights the grouped products read in place). Returns (x, the
+    layer's count of assignments [held + 1]; 0 for a dense FFN)."""
+    c = config
+    if c.mlp_layer_types is None or "gate_w" not in lp:
+        return _mlp(x, lp, c), 0
+    g = block_in(x.astype(jnp.float32), lp["ln2"], c)
+    lp = {**lp, **{k: stacked[k] for k in
+                   ("moe_w_gate", "moe_w_up", "moe_w_down")}}
+    y, counts = moe_dropless(
+        g.reshape(-1, g.shape[-1]), lp, c, c.ffn_index(layer)[1],
+        valid.reshape(-1), interpret)
+    return x + block_out(y.reshape(x.shape), lp["ln2"], c), counts
+
+
+def _ring_step(q, k, v, ring_k, ring_v, pos32, config: LlamaConfig, interpret):
+    """One token of every slot in a SLIDING layer: its K/V row goes into row
+    ``pos % window`` of the slot's ring (scope ``kv_write``), and q attends
+    the ring's live rows (scope ``win_read``): rows <= pos until the ring
+    has wrapped, all of them after, which are the positions (pos - window,
+    pos] in some order, and softmax knows no order. A ring is a page pool
+    with one page of ``window`` rows a slot, so where the pool kernels take
+    that geometry (``decode_supported`` / ``scatter_supported``) they read
+    and write it; otherwise XLA does (a row scatter, masked attention).
+    q [B, 1, H, hd]; k, v [B, KV, hd]. Returns (att, ring_k, ring_v)."""
+    c = config
+    B, W, KV, hd = ring_k.shape
+    row = pos32 % jnp.int32(W)
+    slots = jnp.arange(B, dtype=jnp.int32)
+    with jax.named_scope("kv_write"):
+        if scatter_supported(hd, KV, W):
+            ring_k, ring_v = paged_kv_scatter(
+                ring_k, ring_v, k[:, None], v[:, None], slots, row,
+                interpret=interpret)
+        else:
+            ring_k = ring_k.at[slots, row].set(k, unique_indices=True)
+            ring_v = ring_v.at[slots, row].set(v, unique_indices=True)
+    with jax.named_scope("win_read"):
+        if decode_supported(hd, KV, W):
+            att = paged_decode_attention(
+                q, ring_k, ring_v, slots[:, None], jnp.ones((B,), jnp.int32),
+                jnp.minimum(pos32 + 1, jnp.int32(W)), interpret=interpret)
+        else:
+            att = _cached_attention_slots(q, ring_k, ring_v, pos32, c)
+    return att, ring_k, ring_v
+
+
+def _ring_of_prompt(rows, tlen, window: int):
+    """The ring [window, KV, hd] a prompt of ``tlen`` real tokens leaves:
+    row r holds the last position p < tlen with p % window == r (rows no
+    position has reached yet hold a copy of another row, which no read sees
+    before a decode step has written them). rows [T, KV, hd]."""
+    r = jnp.arange(window, dtype=jnp.int32)
+    last = tlen.astype(jnp.int32) - 1
+    p = last - (last - r) % jnp.int32(window)
+    return jnp.take(rows, jnp.clip(p, 0, rows.shape[0] - 1), axis=0)
+
+
 def _paged_decode_step_slots(params, cache, block_table, pos, tok,
                              config: LlamaConfig, kv_dtype: str | None = None,
                              kv_read: str | None = None,
@@ -383,7 +452,11 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
     kss = list(cache["k_scale"]) if quant else None
     vss = list(cache["v_scale"]) if quant else None
     states, tails = list(cache.get("state", ())), list(cache.get("conv", ()))
+    rk, rv = list(cache.get("win_k", ())), list(cache.get("win_v", ()))
+    moe = cache.get("moe_counts")       # (burst, prefill) x assignments
+    routed = 0
     frozen = jnp.zeros((B,), bool) if done is None else done
+    real = ~frozen[:, None]
     for layer in range(c.num_hidden_layers):
         lp = layer_params_at(layer_p, c, layer)
         kind, l = c.kind_index(layer)   # l: its place among its kind
@@ -393,68 +466,78 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
                 h[:, 0], lp, c, states[l], tails[l], frozen)
             y = x + block_out(mix[:, None], lp["ln1"], c)
             with jax.named_scope("mlp"):
-                x = _mlp(y, lp, c)
+                x, n = _ffn(y, lp, c, real, interpret, layer_p, layer)
+            routed = routed + n
             continue
-        q, k, v = attn_qkv(h, lp, c, positions)
-        kp, vp = ks[l], vs[l]
-        ku, vu = _pad_heads(k[:, 0], pool_heads), _pad_heads(v[:, 0],
-                                                             pool_heads)
-        ksp = vsp = None
-        if quant:
-            ku, ksr = _kv_encode(ku, kv_dtype)   # [B, KV, hd] + [B, KV]
-            vu, vsr = _kv_encode(vu, kv_dtype)
-            ksp, vsp = kss[l], vss[l]
-        with jax.named_scope("kv_write"):
-            if kernel_write:
-                kp, vp = paged_kv_scatter(kp, vp, ku[:, None], vu[:, None],
-                                          wpage, row_of, interpret=interpret)
-            else:
-                for b in range(B):
-                    at = (wpage[b], row_of[b], z, z)
-                    kp = jax.lax.dynamic_update_slice(
-                        kp, ku[b][None, None], at)
-                    vp = jax.lax.dynamic_update_slice(
-                        vp, vu[b][None, None], at)
+        q, k, v = attn_qkv(h, lp, c, positions, kind)
+        if kind == c.SLIDING:
+            att, rk[l], rv[l] = _ring_step(q, k[:, 0], v[:, 0], rk[l], rv[l],
+                                           pos32, c, interpret)
+        else:
+            kp, vp = ks[l], vs[l]
+            ku, vu = _pad_heads(k[:, 0], pool_heads), _pad_heads(v[:, 0],
+                                                                 pool_heads)
+            ksp = vsp = None
+            if quant:
+                ku, ksr = _kv_encode(ku, kv_dtype)   # [B, KV, hd] + [B, KV]
+                vu, vsr = _kv_encode(vu, kv_dtype)
+                ksp, vsp = kss[l], vss[l]
+            with jax.named_scope("kv_write"):
+                if kernel_write:
+                    kp, vp = paged_kv_scatter(kp, vp, ku[:, None], vu[:, None],
+                                              wpage, row_of, interpret=interpret)
+                else:
+                    for b in range(B):
+                        at = (wpage[b], row_of[b], z, z)
+                        kp = jax.lax.dynamic_update_slice(
+                            kp, ku[b][None, None], at)
+                        vp = jax.lax.dynamic_update_slice(
+                            vp, vu[b][None, None], at)
+                        if quant:
+                            ats = (wpage[b], row_of[b], z)
+                            ksp = jax.lax.dynamic_update_slice(
+                                ksp, ksr[b][None, None], ats)
+                            vsp = jax.lax.dynamic_update_slice(
+                                vsp, vsr[b][None, None], ats)
+            ks[l], vs[l] = kp, vp
+            if quant:
+                kss[l], vss[l] = ksp, vsp
+            with jax.named_scope("kv_read"):
+                if kv_read == "kernel":
+                    att = paged_decode_attention(
+                        _pad_heads(q, q_heads), kp, vp, block_table, one,
+                        pos32 + 1, interpret=interpret)
+                    att = att[:, :, :c.num_attention_heads]
+                else:
+                    # gather the slot's pages into a [B, P*ps, KV, hd] view:
+                    # the read whose bytes scale with the page bucket
+                    kc = _take_pages(kp, block_table)
+                    vc = _take_pages(vp, block_table)
                     if quant:
-                        ats = (wpage[b], row_of[b], z)
-                        ksp = jax.lax.dynamic_update_slice(
-                            ksp, ksr[b][None, None], ats)
-                        vsp = jax.lax.dynamic_update_slice(
-                            vsp, vsr[b][None, None], ats)
-        ks[l], vs[l] = kp, vp
-        if quant:
-            kss[l], vss[l] = ksp, vsp
-        with jax.named_scope("kv_read"):
-            if kv_read == "kernel":
-                att = paged_decode_attention(
-                    _pad_heads(q, q_heads), kp, vp, block_table, one,
-                    pos32 + 1, interpret=interpret)
-                att = att[:, :, :c.num_attention_heads]
-            else:
-                # gather the slot's pages into a [B, P*ps, KV, hd] view:
-                # the read whose bytes scale with the page bucket
-                kc = _take_pages(kp, block_table)
-                vc = _take_pages(vp, block_table)
-                if quant:
-                    kc = _kv_decode(kc, _take_pages(ksp, block_table),
-                                    c.dtype)
-                    vc = _kv_decode(vc, _take_pages(vsp, block_table),
-                                    c.dtype)
-                kc = kc.reshape(B, -1, pool_heads, c.head_dim)
-                vc = vc.reshape(B, -1, pool_heads, c.head_dim)
-                att = _cached_attention_slots(
-                    q, kc[:, :, :c.num_key_value_heads],
-                    vc[:, :, :c.num_key_value_heads], pos, c)
+                        kc = _kv_decode(kc, _take_pages(ksp, block_table),
+                                        c.dtype)
+                        vc = _kv_decode(vc, _take_pages(vsp, block_table),
+                                        c.dtype)
+                    kc = kc.reshape(B, -1, pool_heads, c.head_dim)
+                    vc = vc.reshape(B, -1, pool_heads, c.head_dim)
+                    att = _cached_attention_slots(
+                        q, kc[:, :, :c.num_key_value_heads],
+                        vc[:, :, :c.num_key_value_heads], pos, c)
         with jax.named_scope("attn_out"):
             y = x + block_out(att.reshape(B, 1, -1) @ lp["wo"], lp["ln1"], c)
         with jax.named_scope("mlp"):
-            x = _mlp(y, lp, c)
+            x, n = _ffn(y, lp, c, real, interpret, layer_p, layer)
+        routed = routed + n
 
     out = {"k": tuple(ks), "v": tuple(vs)}
     if quant:
         out["k_scale"], out["v_scale"] = tuple(kss), tuple(vss)
     if states:
         out["state"], out["conv"] = tuple(states), tuple(tails)
+    if rk:
+        out["win_k"], out["win_v"] = tuple(rk), tuple(rv)
+    if moe is not None:
+        out["moe_counts"] = (moe[0].at[0].add(routed),)
     with jax.named_scope("head_sample"):
         logits = lm_head_logits(x[:, 0, :], other, c)
     return logits, out
@@ -509,13 +592,18 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     are written by one ``paged_kv_scatter`` launch a layer instead of two
     ``dynamic_update_slice``s a page (``_kernel_write``).
 
-    A layer pattern (``config.layer_types``): the layers are walked one by
-    one (no scan: they are not alike). A LINEAR layer runs the chunk scan
-    from a zero state over the ``tlen`` real tokens
-    (``linear_mixer.mixer_prefill``) and writes the state and the
+    A layer pattern (``config.layer_types`` / ``mlp_layer_types``): the
+    layers are walked one by one (no scan: they are not alike). A LINEAR
+    layer runs the chunk scan from a zero state over the ``tlen`` real
+    tokens (``linear_mixer.mixer_prefill``) and writes the state and the
     convolution's tail into row ``slot`` (traced; the engine's slot) of its
     ``state`` / ``conv`` buffers, whatever the slot's last request left
-    there; pages are written for the FULL layers only.
+    there; a SLIDING layer attends through the flash forward with a window
+    (scope ``win_attn``) and writes the prompt's last ``sliding_window``
+    rows into row ``slot`` of its ring (``_ring_of_prompt``); pages are
+    written for the FULL layers only. A SPARSE FFN is the dropless expert
+    layer over the real tokens (``_ffn``); its counts of assignments add to
+    ``cache["moe_counts"][0][1]`` (a burst's to row 0).
     """
     c = config
     if dequant is not None:
@@ -544,27 +632,47 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
         return y, (k, v)
 
     states, tails = list(cache.get("state", ())), list(cache.get("conv", ()))
-    if c.layer_types is None:
+    rk, rv = list(cache.get("win_k", ())), list(cache.get("win_v", ()))
+    moe, routed = cache.get("moe_counts"), 0
+    if c.layer_types is None and c.mlp_layer_types is None:
         x, (ks, vs) = jax.lax.scan(body, x, layer_p)  # ks [L, 1, T, KV, hd]
     else:
         ks, vs = [], []
+        real = positions < tlen                       # [1, T]: not padding
         for layer in range(c.num_hidden_layers):
             lp = layer_params_at(layer_p, c, layer)
             kind, l = c.kind_index(layer)
-            if kind == c.FULL:
-                x, (k, v) = body(x, lp)
-                ks.append(k)
-                vs.append(v)
-                continue
-            mix, state, tail = mixer_prefill(
-                block_in(x, lp["ln1"], c)[0], lp, c, tlen)
-            x = _mlp(x + block_out(mix[None], lp["ln1"], c), lp, c)
-            states[l] = jax.lax.dynamic_update_slice(
-                states[l], state[None].astype(states[l].dtype),
-                (slot,) + (jnp.int32(0),) * 3)
-            tails[l] = jax.lax.dynamic_update_slice(
-                tails[l], tail[None].astype(tails[l].dtype),
-                (slot,) + (jnp.int32(0),) * 2)
+            h = block_in(x, lp["ln1"], c)
+            if kind == c.LINEAR:
+                mix, state, tail = mixer_prefill(h[0], lp, c, tlen)
+                y = x + block_out(mix[None], lp["ln1"], c)
+                states[l] = jax.lax.dynamic_update_slice(
+                    states[l], state[None].astype(states[l].dtype),
+                    (slot,) + (jnp.int32(0),) * 3)
+                tails[l] = jax.lax.dynamic_update_slice(
+                    tails[l], tail[None].astype(tails[l].dtype),
+                    (slot,) + (jnp.int32(0),) * 2)
+            else:
+                q, k, v = attn_qkv(h, lp, c, positions, kind)
+                if kind == c.SLIDING:   # the window's rows go to the ring
+                    with jax.named_scope("win_attn"):
+                        att = _attention(q, k, v, c, window=c.sliding_window)
+                    at = (slot,) + (jnp.int32(0),) * 3
+                    with jax.named_scope("kv_write"):
+                        rk[l] = jax.lax.dynamic_update_slice(
+                            rk[l], _ring_of_prompt(
+                                k[0], tlen, c.sliding_window)[None], at)
+                        rv[l] = jax.lax.dynamic_update_slice(
+                            rv[l], _ring_of_prompt(
+                                v[0], tlen, c.sliding_window)[None], at)
+                else:
+                    att = _attention(q, k, v, c)
+                    ks.append(k)
+                    vs.append(v)
+                y = x + block_out(att.reshape(1, T, -1) @ lp["wo"],
+                                  lp["ln1"], c)
+            x, n = _ffn(y, lp, c, real, interpret, layer_p, layer)
+            routed = routed + n
 
     quant = kv_dtype is not None
     z = jnp.int32(0)
@@ -606,6 +714,10 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
         cache["k_scale"], cache["v_scale"] = tuple(ksl), tuple(vsl)
     if states:
         cache["state"], cache["conv"] = tuple(states), tuple(tails)
+    if rk:
+        cache["win_k"], cache["win_v"] = tuple(rk), tuple(rv)
+    if moe is not None:
+        cache["moe_counts"] = (moe[0].at[1].add(routed),)
 
     last = jax.lax.dynamic_slice_in_dim(x[0], tlen - 1, 1, axis=0)  # [1, D]
     logits = lm_head_logits(last, other, c)

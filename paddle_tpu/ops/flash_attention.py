@@ -70,12 +70,14 @@ def masked_softmax(logits, mask):
     return p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
 
 
-def _fa_reference(q, k, v, causal):
+def _fa_reference(q, k, v, causal, window=None):
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("blhd,bshd->bhls", q, k).astype(jnp.float32) * scale
     if causal:
         ql, kl = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((ql, kl), bool), k=kl - ql)
+        if window is not None:      # row r sees cols r + kl - ql - window < c
+            mask &= ~jnp.tril(jnp.ones((ql, kl), bool), k=kl - ql - window)
         probs = masked_softmax(logits, mask).astype(q.dtype)
     else:
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -83,7 +85,8 @@ def _fa_reference(q, k, v, causal):
 
 
 def flash_attention_raw(q, k, v, causal: bool = False, block_q: int = 512,
-                        block_k: int = 512, mesh=None, spec=None):
+                        block_k: int = 512, mesh=None, spec=None,
+                        window: int | None = None):
     """Raw-jnp-array flash attention ([B, L, H, D] in/out) — the shared entry
     for the Tensor API and model code.
 
@@ -106,24 +109,39 @@ def flash_attention_raw(q, k, v, causal: bool = False, block_q: int = 512,
     adapts to L, S, D and the dtype is the major block a grid step holds
     (`_major_block`) and the scoped VMEM asked for (`_compiler_params`).
     FLAGS_flash_block_q / FLAGS_flash_block_k (env or set_flags) override
-    the tile sizes globally, for sweeps; 0 keeps the caller's value."""
+    the tile sizes globally, for sweeps; 0 keeps the caller's value.
+
+    window (a sliding-window layer's; needs causal): row r sees only the
+    `window` newest of the columns a causal row sees, i - window < j <= i
+    for L == S. The forward's inner loop then STARTS at the tile that holds
+    the row block's oldest visible column (`_kv_first_tile`), so its trip
+    count is the window's and not the prefix's. Forward only: `jax.grad`
+    through a windowed call raises (no cell trains a window). None keeps
+    the trip counts and the arithmetic of a call without it, bit for bit."""
     from ..utils.flags import flag_value
     block_q = int(flag_value("flash_block_q") or block_q)
     block_k = int(flag_value("flash_block_k") or block_k)
     L, S = q.shape[1], k.shape[1]
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window needs causal=True and window >= 1")
     if (L % _MIN_BLOCK) or (S % _MIN_BLOCK) or not flash_attention_tpu_available():
-        return _fa_reference(q, k, v, causal)
+        return _fa_reference(q, k, v, causal, window)
     kernel = functools.partial(_flash_kernel, causal=causal,
                                bq=_fit_block(block_q, L),
-                               bk=_fit_block(block_k, S))
+                               bk=_fit_block(block_k, S), window=window)
     if mesh is not None:
         from ..utils.jax_compat import shard_map
         kernel = shard_map(kernel, mesh, (spec, spec, spec), spec)
     return kernel(q, k, v)
 
 
-def _flash_kernel(q, k, v, *, causal, bq, bk):
+def _flash_kernel(q, k, v, *, causal, bq, bk, window=None):
     D = q.shape[-1]
+    if window is not None:
+        if D % 128:
+            raise NotImplementedError(
+                f"a windowed flash forward needs head_dim % 128 == 0, not {D}")
+        return _flash_fwd_window(q, k, v, window, bq, bk)
     if D % 128 == 0:
         return _flash_fwd_bwd(q, k, v, causal, bq, bk)
     # head_dim 64 (GPT-2 / tiny-llama class): zero-pad D to the 128-lane
@@ -182,6 +200,22 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, sm_scale, res, dout):
 _flash_fwd_bwd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_fwd_window(q, k, v, window, block_q, block_k, interpret=False):
+    """The causal forward under a window; no backward exists."""
+    return _flash_fwd_impl(q, k, v, True, block_q, block_k, interpret,
+                           window=window)[0]
+
+
+def _no_window_grad(*_):
+    raise NotImplementedError(
+        "flash attention with a window is forward only: the backward "
+        "kernels know no window (ROADMAP Queue 2(a) M3)")
+
+
+_flash_fwd_window.defvjp(_no_window_grad, _no_window_grad)
+
+
 def _clip(x, lo, hi):
     return jnp.minimum(jnp.maximum(x, _np.int32(lo)), _np.int32(hi))
 
@@ -213,6 +247,16 @@ def _kv_tiles(qi, block_q, block_k, L, S, causal):
     return _clip(last_row // i32(block_k) + i32(1), 0, n)
 
 
+def _kv_first_tile(qi, block_q, block_k, L, S, window):
+    """The first kv tile of `block_k` that holds a column q block `qi` sees
+    under a window: its first row r = qi * block_q sees columns
+    > r + S - L - window. 0 without a window."""
+    if window is None:
+        return _np.int32(0)
+    first_col = qi * _np.int32(block_q) + _np.int32(S - L - window + 1)
+    return _clip(first_col // _np.int32(block_k), 0, S // block_k)
+
+
 def _q_tiles(ki, block_q, block_k, L, S, causal):
     """The first q tile of `block_q` rows that sees an entry of kv block
     `ki`; the tiles before it are never run."""
@@ -222,13 +266,17 @@ def _q_tiles(ki, block_q, block_k, L, S, causal):
     return _clip(first_col // _np.int32(block_q), 0, L // block_q)
 
 
-def _causal_mask_scores(s, q_axis, q0, k0, off):
+def _causal_mask_scores(s, q_axis, q0, k0, off, window=None):
     """Apply the bottom-right causal mask to a score tile whose q positions
     run along axis `q_axis` from q0 and whose kv positions along the other
-    from k0: q position r sees kv positions <= r + off."""
+    from k0: q position r sees kv positions <= r + off, and under a window
+    only those > r + off - window."""
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
-    return jnp.where(q_pos + _np.int32(off) >= k_pos, s, -jnp.inf)
+    seen = q_pos + _np.int32(off) >= k_pos
+    if window is not None:
+        seen &= q_pos + _np.int32(off - window) < k_pos
+    return jnp.where(seen, s, -jnp.inf)
 
 
 def _row_block(b, h, i, j):
@@ -491,7 +539,7 @@ def _flash_bwd_impl(q, k, v, out, lse, dout, causal, block_q, block_k,
 
 
 def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret=False,
-                    sm_scale=None):
+                    sm_scale=None, window=None):
     """Tiled online-softmax forward in Pallas (interpret=True runs the same
     kernel on CPU for correctness tests without a TPU). Returns out
     [B, L, H, D] in q's dtype and lse [B, H, L] float32 (-inf for a row
@@ -505,7 +553,9 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret=False,
     last block that ran, so nothing is fetched for it. Under `causal` every
     tile that runs is masked (iota / compare / select): a second, maskless
     body for the tiles wholly under the diagonal measured 2-3 % slower, the
-    mask's vector work hides under the products.
+    mask's vector work hides under the products. Under a `window` (causal)
+    the loop starts at `_kv_first_tile` and the mask has the window's lower
+    bound too; None runs the loop from tile 0 as before.
 
     The arithmetic: q, k, v go to the MXU in the dtype they have, with
     float32 accumulation; the scale multiplies the float32 product; exp and
@@ -549,6 +599,9 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret=False,
 
         first = kj * i32(kv_subs)
         run_end = _clip(_kv_tiles(qi, *geometry) - first, 0, kv_subs)
+        run_start = i32(0) if window is None else _clip(
+            _kv_first_tile(qi, block_q, block_k, L, S, window) - first,
+            0, kv_subs)
         qb = q_ref[0, 0]                                  # [block_q, D]
 
         def step(t):
@@ -559,7 +612,8 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret=False,
             m_prev = m_i[...]                             # [block_q, 128]
             if causal:
                 s = _causal_mask_scores(s, 0, qi * i32(block_q),
-                                        (first + t) * i32(block_k), S - L)
+                                        (first + t) * i32(block_k), S - L,
+                                        window)
             m_new = safe_m = jnp.maximum(m_prev,
                                          jnp.max(s, axis=1, keepdims=True))
             if causal:
@@ -576,7 +630,7 @@ def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret=False,
                 preferred_element_type=jnp.float32)
             m_i[...] = m_new
 
-        _for_tiles(pl, kv_subs, i32(0), run_end, step)
+        _for_tiles(pl, kv_subs, run_start, run_end, step)
 
         @pl.when(kj == grid_k - 1)
         def _fin():

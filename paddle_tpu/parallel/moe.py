@@ -11,6 +11,14 @@ the dispatch einsum contracts a replicated token tensor against an
 expert-sharded weight — XLA emits exactly the all-to-all pair the reference's
 global_scatter/global_gather kernels implement, scheduled on ICI. Capacity
 keeps shapes static (XLA requirement); dropped tokens pass through residually.
+
+This is the TRAINING block only (with ``models/llama.py::_moe_block``): a
+token past an expert's capacity (1.25 x the even share) is DROPPED, so the
+output differs from the layer's equations wherever routing is uneven, and no
+reference can agree with it. Serving runs the dropless layer,
+``ops/moe_dropless.py`` (no capacity, sorted rows, grouped products, the
+experts one device holds); training through that layer is ROADMAP Queue 2(a)
+M1.
 """
 from __future__ import annotations
 

@@ -412,3 +412,74 @@ def test_paged_program_of_a_cell_holds_its_kernels(one_chip,
     for name in want:
         assert [ln for ln in calls if name in ln], (name, len(calls))
     assert not _pool_copies(text, pages)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "prefill_slot"])
+def test_paged_program_of_the_expert_cell_holds_its_kernels(
+        one_chip, no_compile_cache, monkeypatch, program):
+    """`k-exaone-236b.reasoning-batch` at three layers of its widths (a
+    window layer with the dense FFN, a window layer and a full layer with
+    experts), its 64 slots, page bucket, pool and longest prompt bucket,
+    compiled whole for a described v5e under paddle_tpu's jax_enable_x64:
+    the burst reads pool AND ring through `paged_decode_attention`, writes
+    both through `paged_kv_scatter`, and runs the experts' three grouped
+    products a sparse layer (`gmm`, whose grid was an i64 the compiler
+    refused until it was traced 32-bit); the prefill holds the flash
+    forward, with a window in two layers of three. No stacked expert weight
+    is copied: the products read a layer's experts in place (a slice as the
+    kernel's operand was 384 MiB a matrix a layer a step, PR 34)."""
+    import dataclasses
+    monkeypatch.setattr(fa, "flash_attention_tpu_available", lambda: True)
+    from paddle_tpu.inference.paging import pages_for_budget
+    from paddle_tpu.inference.replica import _spec_config
+    from paddle_tpu.models import llama_init_params
+    from paddle_tpu.models.llama_paged import (
+        init_paged_kv_cache, llama_paged_decode_burst,
+        llama_paged_prefill_slot, page_bytes, paged_kv_read)
+    from perfbench import harness
+    from perfbench.families import exaone_moe
+    cell = harness.load_cell("k-exaone-236b.reasoning-batch", rehearse=False)
+    eng = cell["traffic"]["engine"]
+    cfg = _spec_config({"config": exaone_moe.model_spec(cell["cfg"],
+                                                        eng["max_len"])})
+    cfg = dataclasses.replace(
+        cfg, num_hidden_layers=3,
+        layer_types=(cfg.SLIDING, cfg.SLIDING, cfg.FULL),
+        mlp_layer_types=(cfg.DENSE, cfg.SPARSE, cfg.SPARSE))
+    ps, B = eng["page_size"], eng["max_batch"]
+    assert paged_kv_read(cfg, ps) == "kernel"
+    pages = pages_for_budget(eng["pool_hbm_bytes"], page_bytes(cfg, ps))
+    pages = min(pages, B * eng["max_len"] // ps + 1)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: llama_init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, pages, ps, max_batch=B)))
+    assert cache["win_k"][0].shape == (B, 128, 8, 128)
+    sds = _shapes(one_chip, "int32")
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    if program == "decode_burst":
+        text = llama_paged_decode_burst.lower(
+            params, cache, sds((B, eng["page_buckets"][-1])), sds((B,)),
+            sds((B,)), sds((B,), "bool"), sds((B,)), sds(()), key,
+            config=cfg, n=eng["burst"], kv_read="kernel",
+            interpret=False).compile().as_text()
+        want = {"paged_decode_attention": 3, "paged_kv_scatter": 3, "gmm": 6}
+    else:
+        bucket = eng["prompt_buckets"][-1]
+        text = llama_paged_prefill_slot.lower(
+            params, cache, sds((bucket,)), sds((bucket // ps,)), sds(()),
+            key, config=cfg, kv_read="kernel", interpret=False,
+            slot=sds(())).compile().as_text()
+        want = {"flash_fwd": 3, "paged_kv_scatter": 1, "gmm": 6}
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name, n in want.items():
+        assert len([ln for ln in calls if name in ln]) == n, (name, len(calls))
+    assert not _pool_copies(text, pages)
+    entry = text[text.index("\nENTRY"):]
+    assert not [ln for ln in entry.splitlines()
+                if " copy(" in ln and "bf16[2,16," in ln]
