@@ -77,6 +77,16 @@ and read back with the step's one ``device_get``:
 ``serve.dispatch_burst`` the arguments ``moe_local`` / ``moe_max`` (that
 burst's assignments on held experts: all, and the busiest expert's).
 
+The burst's weights (ISSUE 35): the paged burst walks the layers unrolled, and
+handed a layer STACK of a projection whose output is split into heads at once
+(q/k/v, a linear mixer's gate) the TPU compiler copies the stack transposed
+at every call and slices each layer's matrix out of the copy at every step.
+At construction the engine therefore hands the burst those leaves a layer at
+a time, in the layout the compiled burst itself asks for
+(``_hand_over_burst_weights``; ``stats["burst_weights"]``, and
+``per_layer_mb`` / ``relaid_mb`` on the ``serve.init`` span, say what was
+handed over, what was re-laid, and why not where it was not).
+
 Prefix sharing (ISSUE 13, ``PADDLE_PREFIX_CACHE_PAGES`` /
 ``prefix_cache_pages=``): a page-granular prefix cache
 (``inference/prefix_cache.py``) over the paged pool lets shared-prompt
@@ -509,6 +519,7 @@ class ContinuousBatcher:
                       "chaos_retired": 0, "max_concurrent": 0,
                       "page_buckets_used": [], "kv_read": self._kv_read,
                       "state_bytes": self.B * self._state_slot_bytes}
+        self._hand_over_burst_weights()
         # the dropless expert layers' assignments, counted on the device
         # (cache["moe_counts"]: burst and prefill rows, one column a held
         # expert and one for the experts held elsewhere) and read back with
@@ -536,6 +547,85 @@ class ContinuousBatcher:
                               self.slo.policy.active
                               or os.environ.get("PADDLE_TRACE_DIR"))
                           else None)
+
+    def _hand_over_burst_weights(self) -> None:
+        """The form of the weights the paged burst takes (ISSUE 35), made
+        once, here: the leaves a decode step would slice whole out of a
+        transposed copy of their layer stack (``heads_at_once_leaves``) as
+        tuples of per-layer arrays, each in the layout the compiled burst
+        asks for. On a TPU the burst for the widest block table is
+        compiled HERE with those layouts left to the compiler
+        (``burst_for_layouts``): the executable says where it wants them,
+        the slices are placed there, and it stays the program of that
+        table (``_burst_programs``; other page buckets compile through
+        ``jax.jit`` for the layouts the arrays then have, and so do the
+        prefills). Elsewhere no compiler has a layout to choose and the
+        slices stay as stored. A model with a layer pattern is walked by
+        index in its prefill too and takes the same leaves, so the engine
+        drops its hold on their stacks; a model of one layer kind keeps
+        them for the programs that scan or index the stack (the bucketed
+        and the suffix prefill, verification). What the engine does not
+        place for (a quantized tree, a serving mesh, the dense layout) it
+        says in ``stats["burst_weights"]["note"]``; the same numbers are the
+        ``serve.init`` span's ``per_layer_mb`` / ``relaid_mb``."""
+        from ..models.llama_paged import burst_for_layouts, per_layer_weights
+        self._burst_params, self._burst_programs = self._params, {}
+        # the paged burst's static arguments, as every dispatch gives them
+        self._burst_static = dict(
+            config=self._cfg, n=self.burst, temperature=self._temp,
+            top_k=self._top_k, pad_id=self.pad_id, dequant=self._dequant,
+            kv_dtype=self._kv_dtype, kv_read=self._kv_read,
+            interpret=self._interpret, mesh=self._mesh)
+        bw = self.stats["burst_weights"] = {
+            "leaves": 0, "bytes": 0, "names": [], "relaid": 0,
+            "relaid_bytes": 0, "relaid_names": [], "note": ""}
+        unplaced = [w for w, on in (
+            ("no weights given", not isinstance(self._params, dict)),
+            ("kv_layout='dense'", self._layout != "paged"),
+            ("quantized weights", self._dequant is not None),
+            ("a serving mesh", self._mesh is not None)) if on]
+        if unplaced:
+            bw["note"] = "stacks as given: " + ", ".join(unplaced)
+            _spans.annotate(per_layer_mb=0.0, relaid_mb=0.0)
+            return
+        formats = None
+        if self._interpret:
+            bw["note"] = ("layouts as stored: no compiler to ask on "
+                          + jax.default_backend())
+        else:
+            embed = self._params["embed_tokens"]
+            P = self._page_buckets[-1]
+            program = burst_for_layouts(
+                per_layer_weights(jax.tree.map(
+                    lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                    self._params), self._cfg),
+                self._cache, self.B, P, embed.sharding, **self._burst_static)
+            formats = program.input_formats[0][0]
+            self._burst_programs[P] = program
+            # what a program with a placed argument returns is committed to
+            # its device: so is the pool from the start (no copy), or each
+            # prefill would compile once for the fresh pool and once more
+            self._cache = jax.device_put(self._cache, embed.sharding)
+        placed = per_layer_weights(self._params, self._cfg, formats)
+        for name, v in placed.items():
+            if not isinstance(v, tuple):
+                continue
+            stack = self._params[name]
+            size = stack.nbytes // len(v)
+            relaid = sum(a.format.layout != stack.format.layout for a in v) \
+                if formats is not None else 0
+            bw["names"].append(name)
+            bw["leaves"] += len(v)
+            bw["bytes"] += stack.nbytes
+            if relaid:
+                bw["relaid_names"].append(name)
+                bw["relaid"] += relaid
+                bw["relaid_bytes"] += relaid * size
+        self._burst_params = placed
+        if self._walked:    # its prefill walks by index: one form a leaf
+            self._params = placed
+        _spans.annotate(per_layer_mb=round(bw["bytes"] / 1e6, 3),
+                        relaid_mb=round(bw["relaid_bytes"] / 1e6, 3))
 
     def _model_shaped_pool(self, what: str) -> None:
         """Refuse ``what`` by name where the pool's rows are padded."""
@@ -1051,16 +1141,14 @@ class ContinuousBatcher:
 
         old_pos = self._pos.copy()
         self._key, sub = jax.random.split(self._key)
-        (self._cache, pos_d, tok_d, done_d, emitted_d) = \
-            llama_paged_decode_burst(
-                self._params, self._cache, jnp.asarray(bt),
+        args = (self._burst_params, self._cache, jnp.asarray(bt),
                 jnp.asarray(self._pos), jnp.asarray(self._tok),
                 jnp.asarray(self._done), jnp.asarray(self._limit),
-                jnp.int32(self.eos_id), sub, config=self._cfg, n=self.burst,
-                temperature=self._temp, top_k=self._top_k,
-                pad_id=self.pad_id, dequant=self._dequant,
-                kv_dtype=self._kv_dtype, kv_read=self._kv_read,
-                interpret=self._interpret, mesh=self._mesh)
+                jnp.int32(self.eos_id), sub)
+        program = self._burst_programs.get(P)   # compiled at load, or jit's
+        (self._cache, pos_d, tok_d, done_d, emitted_d) = \
+            program(*args) if program is not None else \
+            llama_paged_decode_burst(*args, **self._burst_static)
         self.stats["bursts"] += 1
         self.stats["decode_steps"] += self.burst
         return old_pos, pos_d, tok_d, done_d, emitted_d

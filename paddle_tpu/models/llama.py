@@ -767,7 +767,7 @@ def layer_params_at(layer_p, config: LlamaConfig, layer: int) -> dict:
     of its FFN kind (``ffn_index``), and only the norms over all."""
     c = config
     if c.layer_types is None and c.mlp_layer_types is None:
-        return jax.tree.map(lambda a: a[layer], layer_p)
+        return {k: v[layer] for k, v in layer_p.items()}
     kind, at = c.kind_index(layer)
     linear = kind == c.LINEAR
     ffn, fi = c.ffn_index(layer)
@@ -814,6 +814,22 @@ def attn_qkv(h, lp, config: LlamaConfig, positions, kind: str | None = None):
     if c.rotates(kind or c.FULL):
         q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
     return q, k, v
+
+
+def heads_at_once_leaves(config: LlamaConfig) -> tuple:
+    """The layer leaves whose product's output is split into heads AT ONCE
+    (``attn_qkv``: ``(h @ wq).reshape(.., H, hd)``; ``linear_mixer._out``:
+    ``(h @ lin_wg).reshape(.., Hv, dv)``): the ones a decode step that is
+    handed layer STACKS slices out whole, a matrix a layer, from a
+    transposed copy of the stack (ISSUE 35). A QK-norm over the whole
+    projection stands between product and split, and such a q / k is read in
+    place like every other matrix. ``llama_paged.per_layer_weights`` hands
+    the burst these a layer at a time; which LAYOUT each then takes is the
+    compiler's say, and ``tests/test_tpu_compile.py`` holds this rule to it."""
+    c = config
+    whole_norm = c.qk_norm and not c.qk_norm_per_head
+    return (("wv",) if whole_norm else ("wq", "wk", "wv")) \
+        + (("lin_wg",) if c.is_recurrent else ())
 
 
 def resolve_head(other):

@@ -65,7 +65,8 @@ import jax.numpy as jnp
 
 from .linear_mixer import mixer_prefill, mixer_step
 from .llama import LlamaConfig, _rmsnorm, _rope, attn_qkv, block_in, \
-    block_out, layer_params_at, lm_head_logits, split_layer_params
+    block_out, heads_at_once_leaves, layer_params_at, lm_head_logits, \
+    split_layer_params
 from ..ops.moe_dropless import moe_dropless
 from ..ops.ragged_attention import decode_supported, \
     paged_decode_attention, paged_kv_scatter, scatter_supported
@@ -74,7 +75,7 @@ from .llama_decode import _cached_attention_slots, _mlp, _qkv, _sample
 __all__ = ["init_paged_kv_cache", "paged_kv_read", "pool_kv_heads",
            "llama_paged_prefill_slot",
            "llama_paged_prefill_suffix", "llama_paged_decode_burst",
-           "llama_paged_verify",
+           "llama_paged_verify", "per_layer_weights", "burst_for_layouts",
            "paged_kv_bytes_per_token", "page_bytes",
            "gather_pages", "scatter_pages", "copy_pages"]
 
@@ -634,7 +635,8 @@ def llama_paged_prefill_slot(params, cache, tokens, page_ids, tlen, key,
     states, tails = list(cache.get("state", ())), list(cache.get("conv", ()))
     rk, rv = list(cache.get("win_k", ())), list(cache.get("win_v", ()))
     moe, routed = cache.get("moe_counts"), 0
-    if c.layer_types is None and c.mlp_layer_types is None:
+    if c.layer_types is None and c.mlp_layer_types is None \
+            and not any(isinstance(v, tuple) for v in layer_p.values()):
         x, (ks, vs) = jax.lax.scan(body, x, layer_p)  # ks [L, 1, T, KV, hd]
     else:
         ks, vs = [], []
@@ -799,7 +801,7 @@ def llama_paged_prefill_suffix(params, cache, tokens, page_ids,
     ksl = list(cache["k_scale"]) if quant else None
     vsl = list(cache["v_scale"]) if quant else None
     for l in range(c.num_hidden_layers):
-        lp = jax.tree.map(lambda a: a[l], layer_p)
+        lp = layer_params_at(layer_p, c, l)
         h = _rmsnorm(x, lp["ln1"], c.rms_norm_eps)
         q, k, v = _qkv(h, lp, c)
         q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)
@@ -897,6 +899,70 @@ def llama_paged_decode_burst(params, cache, block_table, pos, tok, done,
     return cache, pos, tok, done, emitted
 
 
+# ------------------------------- the burst's weights, a layer at a time (ISSUE 35)
+# A decode step walks the layers unrolled with static indices. Handed a layer
+# STACK of a projection whose output is split into heads at once, XLA's TPU
+# compiler transposes the whole stack at every call and then slices every
+# layer's matrix out of that copy into a buffer of its own at every step
+# (5-14 % of the serving cells' device time). Handed the same leaf a layer at
+# a time, in the layout the compiled burst asks for, each matrix goes from the
+# parameter to VMEM and nothing else. The engine makes that form once at load.
+
+
+def per_layer_weights(params, config: LlamaConfig, formats=None) -> dict:
+    """``params`` with the leaves of ``heads_at_once_leaves`` as TUPLES of
+    per-layer arrays in place of their layer stacks (``layer_params_at``
+    indexes either), every other leaf as it is (the experts' stacks too:
+    the grouped products read them in place). ``formats``: {leaf: a
+    ``Format`` a layer}, as ``burst_for_layouts(...).input_formats`` names
+    them: each slice is placed in its own; None leaves the slices in the
+    default layout. Arrays or ``ShapeDtypeStruct``s."""
+    out = dict(params)
+    for name in heads_at_once_leaves(config):
+        stack = params.get(name)
+        if stack is None or isinstance(stack, tuple) or not stack.shape[0]:
+            continue
+        if isinstance(stack, jax.ShapeDtypeStruct):
+            out[name] = (jax.ShapeDtypeStruct(stack.shape[1:], stack.dtype),
+                         ) * stack.shape[0]
+        elif formats is None:
+            out[name] = tuple(stack[i] for i in range(stack.shape[0]))
+        else:   # one program a stack: every slice written once, where asked
+            out[name] = jax.jit(tuple, out_shardings=tuple(formats[name]))(
+                stack)
+    return out
+
+
+def burst_for_layouts(params, cache, slots: int, pages: int, sharding,
+                      **static):
+    """``llama_paged_decode_burst`` for a [slots, pages] block table,
+    compiled ahead of time with the LAYOUT of every per-layer leaf of
+    ``params`` (a tuple: ``per_layer_weights``) left to the compiler
+    (``Layout.AUTO``) and every other argument as stored. ``params`` /
+    ``cache``: arrays or ``ShapeDtypeStruct``s (only shapes are read);
+    ``sharding``: the one device's; ``static``: the burst's own static
+    arguments. Returns the ``jax.stages.Compiled``: it IS the burst program
+    for that table (call it with the dynamic arguments), and
+    ``.input_formats[0][0]`` says which format it wants each leaf of
+    ``params`` in. Asking is compiling, so the engine compiles once."""
+    from jax.experimental.layout import Format, Layout
+    auto = Format(Layout.AUTO, sharding)
+    formats = {k: (auto,) * len(v) if isinstance(v, tuple) else sharding
+               for k, v in params.items()}
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), (params, cache))
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    rest = (jax.ShapeDtypeStruct((slots, pages), jnp.int32), i32, i32,
+            jax.ShapeDtypeStruct((slots,), jnp.bool_), i32,
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+
+    return jax.jit(
+        functools.partial(llama_paged_decode_burst.__wrapped__, **static),
+        in_shardings=(formats,) + (sharding,) * 8,
+        donate_argnums=(1,)).lower(*shapes, *rest).compile()
+
+
 # ------------------------------------------------------- verify (ISSUE 14)
 # Speculative decoding's target half: each verifying slot's row carries
 # [current_tok, d_1 .. d_np] — its np draft proposals behind the token the
@@ -989,7 +1055,7 @@ def llama_paged_verify(params, cache, block_table, start, tokens, n_tok,
     kss = list(cache["k_scale"]) if quant else None
     vss = list(cache["v_scale"]) if quant else None
     for l in range(c.num_hidden_layers):
-        lp = jax.tree.map(lambda a: a[l], layer_p)
+        lp = layer_params_at(layer_p, c, l)
         h = _rmsnorm(x, lp["ln1"], c.rms_norm_eps)
         q, k, v = _qkv(h, lp, c)
         q, k = _rope(q, k, positions, c.rope_theta, c.head_dim)

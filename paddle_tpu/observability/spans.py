@@ -71,7 +71,8 @@ except (ImportError, AttributeError):
 
 from . import metrics
 
-__all__ = ["span", "traced", "add_span", "now", "now_ns", "records", "Span",
+__all__ = ["span", "traced", "annotate", "add_span", "now", "now_ns", "records",
+           "Span",
            "tracing_enabled", "enable_tracing", "disable_tracing",
            "export_chrome_trace", "reset", "events", "events_since",
            "dropped", "capacity", "set_trace_metadata", "watch_compiles",
@@ -98,7 +99,7 @@ _ring: deque = deque(maxlen=DEFAULT_CAPACITY)   # Span fields, as tuples
 _ids = itertools.count(1)       # a span's id, taken when it begins
 _seq = itertools.count(1)       # its place in the ring, taken when it ends
 _appended = [0]                 # the newest seq handed out
-_tls = threading.local()        # .stack: ids of this thread's open spans
+_tls = threading.local()        # .stack: this thread's open (nested) spans
 _atexit_registered = [False]
 _extra_meta: dict = {}  # merged into export otherData (xplane links etc.)
 
@@ -136,9 +137,9 @@ class _Span:
         leaves no stale parent on this thread's stack."""
         stack = _stack()
         self.id = next(_ids)
-        self.parent = stack[-1] if stack else 0
+        self.parent = stack[-1].id if stack else 0
         if nest:
-            stack.append(self.id)
+            stack.append(self)
         self._ann = _Annotation(self.name, **self.args) if self.args \
             else _Annotation(self.name)
         self._ann.__enter__()
@@ -151,10 +152,10 @@ class _Span:
         t1 = now_ns()
         self._ann.__exit__(None, None, None)
         stack = _stack()
-        if stack and stack[-1] == self.id:
+        if stack and stack[-1] is self:
             stack.pop()
-        elif self.id in stack:      # ended out of order (manual begin/end)
-            stack.remove(self.id)
+        elif self in stack:         # ended out of order (manual begin/end)
+            stack.remove(self)
         _append(self.name, self.cat, self._t0, t1, self.id, self.parent,
                 self.args)
         self._t0 = None
@@ -176,6 +177,16 @@ def span(name: str, cat: str = "user", **args):
     serve / compile / profiler / user). Extra kwargs (small ints and strs)
     become the span's arguments."""
     return _Span(name, cat, args or None)
+
+
+def annotate(**args) -> None:
+    """Add arguments to the calling thread's innermost open span: for a
+    function under ``@traced`` that learns them while it runs. They reach
+    the ring when the span ends; the profiler's annotation, made at the
+    span's start, does not have them. Outside any span it does nothing."""
+    stack = _stack()
+    if stack:
+        stack[-1].args = {**(stack[-1].args or {}), **args}
 
 
 def traced(name: str, cat: str = "user", **args):
@@ -201,7 +212,7 @@ def add_span(name: str, cat: str, t0: float, t1: float, **args):
     innermost open span; nothing is annotated after the fact."""
     stack = _stack()
     _append(name, cat, int(float(t0) * 1e9), int(float(t1) * 1e9),
-            next(_ids), stack[-1] if stack else 0, args or None)
+            next(_ids), stack[-1].id if stack else 0, args or None)
 
 
 # ------------------------------------------------------------------ reads

@@ -340,18 +340,9 @@ def _cell_model(workload):
     through its own family modules, so that they cannot drift. The hybrid's
     one layer is a FULL layer: 30 KV heads, a pool padded to 32."""
     import dataclasses
-    from paddle_tpu.inference.replica import _spec_config
-    from perfbench import harness
-    from perfbench.families import llama, olmo_hybrid
-    cell = harness.load_cell(workload, rehearse=False)
-    cfg, settings = cell["cfg"], cell["traffic"]["engine"]
-    if cfg["family"] == "llama":
-        model = llama.llama_config(cfg, settings["max_len"])
-        kinds = None
-    else:
-        model = _spec_config({"config": olmo_hybrid.model_spec(
-            cfg, settings["max_len"])})
-        kinds = (model.FULL,)
+    from tools.burst_weight_copies import cell_model
+    model, settings = cell_model(workload)
+    kinds = None if model.layer_types is None else (model.FULL,)
     return dataclasses.replace(model, num_hidden_layers=1,
                                layer_types=kinds), settings
 
@@ -483,3 +474,44 @@ def test_paged_program_of_the_expert_cell_holds_its_kernels(
     entry = text[text.index("\nENTRY"):]
     assert not [ln for ln in entry.splitlines()
                 if " copy(" in ln and "bf16[2,16," in ln]
+
+
+# ------------- the burst's weights a layer at a time, at the cells' depth (PR 35)
+
+@pytest.mark.parametrize("workload", [
+    "internlm2-1.8b.longctx-batch", "olmo-hybrid-7b.longdoc-batch",
+    "k-exaone-236b.reasoning-batch"])
+def test_burst_of_a_cell_copies_no_whole_weight_matrix(one_chip,
+                                                       no_compile_cache,
+                                                       workload):
+    """A serving cell's burst at the cell's widths, slots, page bucket, pool
+    and FULL depth (at 4 layers the stacks fit VMEM and XLA does something
+    else). THE CONTROL, which proves the check sees: handed the layer stacks
+    in the default layout (what the engine compiled until PR 35), the
+    compiled burst transposes the whole stack of every projection whose
+    output is split into heads at once (`heads_at_once_leaves`) at its entry
+    and slices each layer's matrix out of that copy into a buffer of its own
+    at every decode step: 0.40 / 0.65 / 1.0 GB a step. THE ENGINE'S: handed
+    those leaves a layer at a time with their layout left to the compiler
+    (`per_layer_weights`, `burst_for_layouts`), it asks each matrix
+    column-major and no such copy is left, at the entry or in the loop: this
+    is what holds the rule to the compiler."""
+    from paddle_tpu.models.llama import heads_at_once_leaves
+    from tools import burst_weight_copies as bwc
+    cfg, _, params, _ = bwc._cell(workload, "stacks")
+    named = [n for n in heads_at_once_leaves(cfg) if n in params]
+    per_step = sum(params[n].size * 2 for n in named)
+    shapes = {"bf16[1,%d,%d]" % params[n].shape[1:] for n in named}
+
+    control = bwc.copies(bwc.burst_program(workload, one_chip,
+                                           "stacks").as_text())
+    loop = [r for r in control if r["where"] == "loop"]
+    assert {r["shape"] for r in loop} == shapes, control
+    # the layers whose slice lands in VMEM at once (2-5 of them) not counted
+    assert 0.6 * per_step <= sum(r["bytes"] for r in loop) <= per_step
+    assert [r for r in control if r["where"] == "entry"], control
+
+    burst = bwc.burst_program(workload, one_chip, "engine")
+    assert bwc.asked_layouts(burst) == dict.fromkeys(named, (1, 0))
+    assert bwc.copies(burst.as_text()) == []
+
