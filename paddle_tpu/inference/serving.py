@@ -123,7 +123,9 @@ the gather does),
 Spans (observability.spans, on the device trace's clock): every ``step()``
 is one ``serve.step`` (args: burst number, live slots) whose children are
 ``serve.dispatch_burst`` (page growth, block table, transfers, the async
-launch; args ``kv_read``: "kernel", "gather" or "dense", on the paged
+launch; args ``kv_read``: "kernel", "gather" or "dense", ``gdn_step``:
+which body the gated delta rule of a LINEAR layer takes, "kernel" (one
+Pallas pass over the state), "jnp" or "none" (no such layer), on the paged
 path ``state``: bytes of per-slot state (recurrent state, rings) the slots
 in use hold, and for a spec with expert layers ``moe_local`` / ``moe_max``,
 set when the step's readback has come),
@@ -520,10 +522,20 @@ class ContinuousBatcher:
         # construction (a slot's rows are overwritten by its next prefill),
         # never by the pool
         self._state_slot_bytes = model_config.state_bytes_per_request()
+        # which body a decode step's gated delta rule takes (stats
+        # ["gdn_step"] and the serve.dispatch_burst span's argument): the
+        # Pallas pass over the state, jax.numpy, or none without a LINEAR
+        # layer
+        self._gdn_step = "none"
+        if model_config.is_recurrent:
+            from ..ops.gated_delta import gdn_step_body
+            self._gdn_step = gdn_step_body(
+                *model_config.state_shapes(self.B)["state"])
         self.stats = {"bursts": 0, "decode_steps": 0, "prefills": 0,
                       "admission_stalls": 0, "preemptions": 0,
                       "chaos_retired": 0, "max_concurrent": 0,
                       "page_buckets_used": [], "kv_read": self._kv_read,
+                      "gdn_step": self._gdn_step,
                       "state_bytes": self.B * self._state_slot_bytes}
         self._hand_over_burst_weights()
         # the dropless expert layers' assignments, counted on the device
@@ -1735,6 +1747,7 @@ class ContinuousBatcher:
                     metrics.gauge("serve.state_mb_held").set(state_live / 1e6)
                 with _spans.span("serve.dispatch_burst", cat="serve",
                                  kv_read=self._kv_read,
+                                 gdn_step=self._gdn_step,
                                  state=state_live) as dispatched:
                     inflight = self._dispatch_burst_paged()
                 with _spans.span("serve.admit", cat="serve") as sp:
@@ -1777,7 +1790,7 @@ class ContinuousBatcher:
         old_pos = self._pos.copy()
         t0 = _slo.now()
         with _spans.span("serve.dispatch_burst", cat="serve",
-                         kv_read=self._kv_read):
+                         kv_read=self._kv_read, gdn_step=self._gdn_step):
             self._key, sub = jax.random.split(self._key)
             (self._cache, pos_d, tok_d, done_d, emitted) = \
                 llama_decode_burst(

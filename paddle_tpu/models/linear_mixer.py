@@ -85,10 +85,12 @@ def mixer_prefill(h, lp, config, tlen):
         return _out(o, h, lp, c), state.astype(c.state_dtype), tail
 
 
-def mixer_step(h, lp, config, state, tail, frozen):
+def mixer_step(h, lp, config, state, tail, frozen, interpret=None):
     """One token of every slot. h [B, D]; state [B, Hv, dv, dk]; tail [B,
     K - 1, conv_dim]; ``frozen`` [B] bool: slots whose state and tail stay
-    as they are (finished, or free). Returns (y [B, D], state, tail)."""
+    as they are (finished, or free). The rule is ``gdn_step``'s body for
+    the state (``interpret``: as it takes it). Returns (y [B, D], state,
+    tail)."""
     c = config
     with jax.named_scope("lin_proj"):
         x = h @ lp["lin_wqkv"]                              # [B, conv_dim]
@@ -99,8 +101,7 @@ def mixer_step(h, lp, config, state, tail, frozen):
         g, beta = _gates(h, lp, c)
         new_tail = jnp.where(frozen[:, None, None], tail, window[:, 1:])
     with jax.named_scope("gdn_step"):
-        o, new = gdn_step(q, k, v, g, beta, state.astype(_F32))
-        new = jnp.where(frozen[:, None, None, None], state,
-                        new.astype(state.dtype))
+        o, new = gdn_step(q, k, v, g, beta, state, frozen,
+                          interpret=interpret)
     with jax.named_scope("lin_proj"):
         return _out(o, h, lp, c), new, new_tail

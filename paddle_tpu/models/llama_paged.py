@@ -516,7 +516,7 @@ def _paged_decode_step_slots(params, cache, block_table, pos, tok,
             early = _route_early(x, lp, c)
         if kind == c.LINEAR:
             mix, states[l], tails[l] = mixer_step(
-                h[:, 0], lp, c, states[l], tails[l], frozen)
+                h[:, 0], lp, c, states[l], tails[l], frozen, interpret)
             y = x + block_out(mix[:, None], lp["ln1"], c)
             with jax.named_scope("mlp"):
                 x, n = _ffn(y, lp, c, real, interpret, layer_p, layer, early)

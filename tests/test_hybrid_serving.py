@@ -162,6 +162,29 @@ def test_state_is_reported_on_the_span_and_the_gauge(params):
     assert "serve.state_mb_held" in metrics.snapshot()["gauges"]
 
 
+def test_the_rules_body_is_on_every_burst_span(params):
+    """Which body a decode step's gated delta rule takes: the one Pallas
+    pass over the state (interpreted here) for a float32 state, jax.numpy
+    for a state in another dtype, none without a LINEAR layer; the engine
+    says so in its stats and on every serve.dispatch_burst span."""
+    eng = build_batcher(spec(), params=params)
+    assert eng.stats["gdn_step"] == "kernel"
+    t0 = time.time_ns()
+    serve(eng, [(9, 12), (12, 12)])
+    bodies = [s.args.get("gdn_step") for s in spans.records()
+              if s.name == "serve.dispatch_burst" and s.t0_ns >= t0]
+    assert bodies and set(bodies) == {"kernel"}
+    low = json.loads(json.dumps({"config": {**CONFIG,
+                                            "state_dtype": "bfloat16"},
+                                 "seed": 1, "batcher": BATCHER}))
+    assert build_batcher(low).stats["gdn_step"] == "jnp"
+    cfg = LlamaConfig.tiny()
+    plain = ContinuousBatcher(cfg, llama_init_params(cfg,
+                                                     jax.random.PRNGKey(0)),
+                              max_batch=2, max_len=64, page_size=8)
+    assert plain.stats["gdn_step"] == "none"
+
+
 REFUSED = [
     (dict(prefix_cache_pages=4), "a shared page holds K/V rows"),
     (dict(kv_layout="ragged"), "unknown kv_layout 'ragged'"),

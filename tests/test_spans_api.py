@@ -320,7 +320,7 @@ def test_every_pallas_call_has_a_name():
     assert set(found) == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
         "paged_decode_attention", "paged_kv_scatter", "block_sparse_fwd", "block_sparse_bwd_dq",
-        "block_sparse_bwd_dkv"}
+        "block_sparse_bwd_dkv", "gdn_step"}
 
 
 def test_trace_annotation_lives_in_one_place():

@@ -317,21 +317,101 @@ def test_pool_of_30_kv_heads_is_padded_to_whole_tiles(one_chip,
 def test_gated_delta_rule_compiles_at_the_cells_shapes(one_chip,
                                                        no_compile_cache):
     """ops/gated_delta.py at Olmo-Hybrid's widths (30 heads, key 96, value
-    192) for a described v5e: one token of 24 slots, and the chunk scan
-    over the longer prompt bucket of perfbench/traffic/longdoc-batch.json."""
+    192) for a described v5e: one token of 24 slots (the Pallas pass, its
+    state donated and updated in place), and the chunk scan over the
+    longer prompt bucket of perfbench/traffic/longdoc-batch.json."""
+    import functools
     from paddle_tpu.ops.gated_delta import gdn_chunk_scan, gdn_step
     sds = _shapes(one_chip)
     B, T, H, dk, dv = 24, 3072, 30, 96, 192
-    step = jax.jit(gdn_step).lower(
+    step = jax.jit(functools.partial(gdn_step, interpret=False),
+                   donate_argnums=(5,)).lower(
         sds((B, H, dk)), sds((B, H, dk)), sds((B, H, dv)),
         sds((B, H), "float32"), sds((B, H), "float32"),
-        sds((B, H, dv, dk), "float32")).compile()
+        sds((B, H, dv, dk), "float32"), sds((B,), "bool")).compile()
     assert step.memory_analysis().temp_size_in_bytes < 2 * B * H * dv * 128 * 4
+    assert _kernels(step) == 1
     scan = jax.jit(gdn_chunk_scan).lower(
         sds((T, H, dk)), sds((T, H, dk)), sds((T, H, dv)),
         sds((T, H), "float32"), sds((T, H), "float32"),
         sds((H, dv, dk), "float32"), sds((), "int32")).compile()
     assert scan.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+_STATE = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+                    r"(fusion|copy|copy-start|select|add|custom-call)\(", re.M)
+
+
+def _hybrid_burst(one_chip, workload="olmo-hybrid-7b.longdoc-batch"):
+    """The hybrid cell's burst at one period of its layer pattern (three
+    LINEAR layers and a FULL one) at the cell's widths, 24 slots, page
+    bucket and pool, compiled for a described v5e: (its text, the state's
+    type, the LINEAR layers)."""
+    import dataclasses
+    from paddle_tpu.inference.paging import pages_for_budget
+    from paddle_tpu.models import llama_init_params
+    from paddle_tpu.models.llama_paged import (
+        init_paged_kv_cache, llama_paged_decode_burst, page_bytes)
+    from tools.burst_weight_copies import cell_model
+    cfg, eng = cell_model(workload)
+    cfg = dataclasses.replace(cfg, num_hidden_layers=4,
+                              layer_types=cfg.layer_types[:4])
+    ps, B = eng["page_size"], eng["max_batch"]
+    pages = min(pages_for_budget(eng["pool_hbm_bytes"], page_bytes(cfg, ps)),
+                B * eng["max_len"] // ps + 1)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: llama_init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_paged_kv_cache(cfg, pages, ps, max_batch=B)))
+    sds = _shapes(one_chip, "int32")
+    key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    text = llama_paged_decode_burst.lower(
+        params, cache, sds((B, eng["page_buckets"][-1])), sds((B,)),
+        sds((B,)), sds((B,), "bool"), sds((B,)), sds(()), key, config=cfg,
+        n=eng["burst"], kv_read="kernel", interpret=False).compile().as_text()
+    shape, _ = cfg.state_shapes(B)["state"]
+    return text, "f32[%s]" % ",".join(map(str, shape)), cfg.num_linear_layers
+
+
+def test_hybrid_burst_updates_each_state_in_place_by_one_kernel(
+        one_chip, no_compile_cache, monkeypatch):
+    """The hybrid's burst at 24 slots: one `gdn_step` kernel a LINEAR layer,
+    its state operand aliased to its output (the carried state is updated
+    in place), and no copy, select or add over the state's shape beside it.
+    THE CONTROL, which proves the check sees: with the jnp body forced (the
+    rule's body is chosen at trace time, so the burst is traced anew), XLA
+    writes the states in multi-output `add_select` fusions that read each
+    state again, as the cell's traces showed before."""
+    from paddle_tpu.models import llama_paged
+    from paddle_tpu.ops import gated_delta
+    text, state, linear = _hybrid_burst(one_chip)
+    over = [(n, op) for n, types, op in _STATE.findall(text) if state in types]
+    kernels = [ln for ln in text.splitlines()
+               if re.match(r"\s*%gdn_step[\w.]* = ", ln)]
+    assert len(kernels) == linear == 3
+    assert all("tpu_custom_call" in ln and "output_to_operand_aliasing="
+               "{{1}: (7, {})}" in ln for ln in kernels)
+    assert sorted(op for _, op in over) == ["custom-call"] * linear, over
+
+    traced = llama_paged.llama_paged_decode_burst.__wrapped__
+
+    def burst(*args, **kwargs):      # a function of its own: traced anew
+        return traced(*args, **kwargs)
+
+    monkeypatch.setattr(gated_delta, "gdn_step_body", lambda *a: "jnp")
+    monkeypatch.setattr(llama_paged, "llama_paged_decode_burst", jax.jit(
+        burst, static_argnames=("config", "n", "kv_read", "interpret"),
+        donate_argnums=(1,)))
+    control, _, _ = _hybrid_burst(one_chip)
+    fusions = [n for n, types, op in _STATE.findall(control)
+               if state in types and op == "fusion"]
+    assert [n for n in fusions if "add_select" in n], fusions
+    assert "%gdn_step" not in control
 
 
 # ------------------- the serving cells' two programs, the kernels inside them
