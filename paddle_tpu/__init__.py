@@ -19,10 +19,12 @@ import jax as _jax
 _jax.config.update("jax_enable_x64", True)
 
 # every later compilation (the rest of this import's included) becomes a
-# compile.* span: three listeners registered, nothing started or compiled
+# compile.* span, and every collection of the garbage collector counts:
+# four listeners registered, nothing started or compiled
 from .observability import spans as _spans
 
 _spans.watch_compiles()
+_spans.watch_gc()
 
 # -- core ---------------------------------------------------------------
 from .core import dtypes as _dtypes

@@ -128,7 +128,9 @@ which body the gated delta rule of a LINEAR layer takes, "kernel" (one
 Pallas pass over the state), "jnp" or "none" (no such layer), on the paged
 path ``state``: bytes of per-slot state (recurrent state, rings) the slots
 in use hold, and for a spec with expert layers ``moe_local`` / ``moe_max``,
-set when the step's readback has come),
+set when the step's readback has come; three overlay spans,
+``DISPATCH_PHASES``, tile its paged body: ``serve.dispatch.prep``, ``.put``,
+``.launch``),
 ``serve.admit``
 (pop, bucket, allocate, prefill dispatch; under the in-flight burst on the
 paged path; arg: prefills staged),
@@ -186,6 +188,13 @@ __all__ = ["ContinuousBatcher", "PredictorPool", "ServedRequest"]
 # overload thresholds never fire through it (decide_deadline only reads
 # the TTFT histogram), so defaults are irrelevant beyond construction
 _DEADLINE_GATE = AdmissionPolicy()
+
+# overlay spans that tile a paged dispatch after its early returns: page
+# growth, gauges, bucket and numpy block table; the key split and the
+# host-to-device transfers; the call of the burst executable until it
+# returns. Overlays, so the self time of serve.dispatch_burst stays whole
+DISPATCH_PHASES = ("serve.dispatch.prep", "serve.dispatch.put",
+                   "serve.dispatch.launch")
 
 
 @dataclasses.dataclass
@@ -1136,6 +1145,7 @@ class ContinuousBatcher:
         except chaos.ChaosError:
             self._retire_all_active("chaos serve.burst")
             return None
+        t_prep = _spans.now()
         active = self._grow_for_burst(active)
         if not active:
             return None
@@ -1159,15 +1169,21 @@ class ContinuousBatcher:
             bt[b, :len(ids)] = ids
 
         old_pos = self._pos.copy()
+        t_put = _spans.now()
         self._key, sub = jax.random.split(self._key)
         args = (self._burst_params, self._cache, jnp.asarray(bt),
                 jnp.asarray(self._pos), jnp.asarray(self._tok),
                 jnp.asarray(self._done), jnp.asarray(self._limit),
                 jnp.int32(self.eos_id), sub)
+        t_launch = _spans.now()
         program = self._burst_programs.get(P)   # compiled at load, or jit's
         (self._cache, pos_d, tok_d, done_d, emitted_d) = \
             program(*args) if program is not None else \
             llama_paged_decode_burst(*args, **self._burst_static)
+        t_end = _spans.now()
+        for name, t0, t1 in zip(DISPATCH_PHASES, (t_prep, t_put, t_launch),
+                                (t_put, t_launch, t_end)):
+            _spans.add_span(name, "serve", t0, t1, overlay=True)
         self.stats["bursts"] += 1
         self.stats["decode_steps"] += self.burst
         return old_pos, pos_d, tok_d, done_d, emitted_d

@@ -1010,9 +1010,12 @@ def burst_for_layouts(params, cache, slots: int, pages: int, sharding,
             jax.ShapeDtypeStruct((), jnp.int32),
             jax.eval_shape(lambda: jax.random.PRNGKey(0)))
 
+    burst = functools.partial(llama_paged_decode_burst.__wrapped__, **static)
+    # jit names a program by its function's __name__, which a partial lacks
+    # (a trace would show "jit__unknown"): the burst keeps its own name
+    burst.__name__ = llama_paged_decode_burst.__name__
     return jax.jit(
-        functools.partial(llama_paged_decode_burst.__wrapped__, **static),
-        in_shardings=(formats,) + (sharding,) * 8,
+        burst, in_shardings=(formats,) + (sharding,) * 8,
         donate_argnums=(1,)).lower(*shapes, *rest).compile()
 
 

@@ -122,8 +122,11 @@ def otlp_payload(snap: dict, labels: dict | None = None,
         attrs.append({"key": str(k), "value": {"stringValue": str(v)}})
     out = []
     for n, v in snap.get("counters", {}).items():
+        # a counter of seconds (gc.seconds) is a float: no truncation
+        value = {"asInt": str(v)} if isinstance(v, int) \
+            else {"asDouble": float(v)}
         out.append({"name": n, "sum": {
-            "dataPoints": [{"asInt": str(int(v)), "timeUnixNano": str(t)}],
+            "dataPoints": [{**value, "timeUnixNano": str(t)}],
             "aggregationTemporality": 2, "isMonotonic": True}})
     for n, v in snap.get("gauges", {}).items():
         out.append({"name": n, "gauge": {

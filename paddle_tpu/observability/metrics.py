@@ -31,7 +31,8 @@ import threading
 import time
 from collections import deque
 
-__all__ = ["Counter", "Gauge", "Histogram", "counter", "gauge", "histogram",
+__all__ = ["Counter", "Gauge", "Histogram", "counter", "counter_nowait",
+           "gauge", "histogram",
            "remove_gauge", "snapshot", "counter_values", "timer", "set_sink",
            "maybe_emit_step", "reset", "DEFAULT_BUCKETS"]
 
@@ -185,6 +186,19 @@ def counter(name: str) -> Counter:
         if c is None:
             c = _counters[name] = Counter(name)
         return c
+
+
+def counter_nowait(name: str) -> Counter | None:
+    """``counter(name)`` for a caller that must not wait on the registry's
+    lock (the collector's hook, which can run while its own thread holds
+    it): None where the counter is new and the lock is taken."""
+    c = _counters.get(name)
+    if c is not None or not _lock.acquire(blocking=False):
+        return c
+    try:
+        return _counters.setdefault(name, Counter(name))
+    finally:
+        _lock.release()
 
 
 def gauge(name: str) -> Gauge:

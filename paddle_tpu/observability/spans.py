@@ -36,11 +36,26 @@ grows to ``PADDLE_TRACE_MAX_EVENTS`` (100 000) and the process leaves
 than a span to build (request lifecycles, collective sequence numbers) ask
 it first.
 
+Overlays. A span made with ``overlay=True`` is nobody's child and nobody's
+parent: its parent is 0 and it is never pushed on the thread's stack. It
+gives a finer view of an interval without changing the self time (duration
+less child spans) of the span it lies in: ``serve.dispatch.prep`` /
+``.put`` / ``.launch`` tile the body of ``serve.dispatch_burst``, and
+``gc.collect`` marks a collection wherever it fell.
+
+The collector (``watch_gc()``, called with ``watch_compiles()`` when
+paddle_tpu is imported): every collection adds to the counters
+``gc.collections`` and ``gc.seconds``; one of generation 1 or 2 also
+becomes an overlay ``gc.collect`` (args ``gen``, ``collected``). Generation
+0 gets no span: it runs many times a step, and its spans would push a
+window's steps out of the ring.
+
 Usage:
     with spans.span("train.step", cat="step", step=i): ...
     @spans.traced("load_batch", cat="data")
     def load_batch(...): ...
     spans.add_span("req.queue", "request", t0, t1, rid=7)   # spans.now() s
+    spans.add_span("serve.dispatch.put", "serve", t0, t1, overlay=True)
 
 Which program compiled, when, and for how long (``watch_compiles()`` is
 called when paddle_tpu is imported):
@@ -53,6 +68,7 @@ from __future__ import annotations
 
 import atexit
 import functools
+import gc
 import itertools
 import json
 import os
@@ -76,7 +92,7 @@ __all__ = ["span", "traced", "annotate", "add_span", "now", "now_ns", "records",
            "tracing_enabled", "enable_tracing", "disable_tracing",
            "export_chrome_trace", "reset", "events", "events_since",
            "dropped", "capacity", "set_trace_metadata", "watch_compiles",
-           "COMPILE_SPANS"]
+           "COMPILE_SPANS", "watch_gc", "GC_SPAN"]
 
 ENV_DIR = "PADDLE_TRACE_DIR"
 ENV_MAX = "PADDLE_TRACE_MAX_EVENTS"
@@ -99,6 +115,9 @@ _ring: deque = deque(maxlen=DEFAULT_CAPACITY)   # Span fields, as tuples
 _ids = itertools.count(1)       # a span's id, taken when it begins
 _seq = itertools.count(1)       # its place in the ring, taken when it ends
 _appended = [0]                 # the newest seq handed out
+# the collector's rows, before their seq; a process that records no span
+# and reads none keeps the newest ring's worth, not one a collection
+_held: deque = deque(maxlen=DEFAULT_CAPACITY)
 _tls = threading.local()        # .stack: this thread's open (nested) spans
 _atexit_registered = [False]
 _extra_meta: dict = {}  # merged into export otherData (xplane links etc.)
@@ -113,32 +132,55 @@ def _stack() -> list:
 
 
 def _append(name, cat, t0_ns, t1_ns, sid, parent, args):
+    if _held:
+        _ring_held()
     seq = next(_seq)
     _ring.append((seq, name, cat, t0_ns, max(t0_ns, t1_ns),
                   threading.get_ident(), sid, parent, args))
     _appended[0] = seq      # GIL-atomic like the append; readers only
+    if _held:
+        _ring_held()
+
+
+def _ring_held():
+    """Give the collector's held rows their seq and place in the ring. A
+    collection can fall between an append's seq and its ring append (the
+    row's tuple is an allocation), so the hook never appends itself: its
+    row waits here for the next append (before that append's row, or after
+    it where it fell inside) or read, and the ring stays in seq order."""
+    while True:
+        try:
+            row = _held.popleft()
+        except IndexError:
+            return
+        seq = next(_seq)
+        _ring.append((seq,) + row)
+        _appended[0] = seq
 
 
 class _Span:
     """An open span. Context manager, decorator, or manual begin()/end()."""
 
-    __slots__ = ("name", "cat", "args", "id", "parent", "_t0", "_ann")
+    __slots__ = ("name", "cat", "args", "id", "parent", "_t0", "_ann",
+                 "_overlay")
 
-    def __init__(self, name, cat, args):
+    def __init__(self, name, cat, args, overlay=False):
         self.name = name
         self.cat = cat
         self.args = args
         self._t0 = None
+        self._overlay = overlay
 
     def begin(self, nest: bool = True):
         """`nest=False` is for a span that the frame which began it does
         not end (a generator's, a window over many steps): it has a parent
         and is never one, so an end that comes late, or on another thread,
-        leaves no stale parent on this thread's stack."""
+        leaves no stale parent on this thread's stack. An overlay has
+        neither."""
         stack = _stack()
         self.id = next(_ids)
-        self.parent = stack[-1].id if stack else 0
-        if nest:
+        self.parent = stack[-1].id if stack and not self._overlay else 0
+        if nest and not self._overlay:
             stack.append(self)
         self._ann = _Annotation(self.name, **self.args) if self.args \
             else _Annotation(self.name)
@@ -171,12 +213,13 @@ class _Span:
         return traced(self.name, self.cat, **(self.args or {}))(fn)
 
 
-def span(name: str, cat: str = "user", **args):
+def span(name: str, cat: str = "user", overlay: bool = False, **args):
     """Open a span named `name` under category `cat` (the chrome-trace
     category lane: step / checkpoint / collective / data / resilience /
-    serve / compile / profiler / user). Extra kwargs (small ints and strs)
-    become the span's arguments."""
-    return _Span(name, cat, args or None)
+    serve / compile / profiler / gc / user). Extra kwargs (small ints and
+    strs) become the span's arguments. `overlay=True`: parent 0, never a
+    parent (the module docstring)."""
+    return _Span(name, cat, args or None, overlay)
 
 
 def annotate(**args) -> None:
@@ -202,15 +245,17 @@ def traced(name: str, cat: str = "user", **args):
     return deco
 
 
-def add_span(name: str, cat: str, t0: float, t1: float, **args):
+def add_span(name: str, cat: str, t0: float, t1: float,
+             overlay: bool = False, **args):
     """Append a COMPLETED span with explicit endpoints in ``spans.now()``
     seconds. The request-lifecycle tracker (observability.slo) records
     phase timestamps as requests move through the scheduler and
     reconstructs the queue/prefill/decode spans at retire time, and the
     compile listener learns of a compilation when it has ended: a live
     ``span()`` cannot straddle either. The parent is the calling thread's
-    innermost open span; nothing is annotated after the fact."""
-    stack = _stack()
+    innermost open span, 0 for an overlay; nothing is annotated after the
+    fact."""
+    stack = None if overlay else _stack()
     _append(name, cat, int(float(t0) * 1e9), int(float(t1) * 1e9),
             next(_ids), stack[-1].id if stack else 0, args or None)
 
@@ -220,6 +265,7 @@ def add_span(name: str, cat: str, t0: float, t1: float, **args):
 def _tail(since: int = 0) -> list:
     """The ring's rows with seq > since, oldest first: walked from the
     newest end, so an incremental read costs its batch, not the ring."""
+    _ring_held()
     ring = _ring
     for _ in range(5):  # a concurrent append can invalidate the iterator
         try:
@@ -262,6 +308,7 @@ def events_since(start: int) -> tuple[list[dict], int]:
     O(batch) to build, and eviction-safe (a cursor older than the ring's
     oldest span returns the whole ring). A cursor past the newest span (a
     reset() happened) rewinds to 0."""
+    _ring_held()
     if start > _appended[0] or start < 0:
         start = 0
     rows = _tail(start)
@@ -270,6 +317,7 @@ def events_since(start: int) -> tuple[list[dict], int]:
 
 def dropped() -> int:
     """Spans that have fallen off the ring since the last reset()."""
+    _ring_held()
     return max(0, _appended[0] - len(_ring))
 
 
@@ -322,6 +370,7 @@ def reset():
     and the ring takes the size that state asks for."""
     global _seq
     _ring.clear()
+    _held.clear()
     _seq = itertools.count(1)
     _appended[0] = 0
     _extra_meta.clear()
@@ -412,6 +461,48 @@ def watch_compiles():
     # start and end as JAX took them (time.time(): the span clock)
     _monitoring.register_event_time_span_listener(_on_compile_span)
     _monitoring.register_event_listener(_on_event)
+
+
+# ------------------------------------------------------------- collector
+GC_SPAN = "gc.collect"
+_gc_t0 = [0]            # span-clock ns at the running collection's start
+_gc_watching = [False]
+
+
+def _on_gc(phase, info):
+    """A ``gc.callbacks`` hook. It runs inside a collection, between any two
+    bytecodes of whichever thread allocated, so it waits on no lock (a
+    counter not yet made while the registry's lock is held loses one
+    reading), starts nothing and never raises. Its span is held for the
+    next append or read to ring (``_ring_held``)."""
+    try:
+        if phase == "start":
+            _gc_t0[0] = now_ns()
+            return
+        t0, _gc_t0[0] = _gc_t0[0], 0
+        if not t0:      # the start came before the hook was there
+            return
+        t1 = now_ns()
+        for name, inc in (("gc.collections", 1),
+                          ("gc.seconds", (t1 - t0) * 1e-9)):
+            c = metrics.counter_nowait(name)
+            if c is not None:
+                c.inc(inc)
+        gen = info["generation"]
+        if gen:     # an overlay: parent 0
+            _held.append((GC_SPAN, "gc", t0, t1, threading.get_ident(),
+                          next(_ids), 0,
+                          {"gen": gen, "collected": info["collected"]}))
+    except Exception:   # noqa: BLE001 - a collection must never fail
+        pass
+
+
+def watch_gc():
+    """Register the collector's hook, once per process: one list append."""
+    if _gc_watching[0]:
+        return
+    _gc_watching[0] = True
+    gc.callbacks.append(_on_gc)
 
 
 # a run launched with PADDLE_TRACE_DIR set exports from the first import

@@ -51,3 +51,18 @@ def served(request, small_model, wide_model):
     if request.param == "kernel":
         return (*wide_model, "kernel")
     return (*small_model, "gather")
+
+
+@pytest.fixture()
+def no_automatic_collection():
+    """A collection of generation 1 or 2 is a span (`gc.collect`): a test
+    that counts the ring's spans exactly holds automatic collections off,
+    from a fresh ring on. `gc.collect()` still runs, hook and all."""
+    import gc
+    from paddle_tpu.observability import spans
+    was = gc.isenabled()
+    gc.disable()
+    spans.reset()
+    yield
+    if was:
+        gc.enable()

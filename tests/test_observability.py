@@ -46,6 +46,7 @@ def _fresh_telemetry(monkeypatch):
 
 # ---------------------------------------------------------------- spans
 
+@pytest.mark.usefixtures("no_automatic_collection")
 class TestSpans:
     def test_disabled_path_is_a_flagcheck_noop(self, tmp_path, monkeypatch):
         """With the export off a span costs a ring append and nothing else

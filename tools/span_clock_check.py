@@ -17,6 +17,13 @@ timeline by `profile_start_time` alone (no calibration, no offset search):
           cannot be held by any host clock; the tolerance is 2 ms, and the
           lead and tail are printed for the record.
 
+Beside them, `span_clock_fit`: the offset delta (device time = span time
++ delta) that the benchmark's reader `perfbench/reducers/
+idle_along_spans.py` fits to the serving cells, here from the probe's
+pairs: a device interval cannot start before its span began (delta <=
+upper) nor end after its span ended (delta >= lower); delta is the lower
+bound, as there.
+
 On a backend with no device plane (the CPU) only the first is asked. The
 last line of output is one JSON object.
 """
@@ -29,6 +36,8 @@ import sys
 import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from perfbench.reducers.idle_along_spans import fit_offset  # noqa: E402
 
 HOST_TOL_S = 1e-4
 DEVICE_TOL_S = 2e-3
@@ -106,6 +115,8 @@ def check(repeats: int = 5, size: int = 2048) -> dict:
         ok = ok and len(device) == repeats and all(
             d[0] >= s[0] - DEVICE_TOL_S and d[1] <= s[1] + DEVICE_TOL_S
             for s, d in pairs)
+        out["span_clock_fit"] = fit_offset(
+            [(s[0], s[1], d[0], d[1]) for s, d in pairs], min_pairs=1)
     return {**out, "ok": bool(ok)}
 
 
